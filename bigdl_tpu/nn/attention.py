@@ -116,26 +116,6 @@ def get_position_encoding(length: int, hidden_size: int,
     return signal
 
 
-def _flash_kernel_probe() -> None:
-    """AOT-compile the REAL flash kernel, fwd and bwd, at one canonical
-    geometry (T=1024 exercises the 1024/512 block logic; causal + lengths
-    masks both engage) — the thunk for ``kernel_compiles``. Lower+compile
-    on abstract shapes: no device buffers, nothing executed — Mosaic
-    compilability is the thing that can break (r5 tunnel)."""
-    import jax.numpy as jnp
-
-    from ..ops import flash_attention
-
-    sds = jax.ShapeDtypeStruct((1, 1, 1024, 64), jnp.bfloat16)
-
-    def f(q, k, v, lens):
-        return jnp.sum(flash_attention(q, k, v, True, lengths=lens,
-                                       mask_q=True).astype(jnp.float32))
-
-    jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
-        sds, sds, sds, jax.ShapeDtypeStruct((1,), jnp.int32)).compile()
-
-
 def scaled_dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -219,24 +199,12 @@ def scaled_dot_product_attention(
             "impl='ring' requires Engine.set_sequence_parallel(mesh, axis) "
             "to be registered first")
     if impl == "auto" and eligible:
-        # measured on v5e (BENCH_MODE=transformer, 1024/512 blocks): flash
-        # wins in-model from T=1024 (1.13x) through 8k (2.02x); dense also
-        # OOMs near T=16k. The probes guard against runtimes where the TPU
-        # is healthy but the Mosaic compile path is broken (seen round 5:
-        # remote_compile HTTP 500, and it can be KERNEL-specific — the
-        # trivial kernel compiled while maxpool's didn't) — auto degrades
-        # to dense there; explicit impl='flash' still surfaces the real
-        # error. The flash probe compiles fwd+bwd at one canonical
-        # geometry, not per shape — a shape-specific compiler failure
-        # would still surface (accepted: per-shape probing would double
-        # every new attention shape's compile time).
-        from ..ops.pallas_probe import kernel_compiles, pallas_available
-
-        impl = ("flash"
-                if min(q.shape[-2], k.shape[-2]) >= 1024
-                and pallas_available()
-                and kernel_compiles(("flash_attention",), _flash_kernel_probe)
-                else "dense")
+        # builder-measured on v5e in round 3 (BENCH_MODE=transformer,
+        # 1024/512 blocks): flash wins in-model from T=1024 (1.13x) through
+        # 8k (2.02x); dense also OOMs near T=16k. The gate is what the code
+        # can observe — the backend (in `eligible`) and the shapes; a Mosaic
+        # compile failure surfaces as the compiler's own error.
+        impl = "flash" if min(q.shape[-2], k.shape[-2]) >= 1024 else "dense"
     if impl == "flash" and eligible:
         from ..ops import flash_attention
 

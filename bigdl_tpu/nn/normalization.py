@@ -139,9 +139,9 @@ class LayerNormalization(AbstractModule):
         return {"weight": jnp.ones((h,)), "bias": jnp.zeros((h,))}, {}
 
     def _apply(self, params, state, x, training, rng):
-        from ..ops.fused_common import fused_kernels_active
+        from ..utils.engine import Engine
 
-        if fused_kernels_active():
+        if Engine.fused_kernels():
             # one HBM round-trip per pass (fwd + custom VJP) instead of the
             # mean/var/normalize/scale chain; Engine.set_fused_kernels gates
             # this at trace time — off, the path below is bit-identical to
@@ -190,9 +190,9 @@ class RMSNorm(AbstractModule):
         return {"weight": jnp.ones((h,))}, {}
 
     def _apply(self, params, state, x, training, rng):
-        from ..ops.fused_common import fused_kernels_active
+        from ..utils.engine import Engine
 
-        if fused_kernels_active():
+        if Engine.fused_kernels():
             from ..ops.fused_norm import fused_rms_norm
 
             return fused_rms_norm(x, params["weight"], self.eps), state

@@ -205,7 +205,7 @@ def lowered_cost_summary(lowered) -> Optional[Dict[str, Any]]:
     try:
         cost = lowered.cost_analysis()
     except Exception:
-        cost = None  # older jax / backend quirk: try the compiled path
+        cost = None  # no pre-compile cost model here: try the compiled path
     parsed = _parse_cost(cost)
     if parsed is not None:
         return parsed
@@ -222,11 +222,8 @@ def lowered_cost_summary(lowered) -> Optional[Dict[str, Any]]:
 
 
 def _parse_cost(cost) -> Optional[Dict[str, Any]]:
-    """Normalize an XLA cost-analysis result (dict, or [dict] on older jax)
-    into the summary schema shared by ``cost_summary`` and
-    ``lowered_cost_summary``."""
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else None
+    """Normalize an XLA cost-analysis dict into the summary schema shared
+    by ``cost_summary`` and ``lowered_cost_summary``."""
     if not cost:
         return None
     flops = float(cost.get("flops", 0.0)) or None

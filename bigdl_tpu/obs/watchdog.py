@@ -1,6 +1,6 @@
 """Stall watchdog: flag a run that stops completing steps.
 
-A silent hang — a wedged collective, a dead PJRT tunnel, a prefetch thread
+A silent hang — a wedged collective, a lost PJRT device, a prefetch thread
 blocked on a dying filesystem — looks exactly like a very slow step from the
 driver's point of view. :class:`StallWatchdog` keeps a rolling estimate of the
 step time and raises a WARNING (plus callback hooks) when no step completes

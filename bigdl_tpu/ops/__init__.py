@@ -9,13 +9,11 @@ beats the compiler (flash attention's O(T) memory online softmax).
 """
 
 from .flash_attention import flash_attention
-from .fused_common import fused_kernels_active
 from .fused_epilogue import fused_bias_act
 from .fused_norm import fused_layer_norm, fused_rms_norm
 
 __all__ = [
     "flash_attention",
-    "fused_kernels_active",
     "fused_bias_act",
     "fused_layer_norm",
     "fused_rms_norm",

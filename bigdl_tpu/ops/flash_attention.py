@@ -40,9 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from ..utils.compat import pallas_call, pallas_tpu_compiler_params
 from jax.experimental.pallas import tpu as pltpu
+
+from ..utils.compat import pallas_call
 
 NEG_BIG = -1e30
 
@@ -229,7 +229,7 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
             jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
             jax.ShapeDtypeStruct((n * h, 1, tqp), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -421,7 +421,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -453,7 +453,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             jax.ShapeDtypeStruct((n * h, tkp, d), k.dtype),
             jax.ShapeDtypeStruct((n * h, tkp, d), v.dtype),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

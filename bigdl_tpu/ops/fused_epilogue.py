@@ -30,8 +30,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import pallas_call, pallas_tpu_compiler_params
+from ..utils.compat import pallas_call
 from .fused_common import block_rows, pad_rows
 
 __all__ = ["fused_bias_act", "ACTIVATIONS", "act_reference"]
@@ -141,7 +142,8 @@ def _bias_rows(x, b, mode: str):
 def _fwd_call(x, b, act, axis):
     x2, h, mode = _as_rows(x, axis)
     b2 = _bias_rows(x, b, mode)
-    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize), live_factor=6)
+    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize),
+                    x.dtype.itemsize, live_factor=6)
     x2, rows = pad_rows(x2, br)
     if mode == "row":
         b2, _ = pad_rows(b2, br)
@@ -154,7 +156,7 @@ def _fwd_call(x, b, act, axis):
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)), b_spec],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(x2, b2)
@@ -179,7 +181,7 @@ def _vjp_bwd(act, axis, res, dy):
     x2, h, mode = _as_rows(x, axis)
     dy2 = dy.reshape(x2.shape)
     b2 = _bias_rows(x, b, mode)
-    br = block_rows(x2.shape[0], h * 4, live_factor=8)
+    br = block_rows(x2.shape[0], h * 4, x.dtype.itemsize, live_factor=8)
     x2, rows = pad_rows(x2, br)
     dy2, _ = pad_rows(dy2, br)
     if mode == "row":
@@ -204,7 +206,7 @@ def _vjp_bwd(act, axis, res, dy):
         ],
         out_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)), db_spec],
         out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype), db_shape],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
     )(x2, b2, dy2)

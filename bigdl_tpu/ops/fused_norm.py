@@ -18,10 +18,10 @@ single narrowing cast). Weight/bias grads accumulate in fp32 across row
 blocks via the sequential-grid revisited-output-block pattern.
 
 Wired into ``nn.LayerNormalization`` / ``nn.RMSNorm`` behind
-``Engine.set_fused_kernels(True)`` (see ``fused_common.fused_kernels_active``
-for the gate semantics, including the CPU interpret-mode fallback tier-1
-runs under). Parity vs the jnp references and program-size thresholds are
-locked by ``tests/test_fused_kernels.py`` / ``tests/test_kernel_parity.py``.
+``Engine.set_fused_kernels(True)`` (see ``fused_common`` for the gate
+semantics, including the CPU interpret mode tier-1 runs under). Parity vs
+the jnp references and program-size thresholds are locked by
+``tests/test_fused_kernels.py`` / ``tests/test_kernel_parity.py``.
 """
 
 from __future__ import annotations
@@ -31,8 +31,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import pallas_call, pallas_tpu_compiler_params
+from ..utils.compat import pallas_call
 from .fused_common import block_rows, pad_rows
 
 __all__ = ["fused_layer_norm", "fused_rms_norm"]
@@ -91,7 +92,8 @@ def _ln_rows(x):
 
 def _ln_fwd_call(x, w, b, eps):
     x2, h = _ln_rows(x)
-    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize))
+    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize),
+                    x.dtype.itemsize)
     x2, rows = pad_rows(x2, br)
     y = pallas_call(
         partial(_ln_fwd_kernel, eps=eps),
@@ -103,7 +105,7 @@ def _ln_fwd_call(x, w, b, eps):
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(x2, w.reshape(1, h), b.reshape(1, h))
@@ -127,7 +129,7 @@ def _ln_vjp_bwd(eps, res, dy):
     x, w = res
     x2, h = _ln_rows(x)
     dy2 = dy.reshape(-1, h)
-    br = block_rows(x2.shape[0], h * 4, live_factor=10)
+    br = block_rows(x2.shape[0], h * 4, x.dtype.itemsize, live_factor=10)
     x2, rows = pad_rows(x2, br)
     dy2, _ = pad_rows(dy2, br)  # zero cotangent rows: inert in every sum
     dx, dw, db = pallas_call(
@@ -148,7 +150,7 @@ def _ln_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # dw/db accumulate in order
         ),
     )(x2, w.reshape(1, h), dy2)
@@ -207,7 +209,8 @@ def _rms_bwd_kernel(x_ref, w_ref, dy_ref, dx_ref, dw_ref, *, eps: float):
 
 def _rms_fwd_call(x, w, eps):
     x2, h = _ln_rows(x)
-    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize))
+    br = block_rows(x2.shape[0], h * max(4, x.dtype.itemsize),
+                    x.dtype.itemsize)
     x2, rows = pad_rows(x2, br)
     y = pallas_call(
         partial(_rms_fwd_kernel, eps=eps),
@@ -218,7 +221,7 @@ def _rms_fwd_call(x, w, eps):
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(x2, w.reshape(1, h))
@@ -242,7 +245,7 @@ def _rms_vjp_bwd(eps, res, dy):
     x, w = res
     x2, h = _ln_rows(x)
     dy2 = dy.reshape(-1, h)
-    br = block_rows(x2.shape[0], h * 4, live_factor=10)
+    br = block_rows(x2.shape[0], h * 4, x.dtype.itemsize, live_factor=10)
     x2, rows = pad_rows(x2, br)
     dy2, _ = pad_rows(dy2, br)
     dx, dw = pallas_call(
@@ -261,7 +264,7 @@ def _rms_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
     )(x2, w.reshape(1, h), dy2)

@@ -185,7 +185,7 @@ class Optimizer:
         self._jit_step = None  # handle for compile-count introspection/tests
         from ..utils.engine import Engine
 
-        Engine.ensure_compilation_cache()  # BIGDL_COMPILE_CACHE_DIR, if set
+        Engine.ensure_compilation_cache()  # persistent XLA compile cache
         if validate:
             self._validate_at_construction()
         self.optim_method: OptimMethod = SGD()
@@ -575,19 +575,10 @@ class Optimizer:
         self._apply_reader_slice()
         # Suspend CYCLE collection for the duration of the fit (refcount
         # frees are untouched; collection resumes organically once the LAST
-        # concurrent fit returns — see _gc_guard_enter). Two reasons, both
-        # real: (1) CPython gc pauses on the driver thread add jitter in
-        # front of every dispatch; (2) jaxlib 0.4.36's CPU runtime
-        # mishandles buffer ownership around DONATED executables served
-        # from the persistent compilation cache — a collection that frees
-        # dead model/array cycles while such a step is in flight corrupts
-        # live training buffers (deterministically reproduced; fit-boundary
-        # collections are safe, mid-loop ones are not). Deliberately NO
-        # forced gc.collect() here: concentrating the deferred frees at one
-        # point turned the same jaxlib double-free into a hard abort inside
-        # the collector — letting collection trigger organically OUTSIDE
-        # fits keeps both the mid-fit corruption and the forced-detonation
-        # failure modes out.
+        # concurrent fit returns — see _gc_guard_enter): CPython gc pauses
+        # on the driver thread add jitter in front of every dispatch.
+        # Deliberately NO forced gc.collect() here — deferred frees are
+        # collected organically OUTSIDE fits.
         _gc_guard_enter()
         try:
             while True:
@@ -808,22 +799,12 @@ class Optimizer:
         ``jax.export``-serialized step module (when expressible), every
         persistent-compile-cache entry of this process, and the verified
         manifest (written LAST). A preempted run restored onto a fresh host
-        seeds its empty ``BIGDL_COMPILE_CACHE_DIR`` from the bundle
+        seeds its empty compile cache dir from the bundle
         (:meth:`warm_start`) and reaches step 1 with ZERO fresh compiles —
         the resume re-traces, but every XLA compile is a disk read.
 
         Call after (or during) a fit — the step must have dispatched at
-        least once so its geometry is known.
-
-        On the CPU backend the bundle ADDITIONALLY carries the compiled
-        donation-free twin of the step: jaxlib 0.4.36's CPU runtime can
-        corrupt live buffers when a DONATED executable is deserialized from
-        the persistent cache (probabilistic use-after-free — see
-        docs/performance.md), so :meth:`warm_start` runs the resumed fit
-        with ``donate=False`` there, and the twin's cache entry is what
-        keeps that resume at 0 fresh compiles. Numerics are donation-
-        invariant (locked since the donation PR); only CPU host memory pays
-        the shadow copy. TPU keeps donation on both sides."""
+        least once so its geometry is known."""
         info = self._step_export_info
         if info is None:
             raise RuntimeError(
@@ -831,52 +812,11 @@ class Optimizer:
                 "run optimize() (at least one step) first"
             )
         from ..utils import aot
-        from ..utils.compat import donation_safe
 
-        nodonate = False
-        if not donation_safe() and self.donate:
-            nodonate = self._precompile_nodonate_twin(info)
         return aot.export_step_bundle(
             path, fn=info[0], specs=info[1], path_type=type(self).__name__,
-            extra={"nodonate_entry": nodonate, "donate": self.donate},
+            extra={"donate": self.donate},
         )
-
-    def _precompile_nodonate_twin(self, info) -> bool:
-        """AOT-compile the donation-free twin of the captured step so its
-        persistent-cache entry rides the export harvest (no dispatch — the
-        lowered program is compiled against the captured specs only).
-        Best-effort: a path that cannot rebuild its step (or whose lowering
-        refuses) just exports without the twin, and a CPU warm start then
-        re-traces cold for the step — slower, never wrong."""
-        try:
-            twin = self._rebuild_step_nodonate(info[0])
-            if twin is None:
-                return False
-            twin.lower(*info[1]).compile()  # makers return jitted fns
-            return True
-        except Exception as e:  # jax.export-style coverage gap, not fatal
-            log.warning(
-                "donation-free step twin pre-compile failed (%s); a CPU "
-                "warm start will pay this one compile", e,
-            )
-            return False
-
-    def _rebuild_step_nodonate(self, fn):
-        """Rebuild the cached step with donation off — which cache the
-        captured fn came from decides the maker. None = unknown path."""
-        prev = self.donate
-        self.donate = False
-        try:
-            if (self._flat_step_cache is not None
-                    and self._flat_step_cache[3] is fn):
-                return self._make_flat_step(
-                    self._flat_step_cache[0], self._flat_step_cache[1]
-                )
-            if self._step_cache is not None and self._step_cache[3] is fn:
-                return self._make_standard_step(self._step_cache[0])
-            return None
-        finally:
-            self.donate = prev
 
     def warm_start(self, path: str) -> Dict:
         """Verify a step-artifact bundle and seed this process's compile
@@ -892,23 +832,7 @@ class Optimizer:
         # train step — accepting one would record warm_start=<path> while
         # every step compile runs cold, the silent fake the tri-state
         # freshness accounting exists to prevent
-        from ..utils.compat import donation_safe
-
         manifest = aot.warm_start(path, kind="train_step")
-        if not donation_safe() and self.donate:
-            # utils/compat.donation_safe: a DONATED executable deserialized
-            # from the persistent cache can corrupt live buffers on this
-            # backend (probabilistic use-after-free, docs/performance.md).
-            # The warm-started fit therefore runs donation-free here —
-            # numerics are donation-invariant, and the exporter pre-compiled
-            # this exact twin into the bundle so the resume still replays as
-            # cache reads. TPU keeps donation.
-            log.info(
-                "warm start on the CPU backend: running the resumed fit "
-                "with donate=False (jaxlib CPU deserialized-donation "
-                "hazard; see docs/performance.md)"
-            )
-            self.donate = False
         self._warm_start_bundle = path
         return manifest
 
@@ -1373,12 +1297,9 @@ class Optimizer:
                 return self._masked_loss_fn(params, ms, x, t, rng, nvalid)
             return self._loss_fn(params, ms, x, t, rng)
 
-        # donation fenced upstream through self.donate: warm_start() /
-        # export seams consult donation_safe() and force donate=False before
-        # this maker runs (the hazard is deserialized executables only), and
         # optimize()'s driver rebinds params/ms/slots to the step outputs
         # every iteration — no reference to a donated buffer survives
-        @partial(jax.jit, donate_argnums=donate)  # lint: disable=BDL020
+        @partial(jax.jit, donate_argnums=donate)
         def train_step(params, model_state, slots, x, t, nvalid, lr, step, rng):
             (loss, new_model_state), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
@@ -1398,9 +1319,7 @@ class Optimizer:
                     f"micro batch count {n_micro}")
             return a.reshape((n_micro, a.shape[0] // n_micro) + a.shape[1:])
 
-        # same fence as train_step above: self.donate is forced off at the
-        # donation_safe() seams, and the driver rebinds to step outputs
-        @partial(jax.jit, donate_argnums=donate)  # lint: disable=BDL020
+        @partial(jax.jit, donate_argnums=donate)
         def micro_step(params, model_state, slots, x, t, nvalid, lr, step, rng):
             xs = jax.tree_util.tree_map(_split, x)
             ts = jax.tree_util.tree_map(_split, t)
@@ -1508,17 +1427,8 @@ class Optimizer:
         # to the pre-policy build.
         sp, comp = self._precision_for(fp)
         use_err = comp is not None and comp.error_feedback
-        # the EF residual is donated alongside the master vector — except
-        # where utils/compat.donation_safe says the backend cannot (the
-        # jaxlib-0.4.36 CPU deserialized-donation hazard; the extra
-        # same-shape-as-master donated operand is a reliable trigger —
-        # reproduced: cache-hit EF fits segfault at the next cold-seam
-        # unflatten). One undonated params-sized f32 buffer is the CPU-only
-        # cost; TPU donates all four.
-        from ..utils.compat import donation_safe
-
-        err_donated = use_err and donation_safe()
-        donate = ((0, 1, 2, 3) if err_donated else (0, 1, 2)) if self.donate else ()
+        # the EF residual is donated alongside the master vector
+        donate = ((0, 1, 2, 3) if use_err else (0, 1, 2)) if self.donate else ()
 
         def loss_fn(params, ms, x, t, rng, nvalid):
             if use_mask:

@@ -101,7 +101,7 @@ class Predictor:
         # "compile" is then a thin-wrapper trace + a persistent-cache read.
         self._aot: Dict[tuple, Any] = {}
         self._cache_watch = None  # lazy CacheDirWatch (first compile observed)
-        Engine.ensure_compilation_cache()  # BIGDL_COMPILE_CACHE_DIR, if set
+        Engine.ensure_compilation_cache()  # persistent XLA compile cache
         mesh = Engine.mesh() if Engine.is_initialized() else None
         self._n_dev = int(mesh.devices.size) if mesh is not None else 1
         if batch_size is None:

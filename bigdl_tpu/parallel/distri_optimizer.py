@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..dataset.dataset import AbstractDataSet
@@ -41,7 +42,6 @@ from ..nn.criterion import AbstractCriterion
 from ..nn.module import AbstractModule
 from ..obs.trace import span as obs_span
 from ..optim.local_optimizer import Optimizer, _to_device_tree
-from ..utils.compat import shard_map
 from ..utils.engine import Engine
 from ..utils.random import RandomGenerator
 from .parameter import FlatParameter
@@ -198,14 +198,6 @@ class DistriOptimizer(Optimizer):
         # the pre-policy build (test-locked).
         sp, comp = self._precision_for(fp)
         use_err = comp is not None and comp.error_feedback
-        # keep the EF residual OUT of the donation set where the backend
-        # cannot donate safely (utils/compat.donation_safe — the
-        # jaxlib-0.4.36 deserialized-donation hazard; the extra
-        # same-geometry donated operand is a reliable trigger, see
-        # _make_flat_step / docs/performance.md); TPU donates all four
-        from ..utils.compat import donation_safe
-
-        err_donated = use_err and donation_safe()
 
         def per_device(flat_p, model_state, slot_shard, err, x, t, lr, it,
                        rng):
@@ -314,7 +306,7 @@ class DistriOptimizer(Optimizer):
         out_specs = out_specs + (P(),)
         if hm is not None:
             out_specs = out_specs + (P(),)  # replicated health pytree
-        donate = (0, 1, 2, 3) if err_donated else (0, 1, 2)
+        donate = (0, 1, 2, 3) if use_err else (0, 1, 2)  # EF residual too
         return jax.jit(
             shard_map(
                 per_device,
@@ -339,11 +331,8 @@ class DistriOptimizer(Optimizer):
         wd_coeff = self._wd_coefficients(method, fp)
         from ..optim.quantization import MASTER_SCALE_KEY
 
-        from ..utils.compat import donation_safe
-
         sp, comp = self._precision_for(fp)
         use_err = comp is not None and comp.error_feedback
-        err_donated = use_err and donation_safe()  # see _make_sharded_step
 
         def per_device(flat_p, model_state, slots, err, x, t, lr, it, rng):
             rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
@@ -414,7 +403,7 @@ class DistriOptimizer(Optimizer):
         out_specs = out_specs + (P(),)
         if hm is not None:
             out_specs = out_specs + (P(),)
-        donate = (0, 1, 2, 3) if err_donated else (0, 1, 2)
+        donate = (0, 1, 2, 3) if use_err else (0, 1, 2)  # EF residual too
         return jax.jit(
             shard_map(
                 per_device,
@@ -457,11 +446,9 @@ class DistriOptimizer(Optimizer):
         out_specs = (P(), P(), P(), P())
         if hm is not None:
             out_specs = out_specs + (P(),)
-        # donation fenced upstream through self.donate (_build_for_resume
-        # forces donate=False on the AOT-resume path where the
-        # deserialized-donation hazard lives), and optimize()'s driver
-        # rebinds params/ms/slots to the step outputs every iteration
-        return jax.jit(  # lint: disable=BDL020
+        # optimize()'s driver rebinds params/ms/slots to the step outputs
+        # every iteration — no reference to a donated buffer survives
+        return jax.jit(
             shard_map(
                 per_device,
                 mesh=mesh,
@@ -499,30 +486,6 @@ class DistriOptimizer(Optimizer):
             return jax.tree_util.tree_map(put, tree)
 
         return place
-
-    def _rebuild_step_nodonate(self, fn):
-        """Distri twin of the export-time donation-free rebuild (see
-        LocalOptimizer._precompile_nodonate_twin): the cached SPMD step is
-        rebuilt from its own cache tuple's (method, sync, codec)."""
-        cached = None
-        for entry in self._distri_step_cache.values():
-            if entry[3] is fn:
-                cached = entry
-                break
-        if cached is None:
-            return None
-        method, sync, fp, _, _, mesh = cached
-        n_dev = mesh.devices.size
-        prev = self.donate
-        self.donate = False
-        try:
-            if sync == "sharded":
-                return self._make_sharded_step(fp, mesh, method, n_dev)
-            if fp is not None:
-                return self._make_replicated_flat_step(fp, mesh, method, n_dev)
-            return self._make_replicated_step(mesh, method, n_dev)
-        finally:
-            self.donate = prev
 
     def _build_for_resume(self) -> None:
         # the traced apply sees a PER-DEVICE shard (contrast the local/pjit
@@ -728,6 +691,15 @@ class DistriOptimizer(Optimizer):
         # compiles the whole SPMD program TWICE — the time-to-first-step tax
         # this PR exists to kill.
         repl = NamedSharding(mesh, P())
+
+        def out_sharding(spec):
+            # jax hands a fully-replicated output back spelled P() — which is
+            # what P(axis) IS on a one-device mesh. Committing the spelling
+            # the step returns keeps call 2 on call 1's executable there too
+            # (chip run, PR 21: the one-chip ZeRO-1 step compiled twice).
+            sh = NamedSharding(mesh, spec)
+            return repl if sh.is_fully_replicated else sh
+
         with obs_span("commit_shardings"):
             carried = jax.device_put(carried, repl)
             model_state = _tm(lambda a: jax.device_put(jnp.asarray(a), repl),
@@ -735,7 +707,7 @@ class DistriOptimizer(Optimizer):
             slots = _tm(
                 lambda a: jax.device_put(
                     jnp.asarray(a),
-                    NamedSharding(mesh, slots_spec)
+                    out_sharding(slots_spec)
                     if getattr(jnp.asarray(a), "ndim", 0) >= 1
                     else repl,  # scalar slot state (custom methods) replicates
                 ),
@@ -747,7 +719,7 @@ class DistriOptimizer(Optimizer):
                 # axis and donated alongside the master vector
                 box_err = jax.device_put(
                     jnp.asarray(comp.init_residual(n_dev)),
-                    NamedSharding(mesh, P(axis)),
+                    out_sharding(P(axis)),
                 )
 
         # the restore contract is tree-shaped: snapshot the entry TREE (still

@@ -40,10 +40,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-
-from ..utils.compat import shard_map
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 

@@ -7,7 +7,8 @@ hosts N named models, each as a single compiled XLA executable per shape
 bucket (``Predictor`` shape buckets, ≤1 compile per bucket) fed by a
 continuous batcher with latency-SLO flush triggers. Registration warms every
 bucket shape once through the persistent compile cache
-(``BIGDL_COMPILE_CACHE_DIR``) so the first real request never pays a compile.
+(``Engine.ensure_compilation_cache``) so the first real request never pays a
+compile.
 
 Hot-swap: ``update(name, new_model)`` builds + warms the replacement OFF the
 serving path (the old version keeps serving through the compile), then swaps
@@ -586,7 +587,7 @@ class ModelServer:
     def _warmup(self, e: _Entry, predictor: Predictor,
                 version: Optional[int] = None) -> float:
         """Drive every bucket shape once so each executable compiles NOW —
-        served from the persistent ``BIGDL_COMPILE_CACHE_DIR`` cache when a
+        served from the persistent compile cache when a
         previous process (or a mounted artifact bundle) warmed it — instead
         of on the first user request. Emits one ``warmup`` telemetry record:
         wall seconds, traced-compile count, and — the cold-start headline —

@@ -16,7 +16,7 @@ Bundle layout (a directory)::
                             (one per (model, version, bucket) for serving;
                             the cached train step for trainers)
       cache/<entries>       persistent-compile-cache entries harvested from
-                            the exporting process's BIGDL_COMPILE_CACHE_DIR
+                            the exporting process's persistent compile cache
       manifest.json         written LAST, checkpoint-style: its presence
                             marks the bundle complete. Input specs, bucket
                             geometry, jax/jaxlib versions, platform,
@@ -319,12 +319,7 @@ def seed_from_bundle(path: str, manifest: Optional[Dict[str, Any]] = None) -> in
     src = os.path.join(path, "cache")
     if not os.path.isdir(src):
         return 0
-    if Engine.ensure_compilation_cache() is None:
-        raise ArtifactIncompatible(
-            path,
-            "no persistent compile cache configured on this host — set "
-            "BIGDL_COMPILE_CACHE_DIR before warm-starting",
-        )
+    Engine.ensure_compilation_cache()
     try:
         return seed_compile_cache(src)
     except OSError as e:  # disk full / permissions mid-copy: typed, degradable

@@ -1,53 +1,30 @@
-"""Version shims for the narrow band of jax APIs whose spelling moved.
+"""The framework's seams onto jax: the Pallas launch helper, the float8
+capability probe, the per-chip peaks table and the persistent compile cache.
 
-``shard_map`` went through three spellings: ``jax.experimental.shard_map``
-(with ``check_rep=``), then top-level ``jax.shard_map`` (with the kwarg
-renamed to ``check_vma=``). The framework is written against the newest
-spelling; this shim keeps it running on the older runtimes the test image
-ships (the replica-consistency check flag maps 1:1)."""
+Written for the one installation ``pyproject.toml`` pins (jax/jaxlib 0.9.0):
+there is no branch here for a jax that is not installed."""
 
 from __future__ import annotations
+
+import os
 
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    # default True matches jax's own default (replication checking ON); call
-    # sites that need it off for 0.4.x trace compatibility pass False
-    # explicitly
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new name) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 def pallas_interpret_default() -> bool:
-    """Whether Pallas kernels should run in interpret mode on this backend:
-    off-TPU there is no Mosaic compiler, so the kernels execute as their
+    """Whether Pallas kernels run in interpret mode for the current trace:
+    off the TPU there is no Mosaic compiler, so the kernels execute as their
     jnp-level interpretation — slower, but numerically the same program.
     This is what lets tier-1 exercise every kernel under JAX_PLATFORMS=cpu.
 
-    ``BIGDL_PALLAS_INTERPRET=0|1`` overrides the backend heuristic — the
-    resolution is TRACE-time, so a CPU-hosted cross-lowering for the TPU
-    platform (the program-size threshold tests) must force ``0`` to get the
-    real Mosaic custom-call into the lowered module."""
-    import os
-
+    ``BIGDL_PALLAS_INTERPRET=0|1`` overrides the backend rule off the TPU —
+    the resolution is TRACE-time, so a CPU-hosted cross-lowering for the TPU
+    platform (the program-size threshold tests) forces ``0`` to get the real
+    Mosaic custom call into the lowered module. On the ``tpu`` backend the
+    kernels always compile: asking for interpret mode there is an error
+    (see :func:`pallas_call`), not a slower correct answer."""
     forced = os.environ.get("BIGDL_PALLAS_INTERPRET")
-    if forced is not None and forced != "":
+    if forced:
         return forced.lower() in ("1", "true", "yes", "on")
     return jax.default_backend() != "tpu"
 
@@ -56,14 +33,20 @@ def pallas_call(kernel, *, interpret=None, **kwargs):
     """The ONE sanctioned ``pl.pallas_call`` entry point (lint rule BDL009).
 
     ``interpret=None`` resolves via :func:`pallas_interpret_default`, so every
-    kernel in the framework automatically degrades to interpret mode off-TPU
-    instead of dying in the Mosaic compiler. Callers that manage the decision
-    themselves (the runtime probe, A/B tools) pass an explicit bool, which is
-    forwarded untouched."""
+    kernel runs interpreted off the TPU and compiled by Mosaic on it. An
+    explicit bool is forwarded — except that interpret mode on the ``tpu``
+    backend raises: a kernel that silently ran as its jnp expansion there
+    would pass every numeric check while proving nothing about Mosaic."""
     from jax.experimental import pallas as pl
 
     if interpret is None:
         interpret = pallas_interpret_default()
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode requested on the tpu backend "
+            "(interpret=True or BIGDL_PALLAS_INTERPRET=1): kernels compile "
+            "through Mosaic here; interpret mode is for CPU hosts only"
+        )
     return pl.pallas_call(kernel, interpret=interpret, **kwargs)  # lint: disable=BDL009 the helper IS the sanctioned entry
 
 
@@ -178,9 +161,7 @@ def resolve_precision_dtype(name, knob: str = "comms_dtype"):
 class DevicePeaks:
     """Public-spec peaks of one chip kind: bf16 matmul ``flops`` (flops/s),
     ``hbm_bytes_s`` (HBM bandwidth, bytes/s) and ``ici_bytes_s`` (interchip
-    interconnect, bytes/s per chip). Any field may be None (unknown); every
-    consumer (``obs/perf.py`` MFU accounting, ``bench.py``'s headline) is
-    None-graceful by contract."""
+    interconnect, bytes/s per chip)."""
 
     __slots__ = ("kind", "flops", "hbm_bytes_s", "ici_bytes_s")
 
@@ -196,10 +177,12 @@ class DevicePeaks:
 
 
 # bf16 peak matmul TFLOP/s, HBM GB/s and per-chip ICI GB/s by device_kind
-# substring (public TPU specs). THE one table behind every MFU figure in the
-# repo: bench.py's headline and the live obs/perf.py step records both
-# resolve through device_peaks(), so the two can never disagree on the
-# denominator. device_kind spells v5e as "TPU v5 lite".
+# substring. THE one table behind every MFU figure in the repo: bench.py's
+# headline and the live obs/perf.py step records both resolve through
+# device_peaks(), so the two can never disagree on the denominator.
+# Source: Google Cloud TPU documentation, one page per generation ("TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s = 200 GB/s ICI; likewise "TPU
+# v2" ... "TPU v6e"). device_kind spells v5e as "TPU v5 lite".
 _DEVICE_PEAKS = {
     "v2":      (45.0,  700.0,  62.5),
     "v3":      (123.0, 900.0,  81.0),
@@ -214,18 +197,19 @@ _DEVICE_PEAKS = {
 
 def device_peaks(device_kind=None):
     """Resolve a device kind (default: the first local device of the active
-    backend) to its :class:`DevicePeaks`, or None for kinds without a table
-    entry — CPU backends land here, which is exactly the documented graceful
-    fallback (``mfu=None``, roofline unclassified)."""
+    backend) to its :class:`DevicePeaks`.
+
+    The CPU backend has no peaks and yields ``None`` (tier-1 and
+    ``obs/perf.py`` read that as "no MFU, roofline unclassified"). An
+    accelerator whose kind is not in the table raises and names the kind: a
+    utilization figure divided by a guessed or missing peak is worse than
+    none, so an unknown chip is added to ``_DEVICE_PEAKS`` with its source,
+    never defaulted."""
     if device_kind is None:
-        try:
-            devs = jax.local_devices()
-        except Exception:  # backend init failed: no peaks, never a crash
-            return None
-        if not devs or devs[0].platform == "cpu":
-            return None
-        device_kind = getattr(devs[0], "device_kind", "")
+        device_kind = jax.local_devices()[0].device_kind
     kind = str(device_kind).lower()
+    if kind == "cpu":  # how the CPU backend spells its device_kind
+        return None
     # longest key first so "v5e"/"v5p"/"v5 lite" beat the bare "v5" prefix
     for key in sorted(_DEVICE_PEAKS, key=len, reverse=True):
         if key in kind:
@@ -236,103 +220,80 @@ def device_peaks(device_kind=None):
                 hbm_bytes_s=hbm_gbs * 1e9,
                 ici_bytes_s=ici_gbs * 1e9,
             )
-    return None
+    raise ValueError(
+        f"no peak FLOP/s / bandwidth entry for device kind {device_kind!r}; "
+        "add it to utils/compat._DEVICE_PEAKS with its published source"
+    )
 
 
-def donation_safe() -> bool:
-    """Whether buffer donation is safe at the COMPATIBILITY seams on this
-    backend — the one predicate behind the thrice-repeated jaxlib-0.4.36
-    CPU fix (docs/performance.md "deserialized-donation hazard").
-
-    False on the CPU backend: jaxlib 0.4.36's CPU runtime can corrupt live
-    buffers when a DONATED executable is deserialized from the persistent
-    compilation cache and the caller later re-reads a buffer the program
-    aliased (probabilistic use-after-free; reproduced on warm caches as
-    tier-1 segfaults — PR 11, PR 14, and the EF-residual trigger of PR 12).
-    Numerics are donation-invariant everywhere this predicate gates, so the
-    only CPU cost is a shadow copy in host memory. TPU always donates.
-
-    Guarded seams: the optimizer flat steps' error-feedback residual
-    (local + both distri variants), the export/warm-start twin rebuild in
-    ``local_optimizer.py``, and ``TFSession.train``'s donated fit. Audit
-    note (this PR): the remaining donated fits — the standard/flat step
-    buffers and the distri SPMD carried state — rebind every driver-side
-    reference to the step OUTPUTS before the next dispatch, so no caller
-    ever re-reads a donated buffer there; they stay donated on every
-    backend. Any NEW donated seam whose buffers the caller re-reads after
-    dispatch must route through this predicate."""
-    return jax.default_backend() != "cpu"
+# the one place an unplaced cache goes: <checkout>/.jax_cache (gitignored).
+# A FIXED path on purpose — a cache whose directory is minted per run never
+# hits, so nothing here is built from TMPDIR, a uid, a pid or a clock
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_compilation_cache(cache_dir: str) -> None:
-    """Point XLA's persistent compilation cache at ``cache_dir``.
+def resolve_compilation_cache_dir() -> str:
+    """Where this process's persistent compile cache lives — one rule:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax's own
+    variable, the only name there is), else the fixed in-checkout
+    :data:`DEFAULT_COMPILE_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_COMPILE_CACHE_DIR
 
-    A restarted process (or the bench driver's probe window) then deserializes
-    the previous run's XLA binaries instead of recompiling — time-to-first-step
-    drops from the full compile to a disk read. The threshold knobs are forced
-    to "cache everything" (they default to skipping fast/small compiles, which
-    on CPU-sized test graphs would cache nothing); knob spellings that this
-    jax doesn't have are skipped — the cache still works with its defaults.
 
-    Two extra contracts the AOT artifact story (utils/aot.py) depends on:
+def enable_persistent_compilation_cache(cache_dir=None) -> str:
+    """Turn on jax's persistent compilation cache; returns the active dir.
 
-    * **Relocatable cache keys.** jax's default points the XLA autotune cache
-      INSIDE the compile cache dir and fails to strip that path from the
-      cache key — so two hosts mounting the same entries under different
-      paths would never hit. ``jax_persistent_cache_enable_xla_caches`` is
-      forced empty (a GPU-only feature anyway), making the key a pure
-      function of (program, versions, flags): entries harvested into an
-      artifact bundle can seed ANY replica's cache dir.
+    ``cache_dir=None`` applies :func:`resolve_compilation_cache_dir`. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax has already read it into
+    ``jax_compilation_cache_dir`` at import: the directory is left exactly
+    as placed from outside and only the thresholds below are set. An
+    explicit ``cache_dir`` is the mid-process switch the AOT-artifact tests
+    use to simulate a fresh boot (``Engine.set_compilation_cache_dir``).
+
+    Three contracts ride on top of pointing the directory:
+
+    * **Persist everything.** jax's default thresholds skip fast/small
+      compiles, which on CPU-sized test graphs would cache nothing — and a
+      cold compile that persisted nothing would read as a hit.
+    * **Relocatable cache keys.** jax 0.9.0 still points the XLA autotune
+      cache INSIDE the compile cache dir (``compiler.get_compile_options``)
+      and does not strip that path from the cache key, so entries copied to
+      a differently-spelled dir would never hit.
+      ``jax_persistent_cache_enable_xla_caches`` is forced empty (a GPU-only
+      feature anyway), making the key a pure function of (program, versions,
+      flags): entries harvested into an artifact bundle seed ANY dir.
     * **Unlatching.** jax latches "cache unused" at the first compile of the
-      process; configuring the dir after any jnp op has compiled would
-      otherwise silently disable persistence for the process's whole life.
-      :func:`reset_compilation_cache` after (re)configuring unlatches it —
-      this is also what lets one process switch cache dirs (the simulated
-      fresh-boot seam the artifact tests drive).
+      process; configuring after any jnp op has compiled would otherwise
+      silently disable persistence for the process's whole life.
+      :func:`reset_compilation_cache` unlatches it — this is also what lets
+      one process switch cache dirs.
     """
-    import os
-
-    global _cache_thresholds_forced
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    _cache_thresholds_forced = True
-    for knob, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:
-            # this jax spells the knob differently: its default thresholds
-            # may skip persisting fast compiles, so hit detection below
-            # degrades to "unknown" rather than guessing
-            _cache_thresholds_forced = False
+    cache_dir = cache_dir or resolve_compilation_cache_dir()
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     try:
-        # relocatable keys (see docstring); missing knob = an older jax that
-        # never embedded the path in the first place
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "")
-    except AttributeError:
-        pass
+        os.makedirs(cache_dir, exist_ok=True)
+    except OSError as e:
+        raise RuntimeError(
+            f"cannot create the compile cache dir {cache_dir!r}; set "
+            "JAX_COMPILATION_CACHE_DIR to a writable directory"
+        ) from e
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "")
     reset_compilation_cache()
+    return cache_dir
 
 
 def reset_compilation_cache() -> None:
     """Drop jax's in-memory persistent-cache state so the configured dir is
-    (re-)read on the next compile. Private-API seam, best-effort: a jax that
-    renames it just keeps its already-initialized cache, which is only wrong
-    for mid-process dir switches (the artifact tests' fresh-boot simulation),
-    never for the plain boot path."""
-    try:
-        from jax._src.compilation_cache import reset_cache
+    (re-)read on the next compile (see "Unlatching" above)."""
+    from jax._src.compilation_cache import reset_cache
 
-        reset_cache()
-    except Exception:  # lint: disable=BDL007 best-effort private-API shim — a jax that renamed it keeps its already-initialized cache, never a fault to retry
-        pass
-
-
-# True once enable_persistent_compilation_cache forced the "persist
-# everything" thresholds; False if a knob spelling was missing (see above)
-_cache_thresholds_forced = False
+    reset_cache()
 
 
 def compilation_cache_entries():
@@ -340,14 +301,11 @@ def compilation_cache_entries():
     when no persistent cache is configured. Snapshot before compiling, then
     diff with :func:`compilation_cache_hit` to tell a cache hit from a cold
     compile — the bench artifact's ``compile_cache_hit`` field."""
-    import os
-
-    d = getattr(jax.config, "jax_compilation_cache_dir", None)
+    d = jax.config.jax_compilation_cache_dir
     if not d or not os.path.isdir(d):
         return None
-    # jax 0.4.37's LRUCache writes '<key>-cache' + '<key>-atime' pairs; older
-    # backends write bare keys. Excluding the access-time markers covers both
-    # layouts without tying the hit detection to one cache implementation.
+    # jax's LRUCache writes '<key>-cache' + '<key>-atime' pairs; the
+    # access-time markers are rewritten on every read, so only entries count
     return {f for f in os.listdir(d) if not f.endswith("-atime")}
 
 
@@ -355,13 +313,9 @@ def compilation_cache_hit(before, after):
     """True when a compile between the two snapshots wrote no new cache entry
     into a previously non-empty cache — i.e. the executable was served from
     disk rather than rebuilt. False with no cache configured (every compile
-    is cold). ``None`` (unknown) when the persist-everything thresholds could
-    not be forced on this jax: a fast compile might then be skipped by the
-    default thresholds, which would masquerade as a hit."""
+    is cold)."""
     if before is None or after is None:
         return False
-    if not _cache_thresholds_forced:
-        return None
     return bool(before) and not (after - before)
 
 
@@ -392,23 +346,15 @@ class CacheDirWatch:
     def observe(self):
         """``True`` = the compile(s) since last call hit the persistent cache
         (no fresh entries written), ``False`` = at least one fresh entry was
-        persisted (a cold compile), ``None`` = unknowable (no cache dir, or
-        the persist-everything thresholds could not be forced)."""
+        persisted (a cold compile), ``None`` = no cache dir configured."""
         new = self.delta()
-        if new is None or not _cache_thresholds_forced:
-            return None
-        return not new
+        return None if new is None else not new
 
     def fresh_count(self):
-        """Number of fresh entries since the last call, or ``None`` when
-        freshness is unknowable — no cache dir configured, or this jax's
-        default thresholds may skip persisting fast compiles (a cold compile
-        that persisted nothing would otherwise masquerade as 0-fresh, the
-        exact claim the artifact warm-boot telemetry must never fake)."""
+        """Number of fresh entries since the last call, or ``None`` when no
+        cache dir is configured."""
         new = self.delta()
-        if new is None or not _cache_thresholds_forced:
-            return None
-        return len(new)
+        return None if new is None else len(new)
 
 
 def _copy_cache_entries(src: str, dest: str, skip_existing: bool) -> int:
@@ -416,7 +362,6 @@ def _copy_cache_entries(src: str, dest: str, skip_existing: bool) -> int:
     LRU's access-time markers (the receiving LRU recreates them); the ONE
     walk shared by harvest (cache → bundle) and seed (bundle → cache), so
     the entry-name conventions cannot drift between the two directions."""
-    import os
     import shutil
 
     os.makedirs(dest, exist_ok=True)
@@ -436,9 +381,7 @@ def harvest_compile_cache(dest_dir: str) -> int:
     """Copy every entry of the ACTIVE persistent compile cache into
     ``dest_dir``; returns the number of entries copied. 0 when no cache is
     configured. The artifact bundle's ``cache/`` payload."""
-    import os
-
-    src = getattr(jax.config, "jax_compilation_cache_dir", None)
+    src = jax.config.jax_compilation_cache_dir
     if not src or not os.path.isdir(src):
         return 0
     return _copy_cache_entries(src, dest_dir, skip_existing=False)
@@ -449,15 +392,15 @@ def seed_compile_cache(src_dir: str) -> int:
     cache dir (entries already present are left untouched — a shared store
     seeding many replicas must not rewrite concurrently-read files); returns
     the number of entries copied. Raises ``RuntimeError`` when no cache dir
-    is configured — a replica without ``BIGDL_COMPILE_CACHE_DIR`` has nowhere
-    to put the executables, so the warm boot CANNOT work and silently
+    is configured yet (``Engine.ensure_compilation_cache`` has not run): with
+    nowhere to put the executables the warm boot CANNOT work, and silently
     pretending it did would masquerade as the trace-everything cold path."""
-    dest = getattr(jax.config, "jax_compilation_cache_dir", None)
+    dest = jax.config.jax_compilation_cache_dir
     if not dest:
         raise RuntimeError(
             "seed_compile_cache: no persistent compile cache configured — "
-            "set BIGDL_COMPILE_CACHE_DIR (or Engine.set_compilation_cache_dir)"
-            " before warm-starting from an artifact bundle"
+            "call Engine.ensure_compilation_cache() before warm-starting "
+            "from an artifact bundle"
         )
     return _copy_cache_entries(src_dir, dest, skip_existing=True)
 
@@ -469,7 +412,6 @@ def prune_compile_cache(cache_dir: str, max_bytes=None, max_age_days=None):
     until the remaining total is under ``max_bytes``. Returns the pruned
     entry names. Long-lived hosts and shared artifact stores otherwise grow
     without bound — one entry per distinct executable, forever."""
-    import os
     import time as _time
 
     if not os.path.isdir(cache_dir):

@@ -94,9 +94,7 @@ def bias_act(y, b, act=None):
         return y if act is None else fn(y)
     if act is None:
         return bias_add(y, b)
-    from ..ops.fused_common import fused_kernels_active
-
-    if fused_kernels_active():
+    if Engine.fused_kernels():
         from ..ops.fused_epilogue import fused_bias_act
 
         return fused_bias_act(y, b, act, -1)
@@ -113,9 +111,7 @@ def channel_bias_act(y, b, act=None):
     fallback_b = b.reshape((1, -1) + (1,) * (y.ndim - 2))
     if act is None:
         return bias_add(y, fallback_b)
-    from ..ops.fused_common import fused_kernels_active
-
-    if fused_kernels_active():
+    if Engine.fused_kernels():
         from ..ops.fused_epilogue import fused_bias_act
 
         return fused_bias_act(y, b, act, 1)

@@ -166,18 +166,8 @@ class TFSession:
         criterion, endWhen)``). Returns the trained ``nn.Graph``; the
         session keeps using the updated weights."""
         from ..optim import SGD, LocalOptimizer, Trigger
-        from .compat import donation_safe
 
-        # donation gated by utils/compat.donation_safe: the jaxlib-0.4.36
-        # CPU use-after-free (see docs/performance.md and utils/aot.py —
-        # a DONATED step served from the persistent compile cache can
-        # corrupt live buffers) hits exactly this seam, because the session
-        # keeps reading the trained graph's buffers afterwards (run() /
-        # variables()). This is a compatibility fine-tune surface, not the
-        # hot path — numerics are donation-invariant (PR 2-locked), so the
-        # only cost is the shadow params/slots footprint for the fit.
-        opt = LocalOptimizer(self.graph, dataset, criterion,
-                             donate=donation_safe())
+        opt = LocalOptimizer(self.graph, dataset, criterion)
         opt.set_optim_method(optim_method or SGD(learningrate=1e-2))
         opt.set_end_when(end_when or Trigger.max_epoch(1))
         return opt.optimize()

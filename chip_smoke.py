@@ -1,0 +1,539 @@
+"""chip_smoke.py — the standing proof that the program starts on the chip.
+
+One process drives the system's main path once through the entry points a
+user calls, at the full width of the model the north star names (ResNet-50,
+1000 classes, 3x224x224, batch 128 per chip, bf16 operands and activations):
+
+  A  device    the platform is ``tpu`` and the peaks table knows the chip
+  B  train     LocalOptimizer.optimize() on one chip, telemetry attached
+  C  distri    DistriOptimizer(parameter_sync="sharded") — the ZeRO-1
+               shard_map step — over every local chip
+  D  kernels   every Pallas kernel behind a public switch, compiled by Mosaic
+               and checked against its reference; flash attention once more
+               inside a real nn.Transformer LocalOptimizer step
+  E  serving   ModelServer over phase B's model: 32 concurrent single-record
+               requests, bit-equal to the serial Predictor, zero compiles
+               after warm-up
+
+Nothing is caught and carried past: any failed phase raises and the exit code
+is non-zero. On success the last stdout line is one JSON object
+``{"ok": true, "device": {...}}``. There is no platform handling here on
+purpose — under ``JAX_PLATFORMS=cpu`` phase A fails, which is the point.
+
+    python chip_smoke.py                      # what the driver runs
+    python chip_smoke.py --expect-cache-hit   # second run on one cache dir:
+                                              # phase B must compile nothing
+                                              # fresh; prints both walls
+
+The phase functions take their sizes as arguments so tests/test_chip_smoke.py
+can rehearse the control flow at tiny size on the CPU; ``main()`` always runs
+the full width and always demands the chip.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.metadata
+import json
+import os
+import threading
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+# the previous run's phase-B compile record, so the second of two runs on one
+# cache dir can print both compile walls (chiprun_out/ is what a chip call
+# brings back; gitignored)
+LAST_RUN = os.path.join(REPO, "chiprun_out", "chip_smoke_last.json")
+MOSAIC_CALL = "tpu_custom_call"
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def assert_mosaic(lowered_text: str, what: str) -> int:
+    """The lowered program must carry the Mosaic custom call — an interpreted
+    expansion of the kernel would pass every numeric check. Only the TPU
+    lowers to it; the CPU rehearsal runs the interpreter by design."""
+    n = lowered_text.count(MOSAIC_CALL)
+    if on_tpu() and n == 0:
+        raise AssertionError(f"{what}: no {MOSAIC_CALL} in the lowered program")
+    return n
+
+
+# ------------------------------------------------------------------ A: device
+def phase_device() -> dict:
+    import jax
+    import jaxlib
+
+    devs = jax.devices()
+    d = devs[0]
+    log(f"platform: {d.platform}")
+    log(f"device_kind: {d.device_kind}")
+    log(f"device_count: {len(devs)}")
+    log(f"versions: jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {importlib.metadata.version('libtpu')}")
+    if d.platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: no TPU — jax found platform {d.platform!r} "
+            f"({d.device_kind}); this check only passes on the chip")
+    from bigdl_tpu.utils.compat import device_peaks
+
+    peaks = device_peaks()  # raises for a chip the table does not know
+    log(f"phase A device: ok ({peaks!r})")
+    return {"platform": d.platform, "kind": d.device_kind, "count": len(devs)}
+
+
+def build_native() -> bool:
+    """Build the host library from the files git commits. A leftover binary
+    is removed first: csrc/*.so is gitignored, so the driver's checkout never
+    has one and a run that silently loaded it would take another host path."""
+    from bigdl_tpu import native
+
+    so = os.path.join(REPO, "csrc", "libbigdl_host.so")
+    if os.path.exists(so):
+        os.remove(so)
+    built = native.build() and native.available()
+    log(f"native: {'true' if built else 'false'}")
+    return built
+
+
+# ------------------------------------------------------------- B: one chip
+def _image_set(n: int, image: int, classes: int, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((n, 3, image, image)).astype(np.float32)
+    y = rng.integers(0, classes, n)
+    return x, y
+
+
+def _check_losses(tel, iters: int, what: str):
+    losses = [r["loss"] for r in tel.ring.steps()]
+    if len(losses) != iters:
+        raise AssertionError(f"{what}: {len(losses)} step records, want {iters}")
+    if not np.all(np.isfinite(losses)):
+        raise AssertionError(f"{what}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"{what}: loss did not fall: {losses}")
+    return losses
+
+
+def _sync_two_ways(opt, steps: int) -> None:
+    """The step wall taken two ways over the same steps of the optimizer's
+    own jitted step — ending in jax.block_until_ready and ending in
+    float(loss). Plain information (does block_until_ready wait on this
+    PJRT?), under no metric's name. The step is lowered against the geometry
+    its first dispatch recorded and fed zeros: only the clock matters here."""
+    import jax
+    import jax.numpy as jnp
+
+    step, specs = opt._step_export_info
+    compiled = step.lower(*specs).compile()
+    args = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), specs)
+
+    def window(sync):
+        nonlocal args
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            outs = compiled(*args)  # (params, model_state, slots, loss)
+            args = tuple(outs[:3]) + tuple(args[3:])
+        sync(outs)
+        return (time.perf_counter() - t0) / steps * 1e3
+
+    window(lambda outs: float(outs[3]))  # settle
+    wall_block = window(jax.block_until_ready)
+    wall_pull = window(lambda outs: float(outs[3]))
+    log(f"  step wall over {steps} steps: {wall_block:.2f} ms ending in "
+        f"block_until_ready, {wall_pull:.2f} ms ending in float(loss)")
+
+
+def phase_train(depth=50, classes=1000, image=224, batch=128, iters=10,
+                expect_cache_hit=False):
+    """ResNet through LocalOptimizer.optimize() on one chip, as users run it:
+    telemetry (and with it PerfAccountant + FlightRecorder) attached."""
+    import jax
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.models import ResNet
+    from bigdl_tpu.obs import Telemetry
+    from bigdl_tpu.optim import SGD, LocalOptimizer, Trigger
+    from bigdl_tpu.utils.compat import CacheDirWatch
+    from bigdl_tpu.utils.engine import Engine
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    RandomGenerator.set_seed(1)
+    Engine.set_compute_dtype("bfloat16")
+    Engine.set_activation_dtype("bfloat16")
+    model = ResNet(depth, class_num=classes, dataset="imagenet",
+                   with_log_softmax=True)
+    # two fixed batches, seen five times each: the loss must fall
+    x, y = _image_set(2 * batch, image, classes)
+    opt = LocalOptimizer(model, DataSet.array(x, y, batch_size=batch),
+                         nn.ClassNLLCriterion())
+    opt.set_optim_method(SGD(learningrate=0.02, momentum=0.9))
+    opt.set_end_when(Trigger.max_iteration(iters))
+    tel = Telemetry()
+    opt.set_telemetry(tel)
+    watch = CacheDirWatch()
+    opt.optimize()
+    fresh = watch.fresh_count()
+
+    losses = _check_losses(tel, iters, "phase B")
+    if tel.compile_count != 1:
+        raise AssertionError(
+            f"phase B: {tel.compile_count} train-step compiles, want 1")
+    compile_s = next(r["seconds"] for r in tel.ring.records
+                     if r["type"] == "compile")
+    log(f"  train-step compile+first-dispatch wall {compile_s:.1f} s, "
+        f"{fresh} fresh cache entries in {Engine.compilation_cache_dir()}")
+    if expect_cache_hit:
+        with open(LAST_RUN) as f:
+            prev = json.load(f)
+        log(f"  previous run: compile wall {prev['compile_s']:.1f} s, "
+            f"{prev['fresh_entries']} fresh entries")
+        if fresh != 0:
+            raise AssertionError(
+                f"phase B: expected a warm compile cache, but optimize() "
+                f"persisted {fresh} fresh entries")
+    stats = jax.local_devices()[0].memory_stats()
+    if on_tpu() and not stats["peak_bytes_in_use"] > 0:
+        raise AssertionError(f"phase B: no device memory in use: {stats}")
+    _sync_two_ways(opt, steps=iters)
+    tel.close()
+    log(f"phase B train: ok (ResNet-{depth} b{batch}, loss "
+        f"{losses[0]:.4f} -> {losses[-1]:.4f}, 1 compile, peak "
+        f"{(stats or {}).get('peak_bytes_in_use', 0) / 2**30:.2f} GiB)")
+    return model, {"compile_s": compile_s, "fresh_entries": fresh}
+
+
+# ------------------------------------------------------ C: every local chip
+def phase_distri(depth=50, classes=1000, image=224, batch_per_chip=128,
+                 iters=6):
+    """The same model through the ZeRO-1 sharded DistriOptimizer over all of
+    jax.local_devices(). With one chip this is the one-device mesh — the
+    mesh that exists, not a fall-back."""
+    import jax
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.models import ResNet
+    from bigdl_tpu.obs import Telemetry
+    from bigdl_tpu.optim import SGD, Trigger
+    from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
+    from bigdl_tpu.utils.engine import Engine
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    devices = jax.local_devices()
+    n = len(devices)
+    Engine.init(devices=devices)
+    RandomGenerator.set_seed(1)
+    batch = batch_per_chip * n
+    x, y = _image_set(2 * batch, image, classes)
+    model = ResNet(depth, class_num=classes, dataset="imagenet",
+                   with_log_softmax=True)
+    opt = DistriOptimizer(
+        model, DataSet.distributed(DataSet.array(x, y, batch_size=batch), n),
+        nn.ClassNLLCriterion(), parameter_sync="sharded")
+    opt.set_optim_method(SGD(learningrate=0.02, momentum=0.9))
+    opt.set_end_when(Trigger.max_iteration(iters))
+    tel = Telemetry()
+    opt.set_telemetry(tel)
+    opt.optimize()
+    losses = _check_losses(tel, iters, "phase C")
+    if tel.compile_count != 1:
+        raise AssertionError(
+            f"phase C: {tel.compile_count} SPMD-step compiles, want 1")
+
+    # what the step carried, as its first dispatch recorded it: the flat
+    # master vector committed to every device, the slot vectors sharded
+    step, specs = opt._step_export_info
+    flat, slots = specs[0], specs[2]
+    if len(flat.sharding.device_set) != n:
+        raise AssertionError(f"phase C: flat master on {flat.sharding}")
+    for name, s in slots.items():
+        if len(s.sharding.device_set) != n or (
+                n > 1 and s.sharding.is_fully_replicated):
+            raise AssertionError(f"phase C: slot {name} on {s.sharding}")
+    hlo = step.lower(*specs).as_text()
+    collectives = {c: (c in hlo or c.replace("_", "-") in hlo)
+                   for c in ("reduce_scatter", "all_gather")}
+    if n > 1 and not all(collectives.values()):
+        raise AssertionError(f"phase C: collectives in step: {collectives}")
+    log(f"  devices: {n}; flat master {flat.shape} on "
+        f"{len(flat.sharding.device_set)} devices, slots sharded "
+        f"{next(iter(slots.values())).sharding.spec}; collectives {collectives}")
+    for d in devices:
+        stats = d.memory_stats()
+        log(f"  {d.platform}:{d.id} bytes_in_use "
+            f"{(stats or {}).get('bytes_in_use', 0)} peak "
+            f"{(stats or {}).get('peak_bytes_in_use', 0)}")
+        if on_tpu() and not stats["bytes_in_use"] > 0:
+            raise AssertionError(f"phase C: nothing in use on {d}: {stats}")
+    tel.close()
+    log(f"phase C distri: ok (ZeRO-1 ResNet-{depth} b{batch_per_chip}/chip on "
+        f"{n} device(s), loss {losses[0]:.4f} -> {losses[-1]:.4f}, 1 compile)")
+
+
+# --------------------------------------------------------------- D: kernels
+def _fwd_bwd(fn, args, cot):
+    """(lowered text, (output, input cotangents)) of ``fn`` jitted over
+    ``args`` and pulled back along ``cot`` — compared elementwise, because a
+    scalar sum of millions of bf16-rounded terms is all rounding noise."""
+    import jax
+
+    def run(*a):
+        out, vjp = jax.vjp(fn, *a)
+        return out, vjp(cot.astype(out.dtype))
+
+    jitted = jax.jit(run)
+    return jitted.lower(*args).as_text(), jitted(*args)
+
+
+def _close(got, want, tol: float, what: str) -> None:
+    """tests/test_kernel_parity.py's rule over a pytree: every leaf finite
+    and |Δ| ≤ tol · (1 + max|ref|). Reduced on the device; scalars come back."""
+    import jax
+    import jax.numpy as jnp
+
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(g - w)))
+        scale = 1.0 + float(jnp.max(jnp.abs(w)))
+        if not (bool(jnp.all(jnp.isfinite(g))) and err <= tol * scale):
+            raise AssertionError(
+                f"{what}: max |Δ| = {err:.3g} > {tol} * {scale:.3g}")
+
+
+# f32 is looser than the CPU lock (1e-5): Mosaic and XLA:TPU round
+# rsqrt/tanh differently; bf16 is the parity suite's own 8-bit-mantissa band
+F32_TOL, BF16_TOL = 1e-4, 5e-2
+
+
+def _normal(seed: int, shape, dtype):
+    """Operands made on the device: the widest one here is 100M elements."""
+    import jax
+
+    return jax.random.normal(jax.random.PRNGKey(seed), shape, dtype)
+
+
+def check_flash(t: int, d: int, n: int = 2, h: int = 4) -> None:
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.attention import scaled_dot_product_attention as sdpa
+
+    q, k, v = (_normal(t + i, (n, h, t, d), jnp.bfloat16) for i in range(3))
+    lens = jnp.asarray(
+        np.random.default_rng(t).integers(t // 2, t + 1, n), jnp.int32)
+    cot = _normal(t + 3, (n, h, t, d), jnp.float32)
+
+    def attend(impl):
+        return lambda q, k, v: sdpa(q, k, v, impl=impl, causal=True,
+                                    lengths=lens, mask_q=True)
+
+    what = f"flash attention T={t} d={d} bf16 causal+lengths"
+    text, got = _fwd_bwd(attend("flash"), (q, k, v), cot)
+    n_calls = assert_mosaic(text, what)
+    _close(got, _fwd_bwd(attend("dense"), (q, k, v), cot)[1], BF16_TOL, what)
+    log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
+
+
+def check_fused(rows: int, hidden: int, conv_shape) -> None:
+    """Engine.set_fused_kernels(True) against the unfused path of the same
+    public call sites: nn.LayerNormalization (whose unfused chain is
+    layer_norm_reference), nn.RMSNorm, and the Linear / conv bias+activation
+    epilogues (precision.bias_act / channel_bias_act)."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.utils import precision
+    from bigdl_tpu.utils.engine import Engine
+
+    gain = 1.0 + 0.1 * _normal(1, (hidden,), jnp.float32)
+    bias = 0.1 * _normal(2, (hidden,), jnp.float32)
+    cbias = jnp.clip(0.1 * _normal(3, (conv_shape[1],), jnp.float32), -.1, .1)
+    ln, rms = nn.LayerNormalization(hidden), nn.RMSNorm(hidden)
+    cases = []
+    for dtype, tol in ((jnp.float32, F32_TOL), (jnp.bfloat16, BF16_TOL)):
+        x = _normal(4, (rows, hidden), dtype)
+        cot = _normal(5, (rows, hidden), jnp.float32)
+        # ReLU's gate is a step: keep |x + b| away from 0, or the kernel's
+        # f32 sum and the unfused bf16 sum legitimately disagree on the sign
+        g = _normal(6, conv_shape, jnp.float32)
+        xc = (jnp.sign(g) * (0.25 + jnp.abs(g))).astype(dtype)
+        ccot = _normal(7, conv_shape, jnp.float32)
+        tag = jnp.dtype(dtype).name
+        cases += [
+            (f"fused LayerNorm {rows}x{hidden} {tag}", tol, (x, gain, bias),
+             cot, lambda x, g, b: ln.apply(
+                 {"weight": g, "bias": b}, {}, x, training=True, rng=None)[0]),
+            (f"fused RMSNorm {rows}x{hidden} {tag}", tol, (x, gain), cot,
+             lambda x, g: rms.apply(
+                 {"weight": g}, {}, x, training=True, rng=None)[0]),
+            (f"fused bias+gelu {rows}x{hidden} {tag}", tol, (x, bias), cot,
+             lambda x, b: precision.bias_act(x, b, "gelu")),
+            (f"fused conv bias+relu {'x'.join(map(str, conv_shape))} {tag}",
+             tol, (xc, cbias), ccot,
+             lambda x, b: precision.channel_bias_act(x, b, "relu")),
+        ]
+    Engine.set_fused_kernels(True)  # read at trace time
+    try:
+        fused = [_fwd_bwd(fn, args, cot) for _, _, args, cot, fn in cases]
+    finally:
+        Engine.set_fused_kernels(False)
+    for (what, tol, args, cot, fn), (text, got) in zip(cases, fused):
+        n_calls = assert_mosaic(text, what)
+        _close(got, _fwd_bwd(fn, args, cot)[1], tol, what)
+        log(f"  {what}: fwd+bwd match the unfused path "
+            f"({n_calls} Mosaic calls)")
+
+
+def check_maxpool(shape=(128, 64, 112, 112)) -> None:
+    """The Pallas max-pool backward (BIGDL_MAXPOOL_GRAD_IMPL=pallas) at the
+    ResNet-50 stem pool, 3x3 stride 2 pad 1 — the geometry that ran out of
+    VMEM under the round-5 libtpu — against XLA's SelectAndScatter."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu import nn
+
+    pool = nn.SpatialMaxPooling(3, 3, 2, 2, 1, 1)
+
+    def fn(x):
+        return pool.apply({}, {}, x, training=True, rng=None)[0]
+
+    x = _normal(8, shape, jnp.float32)
+    cot = _normal(9, jax.eval_shape(fn, x).shape, jnp.float32)
+    what = f"max-pool backward {'x'.join(map(str, shape))} 3x3/s2 f32"
+    os.environ["BIGDL_MAXPOOL_GRAD_IMPL"] = "pallas"  # read at trace time
+    try:
+        text, got = _fwd_bwd(fn, (x,), cot)
+    finally:
+        del os.environ["BIGDL_MAXPOOL_GRAD_IMPL"]
+    n_calls = assert_mosaic(text, what)
+    _close(got, _fwd_bwd(fn, (x,), cot)[1], F32_TOL, what)
+    log(f"  {what}: matches SelectAndScatter ({n_calls} Mosaic calls)")
+
+
+def check_transformer_step(t=2048, batch=8, vocab=8192, hidden=512, heads=8,
+                           ffn=2048, layers=2, iters=4) -> None:
+    """Flash attention where users meet it: nn.Transformer(mode="lm") through
+    LocalOptimizer, impl="auto" choosing the kernel from the shapes."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.obs import Telemetry
+    from bigdl_tpu.optim import SGD, LocalOptimizer, Trigger
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    RandomGenerator.set_seed(2)
+    ids = np.random.default_rng(2).integers(
+        1, vocab, (2 * batch, t)).astype(np.int32)
+    lm = nn.Transformer(
+        vocab_size=vocab, hidden_size=hidden, num_heads=heads,
+        filter_size=ffn, num_hidden_layers=layers, postprocess_dropout=0.0,
+        attention_dropout=0.0, relu_dropout=0.0, mode="lm")
+    opt = LocalOptimizer(
+        lm, DataSet.array(ids, np.roll(ids, -1, axis=1).astype(np.int64),
+                          batch_size=batch),
+        nn.TimeDistributedCriterion(nn.CrossEntropyCriterion(),
+                                    size_average=True))
+    opt.set_optim_method(SGD(learningrate=0.05, momentum=0.9))
+    opt.set_end_when(Trigger.max_iteration(iters))
+    tel = Telemetry()
+    opt.set_telemetry(tel)
+    opt.optimize()
+    losses = _check_losses(tel, iters, "phase D transformer")
+    step, specs = opt._step_export_info
+    n_calls = assert_mosaic(step.lower(*specs).as_text(),
+                            "nn.Transformer LocalOptimizer step")
+    tel.close()
+    log(f"  nn.Transformer lm T={t} d={hidden // heads} x{layers} layers "
+        f"through LocalOptimizer: loss {losses[0]:.4f} -> {losses[-1]:.4f}, "
+        f"{n_calls} Mosaic calls in the step")
+
+
+def phase_kernels() -> None:
+    check_flash(t=1024, d=64)
+    check_flash(t=4096, d=128)
+    # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
+    # res2 conv epilogue (b128: 128x256x56x56)
+    check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))
+    check_maxpool()
+    check_transformer_step()
+    log("phase D kernels: ok")
+
+
+# --------------------------------------------------------------- E: serving
+def phase_serving(model, image=224, batch=8, requests=32, clients=4) -> None:
+    """ModelServer over the trained phase-B model: concurrent single-record
+    requests must equal the serial Predictor rows bit for bit (the same
+    executable), with no compile after warm-up."""
+    from bigdl_tpu.optim.predictor import Predictor
+    from bigdl_tpu.serving import ModelServer
+
+    records, _ = _image_set(requests, image, 1, seed=3)
+    want = np.asarray(Predictor(model, batch_size=batch).predict(records))
+    got = [None] * requests
+    with ModelServer() as srv:
+        srv.register("resnet50", model, sample_input=records[0],
+                     batch_size=batch, max_delay_ms=5.0)
+        info = srv.models()["resnet50"]
+        warm = srv.telemetry.compile_count
+
+        def client(k: int) -> None:
+            for i in range(k, requests, clients):
+                got[i] = srv.infer("resnet50", records[i]).result(timeout=120)
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(clients)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        after = srv.telemetry.compile_count
+    if any(g is None for g in got):
+        raise AssertionError("phase E: a client thread died before its result")
+    got = np.stack(got)
+    if not (np.all(np.isfinite(got)) and got.shape == want.shape):
+        raise AssertionError(f"phase E: bad outputs {got.shape} vs {want.shape}")
+    if not np.array_equal(got, want):
+        raise AssertionError(
+            f"phase E: served rows differ from the serial Predictor "
+            f"(max abs diff {np.max(np.abs(got - want)):.3g})")
+    if after != warm:
+        raise AssertionError(
+            f"phase E: {after - warm} compiles after warm-up")
+    log(f"phase E serving: ok ({requests} requests from {clients} threads "
+        f"bit-equal to the serial Predictor, warm-up {info['warmup_s']:.1f} s "
+        f"/ {info['warmup_compiles']} compile(s), 0 compiles after)")
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--expect-cache-hit", action="store_true",
+                    help="second run on one cache dir: fail unless the "
+                         "ResNet-50 train step compiled nothing fresh")
+    args = ap.parse_args()
+
+    device = phase_device()
+    build_native()
+    model, compile_rec = phase_train(expect_cache_hit=args.expect_cache_hit)
+    phase_distri()
+    phase_kernels()
+    phase_serving(model)
+    os.makedirs(os.path.dirname(LAST_RUN), exist_ok=True)
+    with open(LAST_RUN, "w") as f:
+        json.dump(compile_rec, f)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

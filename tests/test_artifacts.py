@@ -25,7 +25,7 @@ def cache_sandbox(tmp_path):
     """Switch the persistent compile cache to per-test dirs and restore the
     suite-wide dir afterwards. ``use("name")`` activates a fresh dir — the
     in-process analogue of booting on a new host with an empty
-    BIGDL_COMPILE_CACHE_DIR (jax's in-memory cache state is reset at each
+    JAX_COMPILATION_CACHE_DIR (jax's in-memory cache state is reset at each
     switch by ``enable_persistent_compilation_cache``)."""
     prev_dir = Engine.compilation_cache_dir()
 
@@ -304,7 +304,8 @@ class TestPruneCompileCache:
         d = str(tmp_path / "cache")
         os.makedirs(d)
         self._mk_entry(d, "ancient", 10, 30 * 86400)
-        monkeypatch.setenv("BIGDL_COMPILE_CACHE_DIR", d)
+        monkeypatch.setattr(compat, "DEFAULT_COMPILE_CACHE_DIR", d)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("BIGDL_COMPILE_CACHE_MAX_AGE_DAYS", "7")
         prev = Engine.compilation_cache_dir()
         monkeypatch.setattr(Engine, "_cache_pruned", False)
@@ -378,14 +379,6 @@ class TestStepArtifactSurface:
         )
         with pytest.raises(RuntimeError, match="run optimize"):
             opt.export_step_artifact("/tmp/never-written")
-
-    def test_seed_without_cache_dir_refuses(self, tmp_path, cache_sandbox,
-                                            monkeypatch):
-        bundle, _ = _export_tiny_bundle(tmp_path, cache_sandbox)
-        monkeypatch.delenv("BIGDL_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.setattr(Engine._state, "compilation_cache_dir", None)
-        with pytest.raises(ArtifactIncompatible, match="no persistent"):
-            aot.seed_from_bundle(bundle)
 
     def test_trainer_warm_start_rejects_serving_bundle(self, tmp_path,
                                                        cache_sandbox):

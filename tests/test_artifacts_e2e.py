@@ -7,7 +7,7 @@ traced boot measured in the same test, and predictions bit-identical to the
 exporting server.
 
 Trainer: ``export_step_artifact`` after a checkpointed fit -> simulated
-preemption -> resume on a fresh ``BIGDL_COMPILE_CACHE_DIR`` seeded from the
+preemption -> resume on a fresh ``JAX_COMPILATION_CACHE_DIR`` seeded from the
 bundle reaches the next step with 0 fresh compiles (telemetry-proven:
 every compile record says ``cache_hit`` and the cache dir gained no entry)
 and bit-identical params.
@@ -240,18 +240,13 @@ def _params(model):
 
 
 # The trainer phases run in REAL subprocesses: that is the faithful
-# preemption story (a preempted run resumes in a NEW process on a new host),
-# and it sidesteps a jaxlib 0.4.36 CPU race where mixing an
-# in-memory-compiled donated step with a later disk-deserialized twin IN ONE
-# PROCESS can corrupt live buffers (see docs/performance.md and the gc-guard
-# note in Optimizer.optimize; cross-process deserialization — the real
-# deployment path — has been stable since PR 2).
+# preemption story (a preempted run resumes in a NEW process on a new host).
 _TRAINER_PROBE = """
 import json, os, sys
 import jax
 jax.config.update("jax_platforms", "cpu")
 phase, kind, ckpt, bundle, cache, out = sys.argv[1:7]
-os.environ["BIGDL_COMPILE_CACHE_DIR"] = cache
+assert os.environ["JAX_COMPILATION_CACHE_DIR"] == cache  # placed by the parent
 import numpy as np
 from bigdl_tpu import nn
 from bigdl_tpu.dataset import DataSet
@@ -299,10 +294,8 @@ if phase == "export":
                       "module": man["step"]["module"],
                       "cache_entries": man["cache_entries"]}))
 elif phase == "gold":
-    # the oracle runs donation-free like the CPU warm start does (numerics
-    # are donation-invariant; donate=False also keeps the oracle itself off
-    # the jaxlib CPU deserialized-donation hazard its cache-hit step would
-    # otherwise walk into)
+    # the oracle runs donation-free: numerics are donation-invariant, so
+    # the donated warm resume must match it bit for bit
     opt = parts(donate=False)
     opt.resume(ckpt)
     opt.set_end_when(Trigger.max_iteration(4))
@@ -335,7 +328,7 @@ def _run_trainer_phase(phase, kind, ckpt, bundle, cache, out):
     import subprocess
 
     env = {**os.environ, "PYTHONPATH": str(REPO),
-           "BIGDL_COMPILE_CACHE_DIR": cache}
+           "JAX_COMPILATION_CACHE_DIR": cache}
     proc = subprocess.run(
         [sys.executable, "-c", _TRAINER_PROBE, phase, kind, ckpt, bundle,
          cache, out],

@@ -1,6 +1,6 @@
 """Concurrency audit family: the static auditor's four passes
 (``bigdl_tpu/analysis/concurrency.py``) rule by rule on purpose-built
-fixtures (positive + suppressed + out-of-scope), the BDL017–BDL020 wiring
+fixtures (positive + suppressed + out-of-scope), the BDL017–BDL019 wiring
 through ``tools/lint_framework.py``, the repo-clean gate, thread-entry-map
 resolution on the real ``serving/batcher.py``, the committed lock-order
 graph, the runtime lock sanitizer (``analysis/lock_tracer.py``) end to end
@@ -476,82 +476,6 @@ class TestBDL019:
             "            with self._a:\n"
             "                pass\n"
         ))
-        assert found == []
-
-
-# ---------------------------------------------------------------------------
-# BDL020: unfenced buffer donation (native lint_framework rule)
-# ---------------------------------------------------------------------------
-_BDL020_POS = (
-    "import jax\n"
-    "from functools import partial\n"
-    "def make_step(donate):\n"
-    "    @partial(jax.jit, donate_argnums=donate)\n"
-    "    def step(params, slots, x):\n"
-    "        return params, slots\n"
-    "    return step\n"
-)
-
-
-class TestBDL020:
-    def test_partial_jit_donation_flagged(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", _BDL020_POS)
-        assert codes(found) == ["BDL020"]
-        assert "donation_safe" in found[0].message
-
-    def test_direct_jit_call_flagged(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", (
-            "import jax\n"
-            "def make_step(fn):\n"
-            "    return jax.jit(fn, donate_argnums=(0, 1))\n"
-        ))
-        assert codes(found) == ["BDL020"]
-
-    def test_donation_safe_gate_clean(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", (
-            "import jax\n"
-            "from functools import partial\n"
-            "from bigdl_tpu.utils.compat import donation_safe\n"
-            "def make_step():\n"
-            "    donate = (0, 1) if donation_safe() else ()\n"
-            "    @partial(jax.jit, donate_argnums=donate)\n"
-            "    def step(params, slots, x):\n"
-            "        return params, slots\n"
-            "    return step\n"
-        ))
-        assert found == []
-
-    def test_empty_literal_donation_clean(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", (
-            "import jax\n"
-            "def make_step(fn):\n"
-            "    return jax.jit(fn, donate_argnums=())\n"
-        ))
-        assert found == []
-
-    def test_non_jit_partial_clean(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", (
-            "from functools import partial\n"
-            "def make(helper):\n"
-            "    return partial(helper, donate_argnums=(0,))\n"
-        ))
-        assert found == []
-
-    def test_suppression_honored(self, tmp_path):
-        found = run_lint(tmp_path, "bigdl_tpu/optim/x.py", (
-            "import jax\n"
-            "from functools import partial\n"
-            "def make_step(donate):\n"
-            "    # driver rebinds refs to step outputs every iteration\n"
-            "    @partial(jax.jit, donate_argnums=donate)  # lint: disable=BDL020\n"
-            "    def step(params, slots, x):\n"
-            "        return params, slots\n"
-            "    return step\n"
-        ))
-        assert found == []
-
-    def test_out_of_library_scope_clean(self, tmp_path):
-        found = run_lint(tmp_path, "scripts/x.py", _BDL020_POS)
         assert found == []
 
 

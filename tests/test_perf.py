@@ -30,7 +30,7 @@ from bigdl_tpu.obs.perf import (
 )
 from bigdl_tpu.optim import LocalOptimizer, SGD, Trigger
 from bigdl_tpu.resilience import FaultPlan
-from bigdl_tpu.utils.compat import device_peaks, donation_safe
+from bigdl_tpu.utils.compat import device_peaks
 from bigdl_tpu.utils.random import RandomGenerator
 
 REPO = Path(__file__).resolve().parent.parent
@@ -140,12 +140,6 @@ class TestCostModelMath:
             cost.flops / cost.bytes_accessed, rel=1e-3
         )
         assert not cost.collective_bytes  # no collectives in a local matmul
-
-    def test_donation_safe_predicate(self):
-        # tier-1 runs on the CPU backend, where the jaxlib-0.4.36
-        # deserialized-donation hazard makes donation unsafe at the
-        # compatibility seams (docs/performance.md)
-        assert donation_safe() is False
 
 
 # ---------------------------------------------------------------------------
@@ -470,10 +464,10 @@ class TestPerfGateTool:
         assert all(r["status"] in ("ok", "improved") for r in rows)
 
     def test_trajectory_flags_holes(self):
-        # rounds 1-5 are frozen history (exact); counts are invariants so a
+        # rounds 2-5 are frozen history (exact); counts are invariants so a
         # future bench round cannot break this test
         t = perf_gate.load_trajectory(str(REPO))
-        assert t["n_rounds"] >= 5 and t["n_holes"] >= 3
+        assert t["n_rounds"] >= 4 and t["n_holes"] >= 2
         statuses = {r["round"]: r["status"] for r in t["rounds"]}
         assert statuses[2] == statuses[3] == "ok"
-        assert statuses[1] == statuses[4] == statuses[5] == "null"
+        assert statuses[4] == statuses[5] == "null"

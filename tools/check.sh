@@ -48,8 +48,7 @@
 #                                  # chaos-seam dump matrix, real-SIGSEGV
 #                                  # faulthandler artifact, bundle verify
 #                                  # tamper/truncate, recorder-armed
-#                                  # 1-compile canary, fleet merge,
-#                                  # bench postmortem harvest)
+#                                  # 1-compile canary, fleet merge)
 set -u -o pipefail
 cd "$(dirname "$0")/.."
 
@@ -93,7 +92,7 @@ fi
 if [ "${1:-}" = "--postmortem" ]; then
     echo "== flight recorder / postmortem family (CPU) =="
     exec env JAX_PLATFORMS=cpu python -m pytest \
-        tests/test_blackbox.py tests/test_bench_degraded.py -q \
+        tests/test_blackbox.py -q \
         -p no:cacheprovider -p no:xdist -p no:randomly
 fi
 

@@ -7,7 +7,6 @@ the same data. In-jit repetition divides out dispatch latency; scalar-pull
 sync. Writes bench_artifacts/FLASH_LENGTHS_AB_r4.json.
 """
 
-import json
 import os
 import sys
 import time
@@ -24,17 +23,15 @@ def main() -> None:
 
     from bigdl_tpu.nn.attention import (padding_attention_bias,
                                         scaled_dot_product_attention)
-    from bigdl_tpu.ops.pallas_probe import (pallas_available,
-                                            pallas_unavailable_reason)
 
-    from _bench_io import unavailable_stub, write_unless_clobbering
+    from _bench_io import write_unless_clobbering
 
+    if jax.default_backend() != "tpu":
+        raise SystemExit(
+            f"flash_lengths_ab measures the Mosaic kernel: needs the tpu "
+            f"backend, found {jax.default_backend()!r}")
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         "bench_artifacts", "FLASH_LENGTHS_AB_r4.json")
-    if not pallas_available():
-        unavailable_stub(path, str(jax.devices()[0]),
-                         pallas_unavailable_reason())
-        return
 
     R = 4
     rng = np.random.default_rng(0)

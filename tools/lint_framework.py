@@ -159,15 +159,6 @@ findings, exiting non-zero when any are found. Rules:
   lock-order graph; a cycle means two threads can take the locks in opposite
   orders and deadlock. The runtime half (``analysis/lock_tracer.py``,
   ``BIGDL_LOCK_DEBUG=1``) cross-checks observed orders against this graph.
-* **BDL020 unfenced-buffer-donation** — in ``bigdl_tpu/`` library code, a
-  ``jit``/``pjit`` construction site passing ``donate_argnums``/
-  ``donate_argnames`` must sit in a function that consults
-  ``utils.compat.donation_safe()`` (the jaxlib-0.4.36 CPU
-  deserialized-donation use-after-free fence): donated input buffers are
-  INVALID after dispatch, so any caller that re-reads them needs the
-  predicate to gate donation off on unsafe backends. Sites whose drivers
-  provably rebind references to the step outputs carry a suppression
-  stating that invariant.
 * **BDL021 raw-collective-outside-parallel** — in ``bigdl_tpu/`` library
   code outside ``bigdl_tpu/parallel/``, a direct ``lax.ppermute`` /
   ``lax.all_to_all`` call is a hand-rolled collective schedule: route it
@@ -552,9 +543,6 @@ class _Linter(ast.NodeVisitor):
         self.findings: List[Finding] = []
         self._forward_depth = 0
         self._func_depth = 0
-        # BDL020: per enclosing function, does its body (nested defs
-        # included) consult utils.compat.donation_safe()?
-        self._donation_stack: List[bool] = []
         # BDL022: per enclosing function, does its body (nested defs
         # included) hand trace context/collector across the thread seam?
         self._ctxprop_stack: List[bool] = []
@@ -624,11 +612,6 @@ class _Linter(ast.NodeVisitor):
         if in_forward:
             self._forward_depth += 1
         self._func_depth += 1
-        self._donation_stack.append(any(
-            (isinstance(n, ast.Name) and n.id == "donation_safe")
-            or (isinstance(n, ast.Attribute) and n.attr == "donation_safe")
-            for n in ast.walk(node)
-        ))
         self._ctxprop_stack.append(any(
             (isinstance(n, ast.Name) and n.id in _CTX_PROP_NAMES)
             or (isinstance(n, ast.Attribute) and n.attr in _CTX_PROP_NAMES)
@@ -636,7 +619,6 @@ class _Linter(ast.NodeVisitor):
         ))
         self.generic_visit(node)
         self._ctxprop_stack.pop()
-        self._donation_stack.pop()
         self._func_depth -= 1
         if in_forward:
             self._forward_depth -= 1
@@ -759,8 +741,6 @@ class _Linter(ast.NodeVisitor):
             self._check_unsupervised_thread(node)
         if self._trace_scope:
             self._check_unpropagated_context(node)
-        if self._library_scope:
-            self._check_unfenced_donation(node)
         if self._export_scope:
             chain0 = _attr_chain(node.func)
             root = (
@@ -1273,49 +1253,6 @@ class _Linter(ast.NodeVisitor):
             "serving/resilience.spawn_worker (inherits the context), or "
             "bind_context/context_scope/bind_collector inside the thread "
             "target",
-        )
-
-    def _check_unfenced_donation(self, node: ast.Call) -> None:
-        """BDL020: in ``bigdl_tpu/``, a jit/pjit construction site that
-        donates input buffers (``donate_argnums``/``donate_argnames``) must
-        sit in a function that consults ``utils.compat.donation_safe()`` —
-        the fence for the jaxlib-0.4.36 CPU deserialized-donation
-        use-after-free. Donated buffers are INVALID after dispatch; a caller
-        re-reading them needs the predicate to turn donation off on unsafe
-        backends. Drivers that provably rebind their references to the step
-        outputs carry the suppression stating that invariant."""
-        kws = [
-            k for k in node.keywords
-            if k.arg in ("donate_argnums", "donate_argnames")
-        ]
-        if not kws:
-            return
-        if all(
-            isinstance(k.value, (ast.Tuple, ast.List)) and not k.value.elts
-            for k in kws
-        ):
-            return  # literal empty donation set: donates nothing
-        func = node.func
-        chain = _attr_chain(func)
-        tail = chain[-1] if chain else None
-        is_jit = tail in ("jit", "pjit")
-        if tail == "partial" and node.args:
-            achain = _attr_chain(node.args[0])
-            is_jit = achain is not None and achain[-1] in ("jit", "pjit")
-        if not is_jit:
-            return
-        if any(self._donation_stack):
-            return  # an enclosing function gates on donation_safe()
-        self._report(
-            node,
-            "BDL020",
-            "jit/pjit site donates input buffers without consulting "
-            "utils.compat.donation_safe(): donated arrays are invalid "
-            "after dispatch, and on fenced backends (jaxlib-0.4.36 CPU "
-            "deserialized executables) donation itself corrupts results — "
-            "gate the donate list on donation_safe(), or suppress with the "
-            "invariant that no reference to the donated buffers survives "
-            "the call",
         )
 
     def _check_unbounded_queue(self, node: ast.Call) -> None:

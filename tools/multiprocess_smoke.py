@@ -42,7 +42,7 @@ def _worker(process_id: int, port: int) -> None:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from bigdl_tpu.utils.compat import shard_map
+    from jax import shard_map
     from bigdl_tpu.utils.engine import Engine
 
     Engine.init_distributed(
