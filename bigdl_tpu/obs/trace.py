@@ -4,8 +4,11 @@ A :func:`span` wraps a hot-loop seam (prefetch, pad/mask, dispatch, checkpoint,
 validation, summary flush) in a ``perf_counter`` timing scope on a THREAD-LOCAL
 stack, and simultaneously enters a :class:`jax.profiler.TraceAnnotation` so the
 same seam shows up as a named slice in device traces captured via
-``Optimizer.set_profile`` / ``jax.profiler.start_trace`` (readable by
-``tools/trace_summary.py`` and TensorBoard's profile plugin).
+``Optimizer.set_profile`` / ``jax.profiler.start_trace``: on the profiler's
+clock beside the device ops, where ``benchmark/lib/trace.py`` labels each idle
+gap of the device with the span that covers most of it (TensorBoard's profile
+plugin shows the same slices). ``docs/observability.md`` has the catalogue of
+the training loop's spans: name, seam, thread, and the metric that reads each.
 
 Recording is PULL-based and aggregate-first: span durations accumulate into a
 :class:`SpanCollector` — one per :class:`~bigdl_tpu.obs.telemetry.Telemetry`
@@ -94,9 +97,9 @@ def fault_hook():
 
 
 def fault_point(name: str) -> None:
-    """Bare chaos seam marker for hot paths that are not span-wrapped (the
-    train-step dispatch: timing there is measured around the call and fed via
-    :func:`add_sample`, so there is no ``span`` for the hook to ride)."""
+    """Bare chaos seam marker for paths that are not span-wrapped (a
+    heartbeat write, the serving queue's admission and materialization):
+    a :func:`span` reports its own name to the hook and needs no marker."""
     if _fault_hook is not None:
         _fault_hook(name)
 
@@ -342,8 +345,8 @@ def current_collector() -> Optional[SpanCollector]:
 
 
 def add_sample(name: str, seconds: float) -> None:
-    """Record one externally-timed sample (the dispatch seam times itself so
-    the same measurement can also feed compile-event attribution)."""
+    """Record one externally-timed sample (the ``Predictor``'s dispatch seam
+    times itself; the trainer's is a :func:`span`, read through ``sp.s``)."""
     col = getattr(_tls, "collector", None)
     if col is not None:
         col.add(name, seconds)
@@ -368,13 +371,31 @@ def _stack() -> list:
     return st
 
 
+class _Sample:
+    """What ``with span(name) as sp`` binds: ``sp.s`` is the span's seconds
+    once it has closed, so a seam that needs its own duration (the step
+    record's ``dispatch_s`` and ``input_wait_s``) reads the span's one pair of
+    clock reads instead of taking a second. ``None`` while the span is open,
+    and on a thread with no bound collector, where a span takes no clock."""
+
+    __slots__ = ("s",)
+
+    def __init__(self):
+        self.s = None
+
+
+_UNTIMED = _Sample()  # shared by every detached span: never written
+
+
 @contextlib.contextmanager
 def span(name: str):
     """Time a host-side seam under ``name`` and annotate the profiler trace.
 
     Exception-safe (the duration is recorded even when the body raises — the
     same contract as the fixed ``Metrics.time``). Nested spans record under
-    ``"outer/inner"`` paths via the thread-local stack.
+    ``"outer/inner"`` paths via the thread-local stack; the profiler sees the
+    bare ``name``, so bare names are unique. ``as sp`` binds a
+    :class:`_Sample` whose ``s`` holds the seconds after the block.
 
     When a SAMPLED :class:`TraceContext` is bound on this thread and the
     collector has an ``on_span`` sink, the span also emits one id-bearing
@@ -383,12 +404,12 @@ def span(name: str):
     nesting stack). Emission happens even when the body raises — a fault at
     any seam closes the span rather than orphaning it.
     """
-    if _fault_hook is not None:  # chaos seam (resilience.chaos.FaultPlan)
-        _fault_hook(name)
     with jax.profiler.TraceAnnotation(name):
         col = getattr(_tls, "collector", None)
         if col is None:
-            yield
+            if _fault_hook is not None:  # chaos seam, as below
+                _fault_hook(name)
+            yield _UNTIMED
             return
         ctx = getattr(_tls, "context", None)
         child = None
@@ -398,11 +419,17 @@ def span(name: str):
         stack = _stack()
         qualified = "/".join(stack + [name]) if stack else name
         stack.append(name)
+        sample = _Sample()
         t0 = time.perf_counter()
         try:
-            yield
+            # chaos seam (resilience.chaos.FaultPlan), inside the clock: a
+            # stall injected at a seam is time spent in that seam, and a
+            # fault raised here closes the span like one raised by the body
+            if _fault_hook is not None:
+                _fault_hook(name)
+            yield sample
         finally:
-            dt = time.perf_counter() - t0
+            sample.s = dt = time.perf_counter() - t0
             stack.pop()
             col.add(qualified, dt)
             if child is not None:
@@ -417,6 +444,8 @@ def span(name: str):
 
 def step_annotation(step_num: int):
     """``jax.profiler.StepTraceAnnotation`` around one jitted-step dispatch:
-    gives profiler traces per-step boundaries (TensorBoard's step view,
-    ``tools/trace_summary.py --steps`` alignment)."""
+    gives profiler traces per-step boundaries (TensorBoard's step view). The
+    ``dispatch`` span and its children lie inside it, and
+    ``benchmark/lib/trace.label_gap`` labels an idle gap of the device with
+    the span that covers most of it, the innermost of equals."""
     return jax.profiler.StepTraceAnnotation("train", step_num=int(step_num))

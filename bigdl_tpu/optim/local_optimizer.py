@@ -14,6 +14,7 @@ semantics are preserved exactly:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import threading
@@ -50,24 +51,39 @@ def _to_device_tree(x):
     return jax.tree_util.tree_map(jnp.asarray, x)
 
 
+def _host_nbytes(tree) -> int:
+    """Bytes of a pytree's host leaves: what a ``device_put`` of it moves
+    host→device (a leaf that is already a ``jax.Array`` crosses nothing)."""
+    return sum(
+        getattr(leaf, "nbytes", 0)
+        for leaf in jax.tree_util.tree_leaves(tree)
+        if not isinstance(leaf, jax.Array)
+    )
+
+
 class _DeviceBatch:
     """A MiniBatch whose arrays already live on device (built by the
     prefetcher). ``input_wait_s`` is the prefetch worker's wait for THIS
     batch from the upstream iterator (the host input pipeline's starvation
     signal); ``input_qdepth`` the pipeline staging-ring depth right after
-    the pull (None when the upstream exposes no ring). ``trace`` is the
+    the pull (None when the upstream exposes no ring); ``h2d_bytes`` the
+    bytes of its host leaves, which crossed host→device at the placement
+    seam (None on a detached run, which counts nothing). ``trace`` is the
     batch's causal :class:`~bigdl_tpu.obs.trace.TraceContext` — the
     sanctioned carrier across the prefetch→driver thread seam (BDL022), so
     the driver's dispatch span chains onto the chunk's transform/place
     spans."""
 
-    __slots__ = ("_x", "_t", "_n", "input_wait_s", "input_qdepth", "trace")
+    __slots__ = ("_x", "_t", "_n", "input_wait_s", "input_qdepth",
+                 "h2d_bytes", "trace")
 
-    def __init__(self, x, t, n: int, input_wait_s: float = 0.0,
-                 input_qdepth: Optional[int] = None, trace=None):
+    def __init__(self, x, t, n: int, input_wait_s: Optional[float] = 0.0,
+                 input_qdepth: Optional[int] = None,
+                 h2d_bytes: Optional[int] = None, trace=None):
         self._x, self._t, self._n = x, t, n
         self.input_wait_s = input_wait_s
         self.input_qdepth = input_qdepth
+        self.h2d_bytes = h2d_bytes
         self.trace = trace
 
     def get_input(self):
@@ -1558,37 +1574,49 @@ class Optimizer:
         hm = self.health
 
         def run_iteration(batch, lr: float):
-            x = _to_device_tree(batch.get_input())
-            t = _to_device_tree(batch.get_target())
-            args = (box["params"], box["model_state"], box["slots"])
-            if has_extra:
-                args = args + (box["extra"],)
-            args = args + (
-                x,
-                t,
-                jnp.asarray(batch.size(), jnp.float32),  # real (unpadded) rows
-                jnp.asarray(lr, jnp.float32),
-                jnp.asarray(state["neval"]),
-                RandomGenerator.next_key(),
-            )
-            self._capture_step_specs(train_step, args)
+            # the three spans below split the driver's `dispatch` span: host
+            # work that delays the enqueue, the enqueue, and what follows it
+            # (the carried state rebound, the walk of the module tree)
+            with obs_span("step_args"):
+                x = _to_device_tree(batch.get_input())
+                t = _to_device_tree(batch.get_target())
+                args = (box["params"], box["model_state"], box["slots"])
+                if has_extra:
+                    args = args + (box["extra"],)
+                args = args + (
+                    x,
+                    t,
+                    jnp.asarray(batch.size(), jnp.float32),  # real (unpadded) rows
+                    jnp.asarray(lr, jnp.float32),
+                    jnp.asarray(state["neval"]),
+                    RandomGenerator.next_key(),
+                )
+                self._capture_step_specs(train_step, args)
             # box rebinds to the step OUTPUTS below, so with donation on,
             # nothing downstream (checkpoint/summary/validation readers go
             # through the box getters) ever touches the donated input buffers
-            outs = train_step(*args)
-            if has_extra:
-                (box["params"], box["model_state"], box["slots"],
-                 box["extra"], loss) = outs[:5]
-                tail = 5
-            else:
-                box["params"], box["model_state"], box["slots"], loss = outs[:4]
-                tail = 4
-            if codec is None:
-                # flat mode deliberately skips this: re-materializing the
-                # tree every step is exactly the per-step copy the flat
-                # layout exists to kill (the model syncs at the cold seams)
-                model.set_parameters(box["params"])
-            model.set_state(box["model_state"])
+            with obs_span("step_call"):
+                outs = train_step(*args)
+            with obs_span("model_sync"):
+                # the last references to the step's inputs go here and in
+                # the rebinding below: freeing a few hundred array objects
+                # is most of a millisecond, and it belongs to a span so that
+                # the three close `dispatch`
+                del args
+                if has_extra:
+                    (box["params"], box["model_state"], box["slots"],
+                     box["extra"], loss) = outs[:5]
+                    tail = 5
+                else:
+                    (box["params"], box["model_state"], box["slots"],
+                     loss) = outs[:4]
+                    tail = 4
+                if codec is None:
+                    # flat mode deliberately skips this: re-materializing the
+                    # tree every step is exactly the per-step copy the flat
+                    # layout exists to kill (the model syncs at the cold seams)
+                    model.set_parameters(box["params"])
+                model.set_state(box["model_state"])
             if hm is not None:  # health stats ride the same one-step-late pull
                 return loss, outs[tail]
             return loss  # device array — _drive_loop pulls it one step later
@@ -1610,7 +1638,8 @@ class Optimizer:
         model.set_state(box["model_state"])
         return model
 
-    def _prefetch_batches(self, it, depth: int = 2, qsize=None, close=None):
+    def _prefetch_batches(self, it, depth: int = 2, qsize=None, close=None,
+                          turnover=None):
         """Host→device double-buffering (SURVEY.md §3.1 hot-loop notes).
 
         A background thread converts + ``device_put``s the next ``depth`` batches
@@ -1625,11 +1654,21 @@ class Optimizer:
         semantics) when it doesn't. Either way the jitted step sees ONE shape
         per fit and compiles exactly once.
 
-        Starvation observability: the worker times its wait for each batch
-        from the upstream iterator (``input_wait_s`` on the device batch —
-        host time the input pipeline failed to stay ahead) and samples the
-        pipeline's staging depth through ``qsize`` (a ``DataPipeline``
-        stream's ring gauge) — both land on the telemetry step record.
+        Starvation observability, one wait on each side of the ring. The
+        WORKER's wait is the ``dataset_next`` span around ``next(src)``: the
+        dataset layer's work for one batch, carried to the step record as
+        ``input_wait_s`` (the same pair of clock reads). It says how long a
+        batch took to make, not whether the step waited for it. The
+        DRIVER's wait is the ``ring_wait`` span around ``ring.get()``: time
+        the step itself waited for data, zero while the worker stays ahead.
+        The worker also samples the pipeline's staging depth through
+        ``qsize`` (a ``DataPipeline`` stream's ring gauge) and counts the
+        host bytes it hands to the placement seam (``h2d_bytes``); the
+        rest of its period is its block in ``ring.put``, which needs no
+        span. ``turnover`` is ``_drive_epochs``'s holder of the open
+        ``epoch_turnover`` span: it opens here when the ring reports the
+        epoch's end and closes here before the next epoch's first
+        ``ring.get()``, so that wait is ``ring_wait`` and not the boundary's.
 
         Shutdown is event-aware (``StagingRing``): when the consumer
         abandons the epoch (trigger, exception, retry), ``close()`` wakes a
@@ -1653,12 +1692,10 @@ class Optimizer:
             try:
                 src = iter(it)
                 while True:
-                    t_wait = time.perf_counter()
-                    try:
-                        batch = next(src)
-                    except StopIteration:
+                    with obs_span("dataset_next") as waited:
+                        batch = next(src, END)
+                    if batch is END:
                         break
-                    wait_s = time.perf_counter() - t_wait
                     qdepth = qsize() if qsize is not None else None
                     if ring.closed:
                         return
@@ -1697,6 +1734,12 @@ class Optimizer:
                                     )
                                 continue
                             batch, n = padded  # padded rows, real count n
+                        # counted only on an attached run, like the spans
+                        h2d = (
+                            _host_nbytes((batch.get_input(),
+                                          batch.get_target()))
+                            if span_collector is not None else None
+                        )
                         with obs_span("prefetch"):
                             if place is not None:
                                 # placement seam owns convert + sharding commit
@@ -1713,8 +1756,8 @@ class Optimizer:
                                 x, t = jax.device_put((x, t))
                     finally:
                         obs_trace.bind_context(prev_ctx)
-                    if not ring.put(_DeviceBatch(x, t, n, wait_s, qdepth,
-                                                 trace=ctx)):
+                    if not ring.put(_DeviceBatch(x, t, n, waited.s, qdepth,
+                                                 h2d, trace=ctx)):
                         return
                 ring.put(END)
             except BaseException as e:  # propagate into the training loop
@@ -1723,10 +1766,20 @@ class Optimizer:
         t = threading.Thread(target=worker, daemon=True)
         self._prefetch_thread = t  # shutdown-promptness introspection (tests)
         t.start()
+        if turnover is not None:
+            turnover.close()  # the boundary ends where this epoch's wait begins
         try:
             while True:
-                item = ring.get()
-                if item is END or item is RING_CLOSED:
+                with obs_span("ring_wait"):
+                    item = ring.get()
+                if item is END:
+                    if turnover is not None:
+                        # the epoch ran out: what follows, from this
+                        # generator's own teardown to the next epoch's first
+                        # get, is the boundary's cost
+                        turnover.enter_context(obs_span("epoch_turnover"))
+                    return
+                if item is RING_CLOSED:
                     return
                 if isinstance(item, BaseException):
                     raise item
@@ -1787,11 +1840,14 @@ class Optimizer:
         def flush(rec) -> None:
             """Pull a completed step's loss and emit log line + summaries."""
             (neval, epoch, iter_in_epoch, loss_arr, n, lr, dispatch_s,
-             health_arr, input_wait_s, input_qdepth) = rec
+             health_arr, input_wait_s, input_qdepth, h2d_bytes) = rec
             try:
                 # one-step-late pull: step i's scalar lands after step i+1 is
-                # queued — device-side faults from step i surface HERE
-                loss_f = float(loss_arr)  # lint: disable=BDL005 deliberate delayed host sync
+                # queued — device-side faults from step i surface HERE. The
+                # span holds the sync and nothing else: the host blocked on
+                # the device, the reading of "the device sets the pace"
+                with obs_span("loss_pull"):
+                    loss_f = float(loss_arr)  # lint: disable=BDL005 deliberate delayed host sync
             except Exception as e:
                 try:
                     # attribute the fault to the step that PRODUCED the loss;
@@ -1860,6 +1916,7 @@ class Optimizer:
                         dispatch_s=dispatch_s,
                         input_wait_s=input_wait_s,
                         input_qdepth=input_qdepth,
+                        h2d_bytes=h2d_bytes,
                         **(pa.step_fields(wall) if pa is not None else {}),
                     )
                     if pa is not None:
@@ -1966,9 +2023,15 @@ class Optimizer:
             watchdog.add_callback(self._on_watchdog_stall)
             self._stall_cb_watchdog = watchdog
         try:
-            self._drive_epochs(run_iteration, get_params, get_slots,
-                               get_model_state, state, stop, mark, flush,
-                               param_trigger, flatten_pytree, itertools)
+            # `turnover` holds the open `epoch_turnover` span between one
+            # epoch's last ring.get() and the next one's first; leaving the
+            # block closes a span the run ended or unwound under (the last
+            # epoch's closing lands in the run_end record's spans)
+            with contextlib.ExitStack() as turnover:
+                self._drive_epochs(run_iteration, get_params, get_slots,
+                                   get_model_state, state, stop, mark, flush,
+                                   param_trigger, flatten_pytree, itertools,
+                                   turnover)
         finally:
             # training may end (trigger, exception, retry) mid-trace-window:
             # an unstopped profiler never flushes and poisons the next start
@@ -1986,7 +2049,7 @@ class Optimizer:
 
     def _drive_epochs(self, run_iteration, get_params, get_slots,
                       get_model_state, state, stop, mark, flush,
-                      param_trigger, flatten_pytree, itertools):
+                      param_trigger, flatten_pytree, itertools, turnover):
         pending = None
         # dataset-cooperative poison skip: a dataset that advertises
         # supports_skip_positions (DataPipeline) receives the policy's
@@ -2025,7 +2088,8 @@ class Optimizer:
                 )
                 raw = itertools.islice(raw, max(0, n_yielded), None)
             state["_iter_in_epoch"] = skip
-            for batch in self._prefetch_batches(raw, qsize=qsize, close=close):
+            for batch in self._prefetch_batches(raw, qsize=qsize, close=close,
+                                                turnover=turnover):
                 pol = self._active_policy
                 if cooperative and pol is not None:
                     # quarantined slots were never produced by the dataset:
@@ -2107,21 +2171,20 @@ class Optimizer:
                         # may refuse while another capture holds the
                         # profiler; retried next step inside the window
                         profile["on"] = obs_perf.start_capture(profile["dir"])
-                # step boundaries for profiler traces; dispatch wall timed on
-                # host (async dispatch returns fast UNLESS this call compiled)
-                t_dispatch = time.perf_counter()
-                obs_trace.fault_point("dispatch")  # chaos seam (no span here)
+                # step boundaries for profiler traces; the span is the
+                # dispatch's one clock (async dispatch returns fast UNLESS
+                # this call compiled) and its chaos seam
                 with obs_trace.step_annotation(state["neval"]):
-                    res = run_iteration(batch, lr)  # dispatch; no sync
+                    with obs_span("dispatch") as dispatched:
+                        res = run_iteration(batch, lr)  # dispatch; no sync
                 # with health attached, run_iteration also hands back the
                 # step's in-graph stats pytree, pulled at the same
                 # one-step-late flush as the loss
                 loss_arr, health_arr = (
                     res if isinstance(res, tuple) else (res, None)
                 )
-                dispatch_s = time.perf_counter() - t_dispatch
+                dispatch_s = dispatched.s  # None on a detached run
                 if self.telemetry is not None:
-                    obs_trace.add_sample("dispatch", dispatch_s)
                     # close the chunk's causal chain: transform (pipeline
                     # worker) → place (prefetch worker) → dispatch (driver),
                     # carried here on the device batch (BDL022 seam)
@@ -2143,6 +2206,7 @@ class Optimizer:
                     health_arr,
                     getattr(batch, "input_wait_s", None),
                     getattr(batch, "input_qdepth", None),
+                    getattr(batch, "h2d_bytes", None),
                 )
                 if prev is not None:
                     flush(prev)  # overlaps with the step just dispatched

@@ -760,37 +760,44 @@ class DistriOptimizer(Optimizer):
             self._place_batch = None  # serialized baseline (see __init__)
 
         def run_iteration(batch, lr: float):
-            if self.async_placement:
-                x, t = batch.get_input(), batch.get_target()  # already placed
-            else:
-                with obs_span("place_batch"):  # on the DRIVER thread: this
-                    x = commit(batch.get_input())  # transfer serializes in
-                    t = commit(batch.get_target())  # front of the dispatch
-            args = (box["state"], box["model_state"], box["slots"])
-            if use_err:
-                args = args + (box["err"],)
-            args = args + (
-                x,
-                t,
-                jnp.asarray(lr, jnp.float32),
-                jnp.asarray(state["neval"]),
-                RandomGenerator.next_key(),
-            )
-            self._capture_step_specs(step_fn, args)
-            outs = step_fn(*args)
-            if use_err:
-                (box["state"], box["model_state"], box["slots"], box["err"],
-                 loss) = outs[:5]
-                tail = 5
-            else:
-                box["state"], box["model_state"], box["slots"], loss = outs[:4]
-                tail = 4
-            if not flat_mode:
-                # flat mode deliberately skips the per-step model sync: the
-                # tree materialization is exactly the params-sized copy the
-                # flat layout kills (cold seams go through get_params below)
-                model.set_parameters(box["state"])
-            model.set_state(box["model_state"])
+            # the same three spans as LocalOptimizer's run_iteration, under
+            # the drive loop's `dispatch`
+            with obs_span("step_args"):
+                if self.async_placement:
+                    x, t = batch.get_input(), batch.get_target()  # already placed
+                else:
+                    with obs_span("place_batch"):  # on the DRIVER thread: this
+                        x = commit(batch.get_input())  # transfer serializes in
+                        t = commit(batch.get_target())  # front of the dispatch
+                args = (box["state"], box["model_state"], box["slots"])
+                if use_err:
+                    args = args + (box["err"],)
+                args = args + (
+                    x,
+                    t,
+                    jnp.asarray(lr, jnp.float32),
+                    jnp.asarray(state["neval"]),
+                    RandomGenerator.next_key(),
+                )
+                self._capture_step_specs(step_fn, args)
+            with obs_span("step_call"):
+                outs = step_fn(*args)
+            with obs_span("model_sync"):
+                del args  # the inputs' array objects are freed inside the span
+                if use_err:
+                    (box["state"], box["model_state"], box["slots"],
+                     box["err"], loss) = outs[:5]
+                    tail = 5
+                else:
+                    (box["state"], box["model_state"], box["slots"],
+                     loss) = outs[:4]
+                    tail = 4
+                if not flat_mode:
+                    # flat mode deliberately skips the per-step model sync: the
+                    # tree materialization is exactly the params-sized copy the
+                    # flat layout kills (cold seams go through get_params below)
+                    model.set_parameters(box["state"])
+                model.set_state(box["model_state"])
             if hm is not None:  # health stats ride the same one-step-late pull
                 return loss, outs[tail]
             return loss  # device array — _drive_loop pulls it one step later

@@ -88,9 +88,10 @@ def test_placement_overlaps_dispatch_span_proof():
     # structural proof: WHERE the placement span ran
     async_spans = {k for s in s_async for k in s["spans"]}
     serial_spans = {k for s in s_serial for k in s["spans"]}
+    serialized = "dispatch/step_args/place_batch"  # inside the driver's seam
     assert "prefetch/place_batch" in async_spans  # nested = worker thread
-    assert "place_batch" not in async_spans       # nothing on the driver
-    assert "place_batch" in serial_spans          # driver thread = serialized
+    assert serialized not in async_spans          # nothing on the driver
+    assert serialized in serial_spans             # driver thread = serialized
     assert "prefetch/place_batch" not in serial_spans
 
     # timing proof: the gap in front of each dispatch shrank
@@ -171,3 +172,10 @@ def test_dispatch_gap_stats_unit():
     assert g["p50_s"] == 0.01          # worker placement NOT in the gap
     assert g["max_s"] == 0.06          # the dispatch span, not 0.06 + 0.05
     assert obs_report.dispatch_gap_stats([]) is None
+    # the same serialized commit as a stream names it since `dispatch` is a
+    # span: nested under the driver's seam, not under the worker's
+    nested = [{"wall_s": 0.1, "spans": {
+        "dispatch": {"n": 1, "s": 0.06},
+        "dispatch/step_args/place_batch": {"n": 1, "s": 0.05}}}]
+    g = obs_report.dispatch_gap_stats(nested)
+    assert (g["place_serialized_s"], g["place_overlapped_s"]) == (0.05, 0.0)

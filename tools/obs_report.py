@@ -378,11 +378,12 @@ def dispatch_gap_stats(steps: List[Dict]) -> Optional[Dict]:
     Per step, the *dispatch gap* is the DRIVER-thread seam time spent getting
     the next step enqueued — the ``dispatch`` span, which is timed around the
     whole ``run_iteration`` call and therefore ALREADY CONTAINS any sharding
-    commit that ran on the consumer thread (a top-level ``place_batch`` span
+    commit that ran on the consumer thread (``dispatch/step_args/place_batch``
+    — a bare ``place_batch`` in streams from before ``dispatch`` was a span —
     is a sub-interval of it, reported separately as ``place_serialized_s``,
     never added on top). Placement that ran in the prefetch worker instead
-    records as a NESTED ``*/place_batch`` span — it overlapped the in-flight
-    step's compute, is no part of the gap, and totals under
+    nests under the worker's span (``prefetch/place_batch``) — it overlapped
+    the in-flight step's compute, is no part of the gap, and totals under
     ``place_overlapped_s``. So "did the placement overlap dispatch" is
     answered by the span data alone: async placement moves seconds out of
     the gap and from ``place_serialized_s`` into ``place_overlapped_s``."""
@@ -393,10 +394,12 @@ def dispatch_gap_stats(steps: List[Dict]) -> Optional[Dict]:
         v = spans.get("dispatch")
         gaps.append(round(float(v["s"]), 6) if v else 0.0)
         for name, v in spans.items():
-            if name == "place_batch":
-                serialized += float(v["s"])
-            elif name.endswith("/place_batch"):
+            if name.rsplit("/", 1)[-1] != "place_batch":
+                continue
+            if name.startswith("prefetch/"):  # in the worker: overlapped
                 overlapped += float(v["s"])
+            else:  # on the driver, inside its dispatch seam
+                serialized += float(v["s"])
     if not gaps:
         return None
     gs = sorted(gaps)
