@@ -14,6 +14,7 @@ per-device sub-batches along the leading axis — the partition↔device 1:1 map
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,7 +39,14 @@ class Sample:
 class MiniBatch:
     """Batched features+labels (reference: ``MiniBatch``); ``slice`` mirrors the
     per-thread sub-batching the reference used for thread-level DP — here it shards
-    a global batch across mesh devices."""
+    a global batch across mesh devices.
+
+    ``host_lease`` is set by a dataset that assembled ``input`` in a buffer
+    it would take back (:class:`HostBufferLease`); it rides on the batch
+    object, so a stream that passes batches through passes it along. A stream
+    that keeps a batch beyond the next one it yields must not pass it on."""
+
+    host_lease: Optional["HostBufferLease"] = None
 
     def __init__(self, input, target=None):
         self.input = input
@@ -114,6 +122,86 @@ def pad_minibatch(batch: "MiniBatch", total: int):
         if t is None:
             return None
     return MiniBatch(x, t), n
+
+
+class HostBuffers:
+    """A dataset's free list of host batch buffers, all of one shape and dtype.
+
+    A batch-sized ``np.empty`` is a fresh mapping: the kernel faults in and
+    zeroes every 4 KB page of it the first time the gather writes there, which
+    costs several times the copy itself. A buffer that was written before
+    costs the copy alone. So :meth:`lease` takes from the list when it can,
+    and a buffer comes back ONLY through :meth:`HostBufferLease.hand_back`,
+    called by a consumer that knows nothing reads it any more (the prefetch
+    worker, once the host→device copy is done and no device array lives in
+    it). A consumer that never hands back gets a fresh array per batch, as
+    before. The list therefore never holds more than were in flight at once.
+
+    :meth:`clear` (the end of a run; a batch of another shape) also cuts off
+    every lease made before it, so a worker thread that outlives its run
+    cannot refill the list."""
+
+    # where a miss gets its memory; a test substitutes an allocator whose
+    # arrays the CPU client aliases
+    _allocate = staticmethod(np.empty)
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: List[np.ndarray] = []
+        self._key: Optional[Tuple] = None
+        self._round = 0
+
+    def __len__(self) -> int:
+        return len(self._free)
+
+    def __reduce__(self):
+        return (HostBuffers, ())  # a copy of the dataset starts with none
+
+    def lease(self, shape, dtype) -> "HostBufferLease":
+        key = (tuple(shape), np.dtype(dtype))
+        with self._lock:
+            if key != self._key:
+                self._key = key
+                self._drop()
+            buf = self._free.pop() if self._free else None
+            rnd = self._round
+        reused = buf is not None
+        if buf is None:
+            buf = self._allocate(key[0], key[1])
+        return HostBufferLease(self, buf, reused, rnd)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._drop()
+
+    def _drop(self) -> None:  # under the lock
+        self._free.clear()
+        self._round += 1
+
+    def _give(self, buf: np.ndarray, rnd: int) -> None:
+        with self._lock:
+            if rnd == self._round:
+                self._free.append(buf)
+
+
+class HostBufferLease:
+    """One batch's hold on a buffer of a :class:`HostBuffers` list.
+    ``reused`` says whether the buffer came from the list (warm) or from
+    ``np.empty`` (a miss)."""
+
+    __slots__ = ("buffer", "reused", "pool", "_round")
+
+    def __init__(self, pool: HostBuffers, buffer: np.ndarray, reused: bool,
+                 rnd: int):
+        self.pool, self.buffer, self.reused, self._round = (
+            pool, buffer, reused, rnd)
+
+    def hand_back(self) -> None:
+        """The caller vouches that nothing reads ``buffer`` or lives in its
+        memory any more. Only the first call returns it."""
+        buf, self.buffer = self.buffer, None
+        if buf is not None:
+            self.pool._give(buf, self._round)
 
 
 class Transformer:
@@ -227,6 +315,9 @@ class LocalArrayDataSet(AbstractDataSet):
         self.transformer = transformer
         self.batch_size = batch_size
         self._order = np.arange(len(self.features))
+        # lives with the dataset, not with an epoch's data() call: a list per
+        # epoch would start every epoch on cold buffers
+        self._host_buffers = HostBuffers()
 
     def size(self) -> int:
         return len(self.features)
@@ -249,7 +340,8 @@ class LocalArrayDataSet(AbstractDataSet):
         if self.transformer is None and isinstance(self.features, np.ndarray):
             # fast path: assemble whole minibatches with one (native-threaded
             # when built — see bigdl_tpu.native) row gather per batch instead
-            # of per-sample stacking
+            # of per-sample stacking, into a buffer a consumer handed back
+            # when there is one (HostBuffers)
             from ..native import gather_rows
 
             bs = self.batch_size
@@ -258,9 +350,13 @@ class LocalArrayDataSet(AbstractDataSet):
                 idx = self._order[start:start + bs]
                 if train and len(idx) < bs:
                     break  # reference drops ragged train batches
-                x = gather_rows(self.features, idx)
+                lease = self._host_buffers.lease(
+                    (len(idx),) + self.features.shape[1:], self.features.dtype)
+                x = gather_rows(self.features, idx, out=lease.buffer)
                 t = None if self.labels is None else self.labels[idx]
-                yield MiniBatch(x, t)
+                batch = MiniBatch(x, t)
+                batch.host_lease = lease
+                yield batch
             return
         it: Iterator = self._samples()
         t = self.transformer

@@ -117,17 +117,34 @@ def u8hwc_to_f32chw(batch: np.ndarray, mean, std) -> np.ndarray:
 _GATHER_NATIVE_MIN_BYTES = 1 << 20
 
 
-def gather_rows(src: np.ndarray, indices: np.ndarray) -> np.ndarray:
+def gather_rows(src: np.ndarray, indices: np.ndarray,
+                out: Optional[np.ndarray] = None) -> np.ndarray:
     """dst[i] = src[indices[i]] over the leading axis (minibatch assembly).
 
     Native (threaded) only for float32 contiguous sources with enough bytes of
     work to amortize the thread pool; numpy fancy indexing otherwise.
+
+    ``out`` (C-contiguous, writable, of the result's shape and ``src``'s
+    dtype) is written in place of a fresh array and returned: a buffer the
+    process has written before costs no page faults, which a fresh one of
+    batch size pays on every 4 KB page.
     """
     indices = np.ascontiguousarray(np.asarray(indices, np.int64))
     # validate BEFORE choosing a path: the numpy fallback would otherwise
     # silently wrap negative indices while the native branch raises
     if indices.size and (indices.min() < 0 or indices.max() >= src.shape[0]):
         raise IndexError("gather index out of range")
+    shape = (len(indices),) + src.shape[1:]
+    if out is not None and (
+        not isinstance(out, np.ndarray)
+        or out.shape != shape
+        or out.dtype != src.dtype
+        or not out.flags["C_CONTIGUOUS"]
+        or not out.flags["WRITEABLE"]
+    ):
+        raise ValueError(
+            f"out must be a writable C-contiguous {src.dtype} array of shape "
+            f"{shape}")
     row_len = int(np.prod(src.shape[1:], dtype=np.int64))
     work_bytes = len(indices) * row_len * 4
     lib = _load()
@@ -137,8 +154,12 @@ def gather_rows(src: np.ndarray, indices: np.ndarray) -> np.ndarray:
         or not src.flags["C_CONTIGUOUS"]
         or work_bytes < _GATHER_NATIVE_MIN_BYTES
     ):
-        return np.ascontiguousarray(src[indices])
-    dst = np.empty((len(indices),) + src.shape[1:], np.float32)
+        if out is None:
+            return np.ascontiguousarray(src[indices])
+        # bounds were checked above; "clip" only spares numpy the bounce
+        # buffer its default mode gathers into before copying to ``out``
+        return np.take(src, indices, axis=0, out=out, mode="clip")
+    dst = np.empty(shape, np.float32) if out is None else out
     lib.bigdl_gather_f32(
         src.ctypes.data, indices.ctypes.data, dst.ctypes.data,
         len(indices), row_len,

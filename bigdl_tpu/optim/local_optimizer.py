@@ -14,6 +14,7 @@ semantics are preserved exactly:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import math
@@ -61,6 +62,32 @@ def _host_nbytes(tree) -> int:
     )
 
 
+def _host_buffer_free(placed, buf: np.ndarray) -> Optional[bool]:
+    """May the host array ``buf`` be written again, now that it went through
+    the placement seam as the device leaves ``placed``? Decided from what the
+    leaves themselves show, not from the platform's name:
+
+    - ``None``: not yet. A leaf is not ready, so its host→device copy may
+      still be reading ``buf`` (the TPU client copies after the call returns).
+    - ``False``: never. A leaf's memory lies inside ``buf`` (the CPU client
+      does not copy a 64-byte-aligned numpy argument, it adopts it), or a leaf
+      cannot be asked (a placement seam that returns something other than
+      device arrays: it may be ``buf`` itself).
+    - ``True``: every leaf is ready and lives elsewhere."""
+    for leaf in placed:
+        if not hasattr(leaf, "is_ready"):
+            return False
+        if not leaf.is_ready():
+            return None
+    lo = buf.ctypes.data
+    hi = lo + buf.nbytes
+    for leaf in placed:
+        for shard in leaf.addressable_shards:
+            if lo <= shard.data.unsafe_buffer_pointer() < hi:
+                return False
+    return True
+
+
 class _DeviceBatch:
     """A MiniBatch whose arrays already live on device (built by the
     prefetcher). ``input_wait_s`` is the prefetch worker's wait for THIS
@@ -68,22 +95,27 @@ class _DeviceBatch:
     signal); ``input_qdepth`` the pipeline staging-ring depth right after
     the pull (None when the upstream exposes no ring); ``h2d_bytes`` the
     bytes of its host leaves, which crossed host→device at the placement
-    seam (None on a detached run, which counts nothing). ``trace`` is the
+    seam (None on a detached run, which counts nothing);
+    ``host_buf_reused`` 1 when the dataset assembled it in a buffer handed
+    back earlier, 0 when in fresh memory (None on a detached run and from a
+    dataset that recycles nothing). ``trace`` is the
     batch's causal :class:`~bigdl_tpu.obs.trace.TraceContext` — the
     sanctioned carrier across the prefetch→driver thread seam (BDL022), so
     the driver's dispatch span chains onto the chunk's transform/place
     spans."""
 
     __slots__ = ("_x", "_t", "_n", "input_wait_s", "input_qdepth",
-                 "h2d_bytes", "trace")
+                 "h2d_bytes", "host_buf_reused", "trace")
 
     def __init__(self, x, t, n: int, input_wait_s: Optional[float] = 0.0,
                  input_qdepth: Optional[int] = None,
-                 h2d_bytes: Optional[int] = None, trace=None):
+                 h2d_bytes: Optional[int] = None,
+                 host_buf_reused: Optional[int] = None, trace=None):
         self._x, self._t, self._n = x, t, n
         self.input_wait_s = input_wait_s
         self.input_qdepth = input_qdepth
         self.h2d_bytes = h2d_bytes
+        self.host_buf_reused = host_buf_reused
         self.trace = trace
 
     def get_input(self):
@@ -243,6 +275,7 @@ class Optimizer:
         self._compiles_fn = None  # jit fn the compile watermark belongs to
         self._step_cache = None  # (method, n_micro, jitted step) across retries
         self._prefetch_thread = None  # live prefetch worker (tests/shutdown)
+        self._host_buffers = None  # the dataset's HostBuffers, once seen
         # FlatParameter codecs keyed by n_shards — kept across retries AND
         # elastic remeshes, so a rejoin back to a previously-seen mesh
         # configuration reuses its codec (and the jitted programs below)
@@ -1670,6 +1703,26 @@ class Optimizer:
         epoch's end and closes here before the next epoch's first
         ``ring.get()``, so that wait is ``ring_wait`` and not the boundary's.
 
+        Who owns a host batch buffer, when. A batch that carries a
+        ``host_lease`` (``LocalArrayDataSet``'s fast path; see
+        ``dataset.HostBuffers``) was gathered into a buffer its dataset
+        would take back. From ``next(src)`` on the buffer is the worker's:
+        it goes through the placement seam, then waits in a local queue
+        beside the device leaves made from it. At the top of each turn,
+        before ``next(src)``, the worker hands back, oldest first, every
+        buffer that ``_host_buffer_free`` finds free: each leaf ready (the
+        host→device copy is done) and none living inside the buffer (no
+        alias). From the hand-back on it is the dataset's, which gathers the
+        next batch into it. The worker never waits for this: a buffer whose
+        copy is still running stays a turn, and the dataset allocates fresh
+        meanwhile (a miss: ``host_buf_reused`` 0 in that batch's step
+        record). An aliased buffer is never handed back and lives as long as
+        its device array, as every buffer did before. A batch that was
+        padded (the pad is a copy) or dropped is free at once. On early exit
+        the queue is dropped with the thread; ``_drive_loop`` empties the
+        dataset's list when the run ends. A batch without a lease takes none
+        of these steps.
+
         Shutdown is event-aware (``StagingRing``): when the consumer
         abandons the epoch (trigger, exception, retry), ``close()`` wakes a
         blocked worker immediately and drops the buffered device batches, so
@@ -1687,11 +1740,25 @@ class Optimizer:
         # are thread-bound so concurrent runs cannot cross-steal samples)
         span_collector = obs_trace.current_collector()
 
+        # (device leaves, lease) of the batches whose host buffer may still
+        # be read by its copy, oldest first; the worker's own. Bounded by
+        # what can be in flight (the ring, the batch in put, the one being
+        # placed): an entry pushed out is a buffer nobody hands back
+        inflight = collections.deque(maxlen=depth + 2)
+
         def worker():
             obs_trace.bind_collector(span_collector)
             try:
                 src = iter(it)
                 while True:
+                    while inflight:
+                        leaves, held = inflight[0]
+                        free = _host_buffer_free(leaves, held.buffer)
+                        if free is None:
+                            break  # still copying: it waits a turn
+                        inflight.popleft()
+                        if free:
+                            held.hand_back()
                     with obs_span("dataset_next") as waited:
                         batch = next(src, END)
                     if batch is END:
@@ -1707,6 +1774,9 @@ class Optimizer:
                     if ctx is None:
                         ctx = getattr(it, "last_context", None)
                     prev_ctx = obs_trace.bind_context(ctx)
+                    lease = getattr(batch, "host_lease", None)
+                    if lease is not None:
+                        self._host_buffers = lease.pool
                     try:
                         n = batch.size()
                         if policy == "pass":
@@ -1720,6 +1790,8 @@ class Optimizer:
                                     if policy == "pad"
                                     else None
                                 )
+                            if lease is not None:
+                                lease.hand_back()  # padded copy or dropped
                             if padded is None:
                                 if not getattr(self, "_warned_ragged_drop", False):
                                     self._warned_ragged_drop = True
@@ -1740,6 +1812,11 @@ class Optimizer:
                                           batch.get_target()))
                             if span_collector is not None else None
                         )
+                        reused = (
+                            int(lease.reused)
+                            if lease is not None
+                            and span_collector is not None else None
+                        )
                         with obs_span("prefetch"):
                             if place is not None:
                                 # placement seam owns convert + sharding commit
@@ -1756,8 +1833,11 @@ class Optimizer:
                                 x, t = jax.device_put((x, t))
                     finally:
                         obs_trace.bind_context(prev_ctx)
+                    if lease is not None and lease.buffer is not None:
+                        inflight.append(
+                            (jax.tree_util.tree_leaves((x, t)), lease))
                     if not ring.put(_DeviceBatch(x, t, n, waited.s, qdepth,
-                                                 h2d, trace=ctx)):
+                                                 h2d, reused, trace=ctx)):
                         return
                 ring.put(END)
             except BaseException as e:  # propagate into the training loop
@@ -1840,7 +1920,8 @@ class Optimizer:
         def flush(rec) -> None:
             """Pull a completed step's loss and emit log line + summaries."""
             (neval, epoch, iter_in_epoch, loss_arr, n, lr, dispatch_s,
-             health_arr, input_wait_s, input_qdepth, h2d_bytes) = rec
+             health_arr, input_wait_s, input_qdepth, h2d_bytes,
+             host_buf_reused) = rec
             try:
                 # one-step-late pull: step i's scalar lands after step i+1 is
                 # queued — device-side faults from step i surface HERE. The
@@ -1917,6 +1998,7 @@ class Optimizer:
                         input_wait_s=input_wait_s,
                         input_qdepth=input_qdepth,
                         h2d_bytes=h2d_bytes,
+                        host_buf_reused=host_buf_reused,
                         **(pa.step_fields(wall) if pa is not None else {}),
                     )
                     if pa is not None:
@@ -2041,6 +2123,11 @@ class Optimizer:
 
                 obs_perf.stop_capture()
                 self._profile = None
+            # the run is over (or unwinding into a retry): the dataset's
+            # free host buffers go, and leases still out give nothing back
+            pool, self._host_buffers = self._host_buffers, None
+            if pool is not None:
+                pool.clear()
             if pa is not None:
                 pa.end_run()  # a breach capture still open flushes here
             if tel is not None:
@@ -2207,6 +2294,7 @@ class Optimizer:
                     getattr(batch, "input_wait_s", None),
                     getattr(batch, "input_qdepth", None),
                     getattr(batch, "h2d_bytes", None),
+                    getattr(batch, "host_buf_reused", None),
                 )
                 if prev is not None:
                     flush(prev)  # overlaps with the step just dispatched

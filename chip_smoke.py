@@ -14,6 +14,11 @@ user calls, at the full width of the model the north star names (ResNet-50,
   E  serving   ModelServer over phase B's model: 32 concurrent single-record
                requests, bit-equal to the serial Predictor, zero compiles
                after warm-up
+  F  readback  the prefetch seam with recycled host batch buffers: every
+               device batch, pulled back after later batches were gathered,
+               is bitwise the rows the epoch's order names; on one chip
+               (device_put) and over every local chip (DistriOptimizer's
+               place_pair)
 
 Nothing is caught and carried past: any failed phase raises and the exit code
 is non-zero. On success the last stdout line is one JSON object
@@ -33,6 +38,7 @@ the full width and always demands the chip.
 from __future__ import annotations
 
 import argparse
+import collections
 import importlib.metadata
 import json
 import os
@@ -516,6 +522,117 @@ def phase_serving(model, image=224, batch=8, requests=32, clients=4) -> None:
         f"/ {info['warmup_compiles']} compile(s), 0 compiles after)")
 
 
+# ------------------------------------------------------------- F: readback
+def _readback(opt, ds, base, batch, epochs, hold, step_s, what) -> float:
+    """Drive ``opt._prefetch_batches`` over ``ds`` as the epoch loop does, a
+    pull every ``step_s`` seconds, keep the last ``hold`` device batches, and
+    compare each, as it leaves that window, bitwise with the rows the epoch's
+    order names. By then its host buffer has been handed back and gathered
+    into again, so a buffer reused under a running copy, or under a device
+    array that lives in it, shows as another batch's rows. Returns the share
+    of batches gathered into a handed-back buffer."""
+    x, y = base.features, base.labels
+    reused, held, compared = [], collections.deque(), 0
+
+    def noted(stream):
+        for b in stream:
+            reused.append(b.host_lease.reused)
+            yield b
+
+    def check(dev, rows, at):
+        got_x, got_y = np.asarray(dev.get_input()), np.asarray(dev.get_target())
+        if not (np.array_equal(got_x.view(np.uint32), x[rows].view(np.uint32))
+                and np.array_equal(got_y, y[rows])):
+            wrong = np.flatnonzero((got_x != x[rows]).any(axis=(1, 2, 3)))
+            raise AssertionError(
+                f"{what}: device batch {at} is not the rows its epoch's "
+                f"order names ({len(wrong)} of {len(rows)} rows differ, "
+                f"first {wrong[:4]})")
+
+    def check_down_to(n):
+        nonlocal compared
+        while len(held) > n:
+            check(*held.popleft())
+            compared += 1
+
+    for epoch in range(1, epochs + 1):
+        ds.shuffle(epoch)
+        order = base._order.copy()
+        batches = opt._prefetch_batches(noted(ds.data(train=True)))
+        for i, dev in enumerate(batches):
+            time.sleep(step_s)  # the device step the worker runs ahead of
+            held.append((dev, order[i * batch:(i + 1) * batch], (epoch, i)))
+            check_down_to(hold)
+    check_down_to(0)
+    base._host_buffers.clear()  # the run is over
+    if compared != len(reused) or compared != epochs * (len(x) // batch):
+        raise AssertionError(f"{what}: compared {compared} batches of "
+                             f"{len(reused)} gathered")
+    share = sum(reused) / len(reused)
+    if on_tpu() and share < 0.5:
+        raise AssertionError(
+            f"{what}: only {sum(reused)} of {len(reused)} batches were "
+            f"gathered into a handed-back buffer: recycling is not engaged")
+    return share
+
+
+def phase_readback(image=224, batch_per_chip=256, batches=8, epochs=3,
+                   hold=3, step_s=0.05):
+    """Recycled host batch buffers never change what reaches the device.
+    Distinct float32 records (one random image plus the record's number)
+    through the two placement seams the trainers use."""
+    import jax
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.optim import SGD, LocalOptimizer, Trigger
+    from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
+    from bigdl_tpu.utils.engine import Engine
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    def records(batch):
+        n = batch * batches
+        one = np.random.default_rng(0).standard_normal(
+            (3, image, image)).astype(np.float32)
+        x = np.empty((n, 3, image, image), np.float32)
+        np.add(one[None], np.arange(n, dtype=np.float32)[:, None, None, None],
+               out=x)
+        return DataSet.array(x, np.arange(n) % 8, batch_size=batch)
+
+    def model():
+        return nn.Sequential(nn.Flatten(), nn.Linear(3 * image * image, 8),
+                             nn.LogSoftMax())
+
+    RandomGenerator.set_seed(1)
+    base = records(batch_per_chip)
+    opt = LocalOptimizer(model(), base, nn.ClassNLLCriterion())
+    one = _readback(opt, base, base, batch_per_chip, epochs, hold, step_s,
+                    "phase F, device_put")
+
+    devices = jax.local_devices()
+    n = len(devices)
+    Engine.init(devices=devices)
+    batch = batch_per_chip * n
+    base = records(batch)
+    ds = DataSet.distributed(base, n)
+    opt = DistriOptimizer(model(), ds, nn.ClassNLLCriterion(),
+                          parameter_sync="sharded")
+    opt.set_optim_method(SGD(learningrate=0.01))
+    opt.set_end_when(Trigger.max_iteration(2))
+    opt.optimize()  # two steps of a small model: builds the mesh's place_pair
+    if opt._place_batch is None or len(base._host_buffers) != 0:
+        raise AssertionError(
+            f"phase F: after optimize() place_pair is {opt._place_batch} and "
+            f"the dataset holds {len(base._host_buffers)} free buffers")
+    many = _readback(opt, ds, base, batch, epochs, hold, step_s * 3,
+                     "phase F, place_pair")
+    total = epochs * batches
+    log(f"phase F readback: ok ({total} batches of {batch_per_chip}x3x{image}"
+        f"x{image} f32 bitwise equal after {hold} later pulls on 1 device, "
+        f"host_buf_reused {one:.3f}; {total} batches of {batch} through "
+        f"place_pair on {n} device(s), host_buf_reused {many:.3f})")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--expect-cache-hit", action="store_true",
@@ -529,6 +646,7 @@ def main() -> None:
     phase_distri()
     phase_kernels()
     phase_serving(model)
+    phase_readback()
     os.makedirs(os.path.dirname(LAST_RUN), exist_ok=True)
     with open(LAST_RUN, "w") as f:
         json.dump(compile_rec, f)

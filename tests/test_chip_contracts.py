@@ -60,7 +60,8 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
     """The phases after the device check, end to end on the virtual CPU mesh:
     the control flow the chip run takes (optimize() with telemetry, ZeRO-1
     over every device, every kernel against its reference in interpret mode,
-    concurrent serving bit-equal to the serial Predictor).
+    concurrent serving bit-equal to the serial Predictor, the bitwise
+    read-back of device batches made from recycled host buffers).
 
         JAX_PLATFORMS=cpu python -m pytest tests/test_chip_contracts.py -m slow
     """
@@ -82,6 +83,8 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
         # Predictor shards its batch across them — as on a four-chip host
         chip_smoke.phase_serving(model, image=32, batch=8, requests=8,
                                  clients=2)
+        chip_smoke.phase_readback(image=16, batch_per_chip=8, batches=4,
+                                  epochs=3, hold=2, step_s=0.005)
     finally:
         Engine.reset()
         Engine.set_compute_dtype(prev[0])
