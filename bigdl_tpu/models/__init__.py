@@ -8,6 +8,7 @@ from .alexnet import AlexNet
 from .textclassifier import BiLSTMClassifier, CNNTextClassifier, PTBModel
 from .widedeep import WideAndDeep
 from .ncf import NeuralCF
+from . import decoder_lm  # decoder_lm.from_config(dict) -> nn.DecoderLM
 
 def flagship_model(batch: int = 8, seed: int = 0, stem: str = "conv7"):
     """The framework's flagship benchmark config (single source of truth for
@@ -38,4 +39,5 @@ __all__ = [
     "PTBModel",
     "WideAndDeep",
     "NeuralCF",
+    "decoder_lm",
 ]

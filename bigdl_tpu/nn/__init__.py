@@ -174,6 +174,7 @@ from .criterion import (
     ParallelCriterion,
     MultiCriterion,
     TimeDistributedCriterion,
+    TokenCrossEntropyCriterion,
     MarginCriterion,
     MultiLabelMarginCriterion,
     DiceCoefficientCriterion,
@@ -190,7 +191,13 @@ from .attention import (
     padding_attention_bias,
     get_position_encoding,
 )
-from .moe import MoE
+from .moe import MoE, RoutedExperts
+from .decoder import (
+    DecoderBlock,
+    DecoderLM,
+    GroupedQueryAttention,
+    LMHead,
+)
 from .pipelined import PipelinedBlocks
 from .remat import Remat
 from .quantized import (

@@ -127,8 +127,15 @@ def scaled_dot_product_attention(
     causal: bool = False,
     lengths: Optional[jax.Array] = None,
     mask_q: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """softmax(q k^T / sqrt(d) + bias) v over (..., T, d) operands.
+
+    ``window`` (with ``causal``) is sliding-window attention: query i sees
+    key j iff ``j <= i`` and ``i - j < window``. 4-D ``k``/``v`` may carry
+    fewer heads than ``q`` (grouped-query attention: query head h reads K/V
+    head ``h // (Hq / Hkv)``); the flash kernel takes both as they are, the
+    dense path repeats K/V. The ring path has neither.
 
     ``impl='flash'`` routes 4-D operands through the Pallas flash kernel
     (``bigdl_tpu.ops.flash_attention``) when the pattern it supports applies
@@ -155,6 +162,10 @@ def scaled_dot_product_attention(
     """
     if mask_q is None:
         mask_q = q.shape[-2] == k.shape[-2]
+    if window is not None and not causal:
+        raise ValueError("scaled_dot_product_attention: a window needs "
+                         "causal=True")
+    grouped = q.ndim == 4 and k.shape[1] != q.shape[1]
     eligible = (
         bias is None
         and dropout_p == 0.0
@@ -172,6 +183,10 @@ def scaled_dot_product_attention(
     from ..utils.engine import Engine
 
     sp = Engine.sequence_parallel()
+    if (window is not None or grouped) and (
+            impl == "ring" or (impl == "auto" and sp is not None)):
+        raise ValueError("ring attention has neither a window nor grouped "
+                         "K/V heads (parallel/sequence.py)")
     if impl in ("auto", "ring") and sp is not None:
         mesh, axis = sp
         n_sp = mesh.shape[axis]
@@ -218,8 +233,12 @@ def scaled_dot_product_attention(
             causal,
             lengths=lengths,
             mask_q=mask_q,
+            window=window,
         )
         return out.astype(q.dtype)
+    if grouped:
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     tq, tk = q.shape[-2], k.shape[-2]
     if lengths is not None:
         # dense fallback reproduces the kernel's semantics: key mask as an
@@ -235,7 +254,10 @@ def scaled_dot_product_attention(
     if causal:
         rows = jnp.arange(tq)[:, None] + (tk - tq)
         cols = jnp.arange(tk)[None, :]
-        causal_bias = jnp.where(rows >= cols, 0.0, NEG_INF)
+        seen = rows >= cols
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        causal_bias = jnp.where(seen, 0.0, NEG_INF)
         bias = causal_bias if bias is None else bias + causal_bias
     depth = q.shape[-1]
     logits = precision.einsum("...qd,...kd->...qk", q, k) / jnp.sqrt(
@@ -450,21 +472,33 @@ def _block_params(rng, hidden_size: int, num_heads: int, filter_size: int,
     return p
 
 
-def apply_rotary(x: jax.Array, positions: jax.Array) -> jax.Array:
+def apply_rotary(x: jax.Array, positions: jax.Array,
+                 inv_freq: Optional[jax.Array] = None,
+                 factor: Optional[float] = None) -> jax.Array:
     """Rotary position embedding (RoPE, Su et al. 2021) over the last dim.
 
     ``x`` (..., T, d) with d even; ``positions`` (T,) absolute positions.
     Rotates feature pairs (i, i+d/2) by ``positions * 10000^{-2i/d}`` —
     norm-preserving, and q·k after rotation depends only on the RELATIVE
     position (the property the tests pin). Beyond reference (the
-    reference's transformer uses the TF-official sinusoidal table)."""
+    reference's transformer uses the TF-official sinusoidal table).
+
+    ``inv_freq`` (d/2,) replaces the built-in frequencies (another base, or
+    YaRN's blended ones: ``nn.decoder.rope_inv_freq``) and ``factor``
+    multiplies cos and sin (YaRN's attention factor); without them the
+    result is what it always was, bit for bit."""
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rotary needs an even feature dim, got {d}")
     half = d // 2
-    freqs = 10000.0 ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = 10000.0 ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (T, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)

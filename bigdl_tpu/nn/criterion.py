@@ -180,6 +180,46 @@ class CrossEntropyCriterion(AbstractCriterion):
         return (1.0 - eps) * nll + eps * uniform
 
 
+@jax.custom_vjp
+def _token_cross_entropy(logits, target):
+    """Mean over all positions of ``logsumexp(logits) - logits[target]``,
+    float32. The gradient is rebuilt from the logits and their logsumexp in
+    one elementwise pass (``(softmax - onehot) / positions``), so that no
+    second full-size float32 tensor (the log-probabilities, or their
+    cotangent) is kept between the two passes."""
+    return _token_ce_fwd(logits, target)[0]
+
+
+def _token_ce_fwd(logits, target):
+    logits = precision.to_float(logits)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, target[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - picked), (logits, lse, target)
+
+
+def _token_ce_bwd(res, g):
+    logits, lse, target = res
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    d = jnp.exp(logits - lse[..., None]) - (classes == target[..., None])
+    return d * (g / lse.size), None
+
+
+_token_cross_entropy.defvjp(_token_ce_fwd, _token_ce_bwd)
+
+
+class TokenCrossEntropyCriterion(AbstractCriterion):
+    """Token-level cross-entropy of a language model: logits ``(N, T, V)``
+    against zero-based int targets ``(N, T)``, mean over all ``N * T``
+    positions, softmax statistics in float32 (see ``_token_cross_entropy``).
+    Beyond reference: BigDL spells this ``TimeDistributedCriterion(
+    CrossEntropyCriterion)``, which holds the log-probabilities as well."""
+
+    def _apply(self, input, target):
+        with jax.named_scope("lm_head"):
+            return _token_cross_entropy(
+                input, jnp.asarray(target).astype(jnp.int32))
+
+
 class MSECriterion(AbstractCriterion):
     def __init__(self, size_average: bool = True):
         super().__init__()

@@ -435,6 +435,34 @@ class AbstractModule:
         walk(state)
         return total
 
+    def counters_tree(self, state) -> Dict[str, Any]:
+        """Counters a forward pass stashed in the state pytree under
+        ``'_counters'`` keys (``{name: scalar}``, e.g. the routed experts'
+        ``moe_pairs_local``), reduced over the modules that wrote them: a name
+        with ``max`` in it by maximum, any other by sum. The standard train
+        step hands them out as one more output, pulled with the one-step-late
+        loss and written into the telemetry step record under their names
+        (docs/observability.md); ``{}`` for a model that keeps none."""
+        found: Dict[str, Any] = {}
+
+        def walk(s):
+            if not isinstance(s, dict):
+                return
+            for k, v in s.items():
+                if k != "_counters":
+                    walk(v)
+                    continue
+                for name, value in v.items():
+                    if name not in found:
+                        found[name] = value
+                    elif "max" in name:
+                        found[name] = jnp.maximum(found[name], value)
+                    else:
+                        found[name] = found[name] + value
+
+        walk(state)
+        return found
+
     # -------------------------------------------------------------- inference
     def predict(self, data, batch_size: Optional[int] = None):
         """Batched forward over a DataSet / array / list of Samples, reusing one

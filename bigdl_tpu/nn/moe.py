@@ -37,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils import precision
 from .initialization import Xavier
 from .module import AbstractModule
 
@@ -254,3 +255,249 @@ class MoE(AbstractModule):
 
         ys = jax.vmap(gather)(back, expert_id, slot, keep, w)
         return ys.reshape(b, d)
+
+
+# --------------------------------------------------------------------------
+# routed experts without capacity: the chip's share of an expert-parallel
+# layer (top-k softmax router, gated experts, grouped matrix products)
+# --------------------------------------------------------------------------
+
+def _megablox():
+    """JAX's grouped-matmul kernels for the TPU. The package rebinds the name
+    ``gmm`` to its differentiable wrapper, so the module is fetched by path."""
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _tile(size: int, most: int = 1024) -> int:
+    """Largest multiple of 128 that divides ``size`` and is at most ``most``
+    (``size`` itself where none does)."""
+    fits = [t for t in range(128, min(size, most) + 1, 128) if size % t == 0]
+    return fits[-1] if fits else size
+
+
+def _tiling(k: int, n: int):
+    """(rows, contracted, columns) tile of the TPU grouped kernel. Its own
+    default of 128 each ran the expert products at 10 TFLOP/s on a v5e
+    (12.7 ms for 32768 rows of 2304 -> 896, PR 28 chip probe); 512 rows by
+    the widest divisors up to 1024 ran them in 1.24 ms, 109 TFLOP/s, and
+    1024 rows did not fit the kernel's fast memory."""
+    return 512, _tile(k), _tile(n)
+
+
+def _grouped_impl(lhs, rhs, group_sizes, transpose_rhs=False):
+    """Rows of ``lhs`` (M, K), sorted by group, times their group's matrix
+    ``rhs`` (G, K, N) (or (G, N, K) with ``transpose_rhs``): float32 out.
+    Rows past the groups' total are not computed: zero from
+    ``jax.lax.ragged_dot``, UNWRITTEN memory from the TPU kernel, so callers
+    select the rows they use (``_local_rows``) and never multiply the rest."""
+    if jax.default_backend() == "tpu":
+        n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+        return _megablox().gmm(lhs, rhs, group_sizes, jnp.float32,
+                               _tiling(lhs.shape[1], n),
+                               transpose_rhs=transpose_rhs)
+    if transpose_rhs:
+        rhs = rhs.swapaxes(1, 2)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_transposed(lhs, g, group_sizes, out_dtype):
+    """Per group, ``lhs_rows^T (K, m_g) @ g_rows (m_g, N)`` -> (G, K, N)."""
+    n_groups = group_sizes.shape[0]
+    if jax.default_backend() == "tpu":
+        return _megablox().tgmm(lhs.swapaxes(0, 1), g, group_sizes, out_dtype,
+                                _tiling(lhs.shape[1], g.shape[1]),
+                                num_actual_groups=n_groups)
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return jax.lax.ragged_dot_general(
+        lhs, g, group_sizes, dims,
+        preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+@jax.custom_vjp
+def grouped_dot(lhs, rhs, group_sizes):
+    """Grouped matrix product over the experts held: operands in the compute
+    dtype, float32 accumulation and result, forward and backward (see
+    ``precision.dot_acc32``). On the TPU the grouped kernel that ships with
+    JAX (megablox) visits the tiles of the groups' rows only; elsewhere
+    ``jax.lax.ragged_dot``."""
+    dt = precision.compute_dtype()
+    return _grouped_impl(lhs.astype(dt), rhs.astype(dt), group_sizes)
+
+
+def _grouped_dot_fwd(lhs, rhs, group_sizes):
+    return grouped_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_dot_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    dt = precision.compute_dtype()
+    g = g.astype(dt)
+    d_lhs = _grouped_impl(g, rhs.astype(dt), group_sizes, transpose_rhs=True)
+    d_rhs = _grouped_transposed(lhs.astype(dt), g, group_sizes, jnp.float32)
+    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+
+
+grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def _local_rows(rows, pos, local):
+    """``rows[pos]`` where the sorted row is one of the ``local`` rows the
+    grouped products computed, zero elsewhere: a select, never a product, so
+    that what the kernel left unwritten there cannot reach a result."""
+    return jnp.where((pos < local)[:, None], rows[pos], 0)
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, order, pos, local, k):
+    """``x[order // k]``: the token of each sorted (token, choice) pair.
+    ``pos`` is the inverse permutation of ``order``, so the gradient is a
+    gather too (each token's k sorted rows, summed), not a scatter-add."""
+    return x[order // k]
+
+
+def _rows_fwd(x, order, pos, local, k):
+    return x[order // k], (pos, local, k, x.shape[0])
+
+
+def _rows_bwd(res, g):
+    pos, local, k, t = res
+    dx = jnp.sum(_local_rows(g, pos, local).reshape(t, -1, g.shape[-1]), axis=1)
+    return dx, None, None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _pairs_of_rows(y, order, pos, local):
+    """``y[pos]``: each (token, choice) pair's sorted row, back in pair
+    order, zero for a pair of an absent expert; the gradient gathers with
+    ``order``."""
+    return _local_rows(y, pos, local)
+
+
+def _pairs_fwd(y, order, pos, local):
+    return _local_rows(y, pos, local), (order,)
+
+
+def _pairs_bwd(res, g):
+    (order,) = res
+    return g[order], None, None, None
+
+
+_pairs_of_rows.defvjp(_pairs_fwd, _pairs_bwd)
+
+
+def route_top_k(x, router_w, top_k: int):
+    """Softmax router in float32 over ALL experts: x (T, D) -> the k largest
+    probabilities (T, k), renormalised over the chosen, and their expert ids
+    (T, k)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+
+
+def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int):
+    """x (T, D) -> (this share's part of the layer's result (T, D), counters).
+
+    Every (token, choice) pair is kept: the pairs are sorted by the slot of
+    their expert among the experts held (pairs of absent experts last), the
+    three grouped products run over the held experts' rows, and each token
+    sums its k rows weighted by the router. No capacity, no drop: the sorted
+    buffer has a row for every pair, and rows past the held groups cost
+    memory, not matrix work."""
+    t, d = x.shape
+    held = jnp.asarray(experts_held, jnp.int32)
+    n_held = held.shape[0]
+    with jax.named_scope("moe_route"):
+        top_p, top_e = route_top_k(x, params["router"], top_k)
+        # slot among the held experts, n_held for an absent one
+        slot_of = jnp.full((n_experts,), n_held, jnp.int32).at[held].set(
+            jnp.arange(n_held, dtype=jnp.int32))
+        slot = slot_of[top_e].reshape(-1)                       # (T*k,)
+        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+        pos = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        group_sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        local = jnp.sum(group_sizes)  # pairs that hit an expert held here
+        xs = _rows_of_tokens(x.astype(precision.compute_dtype()), order, pos, local,
+                             top_k)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(grouped_dot(xs, params["w_gate"], group_sizes)) \
+            * grouped_dot(xs, params["w_up"], group_sizes)
+        ys = grouped_dot(h, params["w_down"], group_sizes)      # (T*k, D)
+    with jax.named_scope("moe_route"):
+        pairs = _pairs_of_rows(ys, order, pos, local).reshape(t, top_k, d)
+        out = jnp.sum(pairs * top_p[..., None], axis=1)
+        counters = {
+            "moe_pairs_local": local.astype(jnp.float32),
+            "moe_load_max_over_mean": jnp.max(group_sizes) / jnp.maximum(
+                jnp.mean(group_sizes.astype(jnp.float32)), 1.0),
+            # pairs of held experts that the sorted buffer had no row for
+            "moe_dropped_pairs": jnp.maximum(
+                local - xs.shape[0], 0).astype(jnp.float32),
+        }
+    return out.astype(x.dtype), counters
+
+
+class RoutedExperts(AbstractModule):
+    """Top-k routed, gated experts without capacity: ``(..., D) -> (..., D)``.
+
+    ``p = softmax(x W_r)`` over ``n_experts`` in float32; the ``top_k``
+    largest, renormalised over the chosen; ``sum_e w_e W_down,e(silu(
+    W_gate,e x) * W_up,e x)`` over the chosen experts THIS MODULE HOLDS
+    (``experts_held``: ids among ``range(n_experts)``, default all). Held
+    fewer than all, it is one chip's share of an expert-parallel layer run
+    without its exchange: the router keeps its width, pairs routed to absent
+    experts add nothing, and the shares of all chips sum to the whole layer
+    (``tests/test_decoder_lm.py``, the share test). Beside ``MoE`` (switch /
+    GShard with capacity buffers that drop) until ROADMAP D2 merges them.
+
+    State: ``{"_counters": {moe_pairs_local, moe_load_max_over_mean,
+    moe_dropped_pairs}}``, see ``AbstractModule.counters_tree``."""
+
+    def __init__(self, n_experts: int, ffn_size: int, top_k: int,
+                 experts_held=None, init_std: float = 0.02):
+        super().__init__()
+        held = tuple(range(n_experts) if experts_held is None else experts_held)
+        if not held or not all(0 <= e < n_experts for e in held) \
+                or len(set(held)) != len(held):
+            raise ValueError(f"experts_held {held} are not distinct ids "
+                             f"among {n_experts} experts")
+        if not 1 <= top_k <= n_experts:
+            raise ValueError(f"top_k {top_k} not in [1, {n_experts}]")
+        self.n_experts, self.ffn_size, self.top_k = n_experts, ffn_size, top_k
+        self.experts_held = held
+        self.init_std = init_std
+
+    def infer_shape(self, in_spec):
+        return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
+
+    def _build(self, rng, in_spec):
+        d, f, e = in_spec.shape[-1], self.ffn_size, len(self.experts_held)
+        ks = jax.random.split(rng, 4)
+        normal = lambda k, shape: self.init_std * jax.random.normal(  # noqa: E731
+            k, shape, jnp.float32)
+        params = {"router": normal(ks[0], (d, self.n_experts)),
+                  "w_gate": normal(ks[1], (e, d, f)),
+                  "w_up": normal(ks[2], (e, d, f)),
+                  "w_down": normal(ks[3], (e, f, d))}
+        zero = jnp.zeros((), jnp.float32)
+        return params, {"_counters": {
+            "moe_pairs_local": zero, "moe_load_max_over_mean": zero,
+            "moe_dropped_pairs": zero}}
+
+    def _apply(self, params, state, x, training, rng):
+        x = jnp.asarray(x)
+        out, counters = routed_experts(
+            x.reshape(-1, x.shape[-1]), params, n_experts=self.n_experts,
+            experts_held=self.experts_held, top_k=self.top_k)
+        return out.reshape(x.shape), {"_counters": counters}

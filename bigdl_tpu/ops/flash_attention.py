@@ -50,8 +50,13 @@ NEG_BIG = -1e30
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 acc_ref, *, block_q: int, block_k: int, causal: bool,
                 scale: float, causal_offset: int, t_real_k: int, nk: int,
-                has_lengths: bool, mask_q: bool):
+                has_lengths: bool, mask_q: bool, window: Optional[int] = None,
+                nk_real: Optional[int] = None):
     """Grid (BH, num_q_blocks, num_k_blocks); innermost dim streams k/v tiles.
+
+    With a ``window`` the innermost dim has only the ``nk`` k/v tiles a q tile
+    can see (``_window_start`` names the first of them; ``nk_real`` is how
+    many the keys have), so tiles outside the window are never visited.
 
     q_ref (1, block_q, D) and o_ref depend on (b, i); k_ref/v_ref
     (1, block_k, D) on (b, j). Online-softmax state persists in VMEM scratch
@@ -72,6 +77,10 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_BIG)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    last = j == nk - 1
+    if window is not None:  # j counts from the first tile inside the window
+        j = j + _window_start(qi, block_q, block_k, causal_offset, window)
 
     # Tile classification (scalar arithmetic on program ids):
     #   - invisible tiles (past the real key length / fully beyond the causal
@@ -94,6 +103,9 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         full = full & (
             qi * block_q + causal_offset >= (j + 1) * block_k - 1
         )
+    if window is not None:
+        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
+                                      block_q, block_k, causal_offset, window)
 
     def _accumulate(masked: bool):
         # MXU dots run in the INPUT dtype (callers pass bf16 under the mixed-
@@ -117,6 +129,8 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                     allowed = allowed & (rows + causal_offset < kl)
                 if causal:
                     allowed = allowed & (rows + causal_offset >= cols)
+                if window is not None:
+                    allowed = allowed & (rows + causal_offset - cols < window)
             s = jnp.where(allowed, s, NEG_BIG)
 
         m_prev = m_ref[:]
@@ -141,7 +155,7 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     def _tile_masked():
         _accumulate(masked=True)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _finish():
         o_ref[0] = (
             acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[:, None]
@@ -167,6 +181,52 @@ def _pick_block(requested: int, t: int) -> int:
     return b
 
 
+def _window_start(qi, block_q: int, block_k: int, causal_offset: int,
+                  window: int):
+    """First k tile that a q tile's window reaches (for the dK/dV kernel,
+    with the roles swapped and ``window=1``: the first q tile at or below a
+    k tile's diagonal)."""
+    return jnp.maximum(qi * block_q + causal_offset - (window - 1), 0) // block_k
+
+
+def _window_count(n_outer: int, block_o: int, block_i: int, offset: int,
+                  reach: int, back: int, n_inner: int) -> int:
+    """The most inner tiles that any outer tile's window touches: outer tile
+    ``o`` covers positions ``[o * block_o + offset - back, (o + 1) * block_o
+    - 1 + offset + reach]`` of the inner axis (k tiles of a q tile: ``back =
+    window - 1``, ``reach = 0``; q tiles of a k tile: ``back = 0``, ``reach =
+    window - 1``). Counted exactly, tile by tile, so that no grid step is
+    spent on a tile that is never visible."""
+    most = 1
+    for o in range(n_outer):
+        first = max(o * block_o + offset - back, 0) // block_i
+        last = min(((o + 1) * block_o - 1 + offset + reach) // block_i,
+                   n_inner - 1)
+        most = max(most, last - first + 1)
+    return most
+
+
+def _window_tiles(visible, full, in_range, qi, j, block_q: int, block_k: int,
+                  causal_offset: int, window: int):
+    """Tile classification under a window: (row - col) spans
+    [first row - last col, last row - first col] over a tile."""
+    visible = visible & in_range & (
+        qi * block_q + causal_offset - ((j + 1) * block_k - 1) < window)
+    # a step past the last tile (its index was clamped) is neither
+    full = full & in_range & (
+        (qi + 1) * block_q - 1 + causal_offset - j * block_k < window)
+    return visible, full
+
+
+def _kv_row(group: int, h: int):
+    """Grid row (batch * query head) -> row of the flattened K/V, whose
+    ``h // group`` heads each serve ``group`` query heads."""
+    if group == 1:
+        return lambda b: b
+    hkv = h // group
+    return lambda b: (b // h) * hkv + (b % h) // group
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     t = x.shape[axis]
     pad = (-t) % mult
@@ -186,10 +246,12 @@ def _expand_lengths(lengths, n: int, h: int, tk: int):
 
 
 def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
-                    block_q: int, block_k: int, interpret: bool, mask_q: bool):
+                    block_q: int, block_k: int, interpret: bool, mask_q: bool,
+                    window: Optional[int] = None):
     """Returns (out (N,H,Tq,d), lse (N*H, Tq_padded)) — lse is the bwd residual."""
     n, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     bq = _pick_block(block_q, tq)
@@ -197,23 +259,35 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
     has_lengths = lengths is not None
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
-    kf = _pad_to(k.reshape(n * h, tk, d), 1, bk)
-    vf = _pad_to(v.reshape(n * h, tk, d), 1, bk)
+    kf = _pad_to(k.reshape(n * hkv, tk, d), 1, bk)
+    vf = _pad_to(v.reshape(n * hkv, tk, d), 1, bk)
     tqp, tkp = qf.shape[1], kf.shape[1]
     nk = tkp // bk
     lens = _expand_lengths(lengths, n, h, tk)
+    kv_row = _kv_row(group, h)
+    if window is None:
+        nkv, extra = nk, {}
+        kv_map = lambda b, i, j, lens: (kv_row(b), j, 0)  # noqa: E731
+    else:
+        # only the tiles a q tile's window reaches are in the grid
+        nkv = _window_count(tqp // bq, bq, bk, tk - tq, 0, window - 1, nk)
+        extra = dict(window=window, nk_real=nk)
+        kv_map = lambda b, i, j, lens: (  # noqa: E731
+            kv_row(b),
+            jnp.minimum(_window_start(i, bq, bk, tk - tq, window) + j, nk - 1),
+            0)
 
     out, lse = pallas_call(
         partial(_fwd_kernel, block_q=bq, block_k=bk, causal=causal,
-                scale=scale, causal_offset=tk - tq, t_real_k=tk, nk=nk,
-                has_lengths=has_lengths, mask_q=mask_q),
+                scale=scale, causal_offset=tk - tq, t_real_k=tk, nk=nkv,
+                has_lengths=has_lengths, mask_q=mask_q, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n * h, tqp // bq, nk),
+            grid=(n * h, tqp // bq, nkv),
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
@@ -233,12 +307,14 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(lens, qf, kf, vf)
     return out[:, :tq].reshape(n, h, tq, d), lse
 
 
 def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
-                  t_real_q, t_real_k, kl, mask_q, qi, ki, block_q, block_k):
+                  t_real_q, t_real_k, kl, mask_q, qi, ki, block_q, block_k,
+                  window=None):
     """Rebuild the probability tile p = exp(s - lse); ``masked=False`` is the
     fast path for interior tiles where every entry is known visible (padded q
     rows are zeros with finite lse, so their p ≤ 1 and their contributions
@@ -254,6 +330,8 @@ def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
         allowed = allowed & (rows + causal_offset < kl)
     if causal:
         allowed = allowed & (rows + causal_offset >= cols)
+    if window is not None:
+        allowed = allowed & (rows + causal_offset - cols < window)
     # masked/fully-masked entries: s and lse are both NEG_BIG-ish; clamp the
     # exponent so the unselected branch of the where never overflows
     expo = jnp.clip(s - lse[:, None], NEG_BIG, 0.0)
@@ -263,14 +341,21 @@ def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
 def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, dq_acc, *, block_q: int, block_k: int, causal: bool,
                scale: float, causal_offset: int, t_real_q: int,
-               t_real_k: int, nk: int, has_lengths: bool, mask_q: bool):
+               t_real_k: int, nk: int, has_lengths: bool, mask_q: bool,
+               window: Optional[int] = None, nk_real: Optional[int] = None):
     """Grid (BH, num_q_blocks, num_k_blocks): k/v tiles stream through the
-    inner dim while the dQ accumulator for the current q tile sits in VMEM."""
+    inner dim while the dQ accumulator for the current q tile sits in VMEM.
+    Under a ``window`` the inner dim holds the tiles inside it alone, as in
+    the forward kernel."""
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    last = j == nk - 1
+    if window is not None:
+        j = j + _window_start(qi, block_q, block_k, causal_offset, window)
 
     kl = jnp.minimum(lens_ref[pl.program_id(0)], t_real_k) if has_lengths \
         else t_real_k
@@ -284,6 +369,9 @@ def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             (qi + 1) * block_q - 1 + causal_offset >= j * block_k
         )
         full = full & (qi * block_q + causal_offset >= (j + 1) * block_k - 1)
+    if window is not None:
+        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
+                                      block_q, block_k, causal_offset, window)
 
     def _accumulate(masked: bool):
         q = q_ref[0]
@@ -294,7 +382,8 @@ def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           causal=causal, causal_offset=causal_offset,
                           t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
                           mask_q=has_lengths and mask_q,
-                          qi=qi, ki=j, block_q=block_q, block_k=block_k)
+                          qi=qi, ki=j, block_q=block_q, block_k=block_k,
+                          window=window)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = (p * (dp - delta_ref[0, 0][:, None]) * scale).astype(k.dtype)
         dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
@@ -307,7 +396,7 @@ def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _tile_masked():
         _accumulate(masked=True)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _finish():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -316,9 +405,14 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
                 block_k: int, causal: bool, scale: float,
                 causal_offset: int, t_real_q: int, t_real_k: int, nq: int,
-                has_lengths: bool, mask_q: bool):
-    """Grid (BH, num_k_blocks, num_q_blocks): q/do tiles stream through the
-    inner dim; dK/dV accumulators for the current k tile sit in VMEM."""
+                has_lengths: bool, mask_q: bool, window: Optional[int] = None,
+                nq_real: Optional[int] = None, group: int = 1):
+    """Grid (B*Hkv, num_k_blocks, group * num_q_blocks): q/do tiles stream
+    through the inner dim; dK/dV accumulators for the current k tile sit in
+    VMEM. With grouped heads the inner dim runs over the ``group`` query heads
+    that read this K/V head, one after another, and their contributions sum
+    in the accumulators. Under a ``window`` each head's stretch holds only
+    the ``nq`` q tiles whose window reaches this k tile."""
     ki, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -326,7 +420,14 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    kl = jnp.minimum(lens_ref[pl.program_id(0)], t_real_k) if has_lengths \
+    last = j == group * nq - 1
+    if group > 1:
+        j = j % nq
+    if window is not None:
+        j = j + _window_start(ki, block_k, block_q, -causal_offset, 1)
+
+    lens_row = pl.program_id(0) * group if group > 1 else pl.program_id(0)
+    kl = jnp.minimum(lens_ref[lens_row], t_real_k) if has_lengths \
         else t_real_k
     visible = j * block_q < t_real_q
     # full tiles: all k columns real and (under causal) the whole q tile past
@@ -344,6 +445,9 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             (j + 1) * block_q - 1 + causal_offset >= ki * block_k
         )
         full = full & (j * block_q + causal_offset >= (ki + 1) * block_k - 1)
+    if window is not None:
+        visible, full = _window_tiles(visible, full, j < nq_real, j, ki,
+                                      block_q, block_k, causal_offset, window)
 
     def _accumulate(masked: bool):
         q = q_ref[0]
@@ -354,7 +458,8 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           causal=causal, causal_offset=causal_offset,
                           t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
                           mask_q=has_lengths and mask_q,
-                          qi=j, ki=ki, block_q=block_q, block_k=block_k)
+                          qi=j, ki=ki, block_q=block_q, block_k=block_k,
+                          window=window)
         dv_acc[:] += jnp.dot(
             p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
         )
@@ -370,7 +475,7 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _tile_masked():
         _accumulate(masked=True)
 
-    @pl.when(j == nq - 1)
+    @pl.when(last)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -378,9 +483,11 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
                     scale: Optional[float], block_q: int, block_k: int,
-                    interpret: bool, mask_q: bool):
+                    interpret: bool, mask_q: bool,
+                    window: Optional[int] = None):
     n, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     bq = _pick_block(block_q, tq)
@@ -388,30 +495,48 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
     has_lengths = lengths is not None
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
-    kf = _pad_to(k.reshape(n * h, tk, d), 1, bk)
-    vf = _pad_to(v.reshape(n * h, tk, d), 1, bk)
+    kf = _pad_to(k.reshape(n * hkv, tk, d), 1, bk)
+    vf = _pad_to(v.reshape(n * hkv, tk, d), 1, bk)
     dof = _pad_to(g.reshape(n * h, tq, d), 1, bq)  # zero-padded rows
     tqp, tkp = qf.shape[1], kf.shape[1]
     nq, nk = tqp // bq, tkp // bk
     lens = _expand_lengths(lengths, n, h, tk)
+    off = tk - tq
 
     # delta_i = rowsum(dO_i * O_i): O(T d) work — jnp outside the grid
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = _pad_to(delta.reshape(n * h, 1, tq), 2, bq)
 
     common = dict(block_q=bq, block_k=bk, causal=causal, scale=scale,
-                  causal_offset=tk - tq, t_real_q=tq, t_real_k=tk,
+                  causal_offset=off, t_real_q=tq, t_real_k=tk,
                   has_lengths=has_lengths, mask_q=mask_q)
+    kv_row = _kv_row(group, h)
+    if window is None:
+        nkv, nqv, dq_extra, dkv_extra = nk, nq, {}, {}
+        k_of = lambda i, j: j  # noqa: E731  dQ: the k tile of inner step j
+        q_of = lambda i, j: j  # noqa: E731  dK/dV: the q tile of inner step j
+    else:
+        nkv = _window_count(nq, bq, bk, off, 0, window - 1, nk)
+        nqv = _window_count(nk, bk, bq, -off, window - 1, 0, nq)
+        dq_extra = dict(window=window, nk_real=nk)
+        dkv_extra = dict(window=window, nq_real=nq)
+        k_of = lambda i, j: jnp.minimum(  # noqa: E731
+            _window_start(i, bq, bk, off, window) + j, nk - 1)
+        q_of = lambda i, j: jnp.minimum(  # noqa: E731
+            _window_start(i, bk, bq, -off, 1) + j, nq - 1)
+    if group > 1:
+        dkv_extra["group"] = group
 
+    kv_map = lambda b, i, j, lens: (kv_row(b), k_of(i, j), 0)  # noqa: E731
     dq = pallas_call(
-        partial(_dq_kernel, nk=nk, **common),
+        partial(_dq_kernel, nk=nkv, **common, **dq_extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n * h, nq, nk),
+            grid=(n * h, nq, nkv),
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
@@ -425,20 +550,31 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(lens, qf, kf, vf, dof, lse, delta)
 
+    # inner step j of K/V row b: query head j // nqv of the group, and the
+    # j % nqv-th q tile that reaches k tile i
+    if group == 1:
+        q_row = lambda b, j: b  # noqa: E731
+        q_tile = q_of
+    else:
+        q_row = lambda b, j: b * group + j // nqv  # noqa: E731
+        q_tile = lambda i, j: q_of(i, j % nqv)  # noqa: E731
+    q_map = lambda b, i, j, lens: (q_row(b, j), q_tile(i, j), 0)  # noqa: E731
+    row_map = lambda b, i, j, lens: (q_row(b, j), 0, q_tile(i, j))  # noqa: E731
     dk, dv = pallas_call(
-        partial(_dkv_kernel, nq=nq, **common),
+        partial(_dkv_kernel, nq=nqv, **common, **dkv_extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n * h, nk, nq),
+            grid=(n * hkv, nk, group * nqv),
             in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, j, 0)),
+                pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, j, 0)),
-                pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, j)),
-                pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, j)),
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, 1, bq), row_map),
+                pl.BlockSpec((1, 1, bq), row_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
@@ -450,30 +586,37 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n * h, tkp, d), k.dtype),
-            jax.ShapeDtypeStruct((n * h, tkp, d), v.dtype),
+            jax.ShapeDtypeStruct((n * hkv, tkp, d), k.dtype),
+            jax.ShapeDtypeStruct((n * hkv, tkp, d), v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(lens, qf, kf, vf, dof, lse, delta)
 
     return (dq[:, :tq].reshape(n, h, tq, d),
-            dk[:, :tk].reshape(n, h, tk, d),
-            dv[:, :tk].reshape(n, h, tk, d))
+            dk[:, :tk].reshape(n, hkv, tk, d),
+            dv[:, :tk].reshape(n, hkv, tk, d))
 
 
-def _dense_reference(q, k, v, causal: bool, scale: Optional[float]) -> jax.Array:
+def _dense_reference(q, k, v, causal: bool, scale: Optional[float],
+                     window: Optional[int] = None) -> jax.Array:
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if k.shape[1] != q.shape[1]:  # grouped heads: each K/V head, repeated
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("nhqd,nhkd->nhqk", q, k).astype(jnp.float32) * scale
     if causal:
         tq, tk = q.shape[2], k.shape[2]
         rows = jnp.arange(tq)[:, None] + (tk - tq)
         cols = jnp.arange(tk)[None, :]
         mask = rows >= cols
+        if window is not None:
+            mask = mask & (rows - cols < window)
         s = jnp.where(mask, s, -jnp.inf)
         # rows with NO visible keys (Tq > Tk head rows): softmax over all -inf
         # is nan (and nan-poisons the vjp); the flash forward returns 0 there —
@@ -487,25 +630,26 @@ def _dense_reference(q, k, v, causal: bool, scale: Optional[float]) -> jax.Array
     return jnp.einsum("nhqk,nhkd->nhqd", w.astype(q.dtype), v)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_core(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-                mask_q):
+                mask_q, window):
     out, _ = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
-                             block_k, interpret, mask_q)
+                             block_k, interpret, mask_q, window)
     return out
 
 
 def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-              mask_q):
+              mask_q, window):
     out, lse = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
-                               block_k, interpret, mask_q)
+                               block_k, interpret, mask_q, window)
     return out, (q, k, v, lengths, out, lse)
 
 
-def _bwd_rule(causal, scale, block_q, block_k, interpret, mask_q, res, g):
+def _bwd_rule(causal, scale, block_q, block_k, interpret, mask_q, window,
+              res, g):
     q, k, v, lengths, o, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal, scale,
-                                 block_q, block_k, interpret, mask_q)
+                                 block_q, block_k, interpret, mask_q, window)
     return dq, dk, dv, None
 
 
@@ -516,7 +660,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
                     block_q: int = 1024, block_k: int = 512,
                     interpret: bool = False,
                     lengths: Optional[jax.Array] = None,
-                    mask_q: Optional[bool] = None) -> jax.Array:
+                    mask_q: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Exact attention over (N, heads, T, d) operands via the Pallas kernel.
 
     ``causal`` applies the lower-triangular mask (aligned at the end for
@@ -524,6 +669,14 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     sequence n attends only keys ``< lengths[n]`` — so ragged text batches
     (the reference's padded-MiniBatch pipeline, ``$DL/dataset``) stay on
     the kernel path instead of falling back to dense.
+
+    ``window`` (with ``causal``) is sliding-window attention: query i sees
+    key j iff ``j <= i`` and ``i - j < window``. Tiles wholly outside the
+    window are not in the grid at all, so the work is the window's.
+
+    ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
+    attention): query head h reads K/V head ``h // (Hq / Hkv)``; nothing is
+    repeated, and dK/dV sum over a group's query heads inside the kernel.
 
     ``mask_q`` controls whether QUERY rows past the horizon also produce
     zero output and leak no gradient (self-attention semantics, where
@@ -543,5 +696,11 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     """
     if mask_q is None:
         mask_q = q.shape[2] == k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"flash_attention: {q.shape[1]} query heads cannot share "
+            f"{k.shape[1]} key / {v.shape[1]} value heads")
     return _flash_core(q, k, v, lengths, causal, scale, block_q, block_k,
-                       interpret, bool(mask_q))
+                       interpret, bool(mask_q), window)

@@ -281,6 +281,8 @@ class Optimizer:
         # configuration reuses its codec (and the jitted programs below)
         self._flat_fp: Dict[int, object] = {}
         self._flat_step_cache = None  # (method, fp, health, jitted flat step)
+        self._counter_step = (None, ())  # (standard step, its counters' names)
+        self._counter_names = ()
         # jitted (flatten, unflatten, slots_tree_view) per codec identity
         self._flat_jit: Dict[int, tuple] = {}
         # AOT step-artifact seam (utils/aot.py): (jitted step, arg spec tree)
@@ -1328,18 +1330,30 @@ class Optimizer:
         # gains per-data-shard non-finite input/target counts so a poisoned
         # record is blamed on its mesh coordinate (None on the local path)
         mesh_shards = getattr(self, "_health_mesh_shards", None)
+        counter_names = tuple(sorted(
+            self.model.counters_tree(self.model.get_state())))
 
         def finish(grads, old_params, new_params, new_ms, new_slots, loss,
                    x=None, t=None):
             """Common step tail: with health attached, one extra fixed-shape
             f32 output of in-graph statistics; detached, the exact pre-health
-            4-tuple (bit-identical program)."""
-            if hm is None:
-                return new_params, new_ms, new_slots, loss
-            stats = hm.tree_stats(grads, old_params, new_params, new_ms)
-            if mesh_shards is not None and x is not None:
-                stats["shards"] = hm.mesh_shard_stats(x, t, mesh_shards)
-            return (new_params, new_ms, new_slots, loss, stats)
+            4-tuple (bit-identical program). A model that keeps counters in
+            its state adds their vector as the last output."""
+            outs = (new_params, new_ms, new_slots, loss)
+            if hm is not None:
+                stats = hm.tree_stats(grads, old_params, new_params, new_ms)
+                if mesh_shards is not None and x is not None:
+                    stats["shards"] = hm.mesh_shard_stats(x, t, mesh_shards)
+                outs = outs + (stats,)
+            if counter_names:
+                # the model's '_counters' (nn.RoutedExperts), reduced over its
+                # modules: one small vector beside the loss, so the values
+                # outlive the donation of the state that carries them
+                counters = self.model.counters_tree(new_ms)
+                outs = outs + (jnp.stack([
+                    jnp.asarray(counters[k], jnp.float32)
+                    for k in counter_names]),)
+            return outs
 
         def loss_fn(params, ms, x, t, rng, nvalid):
             if use_mask:
@@ -1359,6 +1373,7 @@ class Optimizer:
                           new_slots, loss, x, t)
 
         if n_micro == 1:
+            self._counter_step = (train_step, counter_names)
             return train_step
 
         def _split(a):
@@ -1424,6 +1439,7 @@ class Optimizer:
             return finish(grads, params, new_params, new_model_state,
                           new_slots, l_sum / v_sum, x, t)
 
+        self._counter_step = (micro_step, counter_names)
         return micro_step
 
     def _cached_standard_step(self, method):
@@ -1605,6 +1621,10 @@ class Optimizer:
         self._jit_step = train_step  # compile-count introspection (tests)
 
         hm = self.health
+        # names of the counters this step hands out as its last output: set
+        # by _make_standard_step for the step it built, none for any other
+        made, names = self._counter_step
+        counter_names = self._counter_names = names if made is train_step else ()
 
         def run_iteration(batch, lr: float):
             # the three spans below split the driver's `dispatch` span: host
@@ -1650,8 +1670,11 @@ class Optimizer:
                     # layout exists to kill (the model syncs at the cold seams)
                     model.set_parameters(box["params"])
                 model.set_state(box["model_state"])
+            counters = outs[-1] if counter_names else None
             if hm is not None:  # health stats ride the same one-step-late pull
-                return loss, outs[tail]
+                return loss, outs[tail], counters
+            if counters is not None:
+                return loss, None, counters
             return loss  # device array — _drive_loop pulls it one step later
 
         if codec is None:
@@ -1921,7 +1944,7 @@ class Optimizer:
             """Pull a completed step's loss and emit log line + summaries."""
             (neval, epoch, iter_in_epoch, loss_arr, n, lr, dispatch_s,
              health_arr, input_wait_s, input_qdepth, h2d_bytes,
-             host_buf_reused) = rec
+             host_buf_reused, counters_arr) = rec
             try:
                 # one-step-late pull: step i's scalar lands after step i+1 is
                 # queued — device-side faults from step i surface HERE. The
@@ -1999,6 +2022,12 @@ class Optimizer:
                         input_qdepth=input_qdepth,
                         h2d_bytes=h2d_bytes,
                         host_buf_reused=host_buf_reused,
+                        # the same step's outputs as the loss just pulled:
+                        # a copy of ready buffers, not a new sync
+                        **(dict(zip(
+                            self._counter_names,
+                            np.asarray(counters_arr).tolist()))  # lint: disable=BDL005 rides the delayed loss sync above
+                           if counters_arr is not None else {}),
                         **(pa.step_fields(wall) if pa is not None else {}),
                     )
                     if pa is not None:
@@ -2267,8 +2296,11 @@ class Optimizer:
                 # with health attached, run_iteration also hands back the
                 # step's in-graph stats pytree, pulled at the same
                 # one-step-late flush as the loss
-                loss_arr, health_arr = (
-                    res if isinstance(res, tuple) else (res, None)
+                # a model that keeps counters (nn.RoutedExperts) adds their
+                # vector, pulled at that flush too
+                loss_arr, health_arr, counters_arr = (
+                    (res + (None,))[:3] if isinstance(res, tuple)
+                    else (res, None, None)
                 )
                 dispatch_s = dispatched.s  # None on a detached run
                 if self.telemetry is not None:
@@ -2295,6 +2327,7 @@ class Optimizer:
                     getattr(batch, "input_qdepth", None),
                     getattr(batch, "h2d_bytes", None),
                     getattr(batch, "host_buf_reused", None),
+                    counters_arr,
                 )
                 if prev is not None:
                     flush(prev)  # overlaps with the step just dispatched

@@ -27,6 +27,7 @@ model; already-compiled functions keep the dtypes they were traced with.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -168,3 +169,39 @@ def conv_general_dilated(x, w, **kwargs):
     return lax.conv_general_dilated(_cast(x, dt), _cast(w, dt), **kwargs).astype(
         out_dtype()
     )
+
+
+def _dot_acc32_impl(x, w):
+    dt = compute_dtype()
+    return jnp.dot(_cast(x, dt), _cast(w, dt),
+                   preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def dot_acc32(x, w):
+    """``x (..., K) @ w (K, N)`` with operands in the compute dtype and a
+    FLOAT32 result: the MXU's own accumulator, not rounded to bf16 on the way
+    out as :func:`einsum`'s result is. Both backward products take their
+    operands (the cotangent among them) in the compute dtype too and
+    accumulate in float32, so the whole layer runs at "bf16 operands, float32
+    accumulation" and not at whatever autodiff's transposes would promote to.
+    With a float32 compute dtype this is ``x @ w`` and its plain gradient."""
+    return _dot_acc32_impl(x, w)
+
+
+def _dot_acc32_fwd(x, w):
+    return _dot_acc32_impl(x, w), (x, w)
+
+
+def _dot_acc32_bwd(res, g):
+    x, w = res
+    dt = compute_dtype()
+    g = _cast(g, dt)
+    dx = jnp.dot(g, _cast(w, dt).T, preferred_element_type=jnp.float32)
+    k = x.shape[-1]
+    dw = jnp.dot(_cast(x, dt).reshape(-1, k).T, g.reshape(-1, g.shape[-1]),
+                 preferred_element_type=jnp.float32)
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+dot_acc32.defvjp(_dot_acc32_fwd, _dot_acc32_bwd)

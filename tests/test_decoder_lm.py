@@ -1,0 +1,409 @@
+"""The decoder-only language model (nn.DecoderLM, nn.RoutedExperts, the flash
+kernel's window and grouped heads) against its plain float32 reference, at
+small sizes on the CPU: D 64, 2 periods of 4 layers, 8 query / 2 K/V heads of
+16, window 8 at T 32, 8 experts top-2 of width 32, vocabulary 128."""
+
+import importlib.util
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bigdl_tpu import nn
+from bigdl_tpu.models import decoder_lm, decoder_lm_reference as ref
+from bigdl_tpu.nn.attention import apply_rotary, scaled_dot_product_attention
+from bigdl_tpu.nn.decoder import rope_inv_freq
+from bigdl_tpu.ops.flash_attention import (
+    _dense_reference, _window_count, flash_attention)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192, "beta_fast": 32,
+        "beta_slow": 1, "attention_factor": 1.2772588722239782}
+PLAIN = {"rope_type": "default", "rope_theta": 500000}
+PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+CONFIG = dict(
+    vocab_size=128, hidden_size=64, num_hidden_layers=8, layer_types=PERIOD * 2,
+    num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+    sliding_window=8,
+    rope_parameters={"full_attention": YARN, "sliding_attention": PLAIN},
+    rms_norm_eps=1e-6, num_experts=8, num_experts_per_tok=2,
+    moe_intermediate_size=32, norm_topk_prob=True)
+N, T = 2, 32
+
+
+def _tokens(seed):
+    rng = np.random.default_rng(seed)
+    tok = rng.integers(0, CONFIG["vocab_size"], (N, T + 1)).astype(np.int32)
+    return jnp.asarray(tok[:, :-1]), jnp.asarray(tok[:, 1:])
+
+
+def _built(config, seed=0):
+    model = decoder_lm.from_config(config)
+    model.build(jax.random.PRNGKey(seed), jax.ShapeDtypeStruct((N, T), jnp.int32))
+    return model, model.get_parameters(), model.get_state()
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+@pytest.fixture(scope="module")
+def both():
+    """The module's and the reference's loss, logits and gradients on the
+    same seeded weights and batch; experts 0-3 of 8 held."""
+    config = {**CONFIG, "experts_held": [0, 1, 2, 3]}
+    model, params, state = _built(config)
+    x, y = _tokens(1)
+    criterion = nn.TokenCrossEntropyCriterion()
+
+    def loss(p):
+        out, new_state = model.apply(p, state, x, training=True)
+        return criterion._apply(out, y), (out, new_state)
+
+    (l, (logits, new_state)), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    rcfg = decoder_lm.reference_config(config)
+    rparams = decoder_lm.reference_params(params)
+    rl, rgrads, counts, _ = ref.loss_and_grad(rparams, x, y, rcfg)
+    rlogits = jnp.stack([ref.forward(rparams, x[i], rcfg)[0] for i in range(N)])
+    return dict(model=model, loss=l, logits=logits, state=new_state,
+                grads=decoder_lm.reference_params(grads), rloss=rl,
+                rlogits=rlogits, rgrads=rgrads, counts=counts)
+
+
+def test_module_loss_and_logits_match_the_reference(both):
+    assert float(both["loss"]) == pytest.approx(float(both["rloss"]), abs=1e-5)
+    np.testing.assert_allclose(both["logits"], both["rlogits"], atol=5e-6)
+
+
+LEAVES = ["embed", "final_norm", "head"] + [
+    f"layers/{i}/{k}" for i in range(8) for k in (
+        "ln1", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "ln2", "router",
+        "w_gate", "w_up", "w_down")]
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_module_gradient_leaf_matches_the_reference(both, leaf):
+    got, want = both["grads"], both["rgrads"]
+    for key in leaf.split("/"):
+        key = int(key) if key.isdigit() else key
+        got, want = got[key], want[key]
+    assert got.shape == want.shape
+    assert _rel(got, want) < 2e-5
+
+
+def test_counters_match_the_references_own_routing(both):
+    got = {k: float(v) for k, v in
+           both["model"].counters_tree(both["state"]).items()}
+    want = ref.routing_counters(both["counts"])
+    assert got["moe_pairs_local"] == want["moe_pairs_local"] > 0
+    assert got["moe_load_max_over_mean"] == pytest.approx(
+        want["moe_load_max_over_mean"], rel=1e-6)
+    assert got["moe_dropped_pairs"] == 0.0
+
+
+# ------------------------------------------------------------------ the kernel
+
+@pytest.mark.parametrize("h,hkv,window", [(4, 2, 300), (2, 2, 300), (4, 1, None)],
+                         ids=["window+groups", "window", "groups"])
+def test_flash_window_and_grouped_heads_match_dense(h, hkv, window):
+    """T 640 in tiles of 128: with a window of 300 a k tile 4 below a q tile
+    is skipped, the diagonal and the window's edge are masked, the tile one
+    below the diagonal is full."""
+    t, d = 640, 16
+    rng = np.random.default_rng(0)
+    q, w = (jnp.asarray(rng.standard_normal((1, h, t, d)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, hkv, t, d)), jnp.float32)
+            for _ in range(2))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block_q=128, block_k=128,
+                               interpret=True, window=window)
+
+    def dense(q, k, v):
+        return _dense_reference(q, k, v, True, None, window)
+
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(w * flash(*a)), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(w * dense(*a)), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, atol=2e-5)
+
+
+def test_window_grid_holds_only_the_tiles_a_window_touches():
+    # 8192 keys in tiles of 512, a window of 1024: a q tile of 1024 rows
+    # touches 4 k tiles, not 16; a k tile of 512 touches 2 q tiles
+    assert _window_count(8, 1024, 512, 0, 0, 1023, 16) == 4
+    assert _window_count(16, 512, 1024, 0, 1023, 0, 8) == 2
+    assert _window_count(5, 128, 128, 0, 0, 299, 5) == 4
+    # and never more than there are
+    assert _window_count(2, 128, 128, 0, 0, 4095, 2) == 2
+
+
+def test_window_needs_causal():
+    q = jnp.zeros((1, 2, 128, 16))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, False, interpret=True, window=8)
+    with pytest.raises(ValueError, match="causal"):
+        scaled_dot_product_attention(q, q, q, window=8)
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_dense_attention_path_takes_window_and_grouped_heads(window):
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((2, 8, 32, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, 2, 32, 16)), jnp.float32)
+            for _ in range(2))
+    got = scaled_dot_product_attention(q, k, v, causal=True, mask_q=True,
+                                       window=window, impl="dense")
+    want = jnp.stack([ref.attention(q[i], k[i], v[i], window, 32)
+                      for i in range(2)])
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+# ------------------------------------------------------------------------ RoPE
+
+def test_yarn_inverse_frequencies_and_factor_by_hand():
+    inv, factor = rope_inv_freq(YARN, 128)
+    assert factor == 1.2772588722239782
+    # d(beta) = 128 ln(8192 / (2 pi beta)) / (2 ln 500000)
+    d_fast = 128 * math.log(8192 / (2 * math.pi * 32)) / (2 * math.log(500000))
+    d_slow = 128 * math.log(8192 / (2 * math.pi * 1)) / (2 * math.log(500000))
+    assert (math.floor(d_fast), math.ceil(d_slow)) == (18, 35)
+    for i in (0, 18):     # at and below lo: the plain frequency
+        assert inv[i] == pytest.approx(500000 ** (-2 * i / 128), rel=1e-6)
+    for i in (35, 63):    # at and above hi: divided by 16
+        assert inv[i] == pytest.approx(500000 ** (-2 * i / 128) / 16, rel=1e-6)
+    i, r = 27, (27 - 18) / (35 - 18)  # between: blended
+    base = 500000 ** (-2 * i / 128)
+    assert inv[i] == pytest.approx((1 - r) * base + r * base / 16, rel=1e-6)
+    plain, one = rope_inv_freq(PLAIN, 128)
+    assert one == 1.0
+    np.testing.assert_allclose(
+        plain, 500000.0 ** (-np.arange(64) / 64), rtol=1e-6)
+    # the reference reckons them on its own
+    rinv, rfactor = ref.rope_inv_freq(YARN, 128)
+    np.testing.assert_allclose(inv, rinv, rtol=1e-5)
+    assert rfactor == factor
+    # no attention_factor given: 0.1 ln(factor) + 1
+    bare = {k: v for k, v in YARN.items() if k != "attention_factor"}
+    assert rope_inv_freq(bare, 128)[1] == pytest.approx(0.1 * math.log(16) + 1)
+
+
+def test_apply_rotary_without_the_new_arguments_is_what_it_was():
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 3, 7, 16)),
+                    jnp.float32)
+    pos = jnp.arange(7) + 5
+    half = 8
+    freqs = 10000.0 ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    before = jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+    assert jnp.array_equal(apply_rotary(x, pos), before)
+    # and with them it is the reference's rotation
+    inv, factor = rope_inv_freq(YARN, 16)
+    np.testing.assert_allclose(
+        apply_rotary(x[0], jnp.arange(7), inv, factor),
+        ref.rotate(x[0], jnp.asarray(inv), factor), atol=1e-6)
+
+
+# --------------------------------------------------------------------- experts
+
+def _experts(held, seed=0, **kw):
+    m = nn.RoutedExperts(8, 32, 2, experts_held=held, **kw)
+    m.build(jax.random.PRNGKey(seed), jax.ShapeDtypeStruct((N, T, 64), jnp.float32))
+    return m
+
+
+def test_routing_never_drops_under_a_router_biased_onto_one_expert():
+    m = _experts((0, 1, 2, 3))
+    params = m.get_parameters()
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (N, T, 64))) + 0.1
+    # every token's first choice is expert 2: its column alone is large
+    params["router"] = params["router"].at[:, 2].set(1.0)
+    out, state = m.apply(params, m.get_state(), x)
+    counters = state["_counters"]
+    want, counts = ref.experts(
+        x.reshape(-1, 64), params,
+        dict(num_experts_per_tok=2, experts_held=(0, 1, 2, 3)))
+    assert int(counts[2]) == N * T             # all 64 tokens chose expert 2
+    assert float(counters["moe_dropped_pairs"]) == 0.0
+    assert float(counters["moe_pairs_local"]) == float(jnp.sum(counts))
+    assert float(counters["moe_load_max_over_mean"]) > 2.0
+    np.testing.assert_allclose(out.reshape(-1, 64), want, atol=1e-6)
+
+
+def test_the_four_shares_add_up_to_the_whole_layer():
+    """Experts 0-1, 2-3, 4-5, 6-7 of 8 on four chips: the partial results add
+    up to what the uncut reference gives for the whole layer."""
+    whole = _experts(tuple(range(8)))
+    params = whole.get_parameters()
+    x = jax.random.normal(jax.random.PRNGKey(7), (N, T, 64))
+    want, _ = ref.experts(
+        x.reshape(-1, 64), params,
+        dict(num_experts_per_tok=2, experts_held=tuple(range(8))))
+    total, pairs = 0.0, 0.0
+    for first in (0, 2, 4, 6):
+        held = (first, first + 1)
+        share = _experts(held)
+        share_params = {
+            "router": params["router"],  # whole on every chip
+            **{k: params[k][first:first + 2]
+               for k in ("w_gate", "w_up", "w_down")}}
+        out, state = share.apply(share_params, share.get_state(), x)
+        total = total + out
+        pairs += float(state["_counters"]["moe_pairs_local"])
+        assert float(state["_counters"]["moe_dropped_pairs"]) == 0.0
+    np.testing.assert_allclose(total.reshape(-1, 64), want, atol=1e-6)
+    assert pairs == N * T * 2  # every (token, choice) pair on exactly one chip
+
+
+def test_experts_held_must_be_distinct_ids():
+    with pytest.raises(ValueError, match="experts_held"):
+        nn.RoutedExperts(8, 32, 2, experts_held=(1, 1))
+    with pytest.raises(ValueError, match="experts_held"):
+        nn.RoutedExperts(8, 32, 2, experts_held=(8,))
+    with pytest.raises(ValueError, match="top_k"):
+        nn.RoutedExperts(8, 32, 9)
+
+
+def test_counters_tree_sums_and_takes_the_worst():
+    state = {"a": {"_counters": {"pairs": jnp.float32(3), "load_max": jnp.float32(2)}},
+             "b": {"c": {"_counters": {"pairs": jnp.float32(4), "load_max": jnp.float32(5)}}},
+             "d": {"running_mean": jnp.zeros(3)}}
+    got = nn.Identity().counters_tree(state)
+    assert {k: float(v) for k, v in got.items()} == {"pairs": 7.0, "load_max": 5.0}
+    assert nn.Identity().counters_tree({"x": {}}) == {}
+
+
+# ------------------------------------------------------------ criterion, copies
+
+def test_token_cross_entropy_is_the_mean_negative_log_likelihood():
+    rng = np.random.default_rng(2)
+    logits = jnp.asarray(rng.standard_normal((2, 5, 11)) * 3, jnp.float32)
+    target = jnp.asarray(rng.integers(0, 11, (2, 5)), jnp.int32)
+    criterion = nn.TokenCrossEntropyCriterion()
+
+    def plain(z):
+        logp = jax.nn.log_softmax(z, axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, target[..., None], -1))
+
+    assert float(criterion.forward(logits, target)) == pytest.approx(
+        float(plain(logits)), rel=1e-6)
+    np.testing.assert_allclose(criterion.backward(logits, target),
+                               jax.grad(plain)(logits), atol=1e-7)
+
+
+@pytest.mark.parametrize("operands", [None, "bfloat16"])
+def test_reference_product_rounds_operands_and_cotangent(operands):
+    """``product`` is einsum; at a stated operand precision it rounds both
+    operands first, and the cotangent and the other operand in the two
+    products of its gradient, and still sums in float32."""
+    rng = np.random.default_rng(0)
+    a, b, g = (jnp.asarray(rng.standard_normal(s), jnp.float32)
+               for s in ((5, 7), (7, 3), (5, 3)))
+    cut = (lambda v: v) if operands is None else (
+        lambda v: v.astype(operands).astype(jnp.float32))
+    out, back = jax.vjp(lambda a, b: ref.product("ik,kj->ij", a, b, operands),
+                        a, b)
+    assert out.dtype == jnp.float32
+    np.testing.assert_array_equal(out, jnp.einsum("ik,kj->ij", cut(a), cut(b)))
+    da, db = back(g)
+    np.testing.assert_array_equal(da, jnp.einsum("ij,kj->ik", cut(g), cut(b)))
+    np.testing.assert_array_equal(db, jnp.einsum("ik,ij->kj", cut(a), cut(g)))
+
+
+def test_reference_at_bfloat16_operands_leaves_the_router_in_float32():
+    """The stated precision rounds the products' operands, never the router:
+    on the same weights the routing is the float32 reference's own, and the
+    logits differ by the operand rounding."""
+    config = {**CONFIG, "num_hidden_layers": 1, "experts_held": [0, 1, 2, 3]}
+    _, params, _ = _built(config, seed=5)
+    x, _ = _tokens(6)
+    rcfg = decoder_lm.reference_config(config)
+    rparams = decoder_lm.reference_params(params)
+    lp = rparams["layers"][0]
+    h = jnp.asarray(np.random.default_rng(7).standard_normal((T, 64)),
+                    jnp.float32)
+    plain, counts = ref.experts(h, lp, rcfg)
+    rounded, rcounts = ref.experts(h, lp, {**rcfg, "operands": "bfloat16"})
+    assert jnp.array_equal(counts, rcounts)
+    assert 1e-4 < _rel(rounded, plain) < 2e-2
+
+
+@pytest.mark.parametrize("operands", [None, "bfloat16"])
+def test_the_benchmarks_copy_of_the_reference_gives_identical_outputs(operands):
+    path = os.path.join(ROOT, "benchmark", "configs", "mellum2_12b_reference.py")
+    spec = importlib.util.spec_from_file_location("bench_reference_copy", path)
+    copy = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(copy)
+    config = {**CONFIG, "num_hidden_layers": 4, "experts_held": [2, 3, 4, 5]}
+    _, params, _ = _built(config, seed=3)
+    x, y = _tokens(4)
+    rcfg = {**decoder_lm.reference_config(config), "operands": operands}
+    rparams = decoder_lm.reference_params(params)
+    at = jnp.asarray([[0, 5, 31], [7, 7, 30]])
+    a = ref.loss_and_grad(rparams, x, y, rcfg, at=at)
+    b = copy.loss_and_grad(rparams, x, y, rcfg, at=at)
+    assert a[3].shape == (N, 3, CONFIG["vocab_size"])
+    for u, v in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+        assert jnp.array_equal(u, v)
+    assert jnp.array_equal(ref.forward(rparams, x[0], rcfg)[0],
+                           copy.forward(rparams, x[0], rcfg)[0])
+
+
+# ------------------------------------------------------------- through optimize
+
+class _Keep:
+    def __init__(self):
+        self.records = []
+
+    def emit(self, record):
+        self.records.append(record)
+
+    def flush(self):
+        pass
+
+    def close(self):
+        pass
+
+
+def test_language_model_trains_through_optimize_with_counters_in_the_record():
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.obs import Telemetry
+    from bigdl_tpu.optim import Adam, LocalOptimizer
+    from bigdl_tpu.optim.trigger import Trigger
+
+    config = {**CONFIG, "num_hidden_layers": 4, "experts_held": [0, 1, 2, 3]}
+    p = 1.0 / np.arange(1, 129)
+    tok = np.random.default_rng(0).choice(
+        128, size=(16, T + 1), p=p / p.sum()).astype(np.int32)
+    data = DataSet.array(tok[:, :-1].copy(), tok[:, 1:].copy(), batch_size=2)
+    opt = LocalOptimizer(decoder_lm.from_config(config), data,
+                         nn.TokenCrossEntropyCriterion())
+    opt.set_optim_method(Adam(learningrate=3e-3, beta1=0.9, beta2=0.95))
+    keep = _Keep()
+    tel = Telemetry(exporters=[keep])
+    opt.set_telemetry(tel)
+    opt.set_end_when(Trigger.max_iteration(24))
+    opt.optimize()
+    tel.close()
+    steps = [r for r in keep.records if r.get("type") == "step"]
+    assert len(steps) == 24
+    assert steps[0]["loss"] == pytest.approx(math.log(128), abs=0.5)
+    assert np.median([r["loss"] for r in steps[-8:]]) < np.median(
+        [r["loss"] for r in steps[:4]])
+    for r in steps:
+        assert r["moe_dropped_pairs"] == 0.0
+        assert 0 < r["moe_pairs_local"] <= 4 * N * T * 2
+        assert r["moe_load_max_over_mean"] >= 1.0
+    assert steps[-1]["compile_count"] == 1
+    assert sum(r["count"] for r in keep.records
+               if r.get("type") == "compile") == 1
