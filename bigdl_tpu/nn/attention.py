@@ -144,9 +144,11 @@ def scaled_dot_product_attention(
     dropout); otherwise falls back to the dense path.
     ``impl='auto'`` (the default — so every in-framework attention call site
     inherits the kernel) picks flash under the same conditions once the
-    sequence is long enough to pay the kernel's fixed cost: with the
-    1024/512 block tuning, measured in-model wins on v5e are 1.13x @T=1024,
-    1.35x @2k, 1.61x @4k, 2.02x @8k — auto engages from T=1024; ``'dense'``
+    sequence is long enough to pay the kernel's fixed cost: at tiles of
+    1024/512, measured in-model wins on v5e were 1.13x @T=1024, 1.35x @2k,
+    1.61x @4k, 2.02x @8k (the kernel now picks its tiles from the shapes,
+    ``ops.flash_attention.pick_tiles``, and is faster at each of these)
+    — auto engages from T=1024; ``'dense'``
     forces the XLA path. ``causal`` masks with the aligned-at-end convention
     for Tq != Tk (a 1-query decode step sees every key).
 

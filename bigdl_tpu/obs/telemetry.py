@@ -33,6 +33,7 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Sequence
@@ -155,9 +156,14 @@ def observe_jit_compiles(jit_fn, seen: int, telemetry: "Telemetry", *,
         return seen
     if csize > seen:
         cache_hit = None if cache_watch is None else cache_watch.observe()
-        telemetry.compile_event(iteration=iteration, seconds=seconds,
-                                count=csize - seen, path=path,
-                                cache_hit=cache_hit)
+        # the tiles this dispatch's own trace chose (it began ``seconds``
+        # ago); a program that never imported the kernel chose none
+        flash = sys.modules.get("bigdl_tpu.ops.flash_attention")
+        telemetry.compile_event(
+            iteration=iteration, seconds=seconds, count=csize - seen,
+            path=path, cache_hit=cache_hit,
+            flash_tiles=flash and flash.take_tile_records(
+                since=time.perf_counter() - seconds))
         return csize
     return seen
 
@@ -686,6 +692,7 @@ class Telemetry:
     def compile_event(
         self, *, iteration: int, seconds: float, count: int = 1,
         path: str = "train", cache_hit: Optional[bool] = None,
+        flash_tiles: Optional[List[Dict]] = None,
     ) -> None:
         """One (re)compilation observed — hooked off the jit-cache-size delta
         at dispatch, the same introspection PR 2's ``compile_seconds``
@@ -693,21 +700,25 @@ class Telemetry:
         call (trace + XLA compile + first execution enqueue). ``cache_hit``
         (tri-state) says whether the persistent compile cache served the
         executable from disk — True on every compile is the artifact warm
-        boot's telemetry proof of "0 fresh compiles"."""
+        boot's telemetry proof of "0 fresh compiles". ``flash_tiles`` lists
+        the flash-attention tile choices that the compiling call's trace
+        made (``ops/flash_attention.take_tile_records``); the record carries
+        the field only where there were any."""
         with self._lock:
             self.compile_count += count
             self.compile_seconds += seconds
-        self.emit(
-            {
-                "type": "compile",
-                "path": path,
-                "iteration": int(iteration),
-                "count": int(count),
-                "seconds": round(seconds, 6),
-                "total_compiles": self.compile_count,
-                "cache_hit": cache_hit,
-            }
-        )
+        record = {
+            "type": "compile",
+            "path": path,
+            "iteration": int(iteration),
+            "count": int(count),
+            "seconds": round(seconds, 6),
+            "total_compiles": self.compile_count,
+            "cache_hit": cache_hit,
+        }
+        if flash_tiles:
+            record["flash_tiles"] = flash_tiles
+        self.emit(record)
         self.flush()  # compiles are rare; make them tail-able immediately
 
     # ---------------------------------------------------------------- warmup

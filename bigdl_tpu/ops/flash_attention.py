@@ -33,11 +33,14 @@ directly. ``interpret=True`` runs the kernel in the Pallas interpreter (CPU)
 from __future__ import annotations
 
 import math
+import threading
+import time
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -181,6 +184,114 @@ def _pick_block(requested: int, t: int) -> int:
     return b
 
 
+# Mosaic's default scoped-VMEM limit on a v5e core is 16 MiB (later chips
+# allow more); ``_working_set`` leaves out the compiler's own scratch and the
+# (bq,) row statistics, about 1 MiB at the largest tiles (compiled for a
+# described v5e: 21.0 MiB reported where the sum below says 20.0)
+_VMEM_BUDGET = 15 * 2 ** 20
+_LARGEST_TILE = 1024
+
+
+def _working_set(bq: int, bk: int, d: int, itemsize: int) -> int:
+    """Bytes of VMEM the hungriest of the three kernels holds at once: the
+    float32 score and probability tiles, every operand and result tile twice
+    (the pipeline fetches the next while this one computes), the float32
+    accumulators."""
+    scores = 2 * bq * bk * 4
+    q_tile, k_tile = bq * d * itemsize, bk * d * itemsize
+    fwd = scores + 2 * (2 * q_tile + 2 * k_tile) + bq * d * 4      # q o | k v
+    dq = scores + 2 * (3 * q_tile + 2 * k_tile) + bq * d * 4       # q do dq | k v
+    dkv = scores + 2 * (2 * q_tile + 4 * k_tile) + 2 * bk * d * 4  # q do | k v dk dv
+    return max(fwd, dq, dkv)
+
+
+def pick_tiles(tq: int, tk: int, d: int, itemsize: int):
+    """(block_q, block_k) of the three kernels, from the shapes of a call.
+
+    The largest tiles win: on a v5e 1024 x 1024 was the fastest forward and,
+    but for 1.8 % in one row, the fastest forward + backward of the nine
+    pairs of 256 / 512 / 1024, at T 1024 - 8192, head sizes 64 and 128, with
+    and without a window or lengths; (512, 1024) came second; k tiles of
+    2048 lost (``tools/flash_tile_table.py``; the table is in
+    docs/performance.md). So each axis takes the largest tile whose padding
+    ``_pick_block`` tolerates, the k axis 1024 only where the keys fill one
+    (below that nothing was measured and it stays at 512's), and while
+    ``_working_set`` is over ``_VMEM_BUDGET`` the larger of the two halves,
+    the q tile first. The mask's geometry does not enter: under a window of
+    1024 a smaller q tile computes fewer masked pairs and still lost,
+    forward and with the forward recomputed."""
+    bq = _pick_block(_LARGEST_TILE, tq)
+    bk = _pick_block(
+        _LARGEST_TILE if tk >= _LARGEST_TILE else _LARGEST_TILE // 2, tk)
+    while _working_set(bq, bk, d, itemsize) > _VMEM_BUDGET and max(bq, bk) > 128:
+        if bq >= bk:
+            bq //= 2
+        else:
+            bk //= 2
+    return bq, bk
+
+
+def _tile_geometry(tq: int, tk: int, bq: int, bk: int, causal: bool,
+                   window: Optional[int]):
+    """(tiles a head's grid computes on, pairs in them / pairs the mask lets
+    through): the kernels' own tile classification without per-sequence
+    lengths, which only a run knows."""
+    off = tk - tq
+    first_row = np.arange(-(-tq // bq))[:, None] * bq + off
+    first_col = np.arange(-(-tk // bk))[None, :] * bk
+    visited = np.ones((first_row.size, first_col.size), bool)
+    rows = np.arange(tq) + off
+    hi = np.full(tq, tk - 1)
+    lo = np.zeros(tq, np.int64)
+    if causal:
+        visited = visited & (first_row + bq - 1 >= first_col)
+        hi = np.minimum(rows, hi)
+    if window is not None:
+        visited = visited & (first_row - (first_col + bk - 1) < window)
+        lo = np.maximum(rows - (window - 1), 0)
+    visible = int(np.maximum(hi - lo + 1, 0).sum())
+    tiles = int(visited.sum())
+    return tiles, tiles * bq * bk / max(visible, 1)
+
+
+_tile_records: dict = {}  # shape and tile -> (when last traced, the record)
+_tile_records_lock = threading.Lock()
+
+
+def take_tile_records(since: float = 0.0) -> list:
+    """The tile choices traced at or after ``since`` (a ``time.perf_counter``
+    reading), one per distinct (Tq, Tk, d, dtype, causal, window), and forget
+    them all: ``Telemetry`` writes those that the compiling call's own trace
+    made into its ``compile`` record; what an earlier, unobserved trace left
+    behind belongs to no record."""
+    with _tile_records_lock:
+        out = [record for at, record in _tile_records.values() if at >= since]
+        _tile_records.clear()
+    return out
+
+
+def _resolve_tiles(q, k, causal: bool, window: Optional[int],
+                   block_q: Optional[int], block_k: Optional[int]):
+    tq, tk, d = q.shape[2], k.shape[2], q.shape[3]
+    bq, bk = pick_tiles(tq, tk, d, q.dtype.itemsize)
+    if block_q is not None:
+        bq = _pick_block(block_q, tq)
+    if block_k is not None:
+        bk = _pick_block(block_k, tk)
+    key = (tq, tk, d, q.dtype.name, causal, window, bq, bk)
+    with _tile_records_lock:
+        if key in _tile_records:
+            record = _tile_records[key][1]
+        else:
+            tiles, waste = _tile_geometry(tq, tk, bq, bk, causal, window)
+            record = dict(
+                tq=tq, tk=tk, d=d, dtype=q.dtype.name, causal=causal,
+                window=window, block_q=bq, block_k=bk, visited_tiles=tiles,
+                visited_over_visible=round(waste, 4))
+        _tile_records[key] = (time.perf_counter(), record)
+    return bq, bk
+
+
 def _window_start(qi, block_q: int, block_k: int, causal_offset: int,
                   window: int):
     """First k tile that a q tile's window reaches (for the dK/dV kernel,
@@ -246,16 +357,15 @@ def _expand_lengths(lengths, n: int, h: int, tk: int):
 
 
 def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
-                    block_q: int, block_k: int, interpret: bool, mask_q: bool,
+                    bq: int, bk: int, interpret: bool, mask_q: bool,
                     window: Optional[int] = None):
-    """Returns (out (N,H,Tq,d), lse (N*H, Tq_padded)) — lse is the bwd residual."""
+    """Returns (out (N,H,Tq,d), lse (N*H, Tq_padded)) — lse is the bwd residual.
+    ``bq``/``bk`` are the resolved tiles (``_resolve_tiles``)."""
     n, h, tq, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    bq = _pick_block(block_q, tq)
-    bk = _pick_block(block_k, tk)
     has_lengths = lengths is not None
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
@@ -482,7 +592,7 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
-                    scale: Optional[float], block_q: int, block_k: int,
+                    scale: Optional[float], bq: int, bk: int,
                     interpret: bool, mask_q: bool,
                     window: Optional[int] = None):
     n, h, tq, d = q.shape
@@ -490,8 +600,6 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
     group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    bq = _pick_block(block_q, tq)
-    bk = _pick_block(block_k, tk)
     has_lengths = lengths is not None
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
@@ -657,7 +765,8 @@ _flash_core.defvjp(_fwd_rule, _bwd_rule)
 
 
 def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
-                    block_q: int = 1024, block_k: int = 512,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False,
                     lengths: Optional[jax.Array] = None,
                     mask_q: Optional[bool] = None,
@@ -690,6 +799,9 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     convention (row i ↔ global position ``i + Tk - Tq``), matching
     ``causal``. Composes with ``causal``.
 
+    The (q, k) tile of the three kernels follows the shapes
+    (``pick_tiles``); ``block_q`` / ``block_k`` override it, for tests.
+
     ``interpret=True`` runs through the Pallas interpreter (for CPU
     tests). Differentiable: the backward is a pair of Pallas kernels
     streaming tiles off the saved logsumexp (module docstring).
@@ -702,5 +814,6 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
         raise ValueError(
             f"flash_attention: {q.shape[1]} query heads cannot share "
             f"{k.shape[1]} key / {v.shape[1]} value heads")
-    return _flash_core(q, k, v, lengths, causal, scale, block_q, block_k,
+    bq, bk = _resolve_tiles(q, k, causal, window, block_q, block_k)
+    return _flash_core(q, k, v, lengths, causal, scale, bq, bk,
                        interpret, bool(mask_q), window)

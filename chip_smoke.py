@@ -460,10 +460,17 @@ def check_transformer_step(t=2048, batch=8, vocab=8192, hidden=512, heads=8,
     step, specs = opt._step_export_info
     n_calls = assert_mosaic(step.lower(*specs).as_text(),
                             "nn.Transformer LocalOptimizer step")
+    tiles = [tile for r in tel.ring.records if r.get("type") == "compile"
+             for tile in r.get("flash_tiles", ())]
     tel.close()
+    if on_tpu() and len(tiles) != 1:  # one attention shape; the CPU is dense
+        raise AssertionError(
+            f"phase D transformer: want one flash tile choice in the compile "
+            f"record, got {tiles}")
     log(f"  nn.Transformer lm T={t} d={hidden // heads} x{layers} layers "
         f"through LocalOptimizer: loss {losses[0]:.4f} -> {losses[-1]:.4f}, "
-        f"{n_calls} Mosaic calls in the step")
+        f"{n_calls} Mosaic calls in the step, compile record's flash_tiles "
+        f"{tiles}")
 
 
 def phase_kernels() -> None:

@@ -6,6 +6,7 @@ small sizes on the CPU: D 64, 2 periods of 4 layers, 8 query / 2 K/V heads of
 import importlib.util
 import math
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,8 @@ from bigdl_tpu.models import decoder_lm, decoder_lm_reference as ref
 from bigdl_tpu.nn.attention import apply_rotary, scaled_dot_product_attention
 from bigdl_tpu.nn.decoder import rope_inv_freq
 from bigdl_tpu.ops.flash_attention import (
-    _dense_reference, _window_count, flash_attention)
+    _VMEM_BUDGET, _dense_reference, _tile_geometry, _window_count,
+    _working_set, flash_attention, pick_tiles, take_tile_records)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -107,13 +109,19 @@ def test_counters_match_the_references_own_routing(both):
 
 # ------------------------------------------------------------------ the kernel
 
-@pytest.mark.parametrize("h,hkv,window", [(4, 2, 300), (2, 2, 300), (4, 1, None)],
-                         ids=["window+groups", "window", "groups"])
-def test_flash_window_and_grouped_heads_match_dense(h, hkv, window):
+@pytest.mark.parametrize("t,tile,h,hkv,window", [
+    (640, 128, 4, 2, 300), (640, 128, 2, 2, 300), (640, 128, 4, 1, None),
+    (4608, None, 2, 1, 2200), (600, None, 2, 1, 300)],
+    ids=["window+groups", "window", "groups", "rule-1024x1024", "rule-600x128"])
+def test_flash_window_and_grouped_heads_match_dense(t, tile, h, hkv, window):
     """T 640 in tiles of 128: with a window of 300 a k tile 4 below a q tile
     is skipped, the diagonal and the window's edge are masked, the tile one
-    below the diagonal is full."""
-    t, d = 640, 16
+    below the diagonal is full. At the tiles the rule picks (``tile`` None):
+    T 4608 is four and a half tiles of 1024, so under a window of 2200 the
+    last q tile is half padding and sees one skipped, two masked and two full
+    k tiles, and the last k tile's q-tile stretch is clamped; T 600 is one
+    q tile against five k tiles of 128, the last one padded."""
+    d = 16
     rng = np.random.default_rng(0)
     q, w = (jnp.asarray(rng.standard_normal((1, h, t, d)), jnp.float32)
             for _ in range(2))
@@ -121,7 +129,7 @@ def test_flash_window_and_grouped_heads_match_dense(h, hkv, window):
             for _ in range(2))
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, True, block_q=128, block_k=128,
+        return flash_attention(q, k, v, True, block_q=tile, block_k=tile,
                                interpret=True, window=window)
 
     def dense(q, k, v):
@@ -133,6 +141,85 @@ def test_flash_window_and_grouped_heads_match_dense(h, hkv, window):
     for g, r in zip(got, want):
         assert g.shape == r.shape
         np.testing.assert_allclose(g, r, atol=2e-5)
+
+
+@pytest.mark.parametrize("tq,tk,d,itemsize,tiles", [
+    (600, 600, 64, 2, (600, 128)),        # the padding bound, as before
+    (1536, 1536, 64, 2, (512, 512)),      # 1024 would pad a third on
+    (1024, 1024, 64, 2, (1024, 1024)),    # as measured (chip_smoke phase D)
+    (2048, 2048, 64, 2, (1024, 1024)),    # as measured (nn.Transformer)
+    (4096, 4096, 128, 2, (1024, 1024)),   # as measured (chip_smoke phase D)
+    (8192, 8192, 128, 2, (1024, 1024)),   # as measured (Mellum2's layers)
+    (8192, 8192, 128, 4, (1024, 1024)),   # float32 operands still fit
+    (8192, 8192, 256, 4, (512, 512)),     # over the budget: q halves, then k
+    (8192, 8192, 512, 4, (256, 512)),     # and q again
+    (1, 4096, 128, 2, (1, 1024)),         # a decode step: one row, whole k tiles
+])
+def test_tiles_follow_the_shapes(tq, tk, d, itemsize, tiles):
+    assert pick_tiles(tq, tk, d, itemsize) == tiles
+    bq, bk = tiles
+    assert _working_set(bq, bk, d, itemsize) <= _VMEM_BUDGET
+    for t, b in ((tq, bq), (tk, bk)):  # _pick_block's bound on padded rows
+        assert b <= 128 or ((-t) % b) * 8 <= t
+    if d == 256:  # the step before did not fit
+        assert _working_set(512, 1024, d, itemsize) > _VMEM_BUDGET
+
+
+def test_tile_records_say_what_ran_and_how_much_of_it_is_masked():
+    # per head: 36 of 64 tile pairs at 1024 x 1024 under the causal mask,
+    # 12 % more pairs than the mask lets through; under a window of 1024 a
+    # q tile computes two half-masked k tiles: twice the visible pairs
+    assert _tile_geometry(8192, 8192, 1024, 1024, True, None) == (
+        36, pytest.approx(1.1249, abs=1e-4))
+    assert _tile_geometry(8192, 8192, 1024, 1024, True, 1024) == (
+        15, pytest.approx(2.0, abs=1e-3))
+    assert _tile_geometry(8192, 8192, 512, 512, True, 1024) == (
+        45, pytest.approx(1.5, abs=1e-3))
+    assert _tile_geometry(600, 600, 600, 128, False, None) == (
+        5, pytest.approx(640 / 600))
+
+    take_tile_records()
+    q = jax.ShapeDtypeStruct((1, 2, 2048, 16), jnp.bfloat16)
+    attend = lambda **kw: jax.eval_shape(  # noqa: E731
+        lambda q: flash_attention(q, q, q, True, **kw), q)
+    attend()
+    attend()                     # the same shape again: one record
+    attend(window=512)
+    attend(block_q=256)          # an explicit tile wins, and is what is recorded
+    got = {(r["window"], r["block_q"], r["block_k"]): r
+           for r in take_tile_records()}
+    assert set(got) == {
+        (None, 1024, 1024), (512, 1024, 1024), (None, 256, 1024)}
+    assert got[(None, 1024, 1024)] == dict(
+        tq=2048, tk=2048, d=16, dtype="bfloat16", causal=True, window=None,
+        block_q=1024, block_k=1024, visited_tiles=3,
+        visited_over_visible=pytest.approx(1.4993, abs=1e-4))
+    assert take_tile_records() == []
+    attend()                     # a trace older than the caller asks about
+    assert take_tile_records(since=time.perf_counter()) == []
+    assert take_tile_records() == []  # is dropped, not kept for the next one
+
+
+def test_compile_record_lists_the_tiles_its_trace_chose():
+    from bigdl_tpu.obs.telemetry import Telemetry, observe_jit_compiles
+
+    q = jnp.ones((1, 1, 256, 8), jnp.float32)
+    # a trace that no telemetry observed (another shape) must not ride along
+    jax.eval_shape(lambda q: flash_attention(q, q, q, True), q[:, :, :128])
+    tel = Telemetry()
+    for fn in (lambda q: flash_attention(q, q, q, True, interpret=True),
+               lambda q: q + 1.0):
+        step = jax.jit(fn)
+        t0 = time.perf_counter()
+        step(q)
+        observe_jit_compiles(step, 0, tel, iteration=1,
+                             seconds=time.perf_counter() - t0, path="test")
+    with_flash, without = [r for r in tel.ring.records
+                           if r["type"] == "compile"]
+    tel.close()
+    assert [(r["tq"], r["block_q"], r["block_k"], r["visited_tiles"])
+            for r in with_flash["flash_tiles"]] == [(256, 256, 256, 1)]
+    assert "flash_tiles" not in without
 
 
 def test_window_grid_holds_only_the_tiles_a_window_touches():

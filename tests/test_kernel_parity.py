@@ -115,11 +115,13 @@ class TestFusedEpilogueParity:
 
 @pytest.mark.slow  # tier-1 covers this kernel via tests/test_flash_attention.py
 @pytest.mark.parametrize("dtype", DTYPES, ids=("f32", "bf16"))
+@pytest.mark.parametrize("tile", (64, None), ids=("tile64", "rule"))
 @pytest.mark.parametrize("tq,tk", ((128, 128), (96, 160)),
                          ids=("square", "rect"))
-def test_flash_attention_parity(tq, tk, dtype):
+def test_flash_attention_parity(tq, tk, dtype, tile):
     """The pre-existing flash kernel rides the same gate: fwd + q-grad vs the
-    dense softmax reference, in interpret mode."""
+    dense softmax reference, in interpret mode; at tiles of 64 and at the
+    tiles the rule picks from the shapes."""
     n, h, d = 1, 2, 16
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(9), 3)
     q = _rand(kq, (n, h, tq, d), dtype)
@@ -127,12 +129,12 @@ def test_flash_attention_parity(tq, tk, dtype):
     v = _rand(kv, (n, h, tk, d), dtype)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2  # softmax chain: looser f32
     out = flash_attention(q, k, v, causal=True, interpret=True,
-                          block_q=64, block_k=64)
+                          block_q=tile, block_k=tile)
     ref = _dense_reference(q, k, v, True, None)
     _close(out, ref, tol, "flash fwd")
     gk = jax.grad(lambda q: jnp.sum(
         flash_attention(q, k, v, causal=True, interpret=True,
-                        block_q=64, block_k=64).astype(jnp.float32) ** 2))(q)
+                        block_q=tile, block_k=tile).astype(jnp.float32) ** 2))(q)
     gr = jax.grad(lambda q: jnp.sum(
         _dense_reference(q, k, v, True, None).astype(jnp.float32) ** 2))(q)
     _close(gk, gr, tol, "flash dq")
