@@ -1,9 +1,8 @@
 """Shared planted-signal synthetic data generators.
 
-The offline-feasible accuracy evidence (tools/convergence.py) and the
-north-star recipe proxy (tools/northstar_proxy.py) must draw from the SAME
-planted signal, or their findings silently decouple — one generator,
-parameterized by layout/dtype/noise, keeps them bound (round-5 review).
+The offline-feasible accuracy evidence (tools/convergence.py) draws its
+planted signal from here: one generator, parameterized by
+layout/dtype/noise.
 
 The recipe is the cifar loader's template trick (``dataset/cifar.py``)
 scaled to arbitrary resolution: K low-res class templates, nearest-neighbor

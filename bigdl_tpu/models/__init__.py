@@ -11,8 +11,8 @@ from .ncf import NeuralCF
 from . import decoder_lm  # decoder_lm.from_config(dict) -> nn.DecoderLM
 
 def flagship_model(batch: int = 8, seed: int = 0, stem: str = "conv7"):
-    """The framework's flagship benchmark config (single source of truth for
-    bench.py and __graft_entry__): ResNet-50 / synthetic ImageNet.
+    """The framework's flagship config (what __graft_entry__ builds):
+    ResNet-50 / synthetic ImageNet.
 
     Returns (model, example_images (B,3,224,224) f32, example_labels, name).
     """

@@ -216,9 +216,11 @@ def scaled_dot_product_attention(
             "impl='ring' requires Engine.set_sequence_parallel(mesh, axis) "
             "to be registered first")
     if impl == "auto" and eligible:
-        # builder-measured on v5e in round 3 (BENCH_MODE=transformer,
-        # 1024/512 blocks): flash wins in-model from T=1024 (1.13x) through
-        # 8k (2.02x); dense also OOMs near T=16k. The gate is what the code
+        # flash from T=1024 on: the shortest length the kernel's tile table
+        # covers (PERF.md §6, PR 29), and where the round-3 bare-step A/B
+        # against dense first favoured it (BASELINE.md, history; not
+        # re-measured through optimize()); dense also OOMs near T=16k. The
+        # gate is what the code
         # can observe — the backend (in `eligible`) and the shapes; a Mosaic
         # compile failure surfaces as the compiler's own error.
         impl = "flash" if min(q.shape[-2], k.shape[-2]) >= 1024 else "dense"

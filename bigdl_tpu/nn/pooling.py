@@ -90,10 +90,9 @@ class SpatialMaxPooling(AbstractModule):
         (kh, kw), (sh, sw), (ph, pw) = self.kernel, self.stride, self.pad
         pad_h = _pool_padding(x.shape[2], kh, sh, ph, self.ceil_mode)
         pad_w = _pool_padding(x.shape[3], kw, sw, pw, self.ceil_mode)
-        # forward = XLA reduce_window; backward = the Pallas kernel when
-        # BIGDL_ENABLE_PALLAS_MAXPOOL_GRAD=1 on TPU (opt-in pending the
-        # post-optimization A/B — the committed measurement has XLA's
-        # SelectAndScatter ahead on v5e; see ops/maxpool.py _use_pallas_grad)
+        # forward = XLA reduce_window; backward = XLA's SelectAndScatter
+        # unless BIGDL_MAXPOOL_GRAD_IMPL opts into another (ops/maxpool.py
+        # _grad_impl; ROADMAP Design D3 has the A/B that decides)
         return maxpool2d(x, (kh, kw), (sh, sw), (pad_h, pad_w)), state
 
 

@@ -19,8 +19,7 @@ Four pieces:
 * :mod:`~bigdl_tpu.obs.perf` — always-on MFU/roofline accounting
   (:class:`PerfAccountant`), per-step compute/comms/input/host
   decomposition on ``perf`` records, and the :class:`PerfMonitor`
-  regression detector with bounded triggered profiler capture
-  (``tools/perf_gate.py`` is the CI consumer);
+  regression detector with bounded triggered profiler capture;
 * :mod:`~bigdl_tpu.obs.fleet` — fleet identity (process-tagged records,
   per-process ``telemetry/p<k>.jsonl`` streams), atomic heartbeat files and
   the :class:`FleetMonitor` straggler/lost-host detector;

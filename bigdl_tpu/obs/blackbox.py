@@ -16,10 +16,10 @@ Two halves, both always-on and ~free until the moment of death:
 
 * :func:`dump_postmortem` — on any abnormal exit, freeze the rings plus
   per-thread Python stacks, the active :class:`TraceContext`, an
-  env/config/mesh/XLA-flags fingerprint, the fleet heartbeat snapshot, the
-  last ``PERF_BASELINE.json`` comparison and the newest verified
-  checkpoint's manifest pointer into ``<run_dir>/postmortem/<seq>-<reason>/``
-  as a *verified bundle*: every payload file lands first, then
+  env/config/mesh/XLA-flags fingerprint, the fleet heartbeat snapshot and
+  the newest verified checkpoint's manifest pointer into
+  ``<run_dir>/postmortem/<seq>-<reason>/`` as a *verified bundle*: every
+  payload file lands first, then
   ``MANIFEST.json`` (sha256 + byte size per file) is written LAST via
   tmp+rename — exactly the checkpoint/AOT-artifact discipline, so a
   half-written bundle is detectable (:class:`BundleTruncated`) and a
@@ -40,7 +40,7 @@ Dump triggers are wired at every layer that declares an abnormal exit:
 ``FailurePolicy`` terminal escalations and unhandled exceptions escaping
 ``optimize()``, ``PreemptionGuard`` SIGTERM, ``ElasticCoordinator``
 ``ElasticFleetExhausted``, ``ServingSupervisor`` dead/wedged workers and
-exceptions escaping ``ModelServer``, and the bench child harness.
+exceptions escaping ``ModelServer``.
 """
 
 from __future__ import annotations
@@ -327,7 +327,7 @@ def _fingerprint(**extra: Any) -> Dict[str, Any]:
         "identity": _fleet.process_identity(),
         "env": {
             k: v for k, v in sorted(os.environ.items())
-            if k.startswith(("BIGDL_", "BENCH_", "JAX_", "XLA_", "LIBTPU"))
+            if k.startswith(("BIGDL_", "JAX_", "XLA_", "LIBTPU"))
             and k != "BIGDL_LOCK_DEBUG"
         },
     }
@@ -361,39 +361,6 @@ def _thread_stacks() -> str:
         lines.extend(traceback.format_stack(frame))
         lines.append("\n")
     return "".join(lines)
-
-
-def _perf_comparison(rings: Dict[str, List[Dict]]) -> Optional[Dict]:
-    """Last observed step/perf numbers vs the committed PERF_BASELINE.json
-    (env ``BIGDL_PERF_BASELINE`` overrides the repo-root default)."""
-    path = os.environ.get("BIGDL_PERF_BASELINE")
-    if not path:
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-            "PERF_BASELINE.json")
-    if not os.path.exists(path):
-        return None
-    with open(path) as f:
-        baseline = json.load(f)
-    steps = rings.get("step") or []
-    last = steps[-1] if steps else {}
-    observed = {
-        "img_per_sec_per_chip": last.get("records_per_sec"),
-        "mfu": last.get("mfu"),
-        "step_ms": (round(last["wall_s"] * 1000.0, 3)
-                    if isinstance(last.get("wall_s"), (int, float)) else None),
-    }
-    delta_pct: Dict[str, Optional[float]] = {}
-    for name, spec in (baseline.get("metrics") or {}).items():
-        base, got = spec.get("value"), observed.get(name)
-        if (isinstance(base, (int, float)) and base
-                and isinstance(got, (int, float))):
-            delta_pct[name] = round(100.0 * (got - base) / base, 2)
-        else:
-            delta_pct[name] = None
-    return {"baseline_path": path, "baseline": baseline,
-            "observed": observed, "delta_pct": delta_pct}
 
 
 def _checkpoint_pointer(checkpoint_dir: Optional[str]) -> Optional[Dict]:
@@ -443,7 +410,6 @@ def dump_postmortem(reason: str, *,
     - ``trace.json`` — active :class:`TraceContext` + its recent spans
     - ``fingerprint.json`` — env/config/mesh/XLA-flags identity
     - ``fleet.json`` — heartbeat snapshot of every process in the run dir
-    - ``perf_baseline.json`` — last step vs ``PERF_BASELINE.json``
     - ``checkpoint.json`` — newest verified checkpoint's manifest pointer
     - ``reason.json`` — reason, error + traceback, ring/truncation
       counts, dump latency
@@ -519,12 +485,6 @@ def dump_postmortem(reason: str, *,
             beats = _fleet.read_heartbeats(root)
             _write_json("fleet.json",
                         {str(k): v for k, v in sorted(beats.items())})
-        except Exception:  # lint: disable=BDL007 partial bundle beats no bundle; manifest seals only what landed
-            pass
-        try:
-            perf = _perf_comparison(rings)
-            if perf is not None:
-                _write_json("perf_baseline.json", perf)
         except Exception:  # lint: disable=BDL007 partial bundle beats no bundle; manifest seals only what landed
             pass
         try:
@@ -638,8 +598,8 @@ def verify_bundle(path: str) -> Dict[str, Any]:
 def load_bundle(path: str) -> Dict[str, Any]:
     """Verify then load a bundle into memory:
     ``{"path", "manifest", "rings": {type: [records]}, "reason",
-    "fingerprint", "trace", "fleet", "perf_baseline", "checkpoint",
-    "stacks"}`` (absent sections -> None/{})."""
+    "fingerprint", "trace", "fleet", "checkpoint", "stacks"}`` (absent
+    sections -> None/{})."""
     manifest = verify_bundle(path)
     out: Dict[str, Any] = {"path": os.path.abspath(path),
                            "manifest": manifest, "rings": {}}
@@ -649,8 +609,7 @@ def load_bundle(path: str) -> Dict[str, Any]:
             with open(os.path.join(path, rel)) as f:
                 out["rings"][rtype] = [
                     json.loads(line) for line in f if line.strip()]
-    for name in ("reason", "fingerprint", "trace", "fleet",
-                 "perf_baseline", "checkpoint"):
+    for name in ("reason", "fingerprint", "trace", "fleet", "checkpoint"):
         fp = os.path.join(path, name + ".json")
         if os.path.exists(fp):
             with open(fp) as f:

@@ -4,8 +4,7 @@ decomposition, and anomaly-triggered profiler capture.
 Where :mod:`~bigdl_tpu.obs.health` answers "why is the model unhealthy" and
 :mod:`~bigdl_tpu.obs.fleet` answers "which host is behind", this module
 answers "**how fast is the hardware actually running, and why not faster**"
-— continuously, on every telemetry-attached run, instead of once per
-hand-run ``bench.py`` round:
+— continuously, on every telemetry-attached run:
 
 * **Cost model** — :func:`program_cost` derives a step's model FLOPs / HBM
   bytes / collective operand bytes ONCE per compiled program from the
@@ -38,11 +37,10 @@ hand-run ``bench.py`` round:
   requests (``Optimizer.set_profile`` windows and monitor-triggered
   captures share one profiler) so two windows can never interleave.
 
-Peak hardware numbers come from :func:`bigdl_tpu.utils.compat.device_peaks`
-(the same per-backend table ``bench.py``'s MFU headline uses) so the live
-records and the bench artifact can never disagree on the denominator.
-``tools/perf_gate.py`` is the CI consumer: it gates a run's perf records (or
-a bench artifact) against a committed baseline with tolerance bands.
+Peak hardware numbers come from :func:`bigdl_tpu.utils.compat.device_peaks`.
+These figures are live telemetry, not the yardstick: the measured speeds are
+``benchmark/``'s (``PERF.md`` §2-§3), and ``bytes_accessed`` over-counts HBM
+traffic on the TPU (``PERF.md`` §6, PR 24).
 Schema + knobs: docs/observability.md; the walkthrough: docs/performance.md.
 """
 
@@ -144,8 +142,7 @@ class StepCost:
     """One compiled program's cost-model figures (host metadata only).
 
     ``flops`` / ``bytes_accessed`` come from the HLO cost analysis
-    (``obs/profiler.py``'s sanctioned seam — the same introspection behind
-    ``bench.py``'s MFU headline); ``collective_bytes`` /
+    (``obs/profiler.py``'s sanctioned seam); ``collective_bytes`` /
     ``grad_exchange_bytes`` from the StableHLO collective-operand parser
     (PR 12's compressed-comms lock). All fields ``None``-graceful: a backend
     without a cost model yields an empty cost, and every consumer degrades.
@@ -213,9 +210,8 @@ def program_cost(fn, specs) -> Optional[StepCost]:
 def pipeline_bubble_fraction(n_stages: int, n_micro: int) -> float:
     """The GPipe schedule's idle fraction: T = n_micro + S - 1 ticks, of
     which S - 1 are ramp-up/drain bubbles per stage — (S-1)/(n_micro+S-1).
-    One definition shared by the :class:`PerfAccountant`'s per-step
-    ``pipe_bubble_frac`` stamp and ``tools/pipeline_bubble.py``'s measured
-    schedule sweep (the tests cross-check the two)."""
+    The :class:`PerfAccountant` stamps it on every step as
+    ``pipe_bubble_frac``."""
     if n_stages < 1 or n_micro < 1:
         raise ValueError(
             f"need n_stages >= 1 and n_micro >= 1, got {n_stages}/{n_micro}"
@@ -317,7 +313,7 @@ class PerfConfig:
         peak_flops: per-chip peak override (flops/s). ``None`` resolves the
             backend through :func:`~bigdl_tpu.utils.compat.device_peaks` —
             the CPU entry is empty, so MFU reads ``None`` there unless a
-            test/bench pins this.
+            test pins this.
         monitor: run the :class:`PerfMonitor` breach detection.
         slowdown_factor: rolling-median breach bound — the recent
             step-time median tripping ``factor ×`` the frozen baseline

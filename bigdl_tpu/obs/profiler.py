@@ -4,8 +4,7 @@ Where :mod:`~bigdl_tpu.obs.health` streams per-step statistics, this module
 answers the STATIC half of "why is the model unhealthy": where the HBM goes
 (per-layer parameter and optimizer-slot bytes, per-shard for the ZeRO-1 flat
 layout and GSPMD-committed arrays) and what one train step costs
-(FLOPs / bytes accessed via ``compiled.cost_analysis()`` — the same
-introspection ``bench.py`` uses for its MFU figure).
+(FLOPs / bytes accessed via ``compiled.cost_analysis()``).
 
 Everything here is one-shot and host-side: byte counts come from
 shapes/dtypes and committed shardings (``sharding.shard_shape`` — a metadata

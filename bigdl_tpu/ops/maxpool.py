@@ -1,10 +1,10 @@
 """Max-pooling with a Pallas TPU backward kernel.
 
 XLA derives the gradient of ``lax.reduce_window(max)`` as a SelectAndScatter
-op, which the round-3 trace analysis measured at 346 GB/s — half the v5e's
-elementwise rate — making it 20% of the Inception-v1 train step (13 max
-pools) and 0.7 ms of ResNet-50's (bench_artifacts/TRACE_ANALYSIS_r3.md).
-The reference hits the same problem with a dedicated native kernel
+op: with the backward's ``reduce-window`` it is 13.6 of the 53.1 ms of
+Inception-v1's train step on a v5e (13 max pools; ``PERF.md`` §5,
+``select-and-scatter`` 9.9 + ``reduce-window`` 3.7). The reference hits the
+same problem with a dedicated native kernel
 (``$DL/nn/SpatialMaxPooling.scala`` backward loops in Scala/MKL); this is
 the TPU-native equivalent.
 
@@ -249,9 +249,10 @@ def _grad_impl() -> str:
     shift (pure-XLA strided-compare decomposition, ``maxpool_grad_shift``),
     pallas (the Mosaic kernel — also reachable via the legacy
     ``BIGDL_ENABLE_PALLAS_MAXPOOL_GRAD=1``)}. Both alternatives are
-    opt-in pending the on-chip A/B (tools/maxpool_ab.py)."""
+    opt-in pending the on-chip A/B through ``optimize()`` on
+    ``inception_v1.hostfed`` (ROADMAP Design D3)."""
     impl = os.environ.get("BIGDL_MAXPOOL_GRAD_IMPL", "").lower()
-    if impl == "xla":  # the A/B tool's name for the SelectAndScatter side
+    if impl == "xla":  # a second spelling of the SelectAndScatter side
         impl = "sas"
     if impl in ("sas", "shift", "pallas"):
         return impl
@@ -299,9 +300,9 @@ def maxpool_grad_shift(x, dy, kernel, stride, padding):
     for each in-window offset (a, b), the input positions it addresses are
     one strided slice of the padded input; their gradient contribution is
     ``dy * (x_slice == window_max)``, placed back by an interior-dilated
-    pad (stride-1 interior, offset lo) — all elementwise/pad ops XLA fuses
-    well, vs SelectAndScatter's measured 346 GB/s (half the v5e
-    elementwise rate, TRACE_ANALYSIS_r3.md).
+    pad (stride-1 interior, offset lo) — all elementwise/pad ops XLA
+    fuses. Not measured against SelectAndScatter on the chip (``PERF.md``
+    §5 has what the default costs; ROADMAP Design D3 has the A/B).
 
     Tie semantics differ from SelectAndScatter: gradient flows to EVERY
     tied max position in a window, not just the first in row-major order —

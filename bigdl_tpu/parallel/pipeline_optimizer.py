@@ -268,7 +268,7 @@ class PipelineOptimizer(_StackedParallelOptimizer):
             m.set_mesh(mesh)
         # one bubble-fraction stamp per fit: the schedule is shared (the
         # n_micro override applies to every stack; otherwise modules default
-        # to S) — cross-checked against tools/pipeline_bubble.py in tests
+        # to S)
         n_micro = self.n_micro or mods[0].n_micro or s
         self._perf.note_pipeline_schedule(s, n_micro)
         return mods

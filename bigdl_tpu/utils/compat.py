@@ -177,9 +177,8 @@ class DevicePeaks:
 
 
 # bf16 peak matmul TFLOP/s, HBM GB/s and per-chip ICI GB/s by device_kind
-# substring. THE one table behind every MFU figure in the repo: bench.py's
-# headline and the live obs/perf.py step records both resolve through
-# device_peaks(), so the two can never disagree on the denominator.
+# substring: the table behind the live obs/perf.py step records' MFU and
+# chip_smoke.py's device check.
 # Source: Google Cloud TPU documentation, one page per generation ("TPU v5e":
 # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s = 200 GB/s ICI; likewise "TPU
 # v2" ... "TPU v6e"). device_kind spells v5e as "TPU v5 lite".
@@ -300,7 +299,7 @@ def compilation_cache_entries():
     """Names of the persisted executables in the active cache dir, or ``None``
     when no persistent cache is configured. Snapshot before compiling, then
     diff with :func:`compilation_cache_hit` to tell a cache hit from a cold
-    compile — the bench artifact's ``compile_cache_hit`` field."""
+    compile — the telemetry ``compile`` record's ``cache_hit`` field."""
     d = jax.config.jax_compilation_cache_dir
     if not d or not os.path.isdir(d):
         return None

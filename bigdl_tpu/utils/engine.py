@@ -73,8 +73,7 @@ class _EngineState:
     # ops/ kernels when True. Read at TRACE time (docs/performance.md).
     fused_kernels: Optional[bool] = None
     # XLA scheduler/combiner flags applied via set_xla_flags: name -> value
-    # as Engine manages them in XLA_FLAGS (reported in telemetry run headers
-    # and the bench config artifact).
+    # as Engine manages them in XLA_FLAGS (reported in telemetry run headers).
     xla_flags: dict = dataclasses.field(default_factory=dict)
     # names the user had already pinned in XLA_FLAGS before set_xla_flags
     # ran (env-respecting: Engine never overrides those)
@@ -411,7 +410,7 @@ class Engine:
     # scheduler (overlap collectives/DMAs with compute) and the collective
     # combiners (batch small collectives into fewer, bigger ones). Names are
     # validated so a typo'd knob fails loudly instead of silently doing
-    # nothing for a whole bench round.
+    # nothing.
     XLA_FLAG_ALLOWED = {
         "xla_tpu_enable_latency_hiding_scheduler": bool,
         "xla_latency_hiding_scheduler_rerun": int,
@@ -480,9 +479,9 @@ class Engine:
         before this call is kept (Engine only manages the tokens it wrote
         itself — re-calls update or remove those). Must run before the jax
         backend initializes to affect THIS process; afterwards it still
-        updates the env (bench/child subprocesses inherit it) but warns.
+        updates the env (child subprocesses inherit it) but warns.
         Returns the full mapping Engine now manages; telemetry run headers
-        and the bench config artifact report it (``Engine.xla_flags()``).
+        report it (``Engine.xla_flags()``).
 
         Known defect (chip runs, PR 21: jax 0.9.0 / libtpu 0.0.34 on a v5e
         host): with ``xla_tpu_enable_latency_hiding_scheduler`` and
@@ -517,9 +516,9 @@ class Engine:
             st.xla_flags = {**prev_managed, **merged}
             if not cls._xla_env_target():
                 # CPU-pinned process: the knobs are recorded (telemetry run
-                # headers / bench artifacts still report the requested
-                # config) but NOT written to XLA_FLAGS — the CPU client
-                # aborts on TPU-only flag names
+                # headers still report the requested config) but NOT
+                # written to XLA_FLAGS — the CPU client aborts on TPU-only
+                # flag names
                 if merged:
                     warnings.warn(
                         "set_xla_flags on a CPU-pinned process "
@@ -569,7 +568,7 @@ class Engine:
     @classmethod
     def xla_flags(cls) -> dict:
         """The XLA flags Engine manages (reported in the telemetry run
-        header and bench config artifact); empty when none were set."""
+        header); empty when none were set."""
         return dict(cls._state.xla_flags)
 
     @classmethod

@@ -5,8 +5,7 @@ program leaves the directory alone; where it is not, the cache goes to the
 fixed ``<checkout>/.jax_cache``. The cache config is process-global jax
 state, so the round trips run in subprocesses: a cold run populates the
 cache dir, a restarted process must report a hit (no new entries written) —
-the mechanism ``chip_smoke.py --expect-cache-hit`` and bench.py's
-``compile_cache_hit`` field rely on.
+the mechanism ``chip_smoke.py --expect-cache-hit`` relies on.
 """
 
 import json

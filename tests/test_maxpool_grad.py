@@ -5,8 +5,8 @@ the vjp XLA itself derives for ``lax.reduce_window(max)`` — including its
 first-element-in-scan-order tie-breaking, which the constant-input and
 duplicate-value cases below pin down explicitly.
 
-Runs in Pallas interpret mode (CPU); the TPU lowering is exercised by the
-bench/driver on the real chip.
+Runs in Pallas interpret mode (CPU); the TPU lowering is exercised by
+``chip_smoke.py`` on the real chip.
 """
 
 import numpy as np

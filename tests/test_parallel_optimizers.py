@@ -18,8 +18,7 @@ other production optimizers carry, on the virtual 8-device CPU platform
   (``collective_permute`` ring hops / ``all_to_all`` dispatch) and NO
   stage-stack all-gather (the optimizer update runs sharded in place);
 * **observability** — perf records stamp ``pipe_bubble_frac`` (the GPipe
-  idle fraction (S-1)/(n_micro+S-1), the same formula
-  ``tools/pipeline_bubble.py`` measures against) and the per-step
+  idle fraction (S-1)/(n_micro+S-1)) and the per-step
   ``ppermute_bytes``/``all_to_all_bytes`` wire cost, and
   ``tools/obs_report.py`` validates and renders them;
 * **resilience** — injected faults at the ``dispatch`` seam recover, and
@@ -225,8 +224,7 @@ class TestPipelineParity:
 
     def test_bubble_frac_stamped_from_schedule(self, pp_fit):
         opt, _ = pp_fit
-        # the same closed form tools/pipeline_bubble.py measures against:
-        # (S-1)/(n_micro+S-1); default n_micro = S
+        # the closed form (S-1)/(n_micro+S-1); default n_micro = S
         want = (N_STAGES - 1) / (N_STAGES + N_STAGES - 1)
         assert opt._perf.pipe_bubble_frac == round(want, 6)
         assert opt._perf.pipe_bubble_frac == round(

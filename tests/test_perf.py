@@ -3,11 +3,9 @@ units, schema-valid always-on perf streams on Local/Distri/Hybrid, the
 1-compile canary with perf accounting on, the direct-driven PerfMonitor
 matrix (breach / once-per-episode / re-arm / component attribution),
 chaos-``delay``-driven profiler capture end-to-end on CPU, serving
-bucket-cost stamping, and the tools/perf_gate.py pass/fail/tolerance gate."""
+bucket-cost stamping."""
 
 import importlib.util
-import json
-import os
 import sys
 from pathlib import Path
 
@@ -56,7 +54,6 @@ def _load_tool(name):
 
 
 obs_report = _load_tool("obs_report")
-perf_gate = _load_tool("perf_gate")
 
 
 def _problem(n=20, d=5, classes=3, seed=0):
@@ -415,59 +412,3 @@ class TestServingBucketCost:
             assert "mfu" not in s or s["mfu"] is None  # CPU: no peak
         for rec in tel.ring.records:
             obs_report.validate_record(rec)
-
-
-# ---------------------------------------------------------------------------
-class TestPerfGateTool:
-    def test_selftest_passes(self):
-        assert perf_gate.selftest() == 0
-
-    def test_gate_stream_roundtrip(self, tmp_path):
-        stream = tmp_path / "p0.jsonl"
-        rows = []
-        for i in range(1, 9):
-            rows.append({
-                "type": "step", "ts": float(i), "iteration": i,
-                "records": 8, "wall_s": 0.05, "compile_count": 1,
-                "spans": {}, "records_per_sec": 160.0,
-            })
-        rows.append({
-            "type": "perf", "ts": 9.0, "iteration": 8, "window": 8,
-            "wall_mean_s": 0.05, "mfu": 0.25,
-            "breakdown": {"compute_s": 0.04, "comms_s": None,
-                          "input_s": 0.005, "host_s": 0.005},
-        })
-        stream.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        measured = perf_gate.measure(str(stream))
-        assert measured == {
-            "step_ms": 50.0, "records_per_sec": 160.0, "mfu": 0.25,
-        }
-        base = {"source": "test", "metrics": {
-            "step_ms": {"value": 52.0, "tolerance_pct": 10.0,
-                        "higher_is_better": False},
-            "mfu": {"value": 0.26, "tolerance_pct": 10.0,
-                    "higher_is_better": True},
-        }}
-        bpath = tmp_path / "base.json"
-        bpath.write_text(json.dumps(base))
-        assert perf_gate.main([str(stream), "--baseline", str(bpath)]) == 0
-        # seed a regression: baseline demands twice the measured MFU
-        base["metrics"]["mfu"]["value"] = 0.5
-        bpath.write_text(json.dumps(base))
-        assert perf_gate.main([str(stream), "--baseline", str(bpath)]) == 1
-
-    def test_gate_bench_artifact(self):
-        measured = perf_gate.measure(str(REPO / "BENCH_r03.json"))
-        assert measured["img_per_sec_per_chip"] == 2265.57
-        baseline = perf_gate.load_baseline(str(REPO / "PERF_BASELINE.json"))
-        rows = perf_gate.gate(measured, baseline)
-        assert all(r["status"] in ("ok", "improved") for r in rows)
-
-    def test_trajectory_flags_holes(self):
-        # rounds 2-5 are frozen history (exact); counts are invariants so a
-        # future bench round cannot break this test
-        t = perf_gate.load_trajectory(str(REPO))
-        assert t["n_rounds"] >= 4 and t["n_holes"] >= 2
-        statuses = {r["round"]: r["status"] for r in t["rounds"]}
-        assert statuses[2] == statuses[3] == "ok"
-        assert statuses[4] == statuses[5] == "null"

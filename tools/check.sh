@@ -35,7 +35,7 @@
 #   bash tools/check.sh --perf     # performance observability family
 #                                  # (MFU/roofline accounting, step-time
 #                                  # decomposition, PerfMonitor + triggered
-#                                  # capture, perf_gate baseline/trajectory)
+#                                  # capture)
 #   bash tools/check.sh --concurrency # concurrency audit family (static
 #                                  # lock-discipline/lock-order auditor over
 #                                  # the threaded runtime + runtime lock
@@ -57,9 +57,6 @@ python tools/lint_framework.py bigdl_tpu tools || exit 1
 
 echo "== obs_report selftest (golden telemetry fixture) =="
 python tools/obs_report.py --selftest || exit 1
-
-echo "== perf_gate selftest (committed baseline + bench trajectory) =="
-python tools/perf_gate.py --selftest || exit 1
 
 echo "== concurrency audit selftest (fixtures + repo-clean + acyclic lock graph) =="
 python bigdl_tpu/analysis/concurrency.py --selftest || exit 1
@@ -97,8 +94,6 @@ if [ "${1:-}" = "--postmortem" ]; then
 fi
 
 if [ "${1:-}" = "--perf" ]; then
-    echo "== bench trajectory =="
-    python tools/perf_gate.py --trajectory || exit 1
     echo "== perf observability family (CPU) =="
     exec env JAX_PLATFORMS=cpu python -m pytest \
         tests/test_perf.py tests/test_obs.py -q \

@@ -13,8 +13,8 @@ Usage:
     python tools/multiprocess_smoke.py            # launcher: spawns 2 workers
     python tools/multiprocess_smoke.py --json     # also print artifact JSON
 
-Exit code 0 + "MULTIPROC OK" on success. The launcher writes
-``bench_artifacts/MULTIPROC_r04.json`` when --artifact is given.
+Exit code 0 + "MULTIPROC OK" on success. The launcher writes its JSON to
+the path given with --artifact.
 """
 
 from __future__ import annotations

@@ -17,12 +17,11 @@ Usage:
 
 The report answers the four triage questions in order: what died (reason +
 error), where it was (last-known-good step), why (failing seam + stack ×
-span correlation), and how it was doing (perf vs PERF_BASELINE.json,
-checkpoint pointer, fleet heartbeats). ``--fleet`` additionally
-cross-references survivors' bundles against the LOST hosts' last
-heartbeats — the host that died hardest is exactly the one with no bundle
-of its own. Documented in docs/observability.md "Flight recorder &
-postmortems".
+span correlation), and how it was doing (checkpoint pointer, fleet
+heartbeats). ``--fleet`` additionally cross-references survivors' bundles
+against the LOST hosts' last heartbeats — the host that died hardest is
+exactly the one with no bundle of its own. Documented in
+docs/observability.md "Flight recorder & postmortems".
 """
 
 import argparse
@@ -110,8 +109,7 @@ def load_bundle(path):
             with open(os.path.join(path, rel)) as f:
                 out["rings"][rtype] = [
                     json.loads(line) for line in f if line.strip()]
-    for name in ("reason", "fingerprint", "trace", "fleet",
-                 "perf_baseline", "checkpoint"):
+    for name in ("reason", "fingerprint", "trace", "fleet", "checkpoint"):
         fp = os.path.join(path, name + ".json")
         out[name] = None
         if os.path.exists(fp):
@@ -198,12 +196,6 @@ def stack_span_correlation(bundle):
     return sorted(span_threads & stack_threads)
 
 
-def _fmt_pct(v):
-    if v is None:
-        return "n/a"
-    return "%+.1f%%" % v
-
-
 def render(bundle):
     """One bundle -> triage report text."""
     lines = []
@@ -255,12 +247,6 @@ def render(bundle):
                      "stacks and the active trace's spans"
                      % ", ".join(correlated))
 
-    perf = bundle.get("perf_baseline")
-    if perf:
-        deltas = perf.get("delta_pct") or {}
-        lines.append("perf vs baseline: " + "  ".join(
-            "%s %s" % (k, _fmt_pct(deltas.get(k)))
-            for k in sorted(deltas)))
     ckpt = bundle.get("checkpoint")
     if ckpt:
         verdict = ckpt.get("verify")
