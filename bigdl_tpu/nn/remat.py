@@ -13,16 +13,30 @@ with ONE block instead of the whole depth::
     nn.Sequential(*[nn.Remat(make_block()) for _ in range(n_layers)])
 
 ``policy`` selects what XLA may still save (names from
-``jax.checkpoint_policies``, e.g. ``'dots_saveable'`` keeps MXU outputs —
-the usual TPU sweet spot — while ``None`` rematerializes everything).
+``jax.checkpoint_policies``, e.g. ``'dots_saveable'`` keeps MXU outputs).
+
+The default (``policy=None``) rematerializes everything EXCEPT what a kernel
+inside the block marked as dear to recompute and cheap to keep
+(``utils/remat_keep.keep``; the names are ``KEPT_NAMES``). Today that is the
+flash-attention kernel's output and per-row logsumexp: the kernel's own
+backward needs exactly those two, and without them the backward would run the
+whole forward kernel a second time only to rebuild them. Keeping them costs
+one activation in the compute dtype and one float32 row statistic per
+attention call and block (134 MB + 2 MB at 2 x 32 heads x 8192 x 128 in bf16);
+it saves one ``flash_fwd`` per block and step (33 ms of Mellum2's 581 ms
+device step, PERF.md section 6, PR 31). A block that marks nothing saves nothing: the
+program is what ``jax.checkpoint`` with no policy traces. What a compiled step
+kept is in its telemetry ``compile`` record (``remat_kept``).
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
 
+from ..utils.remat_keep import KEPT_NAMES, keeping_block
 from .module import Container, AbstractModule
 
 # zero-argument policies only: the other jax.checkpoint_policies attributes
@@ -79,11 +93,17 @@ class Remat(Container):
 
     def _apply(self, params, state, x, training, rng):
         child = self.modules[0]
-        kwargs = {}
-        if self.policy is not None:
-            kwargs["policy"] = getattr(jax.checkpoint_policies, self.policy)
+        if self.policy is None:
+            # a subtree that marks nothing saves nothing, as with no policy
+            policy = jax.checkpoint_policies.save_only_these_names(
+                *KEPT_NAMES)
+            recording = keeping_block()
+        else:
+            policy = getattr(jax.checkpoint_policies, self.policy)
+            recording = contextlib.nullcontext()
         inner = jax.checkpoint(
             lambda p, s, xx, r: child._apply(p, s, xx, training, r),
-            **kwargs)
-        y, ns = inner(params[child.name()], state[child.name()], x, rng)
+            policy=policy)
+        with recording:
+            y, ns = inner(params[child.name()], state[child.name()], x, rng)
         return y, {child.name(): ns}

@@ -156,14 +156,17 @@ def observe_jit_compiles(jit_fn, seen: int, telemetry: "Telemetry", *,
         return seen
     if csize > seen:
         cache_hit = None if cache_watch is None else cache_watch.observe()
-        # the tiles this dispatch's own trace chose (it began ``seconds``
-        # ago); a program that never imported the kernel chose none
+        # the tiles this dispatch's own trace chose, and what its nn.Remat
+        # blocks kept (it began ``seconds`` ago); a program that never
+        # imported the kernel, or nn.Remat, chose and kept none
+        since = time.perf_counter() - seconds
         flash = sys.modules.get("bigdl_tpu.ops.flash_attention")
+        keep = sys.modules.get("bigdl_tpu.utils.remat_keep")
         telemetry.compile_event(
             iteration=iteration, seconds=seconds, count=csize - seen,
             path=path, cache_hit=cache_hit,
-            flash_tiles=flash and flash.take_tile_records(
-                since=time.perf_counter() - seconds))
+            flash_tiles=flash and flash.take_tile_records(since=since),
+            remat_kept=keep and keep.take_kept_records(since=since))
         return csize
     return seen
 
@@ -693,6 +696,7 @@ class Telemetry:
         self, *, iteration: int, seconds: float, count: int = 1,
         path: str = "train", cache_hit: Optional[bool] = None,
         flash_tiles: Optional[List[Dict]] = None,
+        remat_kept: Optional[List[Dict]] = None,
     ) -> None:
         """One (re)compilation observed — hooked off the jit-cache-size delta
         at dispatch, the same introspection PR 2's ``compile_seconds``
@@ -702,8 +706,10 @@ class Telemetry:
         executable from disk — True on every compile is the artifact warm
         boot's telemetry proof of "0 fresh compiles". ``flash_tiles`` lists
         the flash-attention tile choices that the compiling call's trace
-        made (``ops/flash_attention.take_tile_records``); the record carries
-        the field only where there were any."""
+        made (``ops/flash_attention.take_tile_records``), ``remat_kept`` the
+        marked values that its ``nn.Remat`` blocks kept for the backward
+        (``utils/remat_keep.take_kept_records``); the record carries either
+        field only where there were any."""
         with self._lock:
             self.compile_count += count
             self.compile_seconds += seconds
@@ -718,6 +724,8 @@ class Telemetry:
         }
         if flash_tiles:
             record["flash_tiles"] = flash_tiles
+        if remat_kept:
+            record["remat_kept"] = remat_kept
         self.emit(record)
         self.flush()  # compiles are rare; make them tail-able immediately
 

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.compat import pallas_call
+from ..utils.remat_keep import keep
 
 NEG_BIG = -1e30
 
@@ -750,6 +751,9 @@ def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
               mask_q, window):
     out, lse = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
                                block_k, interpret, mask_q, window)
+    # what only a second run of the forward kernel could rebuild: under
+    # nn.Remat these two are kept and the backward runs flash_fwd no more
+    out, lse = keep(out, "flash_out"), keep(lse, "flash_lse")
     return out, (q, k, v, lengths, out, lse)
 
 

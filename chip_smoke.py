@@ -352,6 +352,38 @@ def check_flash(t: int, d: int, n: int = 2, h: int = 4) -> None:
     log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
 
 
+def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
+    """A GroupedQueryAttention under nn.Remat against the same module bare:
+    the same outputs and gradients, and 3 Mosaic calls in the program, not 4:
+    the kernel's output and logsumexp are kept across the boundary, so the
+    backward does not run flash_fwd again (utils/remat_keep.py)."""
+    import jax
+    import jax.numpy as jnp
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn.decoder import GroupedQueryAttention
+
+    attn = GroupedQueryAttention(heads, heads // 2, d)
+    x = _normal(t, (n, t, heads * d), jnp.float32)
+    attn.build(jax.random.PRNGKey(t), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    params, state = attn.get_parameters(), attn.get_state()
+    wrapped = nn.Remat(attn)
+    cot = _normal(t + 1, x.shape, jnp.float32)
+    what = f"nn.Remat(flash attention) T={t} d={d}"
+    text, got = _fwd_bwd(
+        lambda p, x: wrapped.apply({attn.name(): p}, {attn.name(): state}, x)[0],
+        (params, x), cot)
+    bare, want = _fwd_bwd(lambda p, x: attn.apply(p, state, x)[0],
+                          (params, x), cot)
+    n_calls, n_bare = assert_mosaic(text, what), bare.count(MOSAIC_CALL)
+    if on_tpu() and (n_calls, n_bare) != (3, 3):
+        raise AssertionError(f"{what}: {n_calls} Mosaic calls under Remat, "
+                             f"{n_bare} bare; want 3 and 3")
+    # the module's products round their operands to bf16; XLA may fuse the
+    # rematerialised projections differently from the stored ones
+    _close(got, want, BF16_TOL, what)
+    log(f"  {what}: fwd+bwd match the bare module ({n_calls} Mosaic calls)")
+
+
 def check_fused(rows: int, hidden: int, conv_shape) -> None:
     """Engine.set_fused_kernels(True) against the unfused path of the same
     public call sites: nn.LayerNormalization (whose unfused chain is
@@ -476,6 +508,7 @@ def check_transformer_step(t=2048, batch=8, vocab=8192, hidden=512, heads=8,
 def phase_kernels() -> None:
     check_flash(t=1024, d=64)
     check_flash(t=4096, d=128)
+    check_flash_remat(t=2048, d=128)
     # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
     # res2 conv epilogue (b128: 128x256x56x56)
     check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))

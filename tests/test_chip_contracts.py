@@ -68,6 +68,9 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
     monkeypatch.setattr(chip_smoke, "LAST_RUN", str(tmp_path / "last.json"))
     prev = Engine.compute_dtype(), Engine.activation_dtype()
     try:
+        # before phase B sets bf16 operands: XLA:CPU has no bf16 x bf16 -> f32
+        # product, which GroupedQueryAttention's projections are on the chip
+        chip_smoke.check_flash_remat(t=128, d=16, n=1, heads=2)
         model, rec = chip_smoke.phase_train(
             depth=18, classes=10, image=32, batch=4, iters=6)
         assert rec["compile_s"] > 0

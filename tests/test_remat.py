@@ -1,6 +1,9 @@
 """nn.Remat — gradient checkpointing wrapper: bit-identical math, remat'd
 autodiff schedule (the jax.checkpoint HBM lever as framework surface)."""
 
+import re
+import time
+
 import numpy as np
 import pytest
 
@@ -8,7 +11,11 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu import nn
+from bigdl_tpu.nn.module import AbstractModule
+from bigdl_tpu.ops.flash_attention import flash_attention
 from bigdl_tpu.utils.random import RandomGenerator
+from bigdl_tpu.utils.remat_keep import (
+    KEPT_NAMES, keep, keeping_block, take_kept_records)
 
 
 def _pair(policy=None):
@@ -107,3 +114,164 @@ class TestRemat:
         # be rejected at the ctor, not fail late at first backward
         with pytest.raises(ValueError, match="checkpoint policy"):
             nn.Remat(nn.Linear(4, 4), policy="save_from_both_policies")
+
+
+# --------------------------------------------------------------------------
+# what a kernel marks survives the boundary (utils/remat_keep.py): the flash
+# kernel's output and logsumexp are kept, the backward stops running flash_fwd
+# --------------------------------------------------------------------------
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+N, H, T, D = 1, 2, 256, 16
+
+
+class _FlashBlock(AbstractModule):
+    """x + wo(flash(wq x)): the kernel in interpret mode, 2 x 2 tiles a
+    head, between two products as an attention block has them."""
+
+    def infer_shape(self, in_spec):
+        return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
+
+    def _build(self, rng, in_spec):
+        kq, ko = jax.random.split(rng)
+        shape = (in_spec.shape[-1], H * D)
+        return {"wq": 0.3 * jax.random.normal(kq, shape),
+                "wo": 0.3 * jax.random.normal(ko, shape[::-1])}, {}
+
+    def _apply(self, params, state, x, training, rng):
+        q = (x @ params["wq"]).reshape(N, T, H, D).transpose(0, 2, 1, 3)
+        ctx = flash_attention(q, q, q, True, block_q=128, block_k=128,
+                              interpret=True)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(N, T, H * D)
+        return x + ctx @ params["wo"], state
+
+
+def _flash_model(n_blocks=1, policy=None, wrap=True):
+    RandomGenerator.set_seed(7)
+    blocks = [_FlashBlock() for _ in range(n_blocks)]
+    if wrap:
+        blocks = [nn.Remat(b, policy=policy) for b in blocks]
+    model = nn.Sequential(*blocks)
+    x = np.random.default_rng(9).standard_normal((N, T, 24)).astype(np.float32)
+    params, state = model.init(sample_input=x)
+    loss = lambda p: jnp.sum(model.apply(p, state, x)[0] ** 2)  # noqa: E731
+    return model, params, jax.grad(loss)
+
+
+def _kernel_calls(grad_fn, *args):
+    text = str(jax.make_jaxpr(grad_fn)(*args))
+    return tuple(len(re.findall(rf"name={k}\b", text)) for k in KERNELS)
+
+
+def _plain_checkpoint(monkeypatch):
+    """nn.Remat as the parent had it: jax.checkpoint with no policy."""
+    monkeypatch.setattr(
+        jax.checkpoint_policies, "save_only_these_names", lambda *names: None)
+
+
+class TestKeptAcrossRemat:
+    def test_backward_runs_the_forward_kernel_once(self, monkeypatch):
+        _, params, grad = _flash_model()
+        assert _kernel_calls(grad, params) == (1, 1, 1)
+        _plain_checkpoint(monkeypatch)  # the parent's: the forward twice
+        _, params, grad = _flash_model()  # (a traced function is cached)
+        assert _kernel_calls(grad, params) == (2, 1, 1)
+
+    def test_attention_module_on_the_tpu_path(self, monkeypatch):
+        # the module M runs, through scaled_dot_product_attention's own gate
+        # (flash from T 1024 on the tpu backend); traced, never run
+        from bigdl_tpu.nn.decoder import GroupedQueryAttention
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        take_kept_records()
+        attn = nn.Remat(GroupedQueryAttention(2, 1, 64, window=256))
+        x = jax.ShapeDtypeStruct((1, 1024, 32), jnp.float32)
+        attn.build(jax.random.PRNGKey(0), x)
+        params, state = attn.get_parameters(), attn.get_state()
+        grad = jax.grad(lambda p, x: jnp.sum(attn.apply(p, state, x)[0]))
+        assert _kernel_calls(grad, params, x) == (1, 1, 1)
+        assert {(r["name"], tuple(r["shape"]), r["blocks"], r["values"])
+                for r in take_kept_records()} == {
+            ("flash_out", (1, 2, 1024, 64), 1, 1),
+            ("flash_lse", (2, 1, 1024), 1, 1)}
+
+    def test_kept_values_leave_gradients_identical(self):
+        _, p0, plain = _flash_model(wrap=False)
+        _, p1, wrapped = _flash_model()
+        g0, g1 = plain(p0), wrapped(p1)
+        leaves = lambda g: [np.asarray(a) for a in  # noqa: E731
+                            jax.tree_util.tree_leaves(g)]
+        for a, b in zip(leaves(g0), leaves(g1)):
+            # the kept values are the ones a second run would have produced;
+            # the host's FMA contractions may still differ by a few ulp in
+            # the products around the kernel (see TestRemat above)
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+    def test_block_that_marks_nothing_is_a_plain_checkpoint(self, monkeypatch):
+        _, _, wrapped, (wp, ws), x = _pair()
+
+        def traced():  # a fresh function each time: a traced one is cached
+            grad = jax.grad(lambda p: jnp.sum(wrapped.apply(p, ws, x)[0] ** 2))
+            return re.subn(r"policy=<function .*>", "policy=None",
+                           str(jax.make_jaxpr(grad)(wp)))
+
+        ours, named = traced()
+        _plain_checkpoint(monkeypatch)
+        plain, unnamed = traced()
+        assert ours == plain and "remat" in ours
+        assert named >= 1 and unnamed == 0  # the two did differ in the policy
+
+    @pytest.mark.parametrize("policy,forward_runs", [
+        ("nothing_saveable", 2),      # marks or not, nothing is saved
+        ("dots_saveable", 2),         # a kernel call is no dot
+        ("everything_saveable", 1),   # nothing is rematerialised at all
+    ])
+    def test_explicit_policy_keeps_its_meaning(self, policy, forward_runs):
+        take_kept_records()
+        _, params, grad = _flash_model(policy=policy)
+        assert _kernel_calls(grad, params) == (forward_runs, 1, 1)
+        assert take_kept_records() == []  # the default's counter is not theirs
+
+    def test_compile_record_lists_what_was_kept(self):
+        from bigdl_tpu.obs.telemetry import Telemetry, observe_jit_compiles
+
+        # a trace that no telemetry observed must not ride along
+        jax.make_jaxpr(_flash_model()[2])(_flash_model()[1])
+        tel = Telemetry()
+        for n_blocks, wrap in ((2, True), (2, False)):
+            _, params, grad = _flash_model(n_blocks, wrap=wrap)
+            step = jax.jit(grad)
+            t0 = time.perf_counter()
+            step(params)
+            observe_jit_compiles(step, 0, tel, iteration=1,
+                                 seconds=time.perf_counter() - t0, path="test")
+        with_remat, without = [r for r in tel.ring.records
+                               if r["type"] == "compile"]
+        tel.close()
+        assert sorted(with_remat["remat_kept"], key=lambda r: r["name"]) == [
+            dict(name="flash_lse", shape=[N * H, 1, T], dtype="float32",
+                 blocks=2, values=2, bytes=N * H * T * 4),
+            dict(name="flash_out", shape=[N, H, T, D], dtype="float32",
+                 blocks=2, values=2, bytes=N * H * T * D * 4)]
+        assert "remat_kept" not in without
+
+    def test_marks_are_counted_per_block_and_only_inside_one(self):
+        take_kept_records()
+        x = jnp.ones((4, 8), jnp.bfloat16)
+        assert keep(x, KEPT_NAMES[0]) is not None  # outside: only a name
+        assert take_kept_records() == []
+        with keeping_block():       # two values of one shape in one block
+            keep(x, "flash_out")
+            keep(x, "flash_out")
+        with keeping_block():
+            keep(x, "flash_out")
+        stamp = time.perf_counter()
+        assert take_kept_records() == [dict(
+            name="flash_out", shape=[4, 8], dtype="bfloat16", blocks=2,
+            values=3, bytes=64)]
+        with keeping_block():
+            keep(x, "flash_out")
+        # older than the caller asks about: dropped, not kept for the next
+        assert take_kept_records(since=time.perf_counter()) == []
+        assert take_kept_records(since=stamp) == []
+        with pytest.raises(ValueError, match="not one of"):
+            keep(x, "my_activation")
