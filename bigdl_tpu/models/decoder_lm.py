@@ -1,23 +1,35 @@
 """Decoder-only language models from a catalog-style config dict, so that the
 next one is a dict and not a class.
 
-``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses
-(the first: Mellum2-12B-A2.5B-Instruct, whose cut to one chip is
-``benchmark/configs/mellum2_12b.json``)::
+``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses.
+Two families so far, told apart by their own keys (the cuts to one chip are
+``benchmark/configs/mellum2_12b.json`` and ``granite_4_0_h_micro.json``)::
 
     vocab_size, hidden_size, num_hidden_layers, layer_types,
-    num_attention_heads, num_key_value_heads, head_dim, sliding_window,
-    rope_parameters, rms_norm_eps, num_experts, num_experts_per_tok,
-    moe_intermediate_size, norm_topk_prob
+    num_attention_heads, num_key_value_heads, head_dim, rms_norm_eps
+
+    sparse, windowed (Mellum2-12B-A2.5B-Instruct):
+    sliding_window, rope_parameters, num_experts, num_experts_per_tok,
+    moe_intermediate_size, norm_topk_prob, mlp_layer_types
+
+    state-space hybrid (granite-4.0-h-micro; ``layer_types`` of ``mamba`` and
+    ``attention``, ``num_local_experts`` 0 so the gated MLP is the whole
+    feed-forward): mamba_n_heads, mamba_d_head, mamba_d_state, mamba_d_conv,
+    mamba_chunk_size, mamba_expand, mamba_n_groups, shared_intermediate_size,
+    attention_multiplier, embedding_multiplier, residual_multiplier,
+    logits_scaling, position_embedding_type, tie_word_embeddings
 
 and two of this repo's own: ``experts_held`` (ids of the experts this chip
 holds, default all: one chip's share of an expert-parallel layer) and
 ``initializer_range`` (default 0.02). ``layer_types`` may be longer than
-``num_hidden_layers``: the first that many are built.
+``num_hidden_layers``: the first that many are built. ``head_dim`` defaults
+to ``hidden_size / num_attention_heads``.
 
-``decoder_lm_reference`` is the plain float32 reference of the same
-equations; ``reference_config`` and ``reference_params`` hand it this
-model's sizes and parameters.
+``decoder_lm_reference`` (attention + routed experts) and
+``hybrid_lm_reference`` (state-space + attention, dense MLP) are the plain
+float32 references of the same equations; ``reference_config`` and
+``reference_params`` hand the one that fits this model's sizes and
+parameters.
 """
 
 from __future__ import annotations
@@ -40,35 +52,107 @@ def experts_held(config: Dict):
     return tuple(config.get("experts_held", range(int(config["num_experts"]))))
 
 
+MLP_KINDS = ("sparse",)        # of mlp_layer_types; no key: one dense gated MLP
+
+
+def is_hybrid(config: Dict) -> bool:
+    """Whether ``config`` is of the state-space hybrid family."""
+    return "mamba" in layer_types(config)
+
+
+def head_dim(config: Dict) -> int:
+    return int(config.get("head_dim") or int(config["hidden_size"])
+               // int(config["num_attention_heads"]))
+
+
+def _mamba(config: Dict) -> Dict:
+    """``nn.Mamba2Mixer``'s sizes from the ``mamba_*`` keys."""
+    heads, dim = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    if heads * dim != int(config["mamba_expand"]) * int(config["hidden_size"]):
+        raise ValueError(
+            f"decoder_lm.from_config: {heads} mamba heads of {dim} are not "
+            f"mamba_expand {config['mamba_expand']} x hidden_size")
+    if int(config.get("mamba_n_groups", 1)) != 1:
+        raise ValueError("decoder_lm.from_config: accepts mamba_n_groups 1 "
+                         "(one B/C group shared by all heads), got "
+                         f"{config['mamba_n_groups']}")
+    if not config.get("mamba_conv_bias", True) or config.get("mamba_proj_bias"):
+        raise ValueError("decoder_lm.from_config: accepts mamba_conv_bias "
+                         "true and mamba_proj_bias false")
+    return dict(heads=heads, head_dim=dim, state=int(config["mamba_d_state"]),
+                conv=int(config["mamba_d_conv"]),
+                chunk=int(config["mamba_chunk_size"]))
+
+
 def from_config(config: Dict) -> nn.DecoderLM:
     """The ``nn.DecoderLM`` that ``config`` describes (not yet built: the
     optimizer builds it from the first batch, or call ``build``)."""
-    if any(t != "sparse" for t in config.get("mlp_layer_types", [])[
-            :int(config["num_hidden_layers"])]):
-        raise ValueError("decoder_lm.from_config: only sparse (routed-expert) MLP layers")
+    kinds = layer_types(config)
+    bad = sorted(set(kinds) - set(nn.decoder.LAYER_KINDS))
+    if bad:
+        raise ValueError(f"decoder_lm.from_config: layer_types {bad}; accepts "
+                         f"{nn.decoder.LAYER_KINDS}")
+    bad = sorted(set(config.get("mlp_layer_types", [])[:len(kinds)])
+                 - set(MLP_KINDS))
+    if bad:
+        raise ValueError(f"decoder_lm.from_config: mlp_layer_types {bad}; "
+                         f"accepts {MLP_KINDS}, or no such key and "
+                         "num_local_experts 0 for one dense gated MLP a layer")
+    common = dict(
+        vocab_size=int(config["vocab_size"]),
+        hidden_size=int(config["hidden_size"]),
+        layer_types=kinds,
+        num_heads=int(config["num_attention_heads"]),
+        num_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=head_dim(config),
+        eps=float(config["rms_norm_eps"]),
+        init_std=float(config.get("initializer_range", 0.02)),
+    )
+    if is_hybrid(config):
+        if int(config.get("num_local_experts", 0)):
+            raise ValueError("decoder_lm.from_config: a state-space hybrid "
+                             "with routed experts (num_local_experts > 0) is "
+                             "not built; accepts 0")
+        if config.get("position_embedding_type", "nope") != "nope":
+            raise ValueError("decoder_lm.from_config: position_embedding_type "
+                             f"{config['position_embedding_type']!r}; accepts "
+                             "'nope' beside mamba layers")
+        return nn.DecoderLM(
+            **common,
+            mlp_size=int(config["shared_intermediate_size"]),
+            qk_norm=False,
+            attention_scale=float(config["attention_multiplier"]),
+            mamba=_mamba(config),
+            embedding_multiplier=float(config["embedding_multiplier"]),
+            residual_multiplier=float(config["residual_multiplier"]),
+            logits_divisor=float(config["logits_scaling"]),
+            tie_embeddings=bool(config["tie_word_embeddings"]))
     if not config.get("norm_topk_prob", True):
         raise ValueError("decoder_lm.from_config: the router's chosen "
                          "probabilities are renormalised (norm_topk_prob)")
     return nn.DecoderLM(
-        vocab_size=int(config["vocab_size"]),
-        hidden_size=int(config["hidden_size"]),
-        layer_types=layer_types(config),
-        num_heads=int(config["num_attention_heads"]),
-        num_kv_heads=int(config["num_key_value_heads"]),
-        head_dim=int(config["head_dim"]),
+        **common,
         sliding_window=int(config["sliding_window"]),
         rope_parameters=config["rope_parameters"],
         n_experts=int(config["num_experts"]),
         experts_per_token=int(config["num_experts_per_tok"]),
         expert_size=int(config["moe_intermediate_size"]),
         experts_held=experts_held(config),
-        eps=float(config["rms_norm_eps"]),
-        init_std=float(config.get("initializer_range", 0.02)),
     )
 
 
 def reference_config(config: Dict) -> Dict:
-    """What ``decoder_lm_reference`` reads, from the same dict."""
+    """What the family's reference reads, from the same dict."""
+    if is_hybrid(config):
+        keys = ("num_attention_heads", "num_key_value_heads", "rms_norm_eps",
+                "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                "mamba_chunk_size", "attention_multiplier",
+                "embedding_multiplier", "residual_multiplier",
+                "logits_scaling")
+        out = {k: config[k] for k in keys}
+        out["head_dim"] = head_dim(config)
+        out["layer_types"] = layer_types(config)
+        return out
     keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
             "sliding_window", "rope_parameters", "rms_norm_eps",
             "num_experts_per_tok")
@@ -78,21 +162,23 @@ def reference_config(config: Dict) -> Dict:
     return out
 
 
+def _reference_layer(block: Dict) -> Dict:
+    out = {"ln1": block["ln1"]["weight"], "ln2": block["ln2"]["weight"]}
+    for name in ("attn", "ssm", "experts", "mlp"):   # the leaves as they are
+        out.update(block.get(name, {}))
+    return out
+
+
 def reference_params(params: Dict) -> Dict:
     """A built ``DecoderLM``'s parameter (or gradient) tree in the layout of
-    ``decoder_lm_reference``; the leaves are the same arrays."""
-    layers = []
-    for name in sorted((k for k in params if k.startswith("layer_")),
-                       key=lambda k: int(k.split("_")[1])):
-        block = params[name]["block"]  # inside nn.Remat
-        attn, ex = block["attn"], block["experts"]
-        layers.append({
-            "ln1": block["ln1"]["weight"], "ln2": block["ln2"]["weight"],
-            "wq": attn["wq"], "wk": attn["wk"], "wv": attn["wv"],
-            "wo": attn["wo"], "q_norm": attn["q_norm"],
-            "k_norm": attn["k_norm"], "router": ex["router"],
-            "w_gate": ex["w_gate"], "w_up": ex["w_up"],
-            "w_down": ex["w_down"]})
-    return {"embed": params["embed"]["weight"], "layers": layers,
-            "final_norm": params["final_norm"]["weight"],
-            "head": params["head"]["weight"]}
+    its reference; the leaves are the same arrays. A tied model has no
+    ``head``."""
+    names = sorted((k for k in params if k.startswith("layer_")),
+                   key=lambda k: int(k.split("_")[1]))
+    out = {"embed": params["embed"]["weight"],
+           "layers": [_reference_layer(params[n]["block"])  # inside nn.Remat
+                      for n in names],
+           "final_norm": params["final_norm"]["weight"]}
+    if params["head"]:
+        out["head"] = params["head"]["weight"]
+    return out

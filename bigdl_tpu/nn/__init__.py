@@ -195,9 +195,11 @@ from .moe import MoE, RoutedExperts
 from .decoder import (
     DecoderBlock,
     DecoderLM,
+    GatedMLP,
     GroupedQueryAttention,
     LMHead,
 )
+from .ssm import Mamba2Mixer
 from .pipelined import PipelinedBlocks
 from .remat import Remat
 from .quantized import (

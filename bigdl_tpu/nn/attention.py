@@ -128,8 +128,11 @@ def scaled_dot_product_attention(
     lengths: Optional[jax.Array] = None,
     mask_q: Optional[bool] = None,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """softmax(q k^T / sqrt(d) + bias) v over (..., T, d) operands.
+    """softmax(scale * q k^T + bias) v over (..., T, d) operands; ``scale``
+    is ``1/sqrt(d)`` where none is given. The flash and dense paths take it
+    as it is; the ring path has none.
 
     ``window`` (with ``causal``) is sliding-window attention: query i sees
     key j iff ``j <= i`` and ``i - j < window``. 4-D ``k``/``v`` may carry
@@ -185,10 +188,10 @@ def scaled_dot_product_attention(
     from ..utils.engine import Engine
 
     sp = Engine.sequence_parallel()
-    if (window is not None or grouped) and (
+    if (window is not None or grouped or scale is not None) and (
             impl == "ring" or (impl == "auto" and sp is not None)):
-        raise ValueError("ring attention has neither a window nor grouped "
-                         "K/V heads (parallel/sequence.py)")
+        raise ValueError("ring attention has neither a window, grouped K/V "
+                         "heads nor a score scale (parallel/sequence.py)")
     if impl in ("auto", "ring") and sp is not None:
         mesh, axis = sp
         n_sp = mesh.shape[axis]
@@ -238,6 +241,7 @@ def scaled_dot_product_attention(
             lengths=lengths,
             mask_q=mask_q,
             window=window,
+            scale=scale,
         )
         return out.astype(q.dtype)
     if grouped:
@@ -264,9 +268,9 @@ def scaled_dot_product_attention(
         causal_bias = jnp.where(seen, 0.0, NEG_INF)
         bias = causal_bias if bias is None else bias + causal_bias
     depth = q.shape[-1]
-    logits = precision.einsum("...qd,...kd->...qk", q, k) / jnp.sqrt(
-        jnp.asarray(depth, q.dtype)
-    )
+    logits = precision.einsum("...qd,...kd->...qk", q, k)
+    logits = (logits / jnp.sqrt(jnp.asarray(depth, q.dtype)) if scale is None
+              else logits * scale)
     if bias is not None:
         logits = logits + bias
     weights = jax.nn.softmax(logits, axis=-1)

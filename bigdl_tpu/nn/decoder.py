@@ -1,27 +1,41 @@
 """A decoder-only language model assembled from a per-layer pattern.
 
 ``DecoderLM(vocab_size, hidden_size, layer_types=[...], ...)`` stacks one
-``DecoderBlock`` per entry of ``layer_types`` (``"sliding_attention"`` or
-``"full_attention"``), each wrapped in ``nn.Remat``::
+``DecoderBlock`` per entry of ``layer_types``, each wrapped in ``nn.Remat``::
 
-    h = x + Attn_l(RMSNorm(x))          GroupedQueryAttention
-    y = h + MoE_l(RMSNorm(h))           nn.RoutedExperts
+    h = x + r * Mixer_l(RMSNorm(x))     GroupedQueryAttention | Mamba2Mixer
+    y = h + r * FFN_l(RMSNorm(h))       nn.RoutedExperts | GatedMLP
 
-then a final RMSNorm and an untied head. Attention is grouped-query with a
-per-head RMSNorm on q and k, RoPE from a given inverse-frequency vector and
-factor (plain or YaRN, ``rope_inv_freq``), causal, with a sliding window on
-the layers that say so, through ``scaled_dot_product_attention`` (the flash
-kernel on the TPU takes window and grouped heads as they are). Matrix
-products take their operands in ``Engine``'s compute dtype and accumulate in
-float32 (``precision.dot_acc32``); norm statistics, the router and softmax
-are float32. No bias anywhere.
+then a final RMSNorm and the head. The layer kinds (``LAYER_KINDS``):
+``"sliding_attention"`` and ``"full_attention"`` / ``"attention"`` are
+grouped-query attention, causal, with a sliding window on the layers that say
+so, through ``scaled_dot_product_attention`` (the flash kernel on the TPU
+takes window, grouped heads and the score scale as they are); ``"mamba"`` is
+the Mamba-2 mixer (``nn/ssm.py``, a chunked state-space scan). Attention
+takes a per-head RMSNorm on q and k (``qk_norm``), RoPE from a given
+inverse-frequency vector and factor (plain or YaRN, ``rope_inv_freq``; a
+kind without an entry in ``rope_parameters`` has no positional encoding) and
+the score scale ``1/sqrt(head_dim)`` unless ``attention_scale`` gives
+another. The feed-forward is routed experts where the model has experts and
+one gated MLP where it has none. Four scalars change the paths: the
+embedding is multiplied by ``embedding_multiplier``, each residual branch by
+``residual_multiplier`` (``r``), the logits divided by ``logits_divisor``;
+each is left out of the program at its neutral value. The head is its own
+matrix, or with ``tie_embeddings`` the embedding's transpose: one leaf used
+twice, its gradient the sum of both uses.
+
+Matrix products take their operands in ``Engine``'s compute dtype and
+accumulate in float32 (``precision.dot_acc32``); norm statistics, the
+router, softmax and the scan's decays are float32. No bias anywhere but the
+mixer's conv.
 
 This is ROADMAP D1's shape, begun: ``nn.Transformer`` (one flat block steered
 by strings) stays beside it until D1 merges the two.
 
 Device time is attributed by ``jax.named_scope``: ``embed``, ``attn_proj``,
-``attn_window`` / ``attn_full`` (the kernel call alone), ``moe_route``,
-``moe_experts``, ``lm_head`` (docs/observability.md).
+``attn_window`` / ``attn_full`` (the kernel call alone), ``ssm_proj``,
+``ssm_conv``, ``ssm_scan``, ``moe_route``, ``moe_experts``, ``mlp``,
+``lm_head`` (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -41,8 +55,10 @@ from .module import AbstractModule, Container
 from .moe import RoutedExperts
 from .normalization import RMSNorm
 from .remat import Remat
+from .ssm import Mamba2Mixer
 
-LAYER_KINDS = ("sliding_attention", "full_attention")
+# "attention" is "full_attention" under the name the hybrid models give it
+LAYER_KINDS = ("sliding_attention", "full_attention", "attention", "mamba")
 
 
 def rope_inv_freq(rope: Dict, head_dim: int):
@@ -84,21 +100,24 @@ class GroupedQueryAttention(AbstractModule):
     """Causal self-attention, ``num_heads`` query heads over ``num_kv_heads``
     K/V heads of ``head_dim``: ``(N, T, D) -> (N, T, D)``. ``window`` makes it
     sliding-window attention; ``rope`` is the layer kind's entry of
-    ``rope_parameters``. q and k pass a per-head RMSNorm with a learned gain
-    before RoPE."""
+    ``rope_parameters`` (None: no positional encoding). With ``qk_norm`` q
+    and k pass a per-head RMSNorm with a learned gain before RoPE. ``scale``
+    multiplies the scores (None: ``1/sqrt(head_dim)``)."""
 
     def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                  window: Optional[int] = None, rope: Optional[Dict] = None,
-                 eps: float = 1e-6, init_std: float = 0.02):
+                 eps: float = 1e-6, init_std: float = 0.02,
+                 qk_norm: bool = True, scale: Optional[float] = None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads cannot share "
                              f"{num_kv_heads} K/V heads")
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.window = head_dim, window
-        self.init_std = init_std
+        self.init_std, self.scale = init_std, scale
         self._rope = rope_inv_freq(rope, head_dim) if rope else None
-        self._norm = RMSNorm(head_dim, eps)  # statistics in float32
+        # statistics in float32
+        self._norm = RMSNorm(head_dim, eps) if qk_norm else None
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
@@ -110,12 +129,14 @@ class GroupedQueryAttention(AbstractModule):
         ks = jax.random.split(rng, 4)
         normal = lambda k, shape: self.init_std * jax.random.normal(  # noqa: E731
             k, shape, jnp.float32)
-        return {"wq": normal(ks[0], (d_model, hq)),
-                "wk": normal(ks[1], (d_model, hkv)),
-                "wv": normal(ks[2], (d_model, hkv)),
-                "wo": normal(ks[3], (hq, d_model)),
-                "q_norm": jnp.ones((self.head_dim,)),
-                "k_norm": jnp.ones((self.head_dim,))}, {}
+        params = {"wq": normal(ks[0], (d_model, hq)),
+                  "wk": normal(ks[1], (d_model, hkv)),
+                  "wv": normal(ks[2], (d_model, hkv)),
+                  "wo": normal(ks[3], (hq, d_model))}
+        if self._norm is not None:
+            params.update(q_norm=jnp.ones((self.head_dim,)),
+                          k_norm=jnp.ones((self.head_dim,)))
+        return params, {}
 
     def _heads(self, x, w, heads: int):
         n, t, _ = x.shape
@@ -128,10 +149,11 @@ class GroupedQueryAttention(AbstractModule):
             q = self._heads(x, params["wq"], self.num_heads)
             k = self._heads(x, params["wk"], self.num_kv_heads)
             v = self._heads(x, params["wv"], self.num_kv_heads)
-            q = self._norm._apply({"weight": params["q_norm"]}, {}, q,
-                                  training, None)[0]
-            k = self._norm._apply({"weight": params["k_norm"]}, {}, k,
-                                  training, None)[0]
+            if self._norm is not None:
+                q = self._norm._apply({"weight": params["q_norm"]}, {}, q,
+                                      training, None)[0]
+                k = self._norm._apply({"weight": params["k_norm"]}, {}, k,
+                                      training, None)[0]
             if self._rope is not None:
                 inv_freq, factor = self._rope
                 positions = jnp.arange(t)
@@ -139,21 +161,57 @@ class GroupedQueryAttention(AbstractModule):
                 k = apply_rotary(k, positions, inv_freq, factor)
         with jax.named_scope("attn_window" if self.window else "attn_full"):
             ctx = scaled_dot_product_attention(
-                q, k, v, causal=True, mask_q=True, window=self.window)
+                q, k, v, causal=True, mask_q=True, window=self.window,
+                scale=self.scale)
         with jax.named_scope("attn_proj"):
             ctx = ctx.transpose(0, 2, 1, 3).reshape(n, t, -1)
             return precision.dot_acc32(ctx, params["wo"]).astype(x.dtype), state
 
 
-class DecoderBlock(Container):
-    """``h = x + attn(ln1(x))``, ``y = h + experts(ln2(h))``."""
+class GatedMLP(AbstractModule):
+    """``[a, b] = split(x W_in)``, ``(silu(a) * b) W_out``: ``(..., D) ->
+    (..., D)`` through ``size``, no bias; the two halves of ``W_in`` are one
+    matrix and one product."""
 
-    def __init__(self, attn: GroupedQueryAttention, experts: AbstractModule,
-                 eps: float = 1e-6):
+    def __init__(self, size: int, init_std: float = 0.02):
+        super().__init__()
+        self.size, self.init_std = size, init_std
+
+    def infer_shape(self, in_spec):
+        return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
+
+    def _build(self, rng, in_spec):
+        d = in_spec.shape[-1]
+        k_in, k_out = jax.random.split(rng)
+        return {"w_in": self.init_std * jax.random.normal(
+                    k_in, (d, 2 * self.size), jnp.float32),
+                "w_out": self.init_std * jax.random.normal(
+                    k_out, (self.size, d), jnp.float32)}, {}
+
+    def _apply(self, params, state, x, training, rng):
+        with jax.named_scope("mlp"):
+            a, b = jnp.split(precision.dot_acc32(x, params["w_in"]), 2, axis=-1)
+            return precision.dot_acc32(
+                jax.nn.silu(a) * b, params["w_out"]).astype(x.dtype), state
+
+
+# what a block calls its mixer and its feed-forward in the parameter tree
+_CHILD_NAMES = {GroupedQueryAttention: "attn", Mamba2Mixer: "ssm",
+                RoutedExperts: "experts", GatedMLP: "mlp"}
+
+
+class DecoderBlock(Container):
+    """``h = x + r * mixer(ln1(x))``, ``y = h + r * ffn(ln2(h))`` with ``r``
+    the ``residual_multiplier``; any mixer and any feed-forward that map
+    ``(N, T, D)`` to itself."""
+
+    def __init__(self, mixer: AbstractModule, ffn: AbstractModule,
+                 eps: float = 1e-6, residual_multiplier: float = 1.0):
         super().__init__(RMSNorm(eps=eps).set_name("ln1"),
-                         attn.set_name("attn"),
+                         mixer.set_name(_CHILD_NAMES.get(type(mixer), "mixer")),
                          RMSNorm(eps=eps).set_name("ln2"),
-                         experts.set_name("experts"))
+                         ffn.set_name(_CHILD_NAMES.get(type(ffn), "ffn")))
+        self.residual_multiplier = float(residual_multiplier)
 
     def build(self, rng, in_spec):
         for i, m in enumerate(self.modules):
@@ -165,24 +223,38 @@ class DecoderBlock(Container):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
 
     def _apply(self, params, state, x, training, rng):
-        ln1, attn, ln2, experts = self.modules
+        ln1, mixer, ln2, ffn = self.modules
         new_state: Dict = {}
         run = lambda m, v: self._child_apply(  # noqa: E731
             m, v, training, rng, params, state, new_state)
-        h = x + run(attn, run(ln1, x))
-        return h + run(experts, run(ln2, h)), new_state
+        r = self.residual_multiplier
+        scaled = lambda v: v if r == 1.0 else r * v  # noqa: E731
+        h = x + scaled(run(mixer, run(ln1, x)))
+        return h + scaled(run(ffn, run(ln2, h))), new_state
 
 
 class LMHead(AbstractModule):
-    """``logits = x @ W`` (D -> vocabulary), no bias, float32 logits."""
+    """``logits = x @ W / divisor`` (D -> vocabulary), no bias, float32
+    logits. ``tied``: the module holds no matrix of its own; its container
+    hands it the embedding's transpose as ``weight``."""
 
-    def __init__(self, vocab_size: int, init_std: float = 0.02):
+    def __init__(self, vocab_size: int, init_std: float = 0.02,
+                 tied: bool = False, divisor: float = 1.0):
         super().__init__()
         self.vocab_size, self.init_std = vocab_size, init_std
+        self.tied, self.divisor = tied, float(divisor)
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(
             tuple(in_spec.shape[:-1]) + (self.vocab_size,), jnp.float32)
+
+    def build(self, rng, in_spec):
+        if not self.tied:
+            return super().build(rng, in_spec)
+        # nothing of its own to allocate, and no matrix to trace the product with
+        self._params, self._state, self._grads = {}, {}, {}
+        self._built = True
+        return self.infer_shape(in_spec)
 
     def _build(self, rng, in_spec):
         w = self.init_std * jax.random.normal(
@@ -191,51 +263,84 @@ class LMHead(AbstractModule):
 
     def _apply(self, params, state, x, training, rng):
         with jax.named_scope("lm_head"):
-            return precision.dot_acc32(x, params["weight"]), state
+            logits = precision.dot_acc32(x, params["weight"])
+            return (logits if self.divisor == 1.0
+                    else logits / self.divisor), state
 
 
 class DecoderLM(Container):
     """Decoder-only language model: int tokens (N, T) -> logits (N, T, V).
 
     Args:
-        vocab_size, hidden_size: V and D (embedding and head untied).
+        vocab_size, hidden_size: V and D.
         layer_types: one of ``LAYER_KINDS`` per layer.
         num_heads, num_kv_heads, head_dim: attention geometry.
         sliding_window: the window of the sliding layers.
-        rope_parameters: ``{kind: rope dict}`` (``rope_inv_freq``).
-        n_experts, experts_per_token, expert_size: router width, k, F.
+        rope_parameters: ``{kind: rope dict}`` (``rope_inv_freq``); a kind
+            without an entry has no positional encoding.
+        n_experts, experts_per_token, expert_size: router width, k, F; with
+            ``n_experts`` 0 the feed-forward is one ``GatedMLP(mlp_size)``.
         experts_held: ids of the experts this chip holds (default all).
+        qk_norm, attention_scale: see ``GroupedQueryAttention``.
+        mamba: the ``"mamba"`` layers' ``Mamba2Mixer`` arguments (``heads``,
+            ``head_dim``, ``state``, ``conv``, ``chunk``).
+        embedding_multiplier, residual_multiplier, logits_divisor: the
+            module docstring's three scalars.
+        tie_embeddings: the head is the embedding's transpose.
     """
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_types: Sequence[str], num_heads: int,
-                 num_kv_heads: int, head_dim: int, sliding_window: int,
-                 rope_parameters: Dict[str, Dict], n_experts: int,
-                 experts_per_token: int, expert_size: int,
-                 experts_held=None, eps: float = 1e-6,
-                 init_std: float = 0.02):
+                 num_kv_heads: int, head_dim: int,
+                 sliding_window: Optional[int] = None,
+                 rope_parameters: Optional[Dict[str, Dict]] = None,
+                 n_experts: int = 0, experts_per_token: int = 0,
+                 expert_size: int = 0, experts_held=None, eps: float = 1e-6,
+                 init_std: float = 0.02, mlp_size: int = 0,
+                 qk_norm: bool = True,
+                 attention_scale: Optional[float] = None,
+                 mamba: Optional[Dict] = None,
+                 embedding_multiplier: float = 1.0,
+                 residual_multiplier: float = 1.0,
+                 logits_divisor: float = 1.0, tie_embeddings: bool = False):
         super().__init__()
         bad = [k for k in layer_types if k not in LAYER_KINDS]
         if bad:
             raise ValueError(f"layer_types {bad}: each of {LAYER_KINDS}")
+        if "mamba" in layer_types and not mamba:
+            raise ValueError("a 'mamba' layer needs the mamba sizes")
+        if not n_experts and not mlp_size:
+            raise ValueError("neither experts nor a dense MLP: give "
+                             "n_experts or mlp_size")
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.tie_embeddings = tie_embeddings
+        rope_parameters = rope_parameters or {}
         embed = LookupTable(vocab_size, hidden_size)
         embed.weight_init = RandomNormal(0.0, init_std)
         self.add(embed.set_name("embed"))
+        last_mamba = max((i for i, k in enumerate(layer_types) if k == "mamba"),
+                         default=None)
         for i, kind in enumerate(layer_types):
-            block = DecoderBlock(
-                GroupedQueryAttention(
+            if kind == "mamba":
+                mixer = Mamba2Mixer(**mamba, eps=eps, init_std=init_std,
+                                    report_state=i == last_mamba)
+            else:
+                mixer = GroupedQueryAttention(
                     num_heads, num_kv_heads, head_dim,
                     window=sliding_window if kind == "sliding_attention"
                     else None,
                     rope=rope_parameters.get(kind), eps=eps,
-                    init_std=init_std),
-                RoutedExperts(n_experts, expert_size, experts_per_token,
-                              experts_held=experts_held, init_std=init_std),
-                eps=eps).set_name("block")
-            self.add(Remat(block).set_name(f"layer_{i}"))
+                    init_std=init_std, qk_norm=qk_norm, scale=attention_scale)
+            ffn = RoutedExperts(n_experts, expert_size, experts_per_token,
+                                experts_held=experts_held, init_std=init_std) \
+                if n_experts else GatedMLP(mlp_size, init_std)
+            block = DecoderBlock(mixer, ffn, eps=eps,
+                                 residual_multiplier=residual_multiplier)
+            self.add(Remat(block.set_name("block")).set_name(f"layer_{i}"))
         self.add(RMSNorm(eps=eps).set_name("final_norm"))
-        self.add(LMHead(vocab_size, init_std).set_name("head"))
+        self.add(LMHead(vocab_size, init_std, tied=tie_embeddings,
+                        divisor=logits_divisor).set_name("head"))
 
     def build(self, rng, in_spec):
         spec = in_spec
@@ -255,6 +360,15 @@ class DecoderLM(Container):
         embed, *blocks, final_norm, head = self.modules
         with jax.named_scope("embed"):
             h = run(embed, x)
+            if self.embedding_multiplier != 1.0:
+                h = h * self.embedding_multiplier
         for block in blocks:
             h = run(block, h)
-        return run(head, run(final_norm, h)), new_state
+        h = run(final_norm, h)
+        if not self.tie_embeddings:
+            return run(head, h), new_state
+        # the one leaf's second use: its gradient is the sum of both
+        logits, new_state[head.name()] = head._apply(
+            {"weight": params[embed.name()]["weight"].T}, state[head.name()],
+            h, training, rng)
+        return logits, new_state

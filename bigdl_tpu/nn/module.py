@@ -196,7 +196,7 @@ class AbstractModule:
         params, state = self._build(rng, in_spec)
         self._params = params
         self._state = state
-        self._grads = jax.tree_util.tree_map(jnp.zeros_like, params)
+        self._grads = None  # zeros like the parameters, when first asked for
         self._built = True
         out_spec = jax.eval_shape(
             lambda p, s, xx: self._apply(p, s, xx, False, None)[0], params, state, in_spec
@@ -274,6 +274,23 @@ class AbstractModule:
 
     def set_state(self, state: Dict[str, Any]) -> None:
         self._state = state
+
+    @property
+    def _grads(self) -> Dict[str, Any]:
+        """The stateful API's gradient accumulators (``backward`` adds into
+        them, ``parameters()`` lists them): zeros in the parameters' shapes,
+        made when first asked for. A model that trains through an optimizer
+        never asks, and so does not hold a dead copy of its parameters' size
+        on the device (3.09 GB for the 772M-parameter language model, which
+        with it did not fit beside its step's temporaries; PERF.md, PR 32)."""
+        if self._grads_made is None:
+            self._grads_made = jax.tree_util.tree_map(jnp.zeros_like,
+                                                      self._params)
+        return self._grads_made
+
+    @_grads.setter
+    def _grads(self, grads: Optional[Dict[str, Any]]) -> None:
+        self._grads_made = grads
 
     def get_grad_parameters(self) -> Dict[str, Any]:
         return self._grads
@@ -439,7 +456,8 @@ class AbstractModule:
         """Counters a forward pass stashed in the state pytree under
         ``'_counters'`` keys (``{name: scalar}``, e.g. the routed experts'
         ``moe_pairs_local``), reduced over the modules that wrote them: a name
-        with ``max`` in it by maximum, any other by sum. The standard train
+        with ``max`` in it by maximum, one with ``min`` by minimum, any other
+        by sum. The standard train
         step hands them out as one more output, pulled with the one-step-late
         loss and written into the telemetry step record under their names
         (docs/observability.md); ``{}`` for a model that keeps none."""
@@ -457,6 +475,8 @@ class AbstractModule:
                         found[name] = value
                     elif "max" in name:
                         found[name] = jnp.maximum(found[name], value)
+                    elif "min" in name:
+                        found[name] = jnp.minimum(found[name], value)
                     else:
                         found[name] = found[name] + value
 

@@ -156,17 +156,20 @@ def observe_jit_compiles(jit_fn, seen: int, telemetry: "Telemetry", *,
         return seen
     if csize > seen:
         cache_hit = None if cache_watch is None else cache_watch.observe()
-        # the tiles this dispatch's own trace chose, and what its nn.Remat
-        # blocks kept (it began ``seconds`` ago); a program that never
-        # imported the kernel, or nn.Remat, chose and kept none
+        # the tiles this dispatch's own trace chose, what its nn.Remat
+        # blocks kept and how its state-space scans were cut (it began
+        # ``seconds`` ago); a program that never imported the kernel, nn.Remat
+        # or the scan chose and kept none
         since = time.perf_counter() - seconds
         flash = sys.modules.get("bigdl_tpu.ops.flash_attention")
         keep = sys.modules.get("bigdl_tpu.utils.remat_keep")
+        ssd = sys.modules.get("bigdl_tpu.ops.ssd")
         telemetry.compile_event(
             iteration=iteration, seconds=seconds, count=csize - seen,
             path=path, cache_hit=cache_hit,
             flash_tiles=flash and flash.take_tile_records(since=since),
-            remat_kept=keep and keep.take_kept_records(since=since))
+            remat_kept=keep and keep.take_kept_records(since=since),
+            ssd_scans=ssd and ssd.take_scan_records(since=since))
         return csize
     return seen
 
@@ -697,6 +700,7 @@ class Telemetry:
         path: str = "train", cache_hit: Optional[bool] = None,
         flash_tiles: Optional[List[Dict]] = None,
         remat_kept: Optional[List[Dict]] = None,
+        ssd_scans: Optional[List[Dict]] = None,
     ) -> None:
         """One (re)compilation observed — hooked off the jit-cache-size delta
         at dispatch, the same introspection PR 2's ``compile_seconds``
@@ -708,8 +712,10 @@ class Telemetry:
         the flash-attention tile choices that the compiling call's trace
         made (``ops/flash_attention.take_tile_records``), ``remat_kept`` the
         marked values that its ``nn.Remat`` blocks kept for the backward
-        (``utils/remat_keep.take_kept_records``); the record carries either
-        field only where there were any."""
+        (``utils/remat_keep.take_kept_records``), ``ssd_scans`` how its
+        state-space scans were cut (chunk, chunks a record, head group:
+        ``ops/ssd.take_scan_records``); the record carries each field only
+        where there were any."""
         with self._lock:
             self.compile_count += count
             self.compile_seconds += seconds
@@ -726,6 +732,8 @@ class Telemetry:
             record["flash_tiles"] = flash_tiles
         if remat_kept:
             record["remat_kept"] = remat_kept
+        if ssd_scans:
+            record["ssd_scans"] = ssd_scans
         self.emit(record)
         self.flush()  # compiles are rare; make them tail-able immediately
 

@@ -1,0 +1,197 @@
+"""The state-space recurrence of a Mamba-2 layer in its chunked dual form
+(Dao & Gu 2024, "Transformers are SSMs", arXiv:2405.21060, section 6).
+
+Per head, with a scalar decay a token::
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t        S is (P, N), S_0 = 0
+    y_t = S_t C_t + D x_t
+
+Run token by token that is T sequential steps. ``ssd_scan`` cuts the record
+into chunks of ``chunk`` tokens and computes the same values from four matrix
+products and one short scan over the chunks' states. With ``a = dt A <= 0``
+and ``cum`` its inclusive running sum INSIDE a chunk:
+
+* inside a chunk, ``Y = ((C B^T) * L) (dt x)`` with ``L[t, s] = exp(cum_t -
+  cum_s)`` for ``s <= t`` and 0 above the diagonal (products 1 and 2);
+* what a chunk adds to the state, ``B^T (exp(cum_last - cum_s) dt x)``
+  (product 3), carried from chunk to chunk by ``lax.scan``:
+  ``S <- exp(cum_last) S + that``;
+* what the state entering a chunk gives its tokens, ``exp(cum_t) (C S)``
+  (product 4).
+
+Every exponential is of a difference of running sums that is <= 0, never a
+ratio of exponentials. Log decays, their sums and exponentials and the
+carried state are float32; the four products take their operands in
+``Engine``'s compute dtype and sum in float32, like every other product of
+the model (``utils/precision``).
+
+``L`` is (chunks, heads, chunk, chunk): 537 MB in float32 for one record of
+8192 tokens and 64 heads, and autodiff would keep several arrays of its size.
+So the product that needs it runs over the heads in groups (``lax.map``), each
+group recomputed in the backward pass (``jax.checkpoint``): what is live is
+one group's ``L`` and what the backward keeps is the group's inputs. The
+group is chosen from the shapes (``head_group``), never by a caller. What a
+compiled step chose is in its telemetry ``compile`` record (``ssd_scans``).
+
+``ssd_sequential`` is the recurrence as written, one token at a time: the
+tests' and ``chip_smoke.py``'s yardstick, not a path of the model.
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..utils import precision
+
+# one group's float32 L (records x chunks x group x chunk x chunk) stays under
+# this: 8 of 64 heads at one record of 8192 tokens in chunks of 256
+_GROUP_BYTES = 64 * 2**20
+
+_scan_records: dict = {}  # shapes -> (when last traced, the record)
+_scan_records_lock = threading.Lock()
+
+
+def take_scan_records(since: float = 0.0) -> list:
+    """The scans traced at or after ``since`` (a ``time.perf_counter``
+    reading): tokens, chunk, chunks a record, heads and the head group, one
+    entry per distinct shape with the number of ``calls``. Forgets
+    everything, as ``ops/flash_attention.take_tile_records`` does."""
+    with _scan_records_lock:
+        out = [record for at, record in _scan_records.values() if at >= since]
+        _scan_records.clear()
+    return out
+
+
+def _record_scan(**record) -> None:
+    key = tuple(sorted(record.items()))
+    with _scan_records_lock:
+        calls = _scan_records[key][1]["calls"] + 1 if key in _scan_records else 1
+        _scan_records[key] = (time.perf_counter(), {**record, "calls": calls})
+
+
+def head_group(records: int, chunks: int, heads: int, chunk: int) -> int:
+    """Heads whose ``L`` is live at once: the largest divisor of ``heads``
+    whose float32 ``L`` fits ``_GROUP_BYTES`` (at least one head)."""
+    per_head = records * chunks * chunk * chunk * 4
+    fit = max(_GROUP_BYTES // per_head, 1)
+    return max(g for g in range(1, heads + 1) if heads % g == 0 and g <= fit)
+
+
+class ScanStats(NamedTuple):
+    """What the chunked form's precision hangs on, for the step's counters."""
+    log_decay_min: jax.Array    # most negative within-chunk running sum of dt A
+    state_sq_sum: jax.Array     # sum of squares of the chunk-boundary states
+    state_count: int            # how many numbers that is the sum over
+
+
+def _dot(spec: str, a, b):
+    """``einsum`` with operands in the compute dtype and a float32 sum."""
+    return jnp.einsum(spec, precision.cast_compute(a), precision.cast_compute(b),
+                      preferred_element_type=jnp.float32)
+
+
+def _within_chunks(cb, cum, xdt, group: int):
+    """Products 1's result ``cb`` (n, c, l, s), running sums ``cum``
+    (n, c, h, l) and ``dt x`` (n, c, h, s, p) -> (n, c, h, l, p), the heads in
+    groups of ``group``."""
+    n, c, h, q = cum.shape
+    p = xdt.shape[-1]
+    seen = jnp.tril(jnp.ones((q, q), bool))
+
+    @jax.checkpoint
+    def one_group(cb, cum_g, xdt_g):               # (n, c, g, q), (n, c, g, q, p)
+        seg = cum_g[..., :, None] - cum_g[..., None, :]
+        decay = jnp.exp(jnp.where(seen, seg, -jnp.inf))   # L: 0 above the diagonal
+        return _dot("ncgls,ncgsp->ncglp", cb[:, :, None] * decay, xdt_g)
+
+    if group == h:
+        return one_group(cb, cum, xdt)
+    # group-major for lax.map, and back
+    cum = jnp.moveaxis(cum.reshape(n, c, h // group, group, q), 2, 0)
+    xdt = jnp.moveaxis(xdt.reshape(n, c, h // group, group, q, p), 2, 0)
+    out = jax.lax.map(lambda args: one_group(cb, *args), (cum, xdt))
+    return jnp.moveaxis(out, 0, 2).reshape(n, c, h, q, p)
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk: int):
+    """x (n, T, H, P); dt (n, T, H) > 0 (after softplus); a (H,) < 0; b and c
+    (n, T, N), one group shared by all heads; d (H,) -> (y (n, T, H, P)
+    float32, ``ScanStats``). ``chunk`` is the model's (``mamba_chunk_size``);
+    a record shorter than one chunk is one chunk, and a ragged last chunk is
+    padded with tokens that leave the state as it is (dt = 0)."""
+    n, t, h, p = x.shape
+    s = b.shape[-1]
+    q = min(chunk, t)
+    pad = -t % q
+    x32, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
+    xdt = x32 * dt[..., None]
+    if pad:
+        widen = lambda v: jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))  # noqa: E731
+        xdt, dt, b, c = widen(xdt), widen(dt), widen(b), widen(c)
+    nc = (t + pad) // q
+    group = head_group(n, nc, h, q)
+    _record_scan(records=n, tokens=t, chunk=q, chunks=nc, heads=h, head_dim=p,
+                 state=s, head_group=group)
+
+    # (n, c, h, q): inclusive running sums of the log decay inside a chunk
+    cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(n, nc, q, h)
+                     .transpose(0, 1, 3, 2), axis=-1)
+    xdt = xdt.reshape(n, nc, q, h, p).transpose(0, 1, 3, 2, 4)   # (n, c, h, q, p)
+    b, c = b.reshape(n, nc, q, s), c.reshape(n, nc, q, s)
+
+    cb = _dot("ncls,ncts->nclt", c, b)                            # product 1
+    y = _within_chunks(cb, cum, xdt, group)                       # product 2
+    last = cum[..., -1:]
+    added = _dot("ncqs,nchqp->nchps", b, xdt * jnp.exp(last - cum)[..., None])
+
+    def carry(state, step):                 # the chunks' states, one after another
+        decay, add = step
+        return state * decay + add, state
+
+    final, entering = jax.lax.scan(
+        carry, jnp.zeros((n, h, p, s), jnp.float32),
+        (jnp.moveaxis(jnp.exp(last)[..., None], 1, 0), jnp.moveaxis(added, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)                       # (n, c, h, p, s)
+    y = y + _dot("ncqs,nchps->nchqp", c, entering) * jnp.exp(cum)[..., None]
+
+    y = y.transpose(0, 1, 3, 2, 4).reshape(n, nc * q, h, p)[:, :t]
+    y = y + d.astype(jnp.float32)[:, None] * x32
+    # the states at the chunks' ends: those that entered a later chunk (the
+    # first is the zero state) and the last one
+    stats = ScanStats(
+        jax.lax.stop_gradient(jnp.min(cum)),
+        jax.lax.stop_gradient(jnp.sum(entering * entering)
+                              + jnp.sum(final * final)),
+        nc * n * h * p * s)
+    return y, stats
+
+
+def ssd_sequential(x, dt, a, b, c, d, segment=None):
+    """The recurrence one token at a time, in the inputs' own precision:
+    same arguments as ``ssd_scan`` without the chunk, -> y (n, T, H, P).
+    With ``segment`` (a divisor of T) the tokens run in segments that the
+    backward pass recomputes, so that its stored states are one segment's
+    and not the record's (17 GB at 8192 tokens of a 64 x 64 x 128 state)."""
+    def token(state, inputs):
+        x_t, dt_t, b_t, c_t = inputs               # (H, P), (H,), (N,), (N,)
+        state = state * jnp.exp(dt_t * a)[:, None, None] \
+            + (dt_t[:, None] * x_t)[:, :, None] * b_t
+        return state, state @ c_t + d[:, None] * x_t
+
+    def one_record(x, dt, b, c):
+        zero = jnp.zeros(x.shape[1:] + (b.shape[-1],), x.dtype)
+        if segment is None:
+            return jax.lax.scan(token, zero, (x, dt, b, c))[1]
+        cut = lambda v: v.reshape((-1, segment) + v.shape[1:])  # noqa: E731
+        y = jax.lax.scan(
+            jax.checkpoint(lambda state, inputs: jax.lax.scan(
+                token, state, inputs)),
+            zero, (cut(x), cut(dt), cut(b), cut(c)))[1]
+        return y.reshape(x.shape)
+
+    return jax.vmap(one_record)(x, dt, b, c)

@@ -384,6 +384,40 @@ def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
     log(f"  {what}: fwd+bwd match the bare module ({n_calls} Mosaic calls)")
 
 
+def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
+                   n=1) -> None:
+    """The chunked state-space scan (ops/ssd.py, the Mamba-2 layers' path)
+    against the recurrence one token at a time in float32, outputs and the
+    gradient of every input, at granite-4.0-h-micro's widths: decays from
+    one to a thousand tokens, so the chunks' carried states matter."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops import ssd
+
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    x = _normal(1, (n, t, heads, head_dim), jnp.float32)
+    a = -jnp.exp(jax.random.uniform(ks[0], (heads,), maxval=math.log(16.0)))
+    rate = jnp.exp(jnp.linspace(math.log(1e-3), 0.0, heads))   # -dt a, a head
+    dt = rate / -a * jnp.exp(0.3 * jax.random.normal(ks[1], (n, t, heads)))
+    b, c = (_normal(s, (n, t, state), jnp.float32) for s in (2, 3))
+    d = 1.0 + 0.1 * jax.random.normal(ks[2], (heads,))
+    cot = _normal(4, x.shape, jnp.float32)
+    args = (x, dt, a, b, c, d)
+    what = f"ssd_scan T={t} {heads}x{head_dim} state {state} chunk {chunk}"
+    ssd.take_scan_records()
+    _, got = _fwd_bwd(lambda *v: ssd.ssd_scan(*v, chunk=chunk)[0], args, cot)
+    record, = ssd.take_scan_records()
+    _, want = _fwd_bwd(
+        lambda *v: ssd.ssd_sequential(*v, segment=min(chunk, t)), args, cot)
+    _close(got, want, BF16_TOL, what)
+    log(f"  {what}: fwd + 6 input gradients match the sequential recurrence "
+        f"({record['chunks']} chunks, heads in groups of "
+        f"{record['head_group']})")
+
+
 def check_fused(rows: int, hidden: int, conv_shape) -> None:
     """Engine.set_fused_kernels(True) against the unfused path of the same
     public call sites: nn.LayerNormalization (whose unfused chain is
@@ -509,6 +543,7 @@ def phase_kernels() -> None:
     check_flash(t=1024, d=64)
     check_flash(t=4096, d=128)
     check_flash_remat(t=2048, d=128)
+    check_ssd_scan()
     # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
     # res2 conv epilogue (b128: 128x256x56x56)
     check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))

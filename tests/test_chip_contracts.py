@@ -71,6 +71,8 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
         # before phase B sets bf16 operands: XLA:CPU has no bf16 x bf16 -> f32
         # product, which GroupedQueryAttention's projections are on the chip
         chip_smoke.check_flash_remat(t=128, d=16, n=1, heads=2)
+        chip_smoke.check_ssd_scan(t=48, heads=4, head_dim=8, state=16,
+                                  chunk=16, n=2)
         model, rec = chip_smoke.phase_train(
             depth=18, classes=10, image=32, batch=4, iters=6)
         assert rec["compile_s"] > 0
