@@ -25,13 +25,22 @@ carried state are float32; the four products take their operands in
 ``Engine``'s compute dtype and sum in float32, like every other product of
 the model (``utils/precision``).
 
-``L`` is (chunks, heads, chunk, chunk): 537 MB in float32 for one record of
-8192 tokens and 64 heads, and autodiff would keep several arrays of its size.
-So the product that needs it runs over the heads in groups (``lax.map``), each
-group recomputed in the backward pass (``jax.checkpoint``): what is live is
-one group's ``L`` and what the backward keeps is the group's inputs. The
-group is chosen from the shapes (``head_group``), never by a caller. What a
-compiled step chose is in its telemetry ``compile`` record (``ssd_scans``).
+Two forms compute that, chosen from what the code can see and by no caller.
+On the TPU backend, where the shapes tile (``ssd_kernel.heads_per_step``: a
+chunk that is a multiple of 128, heads that fill whole groups of 128 lanes),
+products 2, 3 and 4, the D term and every other pass over an array of the
+tokens' size are four Pallas kernels (``ops/ssd_kernel.py``: the chunks'
+states and the chunks' outputs, each forward and backward), in which ``L``
+exists only in VMEM and ``x`` and ``y`` keep the model's (tokens, heads x
+head_dim) layout; XLA keeps product 1, the running sums and the carry. Every
+other shape and backend takes the XLA form: there ``L`` is (chunks, heads,
+chunk, chunk), 537 MB in float32 for one record of 8192 tokens and 64 heads,
+and autodiff would keep several arrays of its size, so product 2 runs over
+the heads in groups (``lax.map``), each group recomputed in the backward pass
+(``jax.checkpoint``): what is live is one group's ``L`` and what the backward
+keeps is the group's inputs (``head_group``, from the shapes). What a compiled
+step chose is in its telemetry ``compile`` record (``ssd_scans``: ``kernel``,
+and the heads a grid step or the head group).
 
 ``ssd_sequential`` is the recurrence as written, one token at a time: the
 tests' and ``chip_smoke.py``'s yardstick, not a path of the model.
@@ -47,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils import precision
+from . import ssd_kernel
 
 # one group's float32 L (records x chunks x group x chunk x chunk) stays under
 # this: 8 of 64 heads at one record of 8192 tokens in chunks of 256
@@ -58,9 +68,11 @@ _scan_records_lock = threading.Lock()
 
 def take_scan_records(since: float = 0.0) -> list:
     """The scans traced at or after ``since`` (a ``time.perf_counter``
-    reading): tokens, chunk, chunks a record, heads and the head group, one
-    entry per distinct shape with the number of ``calls``. Forgets
-    everything, as ``ops/flash_attention.take_tile_records`` does."""
+    reading): tokens, chunk, chunks a record, heads, whether the kernels ran
+    (``kernel``) and their heads a grid step (``heads_per_step``) or the XLA
+    form's head group (``head_group``), one entry per distinct shape with the
+    number of ``calls``. Forgets everything, as
+    ``ops/flash_attention.take_tile_records`` does."""
     with _scan_records_lock:
         out = [record for at, record in _scan_records.values() if at >= since]
         _scan_records.clear()
@@ -118,49 +130,98 @@ def _within_chunks(cb, cum, xdt, group: int):
     return jnp.moveaxis(out, 0, 2).reshape(n, c, h, q, p)
 
 
-def ssd_scan(x, dt, a, b, c, d, chunk: int):
+def _carry(decay, added):
+    """The chunks' states, one after another: ``S <- decay_c S + added_c``
+    from ``S = 0`` over the chunks (axis 1 of both; ``decay`` broadcasts
+    against a state) -> (the last state, the states entering the chunks)."""
+    def step(state, chunk):
+        by, add = chunk
+        return state * by + add, state
+
+    final, entering = jax.lax.scan(
+        step, jnp.zeros(added.shape[:1] + added.shape[2:], jnp.float32),
+        (jnp.moveaxis(decay, 1, 0), jnp.moveaxis(added, 1, 0)))
+    return final, jnp.moveaxis(entering, 0, 1)
+
+
+def _chunks_xla(xdt, cum, b, c, cb, group: int):
+    """The three products on arrays of the tokens' size and the carry between
+    them in plain ``jax.numpy``: ``dt x`` (n, c q, h, p), running sums ``cum``
+    (n, c, h, q), b and c (n, c, q, s), ``cb`` product 1's result -> (y
+    without the D term (n, c q, h, p), the states entering the chunks, the
+    last state)."""
+    n, nc, h, q = cum.shape
+    p = xdt.shape[-1]
+    xdt = xdt.reshape(n, nc, q, h, p).transpose(0, 1, 3, 2, 4)   # (n, c, h, q, p)
+    y = _within_chunks(cb, cum, xdt, group)                       # product 2
+    last = cum[..., -1:]
+    added = _dot("ncqs,nchqp->nchps", b, xdt * jnp.exp(last - cum)[..., None])
+    final, entering = _carry(jnp.exp(last)[..., None], added)     # (n, c, h, p, s)
+    y = y + _dot("ncqs,nchps->nchqp", c, entering) * jnp.exp(cum)[..., None]
+    return y.transpose(0, 1, 3, 2, 4).reshape(n, nc * q, h, p), entering, final
+
+
+def _chunks_kernels(x, dt, cum, b, c, cb, d, per_step: int, interpret: bool):
+    """The same from ``ops/ssd_kernel``'s two operations, with x (n, c q, h,
+    p) and dt (n, c q, h) in place of ``dt x``, and the D term in y. Nothing
+    of the tokens' size leaves the model's (tokens, heads x head_dim) layout
+    or is touched by XLA; what is one number a head and token goes in as rows
+    of (heads, chunk)."""
+    n, nc, h, q = cum.shape
+    p = x.shape[-1]
+    x = x.reshape(n, nc * q, h * p)
+    dt = dt.reshape(n, nc, q, h).transpose(0, 1, 3, 2)            # (n, c, h, q)
+    last = cum[..., -1:]
+    added = ssd_kernel.chunk_states(x, dt * jnp.exp(last - cum), b,
+                                    per_step, interpret)          # (n, c, s, h p)
+    decay = jnp.repeat(jnp.exp(last[..., 0]), p, axis=-1)         # (n, c, h p)
+    final, entering = _carry(decay[:, :, None], added)
+    y = ssd_kernel.chunk_outputs(
+        cb, dt, cum, x, c, entering, jnp.repeat(d, p)[None], per_step, interpret)
+    return y.reshape(n, nc * q, h, p), entering, final
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
     """x (n, T, H, P); dt (n, T, H) > 0 (after softplus); a (H,) < 0; b and c
     (n, T, N), one group shared by all heads; d (H,) -> (y (n, T, H, P)
     float32, ``ScanStats``). ``chunk`` is the model's (``mamba_chunk_size``);
     a record shorter than one chunk is one chunk, and a ragged last chunk is
-    padded with tokens that leave the state as it is (dt = 0)."""
+    padded with tokens that leave the state as it is (dt = 0). On the TPU
+    backend, where the shapes tile (``ssd_kernel.heads_per_step``), the work
+    is ``ops/ssd_kernel``'s; ``interpret=True`` runs those kernels through
+    the Pallas interpreter wherever the shapes tile: the CPU tests' way in."""
     n, t, h, p = x.shape
     s = b.shape[-1]
     q = min(chunk, t)
     pad = -t % q
-    x32, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
-    xdt = x32 * dt[..., None]
+    x32, dt, d = x.astype(jnp.float32), dt.astype(jnp.float32), d.astype(jnp.float32)
+    x = x32                                  # padded below; x32 keeps T tokens
     if pad:
         widen = lambda v: jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))  # noqa: E731
-        xdt, dt, b, c = widen(xdt), widen(dt), widen(b), widen(c)
+        x, dt, b, c = widen(x), widen(dt), widen(b), widen(c)
     nc = (t + pad) // q
-    group = head_group(n, nc, h, q)
-    _record_scan(records=n, tokens=t, chunk=q, chunks=nc, heads=h, head_dim=p,
-                 state=s, head_group=group)
+    shape = dict(records=n, tokens=t, chunk=q, chunks=nc, heads=h, head_dim=p,
+                 state=s)
 
     # (n, c, h, q): inclusive running sums of the log decay inside a chunk
     cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(n, nc, q, h)
                      .transpose(0, 1, 3, 2), axis=-1)
-    xdt = xdt.reshape(n, nc, q, h, p).transpose(0, 1, 3, 2, 4)   # (n, c, h, q, p)
     b, c = b.reshape(n, nc, q, s), c.reshape(n, nc, q, s)
-
     cb = _dot("ncls,ncts->nclt", c, b)                            # product 1
-    y = _within_chunks(cb, cum, xdt, group)                       # product 2
-    last = cum[..., -1:]
-    added = _dot("ncqs,nchqp->nchps", b, xdt * jnp.exp(last - cum)[..., None])
-
-    def carry(state, step):                 # the chunks' states, one after another
-        decay, add = step
-        return state * decay + add, state
-
-    final, entering = jax.lax.scan(
-        carry, jnp.zeros((n, h, p, s), jnp.float32),
-        (jnp.moveaxis(jnp.exp(last)[..., None], 1, 0), jnp.moveaxis(added, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1)                       # (n, c, h, p, s)
-    y = y + _dot("ncqs,nchps->nchqp", c, entering) * jnp.exp(cum)[..., None]
-
-    y = y.transpose(0, 1, 3, 2, 4).reshape(n, nc * q, h, p)[:, :t]
-    y = y + d.astype(jnp.float32)[:, None] * x32
+    per_step = None
+    if interpret or jax.default_backend() == "tpu":
+        per_step = ssd_kernel.heads_per_step(
+            h, p, q, s, precision.compute_dtype().itemsize)
+    if per_step:
+        _record_scan(**shape, kernel=True, heads_per_step=per_step)
+        y, entering, final = _chunks_kernels(x, dt, cum, b, c, cb, d,
+                                             per_step, interpret)
+        y = y[:, :t]
+    else:
+        group = head_group(n, nc, h, q)
+        _record_scan(**shape, kernel=False, head_group=group)
+        y, entering, final = _chunks_xla(x * dt[..., None], cum, b, c, cb, group)
+        y = y[:, :t] + d[:, None] * x32
     # the states at the chunks' ends: those that entered a later chunk (the
     # first is the zero state) and the last one
     stats = ScanStats(

@@ -385,11 +385,14 @@ def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
 
 
 def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
-                   n=1) -> None:
+                   n=1, kernel=True) -> None:
     """The chunked state-space scan (ops/ssd.py, the Mamba-2 layers' path)
     against the recurrence one token at a time in float32, outputs and the
     gradient of every input, at granite-4.0-h-micro's widths: decays from
-    one to a thousand tokens, so the chunks' carried states matter."""
+    one to a thousand tokens, so the chunks' carried states matter. On the
+    chip these shapes must take the Pallas kernels (ops/ssd_kernel.py;
+    ``kernel``: what the scan's record has to say; off the chip every shape
+    takes the XLA form)."""
     import math
 
     import jax
@@ -408,14 +411,21 @@ def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
     args = (x, dt, a, b, c, d)
     what = f"ssd_scan T={t} {heads}x{head_dim} state {state} chunk {chunk}"
     ssd.take_scan_records()
-    _, got = _fwd_bwd(lambda *v: ssd.ssd_scan(*v, chunk=chunk)[0], args, cot)
+    text, got = _fwd_bwd(lambda *v: ssd.ssd_scan(*v, chunk=chunk)[0], args, cot)
     record, = ssd.take_scan_records()
+    if record["kernel"] != (kernel and on_tpu()):
+        raise AssertionError(f"{what}: kernel={kernel} expected on "
+                             f"{jax.default_backend()}, the record says {record}")
+    if record["kernel"]:
+        how = (f"{assert_mosaic(text, what)} Mosaic calls, "
+               f"{record['heads_per_step']} heads a grid step")
+    else:
+        how = f"the XLA form, heads in groups of {record['head_group']}"
     _, want = _fwd_bwd(
         lambda *v: ssd.ssd_sequential(*v, segment=min(chunk, t)), args, cot)
     _close(got, want, BF16_TOL, what)
     log(f"  {what}: fwd + 6 input gradients match the sequential recurrence "
-        f"({record['chunks']} chunks, heads in groups of "
-        f"{record['head_group']})")
+        f"({record['chunks']} chunks, {how})")
 
 
 def check_fused(rows: int, hidden: int, conv_shape) -> None:
@@ -544,6 +554,8 @@ def phase_kernels() -> None:
     check_flash(t=4096, d=128)
     check_flash_remat(t=2048, d=128)
     check_ssd_scan()
+    # a chunk of 192 is no multiple of 128: this one must fall back
+    check_ssd_scan(t=1536, heads=8, chunk=192, kernel=False)
     # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
     # res2 conv epilogue (b128: 128x256x56x56)
     check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))

@@ -124,7 +124,8 @@ def test_heads_in_groups_give_what_all_heads_at_once_give(monkeypatch):
     np.testing.assert_allclose(g_grouped, g_whole, atol=2e-5)
     record, = ssd.take_scan_records()
     assert record == dict(records=2, tokens=32, chunk=8, chunks=4, heads=8,
-                          head_dim=8, state=16, head_group=2, calls=2)
+                          head_dim=8, state=16, kernel=False, head_group=2,
+                          calls=2)
     assert ssd.take_scan_records() == []
 
 
@@ -573,11 +574,13 @@ def test_hybrid_trains_through_optimize_with_counters_in_the_record():
     assert steps[-1]["compile_count"] == 1
     compiles = [r for r in records if r.get("type") == "compile"]
     assert sum(r["count"] for r in compiles) == 1
-    # how the scans were cut: 32 tokens in 4 chunks of 8, all 8 heads at once
+    # how the scans were cut: 32 tokens in 4 chunks of 8, all 8 heads at once,
+    # in the XLA form (the CPU backend takes no kernel)
     scan, = compiles[0]["ssd_scans"]
-    assert {k: scan[k] for k in ("records", "tokens", "chunk", "chunks",
-                                 "heads", "head_group")} == dict(
-        records=2, tokens=32, chunk=8, chunks=4, heads=8, head_group=8)
+    assert {k: scan[k] for k in ("records", "tokens", "chunk", "chunks", "heads",
+                                 "kernel", "head_group")} == dict(
+        records=2, tokens=32, chunk=8, chunks=4, heads=8, kernel=False,
+        head_group=8)
     assert scan["calls"] >= 2     # two mamba layers, traced at least once each
 
 
