@@ -2,8 +2,9 @@
 next one is a dict and not a class.
 
 ``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses.
-Two families so far, told apart by their own keys (the cuts to one chip are
-``benchmark/configs/mellum2_12b.json`` and ``granite_4_0_h_micro.json``)::
+Three families so far, told apart by their own keys (the cuts to one chip are
+``benchmark/configs/mellum2_12b.json``, ``granite_4_0_h_micro.json`` and
+``joyai_llm_flash.json``)::
 
     vocab_size, hidden_size, num_hidden_layers, layer_types,
     num_attention_heads, num_key_value_heads, head_dim, rms_norm_eps
@@ -19,17 +20,34 @@ Two families so far, told apart by their own keys (the cuts to one chip are
     attention_multiplier, embedding_multiplier, residual_multiplier,
     logits_scaling, position_embedding_type, tie_word_embeddings
 
-and two of this repo's own: ``experts_held`` (ids of the experts this chip
-holds, default all: one chip's share of an expert-parallel layer) and
-``initializer_range`` (default 0.02). ``layer_types`` may be longer than
-``num_hidden_layers``: the first that many are built. ``head_dim`` defaults
-to ``hidden_size / num_attention_heads``.
+    latent attention, sparse after leading dense layers, multi-token
+    prediction (JoyAI-LLM-Flash; DeepSeek-V3's key set, no ``layer_types``:
+    every layer is ``latent_attention``; told by ``kv_lora_rank``):
+    q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+    v_head_dim, rope_theta, rope_scaling (null), rope_interleave,
+    first_k_dense_replace, moe_layer_freq (1), intermediate_size,
+    moe_intermediate_size, n_routed_experts, n_shared_experts,
+    num_experts_per_tok, scoring_func (sigmoid | softmax), topk_method
+    (noaux_tc: the selection bias; greedy: none), n_group and topk_group (1),
+    norm_topk_prob, routed_scaling_factor, num_nextn_predict_layers (0 | 1),
+    tie_word_embeddings (false)
 
-``decoder_lm_reference`` (attention + routed experts) and
-``hybrid_lm_reference`` (state-space + attention, dense MLP) are the plain
-float32 references of the same equations; ``reference_config`` and
-``reference_params`` hand the one that fits this model's sizes and
-parameters.
+and three of this repo's own: ``experts_held`` (ids of the experts this chip
+holds, default all: one chip's share of an expert-parallel layer),
+``initializer_range`` (default 0.02) and, for the third family,
+``router_bias_update_rate`` (the speed of the selection bias, default 0.001:
+DeepSeek-V3's, arXiv:2412.19437 section 4.2). ``layer_types`` may be longer
+than ``num_hidden_layers``: the first that many are built. ``head_dim``
+defaults to ``hidden_size / num_attention_heads``. A model with
+``num_nextn_predict_layers`` 1 returns ``Table(logits, logits_1)`` and
+trains under ``nn.MultiTokenCrossEntropyCriterion``.
+
+``decoder_lm_reference`` (attention + routed experts),
+``hybrid_lm_reference`` (state-space + attention, dense MLP) and
+``latent_moe_lm_reference`` (latent attention, dense and sparse layers, the
+multi-token-prediction module) are the plain float32 references of the same
+equations; ``reference_config`` and ``reference_params`` hand the one that
+fits this model's sizes and parameters.
 """
 
 from __future__ import annotations
@@ -39,7 +57,14 @@ from typing import Dict
 from .. import nn
 
 
+def is_latent(config: Dict) -> bool:
+    """Whether ``config`` is of the latent-attention family."""
+    return "kv_lora_rank" in config
+
+
 def layer_types(config: Dict):
+    if is_latent(config):
+        return ["latent_attention"] * int(config["num_hidden_layers"])
     kinds = list(config["layer_types"])[:int(config["num_hidden_layers"])]
     if len(kinds) != int(config["num_hidden_layers"]):
         raise ValueError(
@@ -49,7 +74,8 @@ def layer_types(config: Dict):
 
 
 def experts_held(config: Dict):
-    return tuple(config.get("experts_held", range(int(config["num_experts"]))))
+    width = config["n_routed_experts" if is_latent(config) else "num_experts"]
+    return tuple(config.get("experts_held", range(int(width))))
 
 
 MLP_KINDS = ("sparse",)        # of mlp_layer_types; no key: one dense gated MLP
@@ -84,9 +110,91 @@ def _mamba(config: Dict) -> Dict:
                 chunk=int(config["mamba_chunk_size"]))
 
 
+def _accepts(config: Dict, key: str, accepted, default=None):
+    value = config.get(key, default)
+    if value not in accepted:
+        raise ValueError(f"decoder_lm.from_config: {key} {value!r}; accepts "
+                         f"{' or '.join(repr(a) for a in accepted)}")
+    return value
+
+
+def bias_update_rate(config: Dict) -> float:
+    """The speed of the latent family's selection bias."""
+    return float(config.get("router_bias_update_rate", 1e-3))
+
+
+def mlp_layer_types(config: Dict):
+    """The latent family's feed-forward, layer by layer."""
+    dense = int(config.get("first_k_dense_replace", 0))
+    return ["dense" if i < dense else "sparse"
+            for i in range(int(config["num_hidden_layers"]))]
+
+
+def _latent(config: Dict) -> nn.DecoderLM:
+    """DeepSeek-V3's key set: latent attention in every layer, leading dense
+    layers, then sparse ones with a shared expert, an MTP module."""
+    _accepts(config, "rope_scaling", (None,))
+    _accepts(config, "n_group", (1,), 1)
+    _accepts(config, "topk_group", (1,), 1)
+    _accepts(config, "moe_layer_freq", (1,), 1)
+    _accepts(config, "tie_word_embeddings", (False,), False)
+    _accepts(config, "attention_bias", (False,), False)
+    scoring = _accepts(config, "scoring_func", ("sigmoid", "softmax"))
+    method = _accepts(config, "topk_method", ("noaux_tc", "greedy"))
+    mtp = _accepts(config, "num_nextn_predict_layers", (0, 1), 0)
+    if not isinstance(config.get("q_lora_rank"), int):
+        raise ValueError("decoder_lm.from_config: q_lora_rank "
+                         f"{config.get('q_lora_rank')!r}; accepts an integer "
+                         "(the low-rank query path)")
+    if scoring == "softmax":
+        if method != "greedy" or float(config["routed_scaling_factor"]) != 1.0 \
+                or int(config.get("n_shared_experts", 0)):
+            raise ValueError(
+                "decoder_lm.from_config: scoring_func 'softmax' accepts "
+                "topk_method 'greedy', routed_scaling_factor 1 and no shared "
+                "expert; 'sigmoid' takes the others")
+        router = {}
+    else:
+        router = dict(
+            scoring="sigmoid",
+            routed_scaling=float(config["routed_scaling_factor"]),
+            bias_update_rate=bias_update_rate(config)
+            if method == "noaux_tc" else None,
+            shared_size=int(config["moe_intermediate_size"])
+            * int(config.get("n_shared_experts", 0)))
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("decoder_lm.from_config: the router's chosen "
+                         "weights are renormalised (norm_topk_prob)")
+    nope, rope = (int(config["qk_nope_head_dim"]),
+                  int(config["qk_rope_head_dim"]))
+    return nn.DecoderLM(
+        vocab_size=int(config["vocab_size"]),
+        hidden_size=int(config["hidden_size"]),
+        layer_types=layer_types(config),
+        num_heads=int(config["num_attention_heads"]),
+        num_kv_heads=int(config["num_attention_heads"]),
+        head_dim=nope + rope,
+        eps=float(config["rms_norm_eps"]),
+        init_std=float(config.get("initializer_range", 0.02)),
+        rope_parameters={"latent_attention": {
+            "rope_type": "default", "rope_theta": config["rope_theta"]}},
+        latent=dict(q_rank=int(config["q_lora_rank"]),
+                    kv_rank=int(config["kv_lora_rank"]), nope_dim=nope,
+                    rope_dim=rope, v_dim=int(config["v_head_dim"]),
+                    interleaved=bool(config.get("rope_interleave", False))),
+        mlp_layer_types=mlp_layer_types(config),
+        mlp_size=int(config["intermediate_size"]),
+        n_experts=int(config["n_routed_experts"]),
+        experts_per_token=int(config["num_experts_per_tok"]),
+        expert_size=int(config["moe_intermediate_size"]),
+        experts_held=experts_held(config), router=router, mtp_modules=mtp)
+
+
 def from_config(config: Dict) -> nn.DecoderLM:
     """The ``nn.DecoderLM`` that ``config`` describes (not yet built: the
     optimizer builds it from the first batch, or call ``build``)."""
+    if is_latent(config):
+        return _latent(config)
     kinds = layer_types(config)
     bad = sorted(set(kinds) - set(nn.decoder.LAYER_KINDS))
     if bad:
@@ -143,6 +251,14 @@ def from_config(config: Dict) -> nn.DecoderLM:
 
 def reference_config(config: Dict) -> Dict:
     """What the family's reference reads, from the same dict."""
+    if is_latent(config):
+        keys = ("num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "rope_theta", "rope_interleave",
+                "rms_norm_eps", "num_experts_per_tok", "routed_scaling_factor")
+        out = {k: config[k] for k in keys}
+        out["experts_held"] = experts_held(config)
+        out["bias_update_rate"] = bias_update_rate(config)
+        return out
     if is_hybrid(config):
         keys = ("num_attention_heads", "num_key_value_heads", "rms_norm_eps",
                 "mamba_n_heads", "mamba_d_head", "mamba_d_state",
@@ -169,10 +285,23 @@ def _reference_layer(block: Dict) -> Dict:
     return out
 
 
+def reference_biases(state: Dict) -> list:
+    """A built latent-family ``DecoderLM``'s router biases (from its state
+    tree) in the order of ``latent_moe_lm_reference``: one for each routed
+    layer, the MTP module's last."""
+    names = sorted((k for k in state if k.startswith("layer_")),
+                   key=lambda k: int(k.split("_")[1]))
+    blocks = [state[n]["block"] for n in names]
+    if "mtp" in state:
+        blocks.append(state["mtp"]["layer"]["block"])
+    return [b["experts"]["selection_bias"] for b in blocks
+            if "selection_bias" in b.get("experts", {})]
+
+
 def reference_params(params: Dict) -> Dict:
     """A built ``DecoderLM``'s parameter (or gradient) tree in the layout of
     its reference; the leaves are the same arrays. A tied model has no
-    ``head``."""
+    ``head``, one without an MTP module no ``mtp``."""
     names = sorted((k for k in params if k.startswith("layer_")),
                    key=lambda k: int(k.split("_")[1]))
     out = {"embed": params["embed"]["weight"],
@@ -181,4 +310,10 @@ def reference_params(params: Dict) -> Dict:
            "final_norm": params["final_norm"]["weight"]}
     if params["head"]:
         out["head"] = params["head"]["weight"]
+    if "mtp" in params:
+        mtp = params["mtp"]
+        out["mtp"] = {
+            "enorm": mtp["enorm"]["weight"], "hnorm": mtp["hnorm"]["weight"],
+            "eh_proj": mtp["eh_proj"]["weight"], "norm": mtp["norm"]["weight"],
+            "layer": _reference_layer(mtp["layer"]["block"])}
     return out

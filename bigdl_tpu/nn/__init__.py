@@ -175,6 +175,7 @@ from .criterion import (
     MultiCriterion,
     TimeDistributedCriterion,
     TokenCrossEntropyCriterion,
+    MultiTokenCrossEntropyCriterion,
     MarginCriterion,
     MultiLabelMarginCriterion,
     DiceCoefficientCriterion,
@@ -197,7 +198,9 @@ from .decoder import (
     DecoderLM,
     GatedMLP,
     GroupedQueryAttention,
+    LatentAttention,
     LMHead,
+    MultiTokenPredictor,
 )
 from .ssm import Mamba2Mixer
 from .pipelined import PipelinedBlocks

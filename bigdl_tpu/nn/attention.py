@@ -482,7 +482,8 @@ def _block_params(rng, hidden_size: int, num_heads: int, filter_size: int,
 
 def apply_rotary(x: jax.Array, positions: jax.Array,
                  inv_freq: Optional[jax.Array] = None,
-                 factor: Optional[float] = None) -> jax.Array:
+                 factor: Optional[float] = None,
+                 interleaved: bool = False) -> jax.Array:
     """Rotary position embedding (RoPE, Su et al. 2021) over the last dim.
 
     ``x`` (..., T, d) with d even; ``positions`` (T,) absolute positions.
@@ -494,7 +495,8 @@ def apply_rotary(x: jax.Array, positions: jax.Array,
     ``inv_freq`` (d/2,) replaces the built-in frequencies (another base, or
     YaRN's blended ones: ``nn.decoder.rope_inv_freq``) and ``factor``
     multiplies cos and sin (YaRN's attention factor); without them the
-    result is what it always was, bit for bit."""
+    result is what it always was, bit for bit. ``interleaved`` rotates the
+    pairs (2i, 2i+1) instead, each left in its place."""
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rotary needs an even feature dim, got {d}")
@@ -507,6 +509,11 @@ def apply_rotary(x: jax.Array, positions: jax.Array,
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if factor is not None:
         cos, sin = cos * factor, sin * factor
+    if interleaved:
+        pairs = x.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)

@@ -32,6 +32,14 @@ class AbstractCriterion:
     def _apply(self, input, target):  # pure scalar loss
         raise NotImplementedError
 
+    def counted(self, input, target):
+        """``(loss, {name: scalar})``: the loss and what of its parts the
+        step record should carry. ``LocalOptimizer``'s loss function writes
+        each part into the model state's ``_counters`` slot of that name
+        (``AbstractModule.counters_tree``); a criterion with no parts is
+        ``_apply`` and nothing else."""
+        return self._apply(input, target), {}
+
     def unreduced(self, input, target):
         """Per-sample loss decomposition, or ``None`` when the criterion has
         no row-wise form.
@@ -218,6 +226,66 @@ class TokenCrossEntropyCriterion(AbstractCriterion):
         with jax.named_scope("lm_head"):
             return _token_cross_entropy(
                 input, jnp.asarray(target).astype(jnp.int32))
+
+
+@jax.custom_vjp
+def _masked_token_cross_entropy(logits, target, valid):
+    """``_token_cross_entropy`` over the positions where ``valid`` (bool, the
+    targets' shape) holds: their mean, and no gradient elsewhere. The logits
+    keep their shape, so that leaving positions out costs no copy of them."""
+    return _masked_token_ce_fwd(logits, target, valid)[0]
+
+
+def _masked_token_ce_fwd(logits, target, valid):
+    logits = precision.to_float(logits)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, target[..., None], axis=-1)[..., 0]
+    count = jnp.sum(valid.astype(jnp.float32))
+    loss = jnp.sum(jnp.where(valid, lse - picked, 0.0)) / count
+    return loss, (logits, lse, target, valid, count)
+
+
+def _masked_token_ce_bwd(res, g):
+    logits, lse, target, valid, count = res
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    d = jnp.exp(logits - lse[..., None]) - (classes == target[..., None])
+    return jnp.where(valid[..., None], d * (g / count), 0.0), None, None
+
+
+_masked_token_cross_entropy.defvjp(_masked_token_ce_fwd, _masked_token_ce_bwd)
+
+
+class MultiTokenCrossEntropyCriterion(AbstractCriterion):
+    """The loss of a language model with one multi-token-prediction module
+    (DeepSeek-V3, arXiv:2412.19437 section 2.2): input ``Table(logits,
+    logits_1)``, both ``(N, T, V)``, zero-based int targets ``(N, T)`` with
+    ``target[t]`` the token after position ``t``;
+    ``CE(logits, target) + weight * CE(logits_1[:, :-1], target[:, 1:])``:
+    ``logits_1[t]`` predicts the token after next, and the last position, which
+    has neither a next input token nor a label, is left out of the second
+    mean. ``counted`` reports the second cross-entropy, before ``weight``, as
+    ``mtp_loss``."""
+
+    def __init__(self, weight: float = 0.1):
+        super().__init__()
+        self.weight = float(weight)
+
+    def counted(self, input, target):
+        logits, logits_1 = input.to_list() if isinstance(input, Table) \
+            else list(input)
+        target = jnp.asarray(target).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            main = _token_cross_entropy(logits, target)
+        with jax.named_scope("mtp"), jax.named_scope("lm_head"):
+            after_next = jnp.concatenate(
+                [target[:, 1:], jnp.zeros_like(target[:, :1])], axis=1)
+            valid = jnp.broadcast_to(
+                jnp.arange(target.shape[1]) < target.shape[1] - 1, target.shape)
+            second = _masked_token_cross_entropy(logits_1, after_next, valid)
+        return main + self.weight * second, {"mtp_loss": second}
+
+    def _apply(self, input, target):
+        return self.counted(input, target)[0]
 
 
 class MSECriterion(AbstractCriterion):

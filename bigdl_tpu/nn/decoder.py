@@ -3,7 +3,8 @@
 ``DecoderLM(vocab_size, hidden_size, layer_types=[...], ...)`` stacks one
 ``DecoderBlock`` per entry of ``layer_types``, each wrapped in ``nn.Remat``::
 
-    h = x + r * Mixer_l(RMSNorm(x))     GroupedQueryAttention | Mamba2Mixer
+    h = x + r * Mixer_l(RMSNorm(x))     GroupedQueryAttention |
+                                        LatentAttention | Mamba2Mixer
     y = h + r * FFN_l(RMSNorm(h))       nn.RoutedExperts | GatedMLP
 
 then a final RMSNorm and the head. The layer kinds (``LAYER_KINDS``):
@@ -11,13 +12,24 @@ then a final RMSNorm and the head. The layer kinds (``LAYER_KINDS``):
 grouped-query attention, causal, with a sliding window on the layers that say
 so, through ``scaled_dot_product_attention`` (the flash kernel on the TPU
 takes window, grouped heads and the score scale as they are); ``"mamba"`` is
-the Mamba-2 mixer (``nn/ssm.py``, a chunked state-space scan). Attention
+the Mamba-2 mixer (``nn/ssm.py``, a chunked state-space scan);
+``"latent_attention"`` is multi-head latent attention (``LatentAttention``:
+low-rank q and k/v paths, q/k heads of a no-position part and a rotary part
+that all heads share on the k side, v heads of their own size, through the
+same kernel). Grouped-query attention
 takes a per-head RMSNorm on q and k (``qk_norm``), RoPE from a given
 inverse-frequency vector and factor (plain or YaRN, ``rope_inv_freq``; a
 kind without an entry in ``rope_parameters`` has no positional encoding) and
 the score scale ``1/sqrt(head_dim)`` unless ``attention_scale`` gives
 another. The feed-forward is routed experts where the model has experts and
-one gated MLP where it has none. Four scalars change the paths: the
+one gated MLP where it has none, or layer by layer what ``mlp_layer_types``
+says (``"dense"`` / ``"sparse"``: leading dense layers before sparse ones).
+With ``mtp_modules`` 1 the model carries DeepSeek-V3's multi-token-prediction
+module (``MultiTokenPredictor``) and returns ``Table(logits, logits_1)``:
+the second predicts the token after next through one more block, using the
+embedding and the head a second time (one leaf each, its gradient the sum of
+its uses); ``nn.MultiTokenCrossEntropyCriterion`` is its loss. Four scalars
+change the paths: the
 embedding is multiplied by ``embedding_multiplier``, each residual branch by
 ``residual_multiplier`` (``r``), the logits divided by ``logits_divisor``;
 each is left out of the program at its neutral value. The head is its own
@@ -33,9 +45,10 @@ This is ROADMAP D1's shape, begun: ``nn.Transformer`` (one flat block steered
 by strings) stays beside it until D1 merges the two.
 
 Device time is attributed by ``jax.named_scope``: ``embed``, ``attn_proj``,
-``attn_window`` / ``attn_full`` (the kernel call alone), ``ssm_proj``,
-``ssm_conv``, ``ssm_scan``, ``moe_route``, ``moe_experts``, ``mlp``,
-``lm_head`` (docs/observability.md).
+``attn_window`` / ``attn_full`` (the kernel call alone), ``mla_proj``,
+``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``moe_route``, ``moe_experts``,
+``moe_shared``, ``mlp``, ``mtp`` (around the whole module), ``lm_head``
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -56,9 +69,12 @@ from .moe import RoutedExperts
 from .normalization import RMSNorm
 from .remat import Remat
 from .ssm import Mamba2Mixer
+from ..utils.table import Table
 
 # "attention" is "full_attention" under the name the hybrid models give it
-LAYER_KINDS = ("sliding_attention", "full_attention", "attention", "mamba")
+LAYER_KINDS = ("sliding_attention", "full_attention", "attention", "mamba",
+               "latent_attention")
+MLP_KINDS = ("dense", "sparse")
 
 
 def rope_inv_freq(rope: Dict, head_dim: int):
@@ -168,6 +184,82 @@ class GroupedQueryAttention(AbstractModule):
             return precision.dot_acc32(ctx, params["wo"]).astype(x.dtype), state
 
 
+class LatentAttention(AbstractModule):
+    """Multi-head latent attention (DeepSeek-V2/V3's), causal, in the
+    expanded form training runs: ``(N, T, D) -> (N, T, D)``.
+
+    ``c_q = RMSNorm(x W_qa)`` (``q_rank``), ``q = c_q W_qb`` -> ``num_heads``
+    heads of ``[q_nope (nope_dim), q_rope (rope_dim)]``; ``[c_kv (kv_rank),
+    k_rope (rope_dim)] = x W_kva``, ``c_kv = RMSNorm(c_kv)``, ``[k_nope
+    (nope_dim), v (v_dim)]`` per head ``= c_kv W_kvb``. RoPE turns ``q_rope``
+    per head and ``k_rope`` ONCE: all heads share it. ``k = [k_nope,
+    k_rope]``; scores ``q k^T / sqrt(nope_dim + rope_dim)``; the output heads
+    have ``v_dim``; ``out = concat W_o``. ``rope``: a ``rope_inv_freq`` dict
+    over ``rope_dim``; ``interleaved``: its pairs are (2i, 2i+1)."""
+
+    def __init__(self, num_heads: int, q_rank: int, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int, rope: Dict,
+                 interleaved: bool = True, eps: float = 1e-6,
+                 init_std: float = 0.02):
+        super().__init__()
+        self.num_heads, self.q_rank, self.kv_rank = num_heads, q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.interleaved, self.init_std = interleaved, init_std
+        self._rope = rope_inv_freq(rope, rope_dim)
+        self._rms = RMSNorm(eps=eps)  # statistics in float32
+
+    def infer_shape(self, in_spec):
+        return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
+
+    def _build(self, rng, in_spec):
+        d_model, h = in_spec.shape[-1], self.num_heads
+        ks = jax.random.split(rng, 5)
+        normal = lambda k, shape: self.init_std * jax.random.normal(  # noqa: E731
+            k, shape, jnp.float32)
+        return {
+            "wq_a": normal(ks[0], (d_model, self.q_rank)),
+            "q_norm": jnp.ones((self.q_rank,)),
+            "wq_b": normal(ks[1], (self.q_rank,
+                                   h * (self.nope_dim + self.rope_dim))),
+            "wkv_a": normal(ks[2], (d_model, self.kv_rank + self.rope_dim)),
+            "kv_norm": jnp.ones((self.kv_rank,)),
+            "wkv_b": normal(ks[3], (self.kv_rank,
+                                    h * (self.nope_dim + self.v_dim))),
+            "wo": normal(ks[4], (h * self.v_dim, d_model)),
+        }, {}
+
+    def _norm(self, x, gain):
+        return self._rms._apply({"weight": gain}, {}, x, False, None)[0]
+
+    def _apply(self, params, state, x, training, rng):
+        n, t, _ = x.shape
+        h, nope = self.num_heads, self.nope_dim
+        inv_freq, factor = self._rope
+        rotary = lambda a: apply_rotary(  # noqa: E731
+            a, jnp.arange(t), inv_freq, factor, interleaved=self.interleaved)
+        with jax.named_scope("mla_proj"):
+            c_q = self._norm(precision.dot_acc32(x, params["wq_a"]),
+                             params["q_norm"])
+            q = precision.dot_acc32(c_q, params["wq_b"]).reshape(
+                n, t, h, -1).transpose(0, 2, 1, 3)
+            q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], -1)
+            kv_a = precision.dot_acc32(x, params["wkv_a"])
+            c_kv = self._norm(kv_a[..., :self.kv_rank], params["kv_norm"])
+            k_rope = rotary(kv_a[..., None, :, self.kv_rank:])  # (N, 1, T, r)
+            kv = precision.dot_acc32(c_kv, params["wkv_b"]).reshape(
+                n, t, h, -1).transpose(0, 2, 1, 3)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (n, h, t, self.rope_dim))], -1)
+            v = kv[..., nope:]
+        with jax.named_scope("attn_full"):
+            ctx = scaled_dot_product_attention(q, k, v, causal=True,
+                                               mask_q=True)
+        with jax.named_scope("mla_proj"):
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(n, t, -1)
+            return precision.dot_acc32(ctx, params["wo"]).astype(x.dtype), state
+
+
 class GatedMLP(AbstractModule):
     """``[a, b] = split(x W_in)``, ``(silu(a) * b) W_out``: ``(..., D) ->
     (..., D)`` through ``size``, no bias; the two halves of ``W_in`` are one
@@ -196,8 +288,8 @@ class GatedMLP(AbstractModule):
 
 
 # what a block calls its mixer and its feed-forward in the parameter tree
-_CHILD_NAMES = {GroupedQueryAttention: "attn", Mamba2Mixer: "ssm",
-                RoutedExperts: "experts", GatedMLP: "mlp"}
+_CHILD_NAMES = {GroupedQueryAttention: "attn", LatentAttention: "attn",
+                Mamba2Mixer: "ssm", RoutedExperts: "experts", GatedMLP: "mlp"}
 
 
 class DecoderBlock(Container):
@@ -268,6 +360,71 @@ class LMHead(AbstractModule):
                     else logits / self.divisor), state
 
 
+class _Projection(AbstractModule):
+    """``x W`` (..., K) -> (..., D), no bias, float32 out."""
+
+    def __init__(self, size: int, init_std: float = 0.02):
+        super().__init__()
+        self.size, self.init_std = size, init_std
+
+    def infer_shape(self, in_spec):
+        return jax.ShapeDtypeStruct(
+            tuple(in_spec.shape[:-1]) + (self.size,), in_spec.dtype)
+
+    def _build(self, rng, in_spec):
+        return {"weight": self.init_std * jax.random.normal(
+            rng, (in_spec.shape[-1], self.size), jnp.float32)}, {}
+
+    def _apply(self, params, state, x, training, rng):
+        return precision.dot_acc32(x, params["weight"]).astype(x.dtype), state
+
+
+class MultiTokenPredictor(Container):
+    """DeepSeek-V3's multi-token-prediction module (arXiv:2412.19437 section
+    2.2), depth 1: from the main model's last hidden state ``h`` (before its
+    final norm) and the embedding ``e`` of the NEXT token,
+    ``h' = [RMSNorm_e(e) ; RMSNorm_h(h)] W_eh`` (2D -> D, the embedding
+    first), one decoder block of its own, and a norm of its own:
+    ``(h, e) -> (N, T, D)``. Embedding and head are the main model's:
+    ``DecoderLM`` looks ``e`` up before and applies the head after.
+
+    State: ``{"_counters": {"mtp_loss": 0}}`` beside its children's: the slot
+    that ``nn.MultiTokenCrossEntropyCriterion`` reports the second
+    cross-entropy under, filled by the optimizer's loss function (a
+    criterion has no state of its own to carry it in)."""
+
+    def __init__(self, hidden_size: int, block: AbstractModule,
+                 eps: float = 1e-6, init_std: float = 0.02):
+        super().__init__(RMSNorm(eps=eps).set_name("enorm"),
+                         RMSNorm(eps=eps).set_name("hnorm"),
+                         _Projection(hidden_size, init_std).set_name("eh_proj"),
+                         Remat(block.set_name("block")).set_name("layer"),
+                         RMSNorm(eps=eps).set_name("norm"))
+
+    def build(self, rng, in_spec):
+        wide = jax.ShapeDtypeStruct(
+            tuple(in_spec.shape[:-1]) + (2 * in_spec.shape[-1],), in_spec.dtype)
+        for i, m in enumerate(self.modules):
+            m.build(jax.random.fold_in(rng, i), wide if i == 2 else in_spec)
+        self._built = True
+        return in_spec
+
+    def get_state(self):
+        return {**super().get_state(),
+                "_counters": {"mtp_loss": jnp.zeros((), jnp.float32)}}
+
+    def _apply(self, params, state, x, training, rng):
+        h, e = x
+        enorm, hnorm, eh_proj, layer, norm = self.modules
+        new_state: Dict = {"_counters": state["_counters"]}
+        run = lambda m, v: self._child_apply(  # noqa: E731
+            m, v, training, rng, params, state, new_state)
+        with jax.named_scope("mla_proj"):
+            both = jnp.concatenate([run(enorm, e), run(hnorm, h)], axis=-1)
+            h = run(eh_proj, both)
+        return run(norm, run(layer, h)), new_state
+
+
 class DecoderLM(Container):
     """Decoder-only language model: int tokens (N, T) -> logits (N, T, V).
 
@@ -280,7 +437,17 @@ class DecoderLM(Container):
             without an entry has no positional encoding.
         n_experts, experts_per_token, expert_size: router width, k, F; with
             ``n_experts`` 0 the feed-forward is one ``GatedMLP(mlp_size)``.
+        mlp_layer_types: one of ``MLP_KINDS`` per layer (default: every
+            layer sparse where the model has experts, dense where not).
         experts_held: ids of the experts this chip holds (default all).
+        router: further ``nn.RoutedExperts`` arguments (``scoring``,
+            ``routed_scaling``, ``bias_update_rate``, ``shared_size``).
+        latent: the ``"latent_attention"`` layers' ``LatentAttention`` sizes
+            (``q_rank``, ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim``,
+            ``interleaved``).
+        mtp_modules: 0, or 1 for a ``MultiTokenPredictor`` whose block is of
+            the last layer's kinds; the model then returns ``Table(logits,
+            logits_1)``.
         qk_norm, attention_scale: see ``GroupedQueryAttention``.
         mamba: the ``"mamba"`` layers' ``Mamba2Mixer`` arguments (``heads``,
             ``head_dim``, ``state``, ``conv``, ``chunk``).
@@ -302,16 +469,34 @@ class DecoderLM(Container):
                  mamba: Optional[Dict] = None,
                  embedding_multiplier: float = 1.0,
                  residual_multiplier: float = 1.0,
-                 logits_divisor: float = 1.0, tie_embeddings: bool = False):
+                 logits_divisor: float = 1.0, tie_embeddings: bool = False,
+                 mlp_layer_types: Optional[Sequence[str]] = None,
+                 router: Optional[Dict] = None,
+                 latent: Optional[Dict] = None, mtp_modules: int = 0):
         super().__init__()
         bad = [k for k in layer_types if k not in LAYER_KINDS]
         if bad:
             raise ValueError(f"layer_types {bad}: each of {LAYER_KINDS}")
         if "mamba" in layer_types and not mamba:
             raise ValueError("a 'mamba' layer needs the mamba sizes")
+        if "latent_attention" in layer_types and not latent:
+            raise ValueError("a 'latent_attention' layer needs the latent sizes")
         if not n_experts and not mlp_size:
             raise ValueError("neither experts nor a dense MLP: give "
                              "n_experts or mlp_size")
+        if mlp_layer_types is None:
+            mlp_layer_types = ["sparse" if n_experts else "dense"] * len(
+                layer_types)
+        bad = [k for k in mlp_layer_types if k not in MLP_KINDS]
+        if bad or len(mlp_layer_types) != len(layer_types):
+            raise ValueError(f"mlp_layer_types {list(mlp_layer_types)}: one of "
+                             f"{MLP_KINDS} for each of {len(layer_types)} layers")
+        if ("sparse" in mlp_layer_types and not n_experts) or (
+                "dense" in mlp_layer_types and not mlp_size):
+            raise ValueError("a 'sparse' layer needs n_experts, a 'dense' "
+                             "one mlp_size")
+        if mtp_modules not in (0, 1):
+            raise ValueError(f"mtp_modules {mtp_modules}: 0 or 1")
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
         self.embedding_multiplier = float(embedding_multiplier)
         self.tie_embeddings = tie_embeddings
@@ -321,10 +506,15 @@ class DecoderLM(Container):
         self.add(embed.set_name("embed"))
         last_mamba = max((i for i, k in enumerate(layer_types) if k == "mamba"),
                          default=None)
-        for i, kind in enumerate(layer_types):
+
+        def block(i, kind, mlp_kind):
             if kind == "mamba":
                 mixer = Mamba2Mixer(**mamba, eps=eps, init_std=init_std,
                                     report_state=i == last_mamba)
+            elif kind == "latent_attention":
+                mixer = LatentAttention(
+                    num_heads, **latent, rope=rope_parameters[kind], eps=eps,
+                    init_std=init_std)
             else:
                 mixer = GroupedQueryAttention(
                     num_heads, num_kv_heads, head_dim,
@@ -333,42 +523,76 @@ class DecoderLM(Container):
                     rope=rope_parameters.get(kind), eps=eps,
                     init_std=init_std, qk_norm=qk_norm, scale=attention_scale)
             ffn = RoutedExperts(n_experts, expert_size, experts_per_token,
-                                experts_held=experts_held, init_std=init_std) \
-                if n_experts else GatedMLP(mlp_size, init_std)
-            block = DecoderBlock(mixer, ffn, eps=eps,
-                                 residual_multiplier=residual_multiplier)
-            self.add(Remat(block.set_name("block")).set_name(f"layer_{i}"))
+                                experts_held=experts_held, init_std=init_std,
+                                **(router or {})) \
+                if mlp_kind == "sparse" else GatedMLP(mlp_size, init_std)
+            return DecoderBlock(mixer, ffn, eps=eps,
+                                residual_multiplier=residual_multiplier)
+
+        for i, (kind, mlp_kind) in enumerate(zip(layer_types, mlp_layer_types)):
+            self.add(Remat(block(i, kind, mlp_kind).set_name("block"))
+                     .set_name(f"layer_{i}"))
         self.add(RMSNorm(eps=eps).set_name("final_norm"))
         self.add(LMHead(vocab_size, init_std, tied=tie_embeddings,
                         divisor=logits_divisor).set_name("head"))
+        self.mtp = None
+        if mtp_modules:
+            self.mtp = MultiTokenPredictor(
+                hidden_size, block(None, layer_types[-1], mlp_layer_types[-1]),
+                eps=eps, init_std=init_std).set_name("mtp")
+            self.add(self.mtp)
 
     def build(self, rng, in_spec):
         spec = in_spec
         for i, m in enumerate(self.modules):
+            if m is self.mtp:  # beside the head, not after it: hidden states in
+                m.build(jax.random.fold_in(rng, i), hidden)
+                continue
+            if m.name() == "final_norm":
+                hidden = spec
             spec = m.build(jax.random.fold_in(rng, i), spec)
         self._built = True
-        return spec
+        return self.infer_shape(in_spec)
 
     def infer_shape(self, in_spec):
-        return jax.ShapeDtypeStruct(
+        logits = jax.ShapeDtypeStruct(
             tuple(in_spec.shape) + (self.vocab_size,), jnp.float32)
+        return logits if self.mtp is None else Table({1: logits, 2: logits})
 
     def _apply(self, params, state, x, training, rng):
         new_state: Dict = {}
         run = lambda m, v: self._child_apply(  # noqa: E731
             m, v, training, rng, params, state, new_state)
-        embed, *blocks, final_norm, head = self.modules
+        embed, *blocks, final_norm, head = [
+            m for m in self.modules if m is not self.mtp]
+
+        def embedded(tokens):
+            h, new_state[embed.name()] = embed._apply(
+                params[embed.name()], state[embed.name()], tokens, training,
+                rng)
+            return (h if self.embedding_multiplier == 1.0
+                    else h * self.embedding_multiplier)
+
+        def logits_of(h):
+            # tied: the one leaf's second use, its gradient the sum of both
+            logits, new_state[head.name()] = head._apply(
+                {"weight": params[embed.name()]["weight"].T}
+                if self.tie_embeddings else params[head.name()],
+                state[head.name()], h, training, rng)
+            return logits
+
         with jax.named_scope("embed"):
-            h = run(embed, x)
-            if self.embedding_multiplier != 1.0:
-                h = h * self.embedding_multiplier
+            h = embedded(x)
         for block in blocks:
             h = run(block, h)
-        h = run(final_norm, h)
-        if not self.tie_embeddings:
-            return run(head, h), new_state
-        # the one leaf's second use: its gradient is the sum of both
-        logits, new_state[head.name()] = head._apply(
-            {"weight": params[embed.name()]["weight"].T}, state[head.name()],
-            h, training, rng)
-        return logits, new_state
+        logits = logits_of(run(final_norm, h))
+        if self.mtp is None:
+            return logits, new_state
+        with jax.named_scope("mtp"):
+            # the record shifted left by one; its last position has no next
+            # token (id 0 stands there, and the loss leaves the position out)
+            shifted = jnp.concatenate([x[:, 1:], jnp.zeros_like(x[:, :1])], 1)
+            with jax.named_scope("embed"):
+                e_next = embedded(shifted)
+            logits_1 = logits_of(run(self.mtp, (h, e_next)))
+        return Table({1: logits, 2: logits_1}), new_state

@@ -483,6 +483,31 @@ class AbstractModule:
         walk(state)
         return found
 
+    def with_counters(self, state, counted: Dict[str, Any]):
+        """``state`` with each of ``counted``'s values in the ``_counters``
+        slot of its name (a criterion's parts: ``AbstractCriterion.counted``).
+        The model declares the slots, so the state's structure is its own; a
+        name with no slot is an error, not a silent drop."""
+        left = set(counted)
+
+        def walk(s):
+            if not isinstance(s, dict):
+                return s
+            out = {}
+            for k, v in s.items():
+                if k == "_counters":
+                    left.difference_update(v)
+                    v = {n: counted.get(n, c) for n, c in v.items()}
+                out[k] = v if k == "_counters" else walk(v)
+            return out
+
+        state = walk(state)
+        if left:
+            raise ValueError(
+                f"the criterion reports {sorted(left)} but {self.name()}'s "
+                "state has no '_counters' slot of that name")
+        return state
+
     # -------------------------------------------------------------- inference
     def predict(self, data, batch_size: Optional[int] = None):
         """Batched forward over a DataSet / array / list of Samples, reusing one

@@ -404,20 +404,50 @@ def route_top_k(x, router_w, top_k: int):
     return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
 
 
-def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int):
-    """x (T, D) -> (this share's part of the layer's result (T, D), counters).
+def route_sigmoid_top_k(x, router_w, bias, top_k: int, scaling: float = 1.0):
+    """Sigmoid router in float32 over ALL experts (DeepSeek-V3's, ``noaux_tc``
+    without groups): ``s = sigmoid(x W_r)``; the k experts with the largest
+    ``s + bias`` are chosen (``bias`` (E,) or None: it enters the choice and
+    nothing else, so it takes no gradient); their weights are ``s`` over
+    the chosen, divided by their sum + 1e-20, times ``scaling``. -> (weights
+    (T, k), expert ids (T, k))."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, top_e = jax.lax.top_k(
+        s if bias is None else s + jax.lax.stop_gradient(bias), top_k)
+    top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return (w if scaling == 1.0 else w * scaling), top_e
+
+
+def selection_bias_update(bias, top_e, rate: float):
+    """The router's selection bias after one step (DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2): ``b_e + rate * sign(mean_e' c_e' - c_e)``
+    with ``c_e`` the (token, choice) pairs of this step that chose expert
+    ``e``, over all experts of the router."""
+    counts = jnp.bincount(top_e.reshape(-1), length=bias.shape[0]).astype(
+        jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(counts) - counts)
+
+
+def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int,
+                   route=None):
+    """x (T, D) -> (this share's part of the layer's result (T, D), counters,
+    the chosen expert ids (T, k)).
 
     Every (token, choice) pair is kept: the pairs are sorted by the slot of
     their expert among the experts held (pairs of absent experts last), the
     three grouped products run over the held experts' rows, and each token
     sums its k rows weighted by the router. No capacity, no drop: the sorted
     buffer has a row for every pair, and rows past the held groups cost
-    memory, not matrix work."""
+    memory, not matrix work. ``route(x, router_w, top_k) -> (weights, ids)``
+    is the router (``route_top_k`` where none is given)."""
     t, d = x.shape
     held = jnp.asarray(experts_held, jnp.int32)
     n_held = held.shape[0]
     with jax.named_scope("moe_route"):
-        top_p, top_e = route_top_k(x, params["router"], top_k)
+        top_p, top_e = (route or route_top_k)(x, params["router"], top_k)
         # slot among the held experts, n_held for an absent one
         slot_of = jnp.full((n_experts,), n_held, jnp.int32).at[held].set(
             jnp.arange(n_held, dtype=jnp.int32))
@@ -445,7 +475,10 @@ def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int):
             "moe_dropped_pairs": jnp.maximum(
                 local - xs.shape[0], 0).astype(jnp.float32),
         }
-    return out.astype(x.dtype), counters
+    return out.astype(x.dtype), counters, top_e
+
+
+SCORINGS = ("softmax", "sigmoid")
 
 
 class RoutedExperts(AbstractModule):
@@ -461,11 +494,26 @@ class RoutedExperts(AbstractModule):
     (``tests/test_decoder_lm.py``, the share test). Beside ``MoE`` (switch /
     GShard with capacity buffers that drop) until ROADMAP D2 merges them.
 
+    ``scoring="sigmoid"`` is DeepSeek-V3's router (``route_sigmoid_top_k``):
+    sigmoid scores, weights normalised over the chosen and multiplied by
+    ``routed_scaling``. With ``bias_update_rate`` (its auxiliary-loss-free
+    balancing) the choice is by ``s + b``: ``b`` (E,) is STATE, not a
+    parameter (``selection_bias``: zero at the start, no gradient, no
+    optimizer slot), and a training forward hands on ``b + rate * sign(mean
+    count - count)`` from this step's counts over all ``n_experts``, as batch
+    norm hands on its running statistics. ``shared_size`` adds one gated MLP
+    of that width that every token passes, whole on every chip, to the routed
+    sum (scope ``moe_shared``); in the share test it counts once.
+
     State: ``{"_counters": {moe_pairs_local, moe_load_max_over_mean,
-    moe_dropped_pairs}}``, see ``AbstractModule.counters_tree``."""
+    moe_dropped_pairs[, moe_bias_abs_max]}[, "selection_bias"]}``, see
+    ``AbstractModule.counters_tree``."""
 
     def __init__(self, n_experts: int, ffn_size: int, top_k: int,
-                 experts_held=None, init_std: float = 0.02):
+                 experts_held=None, init_std: float = 0.02,
+                 scoring: str = "softmax", routed_scaling: float = 1.0,
+                 bias_update_rate: Optional[float] = None,
+                 shared_size: int = 0):
         super().__init__()
         held = tuple(range(n_experts) if experts_held is None else experts_held)
         if not held or not all(0 <= e < n_experts for e in held) \
@@ -474,9 +522,18 @@ class RoutedExperts(AbstractModule):
                              f"among {n_experts} experts")
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k {top_k} not in [1, {n_experts}]")
+        if scoring not in SCORINGS:
+            raise ValueError(f"scoring {scoring!r}: one of {SCORINGS}")
+        if scoring == "softmax" and (routed_scaling != 1.0
+                                     or bias_update_rate is not None):
+            raise ValueError("a scaling factor and a selection bias belong "
+                             "to scoring='sigmoid'")
         self.n_experts, self.ffn_size, self.top_k = n_experts, ffn_size, top_k
         self.experts_held = held
         self.init_std = init_std
+        self.scoring, self.routed_scaling = scoring, float(routed_scaling)
+        self.bias_update_rate = bias_update_rate
+        self.shared_size = shared_size
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
@@ -491,13 +548,42 @@ class RoutedExperts(AbstractModule):
                   "w_up": normal(ks[2], (e, d, f)),
                   "w_down": normal(ks[3], (e, f, d))}
         zero = jnp.zeros((), jnp.float32)
-        return params, {"_counters": {
+        state = {"_counters": {
             "moe_pairs_local": zero, "moe_load_max_over_mean": zero,
             "moe_dropped_pairs": zero}}
+        if self.shared_size:
+            k_in, k_out = jax.random.split(jax.random.fold_in(rng, 4))
+            params.update(
+                shared_in=normal(k_in, (d, 2 * self.shared_size)),
+                shared_out=normal(k_out, (self.shared_size, d)))
+        if self.bias_update_rate is not None:
+            state["selection_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+            state["_counters"]["moe_bias_abs_max"] = zero
+        return params, state
 
     def _apply(self, params, state, x, training, rng):
         x = jnp.asarray(x)
-        out, counters = routed_experts(
-            x.reshape(-1, x.shape[-1]), params, n_experts=self.n_experts,
-            experts_held=self.experts_held, top_k=self.top_k)
-        return out.reshape(x.shape), {"_counters": counters}
+        tokens = x.reshape(-1, x.shape[-1])
+        bias = state.get("selection_bias")
+        route = None
+        if self.scoring == "sigmoid":
+            route = lambda x, w, k: route_sigmoid_top_k(  # noqa: E731
+                x, w, bias, k, self.routed_scaling)
+        out, counters, top_e = routed_experts(
+            tokens, params, n_experts=self.n_experts,
+            experts_held=self.experts_held, top_k=self.top_k, route=route)
+        new_state = {"_counters": counters}
+        if bias is not None:
+            with jax.named_scope("moe_route"):
+                if training:
+                    bias = selection_bias_update(bias, top_e,
+                                                 self.bias_update_rate)
+                new_state["selection_bias"] = bias
+                counters["moe_bias_abs_max"] = jnp.max(jnp.abs(bias))
+        if self.shared_size:
+            with jax.named_scope("moe_shared"):
+                a, b = jnp.split(
+                    precision.dot_acc32(tokens, params["shared_in"]), 2, axis=-1)
+                out = out + precision.dot_acc32(
+                    jax.nn.silu(a) * b, params["shared_out"]).astype(x.dtype)
+        return out.reshape(x.shape), new_state

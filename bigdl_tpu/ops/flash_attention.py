@@ -193,21 +193,31 @@ _VMEM_BUDGET = 15 * 2 ** 20
 _LARGEST_TILE = 1024
 
 
-def _working_set(bq: int, bk: int, d: int, itemsize: int) -> int:
+def _working_set(bq: int, bk: int, d: int, itemsize: int,
+                 d_v: Optional[int] = None) -> int:
     """Bytes of VMEM the hungriest of the three kernels holds at once: the
     float32 score and probability tiles, every operand and result tile twice
     (the pipeline fetches the next while this one computes), the float32
-    accumulators."""
+    accumulators. ``d`` is the head size of q and k, ``d_v`` that of v and
+    the output (``d`` where none is given)."""
+    d_v = d if d_v is None else d_v
     scores = 2 * bq * bk * 4
     q_tile, k_tile = bq * d * itemsize, bk * d * itemsize
-    fwd = scores + 2 * (2 * q_tile + 2 * k_tile) + bq * d * 4      # q o | k v
-    dq = scores + 2 * (3 * q_tile + 2 * k_tile) + bq * d * 4       # q do dq | k v
-    dkv = scores + 2 * (2 * q_tile + 4 * k_tile) + 2 * bk * d * 4  # q do | k v dk dv
+    o_tile, v_tile = bq * d_v * itemsize, bk * d_v * itemsize
+    fwd = scores + 2 * (q_tile + o_tile + k_tile + v_tile) + bq * d_v * 4
+    # q do dq | k v
+    dq = scores + 2 * (2 * q_tile + o_tile + k_tile + v_tile) + bq * d * 4
+    # q do | k v dk dv
+    dkv = scores + 2 * (q_tile + o_tile + 2 * k_tile + 2 * v_tile) \
+        + bk * (d + d_v) * 4
     return max(fwd, dq, dkv)
 
 
-def pick_tiles(tq: int, tk: int, d: int, itemsize: int):
-    """(block_q, block_k) of the three kernels, from the shapes of a call.
+def pick_tiles(tq: int, tk: int, d: int, itemsize: int,
+               d_v: Optional[int] = None):
+    """(block_q, block_k) of the three kernels, from the shapes of a call
+    (``d``: head size of q and k; ``d_v``: of v and the output, ``d`` where
+    none is given).
 
     The largest tiles win: on a v5e 1024 x 1024 was the fastest forward and,
     but for 1.8 % in one row, the fastest forward + backward of the nine
@@ -224,7 +234,8 @@ def pick_tiles(tq: int, tk: int, d: int, itemsize: int):
     bq = _pick_block(_LARGEST_TILE, tq)
     bk = _pick_block(
         _LARGEST_TILE if tk >= _LARGEST_TILE else _LARGEST_TILE // 2, tk)
-    while _working_set(bq, bk, d, itemsize) > _VMEM_BUDGET and max(bq, bk) > 128:
+    while (_working_set(bq, bk, d, itemsize, d_v) > _VMEM_BUDGET
+           and max(bq, bk) > 128):
         if bq >= bk:
             bq //= 2
         else:
@@ -261,7 +272,7 @@ _tile_records_lock = threading.Lock()
 
 def take_tile_records(since: float = 0.0) -> list:
     """The tile choices traced at or after ``since`` (a ``time.perf_counter``
-    reading), one per distinct (Tq, Tk, d, dtype, causal, window), and forget
+    reading), one per distinct (Tq, Tk, d, d_v, dtype, causal, window), and forget
     them all: ``Telemetry`` writes those that the compiling call's own trace
     made into its ``compile`` record; what an earlier, unobserved trace left
     behind belongs to no record."""
@@ -271,15 +282,15 @@ def take_tile_records(since: float = 0.0) -> list:
     return out
 
 
-def _resolve_tiles(q, k, causal: bool, window: Optional[int],
+def _resolve_tiles(q, k, v, causal: bool, window: Optional[int],
                    block_q: Optional[int], block_k: Optional[int]):
-    tq, tk, d = q.shape[2], k.shape[2], q.shape[3]
-    bq, bk = pick_tiles(tq, tk, d, q.dtype.itemsize)
+    tq, tk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    bq, bk = pick_tiles(tq, tk, d, q.dtype.itemsize, d_v)
     if block_q is not None:
         bq = _pick_block(block_q, tq)
     if block_k is not None:
         bk = _pick_block(block_k, tk)
-    key = (tq, tk, d, q.dtype.name, causal, window, bq, bk)
+    key = (tq, tk, d, d_v, q.dtype.name, causal, window, bq, bk)
     with _tile_records_lock:
         if key in _tile_records:
             record = _tile_records[key][1]
@@ -289,6 +300,8 @@ def _resolve_tiles(q, k, causal: bool, window: Optional[int],
                 tq=tq, tk=tk, d=d, dtype=q.dtype.name, causal=causal,
                 window=window, block_q=bq, block_k=bk, visited_tiles=tiles,
                 visited_over_visible=round(waste, 4))
+            if d_v != d:  # v and the output at a head size of their own
+                record["d_v"] = d_v
         _tile_records[key] = (time.perf_counter(), record)
     return bq, bk
 
@@ -360,10 +373,10 @@ def _expand_lengths(lengths, n: int, h: int, tk: int):
 def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
                     bq: int, bk: int, interpret: bool, mask_q: bool,
                     window: Optional[int] = None):
-    """Returns (out (N,H,Tq,d), lse (N*H, Tq_padded)) — lse is the bwd residual.
-    ``bq``/``bk`` are the resolved tiles (``_resolve_tiles``)."""
+    """Returns (out (N,H,Tq,d_v), lse (N*H, Tq_padded)) — lse is the bwd
+    residual. ``bq``/``bk`` are the resolved tiles (``_resolve_tiles``)."""
     n, h, tq, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
+    hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -371,7 +384,7 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
     kf = _pad_to(k.reshape(n * hkv, tk, d), 1, bk)
-    vf = _pad_to(v.reshape(n * hkv, tk, d), 1, bk)
+    vf = _pad_to(v.reshape(n * hkv, tk, d_v), 1, bk)
     tqp, tkp = qf.shape[1], kf.shape[1]
     nk = tkp // bk
     lens = _expand_lengths(lengths, n, h, tk)
@@ -398,20 +411,20 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d_v), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
+                pl.BlockSpec((1, bq, d_v), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq,), jnp.float32),
                 pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, d_v), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
+            jax.ShapeDtypeStruct((n * h, tqp, d_v), q.dtype),
             jax.ShapeDtypeStruct((n * h, 1, tqp), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -420,7 +433,7 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
         interpret=interpret,
         name="flash_fwd",
     )(lens, qf, kf, vf)
-    return out[:, :tq].reshape(n, h, tq, d), lse
+    return out[:, :tq].reshape(n, h, tq, d_v), lse
 
 
 def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
@@ -597,7 +610,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
                     interpret: bool, mask_q: bool,
                     window: Optional[int] = None):
     n, h, tq, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
+    hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -605,8 +618,8 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
 
     qf = _pad_to(q.reshape(n * h, tq, d), 1, bq)
     kf = _pad_to(k.reshape(n * hkv, tk, d), 1, bk)
-    vf = _pad_to(v.reshape(n * hkv, tk, d), 1, bk)
-    dof = _pad_to(g.reshape(n * h, tq, d), 1, bq)  # zero-padded rows
+    vf = _pad_to(v.reshape(n * hkv, tk, d_v), 1, bk)
+    dof = _pad_to(g.reshape(n * h, tq, d_v), 1, bq)  # zero-padded rows
     tqp, tkp = qf.shape[1], kf.shape[1]
     nq, nk = tqp // bq, tkp // bk
     lens = _expand_lengths(lengths, n, h, tk)
@@ -645,8 +658,8 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bq, d), lambda b, i, j, lens: (b, i, 0)),
+                pl.BlockSpec((1, bk, d_v), kv_map),
+                pl.BlockSpec((1, bq, d_v), lambda b, i, j, lens: (b, i, 0)),
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
             ],
@@ -671,6 +684,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
         q_row = lambda b, j: b * group + j // nqv  # noqa: E731
         q_tile = lambda i, j: q_of(i, j % nqv)  # noqa: E731
     q_map = lambda b, i, j, lens: (q_row(b, j), q_tile(i, j), 0)  # noqa: E731
+    kv_tile = lambda b, i, j, lens: (b, i, 0)  # noqa: E731
     row_map = lambda b, i, j, lens: (q_row(b, j), 0, q_tile(i, j))  # noqa: E731
     dk, dv = pallas_call(
         partial(_dkv_kernel, nq=nqv, **common, **dkv_extra),
@@ -679,24 +693,24 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             grid=(n * hkv, nk, group * nqv),
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_map),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, bk, d), kv_tile),
+                pl.BlockSpec((1, bk, d_v), kv_tile),
+                pl.BlockSpec((1, bq, d_v), q_map),
                 pl.BlockSpec((1, 1, bq), row_map),
                 pl.BlockSpec((1, 1, bq), row_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j, lens: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), kv_tile),
+                pl.BlockSpec((1, bk, d_v), kv_tile),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d_v), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((n * hkv, tkp, d), k.dtype),
-            jax.ShapeDtypeStruct((n * hkv, tkp, d), v.dtype),
+            jax.ShapeDtypeStruct((n * hkv, tkp, d_v), v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -707,7 +721,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
 
     return (dq[:, :tq].reshape(n, h, tq, d),
             dk[:, :tk].reshape(n, hkv, tk, d),
-            dv[:, :tk].reshape(n, hkv, tk, d))
+            dv[:, :tk].reshape(n, hkv, tk, d_v))
 
 
 def _dense_reference(q, k, v, causal: bool, scale: Optional[float],
@@ -777,6 +791,12 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
                     window: Optional[int] = None) -> jax.Array:
     """Exact attention over (N, heads, T, d) operands via the Pallas kernel.
 
+    ``q`` and ``k`` share their head size ``d``; ``v`` may have another, and
+    the output then has ``v``'s (latent attention: q/k heads of 192 = 128 +
+    a rotary 64, v heads of 128). ``scale`` is ``1/sqrt(d)`` of q's where
+    none is given. A head size need not be a multiple of the 128 lanes: the
+    tiles span the whole head, and nothing is padded in HBM.
+
     ``causal`` applies the lower-triangular mask (aligned at the end for
     rectangular Tq != Tk). ``lengths`` (int (N,)) masks a PADDED batch:
     sequence n attends only keys ``< lengths[n]`` — so ragged text batches
@@ -818,6 +838,10 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
         raise ValueError(
             f"flash_attention: {q.shape[1]} query heads cannot share "
             f"{k.shape[1]} key / {v.shape[1]} value heads")
-    bq, bk = _resolve_tiles(q, k, causal, window, block_q, block_k)
+    if q.shape[3] != k.shape[3]:
+        raise ValueError(
+            f"flash_attention: q heads of {q.shape[3]} against k heads of "
+            f"{k.shape[3]}; q and k share a head size, v may have its own")
+    bq, bk = _resolve_tiles(q, k, v, causal, window, block_q, block_k)
     return _flash_core(q, k, v, lengths, causal, scale, bq, bk,
                        interpret, bool(mask_q), window)

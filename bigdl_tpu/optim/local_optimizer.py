@@ -1250,7 +1250,9 @@ class Optimizer:
 
     def _loss_fn(self, params, state, x, t, rng):
         y, new_state = self.model.apply(params, state, x, training=True, rng=rng)
-        loss = self.criterion._apply(y, t)
+        loss, counted = self.criterion.counted(y, t)
+        if counted:  # the criterion's parts, into the model's counter slots
+            new_state = self.model.with_counters(new_state, counted)
         reg = self.model.regularization_loss_tree(params)
         aux = self.model.auxiliary_loss_tree(new_state)
         return loss + reg + aux, new_state

@@ -352,6 +352,30 @@ def check_flash(t: int, d: int, n: int = 2, h: int = 4) -> None:
     log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
 
 
+def check_flash_latent(t: int = 8192, d: int = 192, d_v: int = 128,
+                       n: int = 1, h: int = 8) -> None:
+    """The flash kernel at latent attention's head sizes (q and k 192, v and
+    the output 128: JoyAI-LLM-Flash's), full causal, against the dense path:
+    outputs and the gradients of q, k and v."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.attention import scaled_dot_product_attention as sdpa
+
+    q, k = (_normal(t + i, (n, h, t, d), jnp.bfloat16) for i in range(2))
+    v = _normal(t + 2, (n, h, t, d_v), jnp.bfloat16)
+    cot = _normal(t + 3, (n, h, t, d_v), jnp.float32)
+
+    def attend(impl):
+        return lambda q, k, v: sdpa(q, k, v, impl=impl, causal=True,
+                                    mask_q=True)
+
+    what = f"flash attention T={t} q/k {d} v {d_v} bf16 causal"
+    text, got = _fwd_bwd(attend("flash"), (q, k, v), cot)
+    n_calls = assert_mosaic(text, what)
+    _close(got, _fwd_bwd(attend("dense"), (q, k, v), cot)[1], BF16_TOL, what)
+    log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
+
+
 def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
     """A GroupedQueryAttention under nn.Remat against the same module bare:
     the same outputs and gradients, and 3 Mosaic calls in the program, not 4:
@@ -552,6 +576,7 @@ def check_transformer_step(t=2048, batch=8, vocab=8192, hidden=512, heads=8,
 def phase_kernels() -> None:
     check_flash(t=1024, d=64)
     check_flash(t=4096, d=128)
+    check_flash_latent()
     check_flash_remat(t=2048, d=128)
     check_ssd_scan()
     # a chunk of 192 is no multiple of 128: this one must fall back

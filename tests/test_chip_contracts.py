@@ -79,6 +79,7 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
         chip_smoke.phase_distri(
             depth=18, classes=10, image=32, batch_per_chip=2, iters=4)
         chip_smoke.check_flash(t=128, d=16, n=1, h=2)
+        chip_smoke.check_flash_latent(t=128, d=24, d_v=16, n=1, h=2)
         chip_smoke.check_fused(rows=24, hidden=128, conv_shape=(2, 4, 6, 6))
         chip_smoke.check_transformer_step(
             t=32, batch=2, vocab=64, hidden=32, heads=2, ffn=64, layers=1,

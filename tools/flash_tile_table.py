@@ -21,13 +21,16 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-# (batch, query heads, K/V heads, T, head size, window, per-sequence lengths)
+# (batch, query heads, K/V heads, T, head size, window, per-sequence lengths);
+# "d_v": v's and the output's head size where it is not q's and k's
 SHAPES = {
     "a": dict(n=2, h=32, hkv=4, t=8192, d=128, window=None, lengths=False),
     "b": dict(n=2, h=32, hkv=4, t=8192, d=128, window=1024, lengths=False),
     "c1": dict(n=2, h=4, hkv=4, t=1024, d=64, window=None, lengths=True),
     "c2": dict(n=2, h=4, hkv=4, t=4096, d=128, window=None, lengths=True),
     "d": dict(n=8, h=8, hkv=8, t=2048, d=64, window=None, lengths=False),
+    "e": dict(n=2, h=32, hkv=32, t=8192, d=192, d_v=128, window=None,
+              lengths=False),
 }
 TILES = [(bq, bk) for bq in (256, 512, 1024) for bk in (256, 512, 1024)]
 TARGET_S = 0.25  # a timed call repeats the kernels until it lasts about this
@@ -41,11 +44,13 @@ def _functions(shape, bq, bk):
     from bigdl_tpu.ops.flash_attention import flash_attention
 
     n, h, hkv, t, d = (shape[key] for key in ("n", "h", "hkv", "t", "d"))
+    d_v = shape.get("d_v", d)
     key = jax.random.PRNGKey(t + d)
     q, k, v, cot = (
-        jax.random.normal(jax.random.fold_in(key, i), (n, heads, t, d),
+        jax.random.normal(jax.random.fold_in(key, i), (n, heads, t, size),
                           jnp.bfloat16)
-        for i, heads in enumerate((h, hkv, hkv, h)))
+        for i, (heads, size) in enumerate(
+            ((h, d), (hkv, d), (hkv, d_v), (h, d_v))))
     lens = None
     if shape["lengths"]:  # chip_smoke.check_flash's draw
         lens = jnp.asarray(
@@ -57,7 +62,11 @@ def _functions(shape, bq, bk):
 
     def fwd(reps, q, k, v, cot):
         def body(_, q):
-            return q + 1e-6 * attend(q, k, v)
+            out = attend(q, k, v)
+            if d_v != d:  # the output back at q's head size: one pass more
+                out = jnp.pad(out, ((0, 0),) * 3 + ((0, d - d_v),)) \
+                    if d_v < d else out[..., :d]
+            return q + 1e-6 * out
         return jax.lax.fori_loop(0, reps, body, q)
 
     # the cotangent is an argument, not a constant of the executable
@@ -95,7 +104,8 @@ def main(argv) -> None:
     rows = []
     for name in (argv or list(SHAPES)):
         shape = SHAPES[name]
-        rule = pick_tiles(shape["t"], shape["t"], shape["d"], 2)
+        rule = pick_tiles(shape["t"], shape["t"], shape["d"], 2,
+                          shape.get("d_v"))
         for bq, bk in TILES + [(None, None)]:
             row = dict(shape=name, **shape, block_q=bq, block_k=bk,
                        rule=list(rule))
