@@ -32,6 +32,7 @@ expert (zero output — compose the layer residually, the switch convention).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -346,6 +347,26 @@ def _grouped_dot_bwd(res, g):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+# Rows the sorted buffer gets for each row an even router would send to the
+# experts held. Read on the chip before it was fixed (PERF.md section 6,
+# PR 35); a constant like the kernel's tile, not an argument.
+_SHARE_SLACK = 2
+
+
+def buffer_rows(pairs: int, n_held: int, n_experts: int) -> int:
+    """Rows of the buffer between the two permutations, from shapes alone:
+    ``_SHARE_SLACK`` times the held experts' even share of the ``pairs``
+    (token, choice) pairs, rounded up to the grouped kernel's row tile, and
+    never more than a row for every pair (all experts held, or a share of
+    half or more: then there is the one size and one pass)."""
+    share = -(-_SHARE_SLACK * pairs * n_held // n_experts)
+    return min(pairs, _round_up(share, _tiling(128, 128)[0]))
+
+
 def _local_rows(rows, pos, local):
     """``rows[pos]`` where the sorted row is one of the ``local`` rows the
     grouped products computed, zero elsewhere: a select, never a product, so
@@ -394,6 +415,153 @@ def _pairs_bwd(res, g):
 _pairs_of_rows.defvjp(_pairs_fwd, _pairs_bwd)
 
 
+def _gated(xs, w_gate, w_up, w_down, sizes):
+    """The held experts over sorted rows: ``W_down(silu(W_gate x) * W_up
+    x)``, each row by its group's matrices."""
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(grouped_dot(xs, w_gate, sizes)) \
+            * grouped_dot(xs, w_up, sizes)
+        return grouped_dot(h, w_down, sizes)
+
+
+# --- the sized buffer: blocks of C sorted rows, each token's rows ADDED into
+# its place (C rows moved; gathering in pair order writes T*k whatever C is,
+# and lost at every C below T*k on a v5e: PERF.md section 6, PR 35)
+
+def _live_rows(rows, local):
+    """``rows`` (C, D) of a block with those past its first ``local``
+    zeroed: a select, never a product, as in ``_local_rows``."""
+    live = jnp.arange(rows.shape[0], dtype=jnp.int32) < local
+    return jnp.where(live[:, None], rows, 0)
+
+
+@jax.custom_vjp
+def _block_of_tokens(x, tokens, local):
+    """``x[tokens]``: the token of each sorted pair of a block. The gradient
+    adds each of the block's ``local`` live rows into its token's place,
+    summed in float32."""
+    return x.at[tokens].get(mode="promise_in_bounds")
+
+
+def _block_fwd(x, tokens, local):
+    return _block_of_tokens(x, tokens, local), (tokens, local, x.shape[0])
+
+
+def _block_bwd(res, g):
+    tokens, local, t = res
+    dx = jnp.zeros((t, g.shape[1]), jnp.float32).at[tokens].add(
+        _live_rows(g, local).astype(jnp.float32), mode="promise_in_bounds")
+    return dx.astype(g.dtype), None, None
+
+
+_block_of_tokens.defvjp(_block_fwd, _block_bwd)
+
+
+@jax.custom_vjp
+def _block_of_weights(top_p, first, pos, local):
+    """The router's weight of each sorted pair ``first`` (C,) of a block;
+    the gradient goes back in pair order by a gather with ``pos``, the
+    pair's place in the sort less the block's start."""
+    return top_p.reshape(-1).at[first].get(mode="promise_in_bounds")
+
+
+def _weights_fwd(top_p, first, pos, local):
+    return _block_of_weights(top_p, first, pos, local), (pos, local, top_p.shape)
+
+
+def _weights_bwd(res, g):
+    pos, local, shape = res
+    mine = (pos >= 0) & (pos < local)
+    return jnp.where(mine, g.at[pos].get(mode="clip"), 0).reshape(shape), \
+        None, None, None
+
+
+_block_of_weights.defvjp(_weights_fwd, _weights_bwd)
+
+
+def _block_pass(acc, x, w_gate, w_up, w_down, top_p, first, pos, sizes, local):
+    """``acc`` (T, D) float32 plus the held experts' part of the layer from
+    one block of the sort: its pairs ``first`` (C,), their groups' ``sizes``
+    inside the block, its first ``local`` rows those of held experts; x
+    (T, D) in the compute dtype. The products and the gate run over C rows."""
+    with jax.named_scope("moe_route"):
+        tokens = first // top_p.shape[1]
+        xs = _block_of_tokens(x, tokens, local)
+    ys = _gated(xs, w_gate, w_up, w_down, sizes)                # (C, D)
+    with jax.named_scope("moe_route"):
+        # the select before the product: rows past ``local`` are unwritten
+        weighted = _live_rows(ys, local) \
+            * _block_of_weights(top_p, first, pos, local)[:, None]
+        return acc.at[tokens].add(weighted, mode="promise_in_bounds")
+
+
+def _blocks(c: int, order, pos, group_sizes, local):
+    """``block(i)``: (first, pos, sizes, local) of the i-th run of ``c``
+    sorted rows, as ``_block_pass`` takes them."""
+    pairs = order.shape[0]
+    padded = jnp.pad(order, (0, _round_up(pairs, c) - pairs))
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+
+    def block(i):
+        at = i * c
+        sizes = jnp.clip(ends - at, 0, c) - jnp.clip(starts - at, 0, c)
+        return (jax.lax.dynamic_slice(padded, (at,), (c,)), pos - at,
+                sizes.astype(jnp.int32), jnp.clip(local - at, 0, c))
+
+    return block
+
+
+def _while_blocks(c: int, local, turn, start):
+    """``turn(i, carried)`` for every block of ``c`` rows that holds one of
+    the ``local`` rows of held experts."""
+    return jax.lax.while_loop(
+        lambda carry: carry[0] * c < local,
+        lambda carry: (carry[0] + 1, turn(*carry)),
+        (jnp.int32(0), start))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _block_by_block(c: int, x, w_gate, w_up, w_down, top_p, order, pos,
+                    group_sizes, local):
+    """``_block_pass`` over the sorted rows in blocks of ``c``, as many as
+    hold the ``local`` pairs of held experts: one where they fit the buffer
+    (the sizing's case), more in a step whose routing overflows it, so that
+    no pair is dropped and one set of kernels at one shape serves both. The
+    trip count is the step's own, so the loop has its own differentiation
+    rule: the residuals are the inputs, each block's own stay inside its
+    turn, and the gradients are the blocks' sums. (A ``cond`` between this
+    size and the full one compiled both sets of kernels, and differentiated
+    as it stands would have written the full-size residuals every step.)"""
+    block = _blocks(c, order, pos, group_sizes, local)
+    floats = (x, w_gate, w_up, w_down, top_p)
+    return _while_blocks(
+        c, local, lambda i, acc: _block_pass(acc, *floats, *block(i)),
+        jnp.zeros((x.shape[0], w_down.shape[2]), jnp.float32))
+
+
+def _block_by_block_fwd(c, *args):
+    return _block_by_block(c, *args), args
+
+
+def _block_by_block_bwd(c, args, g):
+    *floats, order, pos, group_sizes, local = args
+    block = _blocks(c, order, pos, group_sizes, local)
+
+    def add_grads(i, total):
+        _, pull = jax.vjp(
+            lambda *floats: _block_pass(jnp.zeros_like(g), *floats, *block(i)),
+            *floats)
+        return _tm(jnp.add, total, pull(g))
+
+    total = _while_blocks(c, local, add_grads,
+                          _tm(jnp.zeros_like, tuple(floats)))
+    return (*total, None, None, None, None)
+
+
+_block_by_block.defvjp(_block_by_block_fwd, _block_by_block_bwd)
+
+
 def route_top_k(x, router_w, top_k: int):
     """Softmax router in float32 over ALL experts: x (T, D) -> the k largest
     probabilities (T, k), renormalised over the chosen, and their expert ids
@@ -439,13 +607,20 @@ def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int,
     Every (token, choice) pair is kept: the pairs are sorted by the slot of
     their expert among the experts held (pairs of absent experts last), the
     three grouped products run over the held experts' rows, and each token
-    sums its k rows weighted by the router. No capacity, no drop: the sorted
-    buffer has a row for every pair, and rows past the held groups cost
-    memory, not matrix work. ``route(x, router_w, top_k) -> (weights, ids)``
-    is the router (``route_top_k`` where none is given)."""
-    t, d = x.shape
+    sums its k rows weighted by the router. No capacity, no drop. The rows
+    between the two permutations live in a buffer of ``buffer_rows(T*k,
+    n_held, n_experts)`` rows. Where all experts (or half of them and more)
+    are held that is a row for every pair and the layer is one pass, each
+    pair gathering its row. Where fewer are, it is twice the held experts'
+    even share, so that the gathers, the gate and the sums cross the local
+    pairs' rows and not the absent experts'; a step whose local pairs
+    overflow it goes over the sort block by block (``_block_by_block``;
+    counter ``moe_overflow_layers``). ``route(x, router_w, top_k) ->
+    (weights, ids)`` is the router (``route_top_k`` where none is given)."""
     held = jnp.asarray(experts_held, jnp.int32)
     n_held = held.shape[0]
+    pairs = x.shape[0] * top_k
+    c = buffer_rows(pairs, n_held, n_experts)
     with jax.named_scope("moe_route"):
         top_p, top_e = (route or route_top_k)(x, params["router"], top_k)
         # slot among the held experts, n_held for an absent one
@@ -454,26 +629,34 @@ def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int,
         slot = slot_of[top_e].reshape(-1)                       # (T*k,)
         order = jnp.argsort(slot, stable=True).astype(jnp.int32)
         pos = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
+            jnp.arange(pairs, dtype=jnp.int32))
         group_sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(
             jnp.int32)
         local = jnp.sum(group_sizes)  # pairs that hit an expert held here
-        xs = _rows_of_tokens(x.astype(precision.compute_dtype()), order, pos, local,
-                             top_k)
-    with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(grouped_dot(xs, params["w_gate"], group_sizes)) \
-            * grouped_dot(xs, params["w_up"], group_sizes)
-        ys = grouped_dot(h, params["w_down"], group_sizes)      # (T*k, D)
+    xc = x.astype(precision.compute_dtype())
+    weights = params["w_gate"], params["w_up"], params["w_down"]
+    if c == pairs:  # a row for every pair: one pass, pairs gather their rows
+        with jax.named_scope("moe_route"):
+            xs = _rows_of_tokens(xc, order, pos, local, top_k)
+        ys = _gated(xs, *weights, group_sizes)                  # (T*k, D)
+        with jax.named_scope("moe_route"):
+            rows = _pairs_of_rows(ys, order, pos, local).reshape(
+                x.shape[0], top_k, -1)
+            out = jnp.sum(rows * top_p[..., None], axis=1)
+    else:
+        out = _block_by_block(c, xc, *weights, top_p, order, pos, group_sizes,
+                              local)
     with jax.named_scope("moe_route"):
-        pairs = _pairs_of_rows(ys, order, pos, local).reshape(t, top_k, d)
-        out = jnp.sum(pairs * top_p[..., None], axis=1)
+        blocks = -(-local // c)   # turns of the loop (one, where c == pairs)
         counters = {
             "moe_pairs_local": local.astype(jnp.float32),
             "moe_load_max_over_mean": jnp.max(group_sizes) / jnp.maximum(
                 jnp.mean(group_sizes.astype(jnp.float32)), 1.0),
-            # pairs of held experts that the sorted buffer had no row for
+            # pairs of held experts that the blocks taken had no row for
             "moe_dropped_pairs": jnp.maximum(
-                local - xs.shape[0], 0).astype(jnp.float32),
+                local - blocks * c, 0).astype(jnp.float32),
+            # 1 from a layer whose local pairs overflowed the sized buffer
+            "moe_overflow_layers": (blocks > 1).astype(jnp.float32),
         }
     return out.astype(x.dtype), counters, top_e
 
@@ -491,8 +674,13 @@ class RoutedExperts(AbstractModule):
     fewer than all, it is one chip's share of an expert-parallel layer run
     without its exchange: the router keeps its width, pairs routed to absent
     experts add nothing, and the shares of all chips sum to the whole layer
-    (``tests/test_decoder_lm.py``, the share test). Beside ``MoE`` (switch /
-    GShard with capacity buffers that drop) until ROADMAP D2 merges them.
+    (``tests/test_decoder_lm.py``, the share test). A share of less than
+    half keeps its sorted rows in a buffer sized from the share, not from
+    the pairs (``buffer_rows``: twice the held experts' even share), and a
+    step whose local pairs overflow it goes over the sort block by block:
+    nothing is dropped, ``moe_overflow_layers`` counts the layers that did
+    (``routed_experts``). Beside ``MoE`` (switch / GShard with capacity
+    buffers that drop) until ROADMAP D2 merges them.
 
     ``scoring="sigmoid"`` is DeepSeek-V3's router (``route_sigmoid_top_k``):
     sigmoid scores, weights normalised over the chosen and multiplied by
@@ -506,8 +694,8 @@ class RoutedExperts(AbstractModule):
     sum (scope ``moe_shared``); in the share test it counts once.
 
     State: ``{"_counters": {moe_pairs_local, moe_load_max_over_mean,
-    moe_dropped_pairs[, moe_bias_abs_max]}[, "selection_bias"]}``, see
-    ``AbstractModule.counters_tree``."""
+    moe_dropped_pairs, moe_overflow_layers[, moe_bias_abs_max]}[,
+    "selection_bias"]}``, see ``AbstractModule.counters_tree``."""
 
     def __init__(self, n_experts: int, ffn_size: int, top_k: int,
                  experts_held=None, init_std: float = 0.02,
@@ -550,7 +738,7 @@ class RoutedExperts(AbstractModule):
         zero = jnp.zeros((), jnp.float32)
         state = {"_counters": {
             "moe_pairs_local": zero, "moe_load_max_over_mean": zero,
-            "moe_dropped_pairs": zero}}
+            "moe_dropped_pairs": zero, "moe_overflow_layers": zero}}
         if self.shared_size:
             k_in, k_out = jax.random.split(jax.random.fold_in(rng, 4))
             params.update(

@@ -139,7 +139,12 @@ def spec_tree(args) -> Tuple:
     keys on committedness, so an SPMD step lowered against bare shape/dtype
     specs would be a DIFFERENT program than the one the driver dispatches
     with committed batches — the export-time twin compile and the serialized
-    module must both reproduce the dispatch-time program exactly."""
+    module must both reproduce the dispatch-time program exactly. WEAK TYPES
+    ride along for the same reason (a Python scalar argument such as the
+    step number): without them ``fn.lower(*specs)`` is another program to
+    JAX, traced and lowered afresh (11 s of a language model's launch on the
+    chip's host) and compiled or loaded from the cache a second time; with
+    them it is the dispatch's own lowering, executable included."""
 
     def spec(a):
         sharding = (
@@ -148,7 +153,8 @@ def spec_tree(args) -> Tuple:
             and getattr(a, "sharding", None) is not None
             else None
         )
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding,
+                                    weak_type=getattr(a, "weak_type", False))
 
     return jax.tree_util.tree_map(spec, args)
 

@@ -452,6 +452,71 @@ def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
         f"({record['chunks']} chunks, {how})")
 
 
+def check_routed_experts(tokens=16384, hidden=2048, ffn=768, n_experts=256,
+                         n_held=16, top_k=8) -> None:
+    """One chip's share of a routed-experts layer (nn.RoutedExperts at
+    JoyAI-LLM-Flash's sizes: 16 of 256 experts held, 8 a token) against the
+    same layer computed densely, every held expert over every token with a
+    mask: outputs and the gradients of the input, the router and the three
+    expert tensors. Twice: with a router that spreads the tokens, so the
+    local pairs fit the sized buffer (``moe.buffer_rows``: one block), and
+    with every token's first choice a held expert, so they overflow it and
+    the layer goes over the sort block by block. The path taken is logged."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.utils import precision
+
+    layer = nn.RoutedExperts(n_experts, ffn, top_k,
+                             experts_held=tuple(range(n_held)))
+    x = _normal(tokens, (tokens, hidden), jnp.float32)
+    layer.build(jax.random.PRNGKey(tokens), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    spread = layer.get_parameters()
+    spread = {**spread, **{k: 4.0 * spread[k]       # outputs of order one
+                           for k in ("w_gate", "w_up", "w_down")}}
+    # the held expert 0 ahead of every other for every token
+    crowded = {**spread, "router": spread["router"].at[:, 0].set(
+        jnp.sign(x[0]) * 0.02)}
+    crowded_x = jnp.abs(x) * jnp.sign(x[0])
+    held = jnp.arange(n_held)
+    rows = moe.buffer_rows(tokens * top_k, n_held, n_experts)
+    if rows > tokens:   # the crowded router sends a pair a token to expert 0
+        raise ValueError(f"a share of {n_held}/{n_experts} has no sized buffer "
+                         f"to overflow: {rows} rows for {tokens} tokens")
+    cot = _normal(tokens + 1, x.shape, jnp.float32)
+
+    def dense(params, x):
+        top_p, top_e = moe.route_top_k(x, params["router"], top_k)
+
+        def one_expert(out, expert):
+            e, w_gate, w_up, w_down = expert
+            w = jnp.sum(jnp.where(top_e == e, top_p, 0.0), axis=-1)
+            h = jax.nn.silu(precision.dot_acc32(x, w_gate)) \
+                * precision.dot_acc32(x, w_up)
+            return out + w[:, None] * precision.dot_acc32(h, w_down), None
+
+        return jax.lax.scan(one_expert, jnp.zeros_like(x), (
+            held, params["w_gate"], params["w_up"], params["w_down"]))[0]
+
+    for name, params, x in (("spread", spread, x), ("crowded", crowded, crowded_x)):
+        what = (f"routed experts {name} T={tokens} {n_held}/{n_experts} held "
+                f"top-{top_k}, buffer {rows} of {tokens * top_k} rows")
+        counters = jax.jit(lambda p, x: layer.apply(p, layer.get_state(), x)[1][
+            "_counters"])(params, x)
+        local, over, dropped = (int(counters[k]) for k in (
+            "moe_pairs_local", "moe_overflow_layers", "moe_dropped_pairs"))
+        if dropped or over != (local > rows) or over != (name == "crowded"):
+            raise AssertionError(f"{what}: {local} local pairs, overflow "
+                                 f"{over}, dropped {dropped}")
+        _, got = _fwd_bwd(lambda p, x: layer.apply(p, layer.get_state(), x)[0],
+                          (params, x), cot)
+        _close(got, _fwd_bwd(dense, (params, x), cot)[1], BF16_TOL, what)
+        log(f"  {what}: {local} local pairs in {-(-local // rows)} block(s), "
+            f"fwd + 5 gradients match the dense layer")
+
+
 def check_fused(rows: int, hidden: int, conv_shape) -> None:
     """Engine.set_fused_kernels(True) against the unfused path of the same
     public call sites: nn.LayerNormalization (whose unfused chain is
@@ -581,6 +646,7 @@ def phase_kernels() -> None:
     check_ssd_scan()
     # a chunk of 192 is no multiple of 128: this one must fall back
     check_ssd_scan(t=1536, heads=8, chunk=192, kernel=False)
+    check_routed_experts()
     # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
     # res2 conv epilogue (b128: 128x256x56x56)
     check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))

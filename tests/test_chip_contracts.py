@@ -73,6 +73,8 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
         chip_smoke.check_flash_remat(t=128, d=16, n=1, heads=2)
         chip_smoke.check_ssd_scan(t=48, heads=4, head_dim=8, state=16,
                                   chunk=16, n=2)
+        chip_smoke.check_routed_experts(tokens=1024, hidden=32, ffn=16,
+                                        n_experts=16, n_held=2, top_k=2)
         model, rec = chip_smoke.phase_train(
             depth=18, classes=10, image=32, batch=4, iters=6)
         assert rec["compile_s"] > 0

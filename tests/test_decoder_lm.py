@@ -16,7 +16,9 @@ import pytest
 from bigdl_tpu import nn
 from bigdl_tpu.models import decoder_lm, decoder_lm_reference as ref
 from bigdl_tpu.nn.attention import apply_rotary, scaled_dot_product_attention
+from bigdl_tpu.nn import moe
 from bigdl_tpu.nn.decoder import rope_inv_freq
+from bigdl_tpu.nn.moe import buffer_rows
 from bigdl_tpu.ops.flash_attention import (
     _VMEM_BUDGET, _dense_reference, _tile_geometry, _window_count,
     _working_set, flash_attention, pick_tiles, take_tile_records)
@@ -327,9 +329,14 @@ def test_routing_never_drops_under_a_router_biased_onto_one_expert():
     np.testing.assert_allclose(out.reshape(-1, 64), want, atol=1e-6)
 
 
-def test_the_four_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("n, t", [(N, T), (8, 256)], ids=["one_size", "compact"])
+def test_the_four_shares_add_up_to_the_whole_layer(n, t):
     """Experts 0-1, 2-3, 4-5, 6-7 of 8 on four chips: the partial results add
-    up to what the uncut reference gives for the whole layer."""
+    up to what the uncut reference gives for the whole layer. At 64 tokens
+    the buffer has a row for every pair; at 2048 a share's buffer is half
+    of them (2048 of 4096 rows) and its ~1024 local pairs fit."""
+    N, T = n, t
+    assert (buffer_rows(N * T * 2, 2, 8) < N * T * 2) == (n * t > 64)
     whole = _experts(tuple(range(8)))
     params = whole.get_parameters()
     x = jax.random.normal(jax.random.PRNGKey(7), (N, T, 64))
@@ -348,8 +355,112 @@ def test_the_four_shares_add_up_to_the_whole_layer():
         total = total + out
         pairs += float(state["_counters"]["moe_pairs_local"])
         assert float(state["_counters"]["moe_dropped_pairs"]) == 0.0
+        assert float(state["_counters"]["moe_overflow_layers"]) == 0.0
     np.testing.assert_allclose(total.reshape(-1, 64), want, atol=1e-6)
     assert pairs == N * T * 2  # every (token, choice) pair on exactly one chip
+
+
+@pytest.mark.parametrize("pairs, n_held, n_experts, rows", [
+    (2 * 8192 * 8, 16, 256, 16384),    # JoyAI-LLM-Flash's cell: an eighth
+    (2 * 8192 * 8, 16, 64, 65536),     # Mellum2's cell: a half
+    (2 * 8192 * 8, 64, 64, 131072),    # all held: a row for every pair
+    (2 * 8192 * 8, 32, 64, 131072),    # a share of a half: the same
+    (2 * 8192 * 8, 33, 64, 131072),
+    (4096, 1, 8, 1024),                # twice the share, a whole tile of 512
+    (3000, 1, 8, 1024),                # 750 rounds up to two tiles
+    (3000, 3, 8, 2560),                # 2250 rounds up to five
+    (3000, 1, 100, 512),               # 60: one tile
+    (128, 2, 8, 128),                  # never more than the pairs
+])
+def test_the_buffers_rows_follow_from_the_shapes(pairs, n_held, n_experts, rows):
+    assert buffer_rows(pairs, n_held, n_experts) == rows
+    assert rows == pairs or rows % 512 == 0
+
+
+def _share_layer(n_held, crowded=False, seed=3):
+    """A 16-expert layer's share of ``n_held`` over 1024 tokens (2048 (token,
+    choice) pairs), its parameters and a batch; ``crowded``: every token's
+    first choice is the held expert 0, so 1024 pairs at least are local."""
+    m = nn.RoutedExperts(16, 32, 2, experts_held=tuple(range(n_held)),
+                         init_std=0.3)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(seed), (1024, 64))) + 0.1
+    m.build(jax.random.PRNGKey(seed + 1), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    params = m.get_parameters()
+    if crowded:
+        params["router"] = params["router"].at[:, 0].set(1.0)
+    return m, params, x
+
+
+def _out_and_grads(m, params, x, wrap=lambda m: m):
+    """The layer's output, its counters, and the gradients of a seeded
+    projection of the output by the four parameter tensors and the input."""
+    cot = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+    layer = wrap(m)
+    boxed = layer is not m
+
+    def f(params, x):
+        out, state = layer.apply({m.name(): params} if boxed else params,
+                                 {m.name(): m.get_state()} if boxed
+                                 else m.get_state(), x)
+        return jnp.sum(out * cot), (out, state[m.name()] if boxed else state)
+
+    (_, (out, state)), grads = jax.value_and_grad(
+        f, argnums=(0, 1), has_aux=True)(params, x)
+    return out, state["_counters"], grads
+
+
+# 1 and 2 of 16 held: a buffer of 512 of the 2048 rows; 4 of 16: 1024 rows.
+# Crowded, the local pairs take two to four blocks of the buffer
+@pytest.mark.parametrize("wrap", [lambda m: m, nn.Remat], ids=["bare", "remat"])
+@pytest.mark.parametrize("crowded", [False, True], ids=["fits", "overflows"])
+@pytest.mark.parametrize("n_held", [1, 2, 4])
+def test_the_sized_buffer_gives_what_a_row_for_every_pair_gives(
+        n_held, crowded, wrap, monkeypatch):
+    m, params, x = _share_layer(n_held, crowded)
+    c = buffer_rows(2048, n_held, 16)
+    assert c < 2048
+    out, counters, grads = _out_and_grads(m, params, x, wrap)
+    local = float(counters["moe_pairs_local"])
+    assert (1024 <= local <= 2048 and local > c) if crowded else 0 < local <= c
+    assert float(counters["moe_overflow_layers"]) == float(crowded)
+    assert float(counters["moe_dropped_pairs"]) == 0.0
+    # with a slack of 16 these buffers have a row for every pair: the
+    # one-size computation the layer was before its buffer shrank
+    monkeypatch.setattr(moe, "_SHARE_SLACK", 16)
+    want_out, want_counters, want_grads = _out_and_grads(m, params, x)
+    assert float(want_counters["moe_overflow_layers"]) == 0.0
+    assert float(want_counters["moe_pairs_local"]) == local
+    # the same products; a token's rows (and, block by block, an expert's)
+    # are summed in another order: float32 reassociation
+    leaves = jax.tree_util.tree_leaves_with_path((out, grads))
+    assert len(leaves) == 6   # output; router, the three expert tensors; input
+    for (path, got), want in zip(
+            leaves, jax.tree_util.tree_leaves((want_out, want_grads))):
+        scale = float(jnp.max(jnp.abs(want)))
+        assert scale > 0, path
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * scale,
+                                   err_msg=str(path))
+
+
+def _primitives(jaxpr, found=None):
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("n_held, looped", [(16, False), (8, False), (2, True)])
+def test_a_layer_that_holds_half_or_more_has_one_pass(n_held, looped):
+    """No loop over blocks, no conditional and no rule of its own in the
+    program of a layer whose buffer has a row for every pair."""
+    m, params, x = _share_layer(n_held)
+    for f in (lambda p, x: m.apply(p, m.get_state(), x)[0],
+              jax.grad(lambda p, x: jnp.sum(m.apply(p, m.get_state(), x)[0]))):
+        found = _primitives(jax.make_jaxpr(f)(params, x).jaxpr)
+        assert ("while" in found) == looped
+        assert "cond" not in found
 
 
 def test_experts_held_must_be_distinct_ids():

@@ -160,9 +160,12 @@ def test_counters_and_the_bias_after_the_step_match_the_reference(both):
     got = {k: float(v) for k, v in
            both["model"].counters_tree(both["new_state"]).items()}
     want = ref.routing_counters(both["stats"], both["rcfg"])
-    assert set(got) == set(want) == {
+    assert set(want) == {
         "moe_pairs_local", "moe_load_max_over_mean", "moe_dropped_pairs",
         "moe_bias_abs_max", "mtp_loss"}
+    # and one of the program's own, about its buffer: the reference has none
+    assert set(got) == set(want) | {"moe_overflow_layers"}
+    assert got["moe_overflow_layers"] == 0.0
     assert got["moe_pairs_local"] == want["moe_pairs_local"] > 0
     assert got["moe_load_max_over_mean"] == pytest.approx(
         want["moe_load_max_over_mean"], rel=1e-6)
@@ -302,6 +305,49 @@ def test_the_three_kernels_compile_at_the_cells_shapes_for_a_v5e(one_chip):
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_the_sized_buffers_loop_compiles_at_the_cells_shapes_for_a_v5e(
+        one_chip, monkeypatch):
+    """The routed layer of the cell (16,384 tokens of 2048, 16 of 256 experts
+    of 768 held, 8 a token) forward and backward for the described chip: the
+    grouped kernels at the buffer's 16,384 rows inside the two block loops
+    (12 calls: 3 forward, 3 + 6 backward, one set at one shape), no
+    conditional, and no temporary of the 131,072 pairs' size."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.utils.engine import Engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # megablox
+    monkeypatch.setattr(Engine._state, "compute_dtype", "bfloat16")
+    t, d, f, e, held, k = 16384, 2048, 768, 256, 16, 8
+    assert moe.buffer_rows(t * k, held, e) == 16384
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.float32, sharding=one_chip)
+    params = {"router": shape(d, e), "w_gate": shape(held, d, f),
+              "w_up": shape(held, d, f), "w_down": shape(held, f, d)}
+
+    def fwd_bwd(params, x, g):
+        out, vjp = jax.vjp(lambda p, x: moe.routed_experts(
+            x, p, n_experts=e, experts_held=tuple(range(held)), top_k=k)[0],
+            params, x)
+        return out, vjp(g)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fwd_bwd).lower(
+            params, shape(t, d), shape(t, d)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 12
+    assert " while(" in text and " conditional(" not in text
+    # a row for every pair held 2 float32 buffers of 131072 x 2048 at least
+    assert compiled.memory_analysis().temp_size_in_bytes < 131072 * 2048 * 4
+
+
 # ------------------------------------------------------------- latent attention
 
 def test_interleaved_rotary_by_hand():
@@ -418,7 +464,8 @@ def test_softmax_router_is_what_it_was():
     layer.build(jax.random.PRNGKey(2), jax.ShapeDtypeStruct((5, 8), jnp.float32))
     assert sorted(layer.get_parameters()) == ["router", "w_down", "w_gate", "w_up"]
     assert sorted(layer.get_state()["_counters"]) == [
-        "moe_dropped_pairs", "moe_load_max_over_mean", "moe_pairs_local"]
+        "moe_dropped_pairs", "moe_load_max_over_mean", "moe_overflow_layers",
+        "moe_pairs_local"]
     with pytest.raises(ValueError, match="sigmoid"):
         nn.RoutedExperts(6, 4, 2, routed_scaling=2.5)
     with pytest.raises(ValueError, match="one of"):
@@ -669,6 +716,7 @@ def test_language_model_trains_through_optimize_with_counters_in_the_record():
         [r["loss"] for r in steps[:4]])
     for i, r in enumerate(steps):
         assert r["moe_dropped_pairs"] == 0.0
+        assert r["moe_overflow_layers"] == 0.0   # reaches the step record
         assert 0 < r["moe_pairs_local"] <= 3 * N * T * 2
         assert r["moe_load_max_over_mean"] >= 1.0
         assert 0.0 < r["mtp_loss"] < 6.0

@@ -169,6 +169,38 @@ class TestLivePerfStreams:
         assert tel.compile_count == 1  # the canary holds with perf on
         return perfs
 
+    def test_the_cost_models_lowering_is_the_steps_own(self):
+        """``program_cost`` lowers the step from the specs captured at its
+        first dispatch. They carry the arguments' weak types, so that
+        lowering is the dispatch's own: no second trace, no second MLIR
+        module and, where the compiled cost model is asked (a TPU has no
+        pre-compile one), no second load of the executable: on a language
+        model those were 5 and 3 - 10 s of every launch (PERF.md, PR 35)."""
+        traced = []
+
+        class Counting(nn.ClassNLLCriterion):
+            def counted(self, y, t):
+                traced.append("counted")
+                return super().counted(y, t)
+
+            def unreduced(self, y, t):
+                traced.append("unreduced")
+                return super().unreduced(y, t)
+
+        RandomGenerator.set_seed(7)
+        x, y = _problem()
+        tel = Telemetry()
+        opt = LocalOptimizer(_model(), _ds(x, y), Counting())
+        opt.set_optim_method(SGD(learningrate=0.2))
+        opt.set_end_when(Trigger.max_epoch(1))
+        opt.set_telemetry(tel)
+        opt.optimize()
+        assert all(s.get("model_flops") for s in tel.ring.steps())  # it ran
+        assert len(traced) == 1, traced
+        step, specs = opt._step_export_info
+        lowered = step.lower(*specs)
+        assert len(traced) == 1, traced
+
     def test_local_optimizer(self):
         tel = Telemetry()
         _fit_local(tel, _perf_cfg())
