@@ -28,7 +28,7 @@ from ..nn.detection import (
     multilevel_roi_align,
     nms,
 )
-from ..nn.module import Container
+from ..nn.module import Container, run_child
 
 
 def _conv_backbone(channels: Sequence[int]):
@@ -155,16 +155,14 @@ class MaskRCNN(Container):
         y = x
         for i in range(self.n_backbone):
             m = self.modules[i]
-            y, new_state[m.name()] = m._apply(
-                params[m.name()], state[m.name()], y, training, rng)
+            y = self._child_apply(m, y, training, rng, params, state, new_state)
             feats.append(y)
         fpn = self.modules[self.n_backbone]
-        fpn_feats, new_state[fpn.name()] = fpn._apply(
-            params[fpn.name()], state[fpn.name()], feats, training, rng)
+        fpn_feats = self._child_apply(
+            fpn, feats, training, rng, params, state, new_state)
         rpn = self.modules[self.n_backbone + 1]
-        proposals, new_state[rpn.name()] = rpn._apply(
-            params[rpn.name()], state[rpn.name()], fpn_feats[0], training,
-            rng)  # (N, P, 4)
+        proposals = self._child_apply(
+            rpn, fpn_feats[0], training, rng, params, state, new_state)  # (N, P, 4)
         box_head = self.modules[self.n_backbone + 2]
         mask_head = self.modules[self.n_backbone + 3]
         img_h = x.shape[2]
@@ -175,9 +173,9 @@ class MaskRCNN(Container):
             # multi-level RoiAlign for the box head (compute-all-select-one
             # as in nn.detection.Pooler, inlined to reuse `levels`)
             pooled = self._pool(levels, props, self.box_pool)
-            (scores, deltas), _ = box_head._apply(
-                params[box_head.name()], state[box_head.name()], pooled,
-                training, rng,
+            (scores, deltas), _ = run_child(
+                box_head, params[box_head.name()], state[box_head.name()],
+                pooled, training, rng,
             )
             probs = jax.nn.softmax(scores, axis=-1)  # (P, C); class 0 = bg
             best_cls = jnp.argmax(probs[:, 1:], axis=1) + 1  # (P,)
@@ -199,9 +197,9 @@ class MaskRCNN(Container):
             det_scores = best_score[sel] * valid
             det_labels = (best_cls[sel] * valid).astype(jnp.int32)
             mask_in = self._pool(levels, det_boxes, self.mask_pool)
-            masks, _ = mask_head._apply(
-                params[mask_head.name()], state[mask_head.name()], mask_in,
-                training, rng,
+            masks, _ = run_child(
+                mask_head, params[mask_head.name()], state[mask_head.name()],
+                mask_in, training, rng,
             )
             return det_boxes, det_scores, det_labels, masks
 

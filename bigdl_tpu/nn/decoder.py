@@ -64,7 +64,7 @@ from ..utils import precision
 from .attention import apply_rotary, scaled_dot_product_attention
 from .embedding import LookupTable
 from .initialization import RandomNormal
-from .module import AbstractModule, Container
+from .module import AbstractModule, Container, run_child
 from .moe import RoutedExperts
 from .normalization import RMSNorm
 from .remat import Remat
@@ -567,16 +567,15 @@ class DecoderLM(Container):
             m for m in self.modules if m is not self.mtp]
 
         def embedded(tokens):
-            h, new_state[embed.name()] = embed._apply(
-                params[embed.name()], state[embed.name()], tokens, training,
-                rng)
+            h = self._child_apply(
+                embed, tokens, training, rng, params, state, new_state)
             return (h if self.embedding_multiplier == 1.0
                     else h * self.embedding_multiplier)
 
         def logits_of(h):
             # tied: the one leaf's second use, its gradient the sum of both
-            logits, new_state[head.name()] = head._apply(
-                {"weight": params[embed.name()]["weight"].T}
+            logits, new_state[head.name()] = run_child(
+                head, {"weight": params[embed.name()]["weight"].T}
                 if self.tie_embeddings else params[head.name()],
                 state[head.name()], h, training, rng)
             return logits

@@ -318,8 +318,7 @@ class FPN(Container):
         lat = []
         for i, x in enumerate(xs):
             m = self.modules[i]
-            y, s = m._apply(params[m.name()], state[m.name()], x, training, rng)
-            new_state[m.name()] = s
+            y = self._child_apply(m, x, training, rng, params, state, new_state)
             lat.append(y)
         # top-down pathway, coarsest first; ceil-repeat then crop handles
         # odd pyramid sizes (e.g. 25 over 13 from ceil-mode strides)
@@ -335,8 +334,7 @@ class FPN(Container):
         outs = []
         for i, y in enumerate(merged):
             m = self.modules[self.n_levels + i]
-            o, s = m._apply(params[m.name()], state[m.name()], y, training, rng)
-            new_state[m.name()] = s
+            o = self._child_apply(m, y, training, rng, params, state, new_state)
             outs.append(o)
         return outs, new_state
 
@@ -380,13 +378,10 @@ class RegionProposal(Container):
     def _apply(self, params, state, x, training, rng):
         conv, cls_head, box_head = self.modules
         new_state = dict(state)
-        t, new_state[conv.name()] = conv._apply(
-            params[conv.name()], state[conv.name()], x, training, rng)
+        t = self._child_apply(conv, x, training, rng, params, state, new_state)
         t = jnp.maximum(t, 0.0)
-        logits, new_state[cls_head.name()] = cls_head._apply(
-            params[cls_head.name()], state[cls_head.name()], t, training, rng)
-        deltas, new_state[box_head.name()] = box_head._apply(
-            params[box_head.name()], state[box_head.name()], t, training, rng)
+        logits = self._child_apply(cls_head, t, training, rng, params, state, new_state)
+        deltas = self._child_apply(box_head, t, training, rng, params, state, new_state)
         n, a, hf, wf = logits.shape
         anchors = self.anchor.generate(hf, wf, self.stride)  # (H*W*A, 4)
         img_h, img_w = hf * self.stride, wf * self.stride
@@ -439,16 +434,12 @@ class BoxHead(Container):
         f1, f2, cls, box = self.modules
         new_state = dict(state)
         y = x.reshape(x.shape[0], -1)
-        y, new_state[f1.name()] = f1._apply(
-            params[f1.name()], state[f1.name()], y, training, rng)
+        y = self._child_apply(f1, y, training, rng, params, state, new_state)
         y = jnp.maximum(y, 0.0)
-        y, new_state[f2.name()] = f2._apply(
-            params[f2.name()], state[f2.name()], y, training, rng)
+        y = self._child_apply(f2, y, training, rng, params, state, new_state)
         y = jnp.maximum(y, 0.0)
-        scores, new_state[cls.name()] = cls._apply(
-            params[cls.name()], state[cls.name()], y, training, rng)
-        deltas, new_state[box.name()] = box._apply(
-            params[box.name()], state[box.name()], y, training, rng)
+        scores = self._child_apply(cls, y, training, rng, params, state, new_state)
+        deltas = self._child_apply(box, y, training, rng, params, state, new_state)
         return (scores, deltas), new_state
 
 
@@ -483,8 +474,7 @@ class MaskHead(Container):
         y = x
         new_state = dict(state)
         for i, m in enumerate(self.modules):
-            y, new_state[m.name()] = m._apply(
-                params[m.name()], state[m.name()], y, training, rng)
+            y = self._child_apply(m, y, training, rng, params, state, new_state)
             if i <= self.n_convs:  # relu after convs + deconv, not the predictor
                 y = jnp.maximum(y, 0.0)
         return y, new_state

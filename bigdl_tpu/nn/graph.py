@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 
 from ..utils.table import T, Table
-from .module import AbstractModule, Container, Identity
+from .module import AbstractModule, Container, Identity, run_child
 
 _node_ids = itertools.count(1)
 
@@ -252,8 +252,8 @@ class Graph(Container):
             if node.id in values:
                 continue
             m = node.module
-            y, s = m._apply(
-                params[m.name()], state[m.name()], self._gather(node, values), training, rng
+            y, s = run_child(
+                m, params[m.name()], state[m.name()], self._gather(node, values), training, rng
             )
             new_state[m.name()] = s
             values[node.id] = y

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from ..utils import precision
 from .initialization import InitializationMethod, RandomUniform, Zeros
-from .module import AbstractModule, Container
+from .module import AbstractModule, Container, run_child
 
 
 class Linear(AbstractModule):
@@ -153,7 +153,7 @@ class Maxout(Container):
 
     def _apply(self, params, state, x, training, rng):
         lin = self.modules[0]
-        y, s = lin._apply(params[lin.name()], state[lin.name()], x, training, rng)
+        y, s = run_child(lin, params[lin.name()], state[lin.name()], x, training, rng)
         y = y.reshape(*y.shape[:-1], self.maxout_number, self.output_size)
         return jnp.max(y, axis=-2), {lin.name(): s}
 
@@ -200,9 +200,9 @@ class Highway(Container):
 
     def _apply(self, params, state, x, training, rng):
         hm, tm = self.modules
-        h, hs = hm._apply(params[hm.name()], state[hm.name()], x, training, rng)
+        h, hs = run_child(hm, params[hm.name()], state[hm.name()], x, training, rng)
         if self.activation is not None:
             h = self.activation(h)
-        t, ts = tm._apply(params[tm.name()], state[tm.name()], x, training, rng)
+        t, ts = run_child(tm, params[tm.name()], state[tm.name()], x, training, rng)
         gate = 1.0 / (1.0 + jnp.exp(-t))
         return gate * h + (1.0 - gate) * x, {hm.name(): hs, tm.name(): ts}

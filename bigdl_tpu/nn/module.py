@@ -60,6 +60,27 @@ def _as_jnp(x):
     return jax.tree_util.tree_map(jnp.asarray, x)
 
 
+def child_scope(m: "AbstractModule"):
+    """The name a child's ops carry in the traced program: its key in the
+    parameter tree, so a profile reads as a checkpoint does."""
+    return jax.named_scope(m.name())
+
+
+def run_child(m: "AbstractModule", params, state, x, training, rng):
+    """The ONE seam at which a container runs a child:
+    ``m._apply(params, state, x, training, rng)`` inside
+    ``jax.named_scope(m.name())``. ``params`` and ``state`` are the child's
+    own subtrees. Every container (``Container._child_apply``, ``Graph``'s
+    node loop, the containers that call a child directly) comes through here,
+    so every device op of a container-built model carries its module path
+    (``.../res2a_b1/res2a_b1_conv/conv_general_dilated``; JAX marks the
+    backward's copy ``transpose(jvp(...))`` itself). The scope is metadata on
+    the traced program, not an instruction: always on, no switch. Leaf
+    modules are not touched."""
+    with child_scope(m):
+        return m._apply(params, state, x, training, rng)
+
+
 # --- ctor/build recording for topology serialization (utils/module_serializer) ---
 # The reference's ModuleSerializer reconstructs each layer reflectively from its
 # serialized fields ($DL/utils/serializer, SURVEY.md §2.7); here every subclass
@@ -749,7 +770,7 @@ class Container(AbstractModule):
         return total
 
     def _child_apply(self, m: AbstractModule, x, training, rng, params, state, new_state):
-        y, s = m._apply(params[m.name()], state[m.name()], x, training, rng)
+        y, s = run_child(m, params[m.name()], state[m.name()], x, training, rng)
         new_state[m.name()] = s
         return y
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .module import AbstractModule
+from .module import AbstractModule, run_child
 
 _tm = jax.tree_util.tree_map
 
@@ -154,8 +154,8 @@ class PipelinedBlocks(AbstractModule):
         stacked = params["stages"]
 
         def stage_fn(p_one, h):
-            y, _ = self.stage._apply(p_one, self._stage_state, h, training,
-                                     rng)
+            y, _ = run_child(self.stage, p_one, self._stage_state, h,
+                             training, rng)
             return y
 
         if self.remat_stages:

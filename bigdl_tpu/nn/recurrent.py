@@ -23,7 +23,7 @@ from jax import lax
 from ..utils import precision
 from ..utils.table import T, Table
 from .initialization import InitializationMethod, RandomUniform
-from .module import AbstractModule, Container
+from .module import AbstractModule, Container, child_scope
 
 
 class Cell(AbstractModule):
@@ -343,7 +343,8 @@ class Recurrent(Container):
         carry0 = cell.init_carry(x.shape[0])
 
         def body(carry, x_t):
-            new_carry, y = cell.step(cell_params, carry, x_t)
+            with child_scope(cell):
+                new_carry, y = cell.step(cell_params, carry, x_t)
             return new_carry, y
 
         xs = jnp.swapaxes(x, 0, 1)  # (T, N, D) for scan
@@ -449,7 +450,8 @@ class RecurrentDecoder(Container):
 
         def body(carry_and_x, _):
             carry, x_t = carry_and_x
-            new_carry, y = cell.step(cell_params, carry, x_t)
+            with child_scope(cell):
+                new_carry, y = cell.step(cell_params, carry, x_t)
             return (new_carry, y), y
 
         _, ys = lax.scan(body, (carry0, x), None, length=self.seq_length)

@@ -37,7 +37,7 @@ from typing import Optional
 import jax
 
 from ..utils.remat_keep import KEPT_NAMES, keeping_block
-from .module import Container, AbstractModule
+from .module import Container, AbstractModule, run_child
 
 # zero-argument policies only: the other jax.checkpoint_policies attributes
 # are combinators/factories (save_only_these_names, save_from_both_policies,
@@ -102,7 +102,7 @@ class Remat(Container):
             policy = getattr(jax.checkpoint_policies, self.policy)
             recording = contextlib.nullcontext()
         inner = jax.checkpoint(
-            lambda p, s, xx, r: child._apply(p, s, xx, training, r),
+            lambda p, s, xx, r: run_child(child, p, s, xx, training, r),
             policy=policy)
         with recording:
             y, ns = inner(params[child.name()], state[child.name()], x, rng)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.table import T, Table
-from .module import AbstractModule, Container
+from .module import AbstractModule, Container, run_child
 
 
 def _as_list(x) -> List[Any]:
@@ -175,7 +175,7 @@ class MapTable(Container):
         s = state[m.name()]
         ys = []
         for xi in xs:
-            y, s = m._apply(params[m.name()], s, xi, training, rng)
+            y, s = run_child(m, params[m.name()], s, xi, training, rng)
             ys.append(y)
         return T(*ys), {m.name(): s}
 

@@ -46,6 +46,23 @@ from .validation import ValidationMethod, ValidationResult
 
 log = logging.getLogger("bigdl_tpu.optim")
 
+# Revision of the names a train step's ops carry: the module paths of
+# ``nn.module.run_child`` and the step parts of the step builders
+# (``model_apply``, ``criterion``, ``optim_update``, ``param_views``,
+# ``grad_exchange``, ``param_gather``, ``state_sync``). The persistent compile
+# cache keys a program WITHOUT its name stacks (jax strips locations from the
+# key), so a hit serves the executable, and with it the op names a profile
+# shows, of whichever compile wrote the entry. The revision is part of the
+# step's program name, which IS in the key: bump it when the names change,
+# or a profile of a cached step reads as the older program.
+STEP_SCOPES_REV = "s1"
+
+
+def step_program_name(fn):
+    """``fn`` named ``<name>_<STEP_SCOPES_REV>`` for ``jax.jit``."""
+    fn.__name__ = f"{fn.__name__}_{STEP_SCOPES_REV}"
+    return fn
+
 
 def _to_device_tree(x):
     """asarray over a pytree (features may be a Table holding SparseTensors)."""
@@ -1249,13 +1266,21 @@ class Optimizer:
         return grads
 
     def _loss_fn(self, params, state, x, t, rng):
-        y, new_state = self.model.apply(params, state, x, training=True, rng=rng)
-        loss, counted = self.criterion.counted(y, t)
-        if counted:  # the criterion's parts, into the model's counter slots
-            new_state = self.model.with_counters(new_state, counted)
-        reg = self.model.regularization_loss_tree(params)
-        aux = self.model.auxiliary_loss_tree(new_state)
-        return loss + reg + aux, new_state
+        """The one loss every step builder differentiates. Its two parts
+        carry step-part scopes (``model_apply``, ``criterion``: the loss, the
+        regulariser and the auxiliary terms), so a device op of the step is
+        owned by a part, forward under the bare name and backward under
+        ``transpose(jvp(...))``, which JAX writes itself."""
+        with jax.named_scope("model_apply"):
+            y, new_state = self.model.apply(
+                params, state, x, training=True, rng=rng)
+        with jax.named_scope("criterion"):
+            loss, counted = self.criterion.counted(y, t)
+            if counted:  # the criterion's parts, into the model's counter slots
+                new_state = self.model.with_counters(new_state, counted)
+            reg = self.model.regularization_loss_tree(params)
+            aux = self.model.auxiliary_loss_tree(new_state)
+            return loss + reg + aux, new_state
 
     def _masked_loss_fn(self, params, state, x, t, rng, nvalid):
         """``_loss_fn`` over the first ``nvalid`` rows of a batch padded to the
@@ -1263,7 +1288,14 @@ class Optimizer:
         via the criterion's per-sample decomposition, so the ragged final
         batch of an epoch reuses the full batch's one compiled executable.
         ``nvalid`` is a traced scalar — shape-independent, never a retrace."""
-        y, new_state = self.model.apply(params, state, x, training=True, rng=rng)
+        with jax.named_scope("model_apply"):
+            y, new_state = self.model.apply(
+                params, state, x, training=True, rng=rng)
+        with jax.named_scope("criterion"):
+            return self._masked_criterion(params, new_state, y, t, nvalid)
+
+    def _masked_criterion(self, params, new_state, y, t, nvalid):
+        """The masked loss of ``_masked_loss_fn`` from the model's output on."""
         pair = self.criterion.unreduced(y, t)
         if pair is None:
             raise TypeError(
@@ -1365,12 +1397,15 @@ class Optimizer:
         # optimize()'s driver rebinds params/ms/slots to the step outputs
         # every iteration — no reference to a donated buffer survives
         @partial(jax.jit, donate_argnums=donate)
+        @step_program_name
         def train_step(params, model_state, slots, x, t, nvalid, lr, step, rng):
             (loss, new_model_state), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params, model_state, x, t, rng, nvalid)
-            grads = self._clip_grads(grads)
-            new_params, new_slots = method.update(grads, params, slots, lr, step)
+            with jax.named_scope("optim_update"):
+                grads = self._clip_grads(grads)
+                new_params, new_slots = method.update(
+                    grads, params, slots, lr, step)
             return finish(grads, params, new_params, new_model_state,
                           new_slots, loss, x, t)
 
@@ -1386,6 +1421,7 @@ class Optimizer:
             return a.reshape((n_micro, a.shape[0] // n_micro) + a.shape[1:])
 
         @partial(jax.jit, donate_argnums=donate)
+        @step_program_name
         def micro_step(params, model_state, slots, x, t, nvalid, lr, step, rng):
             xs = jax.tree_util.tree_map(_split, x)
             ts = jax.tree_util.tree_map(_split, t)
@@ -1405,9 +1441,10 @@ class Optimizer:
                 (g_sum, new_model_state), losses = jax.lax.scan(
                     body, (zeros, model_state), (xs, ts, rngs))
                 grads = jax.tree_util.tree_map(lambda g: g / n_micro, g_sum)
-                grads = self._clip_grads(grads)
-                new_params, new_slots = method.update(
-                    grads, params, slots, lr, step)
+                with jax.named_scope("optim_update"):
+                    grads = self._clip_grads(grads)
+                    new_params, new_slots = method.update(
+                        grads, params, slots, lr, step)
                 return finish(grads, params, new_params, new_model_state,
                               new_slots, jnp.mean(losses), x, t)
 
@@ -1436,8 +1473,10 @@ class Optimizer:
                 (xs, ts, rngs, jnp.arange(n_micro, dtype=jnp.float32)))
             v_sum = jnp.maximum(v_sum, 1.0)
             grads = jax.tree_util.tree_map(lambda g: g / v_sum, g_sum)
-            grads = self._clip_grads(grads)
-            new_params, new_slots = method.update(grads, params, slots, lr, step)
+            with jax.named_scope("optim_update"):
+                grads = self._clip_grads(grads)
+                new_params, new_slots = method.update(
+                    grads, params, slots, lr, step)
             return finish(grads, params, new_params, new_model_state,
                           new_slots, l_sum / v_sum, x, t)
 
@@ -1508,13 +1547,17 @@ class Optimizer:
                       rng):
             # the forward differentiates w.r.t. the DECODED f32 master, so
             # gradients stay full-precision whatever the storage dtype
-            if sp is not None:
-                p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
-            else:
-                p32 = flat_p
+            with jax.named_scope("param_views"):
+                if sp is not None:
+                    p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
+                else:
+                    p32 = flat_p
 
             def flat_loss(fvec, ms):
-                return loss_fn(fp.unflatten(fvec), ms, x, t, rng, nvalid)
+                # the views' transpose is the flat gradient's assembly
+                with jax.named_scope("param_views"):
+                    tree = fp.unflatten(fvec)
+                return loss_fn(tree, ms, x, t, rng, nvalid)
 
             (loss, new_ms), flat_g = jax.value_and_grad(
                 flat_loss, has_aux=True
@@ -1522,23 +1565,25 @@ class Optimizer:
             if comp is not None:
                 # single-device wire simulation: quantize→dequantize with
                 # error feedback — the distributed paths' exact numerics
-                g_used, new_err, qstats = comp.exchange_local(
-                    flat_g, err, want_stats=hm is not None
-                )
+                with jax.named_scope("grad_exchange"):
+                    g_used, new_err, qstats = comp.exchange_local(
+                        flat_g, err, want_stats=hm is not None
+                    )
             else:
                 g_used, new_err, qstats = flat_g, None, None
-            g_used = self._clip_grads(g_used)  # one vector: one fused clip
-            if sp is not None:
-                new_flat, new_slots, p_old32, p_new32 = sp.apply_update(
-                    method, g_used, flat_p, slots, lr, step,
-                    wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
-                )
-            else:
-                new_flat, new_slots = method.update_flat(
-                    g_used, flat_p, slots, lr, step, wd_coeff=wd_coeff
-                )
-                new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
-                p_old32, p_new32 = flat_p, new_flat
+            with jax.named_scope("optim_update"):
+                g_used = self._clip_grads(g_used)  # one vector: one fused clip
+                if sp is not None:
+                    new_flat, new_slots, p_old32, p_new32 = sp.apply_update(
+                        method, g_used, flat_p, slots, lr, step,
+                        wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
+                    )
+                else:
+                    new_flat, new_slots = method.update_flat(
+                        g_used, flat_p, slots, lr, step, wd_coeff=wd_coeff
+                    )
+                    new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
+                    p_old32, p_new32 = flat_p, new_flat
             outs = (new_flat, new_ms, new_slots)
             if new_err is not None:
                 outs = outs + (new_err,)
@@ -1558,12 +1603,14 @@ class Optimizer:
 
         if use_err:
             @partial(jax.jit, donate_argnums=donate)
+            @step_program_name
             def flat_step(flat_p, model_state, slots, err, x, t, nvalid, lr,
                           step, rng):
                 return step_body(flat_p, model_state, slots, err, x, t,
                                  nvalid, lr, step, rng)
         else:
             @partial(jax.jit, donate_argnums=donate)
+            @step_program_name
             def flat_step(flat_p, model_state, slots, x, t, nvalid, lr, step,
                           rng):
                 return step_body(flat_p, model_state, slots, None, x, t,

@@ -41,7 +41,8 @@ from ..dataset.dataset import AbstractDataSet
 from ..nn.criterion import AbstractCriterion
 from ..nn.module import AbstractModule
 from ..obs.trace import span as obs_span
-from ..optim.local_optimizer import Optimizer, _to_device_tree
+from ..optim.local_optimizer import (
+    Optimizer, _to_device_tree, step_program_name)
 from ..utils.engine import Engine
 from ..utils.random import RandomGenerator
 from .parameter import FlatParameter
@@ -128,6 +129,16 @@ class DistriOptimizer(Optimizer):
             g_shard = g_shard * scale
         return g_shard
 
+    @staticmethod
+    def _state_sync(new_ms, loss, axis):
+        """The step's other collective: the model state (batch-norm running
+        statistics, counters) and the loss averaged over the replicas, under
+        the step-part scope ``state_sync`` (beside ``grad_exchange``, which
+        owns the gradient's)."""
+        with jax.named_scope("state_sync"):
+            return (_tm(lambda a: jax.lax.pmean(a, axis), new_ms),
+                    jax.lax.pmean(loss, axis))
+
     def _ragged_seam_policy(self) -> str:
         # the SPMD steps take no nvalid scalar: a padded row would train as
         # real data. DistributedDataSet already drops non-divisible train
@@ -204,64 +215,71 @@ class DistriOptimizer(Optimizer):
             rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
             # differentiate w.r.t. the DECODED master so gradients stay
             # full-precision whatever the storage dtype (bf16 master)
-            p_full = sp.decode_master(flat_p) if sp is not None else flat_p
+            with jax.named_scope("param_views"):
+                p_full = (sp.decode_master(flat_p) if sp is not None
+                          else flat_p)
 
             def flat_loss(fvec, ms):
-                return self._loss_fn(fp.unflatten(fvec), ms, x, t, rng_local)
+                # the views' transpose is the flat gradient's assembly
+                with jax.named_scope("param_views"):
+                    tree = fp.unflatten(fvec)
+                return self._loss_fn(tree, ms, x, t, rng_local)
 
             (loss, new_ms), flat_g = jax.value_and_grad(
                 flat_loss, has_aux=True
             )(p_full, model_state)
             me = jax.lax.axis_index(axis)
-            if comp is not None:
-                # compressed exchange: quantized codes on the wire, f32
-                # accumulation, residual carried per device
-                shard_sum, new_err, qstats = comp.exchange_sharded(
-                    flat_g, None if err is None else err[0], axis, n_dev, me,
-                    want_stats=hm is not None,
-                )
-                g_shard = shard_sum / n_dev
-            else:
-                new_err = qstats = None
-                if gdtype is not None:
-                    flat_g = flat_g.astype(gdtype)
-                # reduce-scatter: each device ends with the summed slice it
-                # owns
-                g_shard = jax.lax.psum_scatter(
-                    flat_g, axis, tiled=True
-                ).astype(jnp.float32) / n_dev
-            g_shard = self._clip_shard_global(g_shard, axis)
+            with jax.named_scope("grad_exchange"):
+                if comp is not None:
+                    # compressed exchange: quantized codes on the wire, f32
+                    # accumulation, residual carried per device
+                    shard_sum, new_err, qstats = comp.exchange_sharded(
+                        flat_g, None if err is None else err[0], axis, n_dev,
+                        me, want_stats=hm is not None,
+                    )
+                    g_shard = shard_sum / n_dev
+                else:
+                    new_err = qstats = None
+                    if gdtype is not None:
+                        flat_g = flat_g.astype(gdtype)
+                    # reduce-scatter: each device ends with the summed slice
+                    # it owns
+                    g_shard = jax.lax.psum_scatter(
+                        flat_g, axis, tiled=True
+                    ).astype(jnp.float32) / n_dev
+                g_shard = self._clip_shard_global(g_shard, axis)
             g_stat = g_shard  # post-clip effective gradient (health stats)
-            p_shard = jax.lax.dynamic_slice(
-                flat_p, (me * fp.shard_size,), (fp.shard_size,)
-            )
-            wd_shard = (
-                jax.lax.dynamic_slice(
-                    wd_coeff_full, (me * fp.shard_size,), (fp.shard_size,)
+            with jax.named_scope("optim_update"):
+                p_shard = jax.lax.dynamic_slice(
+                    flat_p, (me * fp.shard_size,), (fp.shard_size,)
                 )
-                if wd_coeff_full is not None
-                else None
-            )
-            if sp is not None:
-                p_shard, slot_shard, p_old, p_new32 = sp.apply_update(
-                    method, g_shard, p_shard, slot_shard, lr, it,
-                    wd_coeff=wd_shard,
-                    pad_zero=lambda v: fp.zero_pad_shard(v, me),
+                wd_shard = (
+                    jax.lax.dynamic_slice(
+                        wd_coeff_full, (me * fp.shard_size,), (fp.shard_size,)
+                    )
+                    if wd_coeff_full is not None
+                    else None
                 )
-            else:
-                p_old = p_shard  # pre-update shard (health ratio)
-                p_shard, slot_shard = method.update_flat(
-                    g_shard, p_shard, slot_shard, lr, it, wd_coeff=wd_shard
-                )
-                # the padding tail must stay zero in the CARRIED master
-                # vector (e.g. Adamax's subnormal eps guard flushes to 0 →
-                # 0/0 = NaN on the inert tail; donation would persist it
-                # forever)
-                p_shard = fp.zero_pad_shard(p_shard, me)
-                p_new32 = p_shard
-            new_flat = jax.lax.all_gather(p_shard, axis, tiled=True)
-            new_ms = _tm(lambda a: jax.lax.pmean(a, axis), new_ms)
-            loss = jax.lax.pmean(loss, axis)
+                if sp is not None:
+                    p_shard, slot_shard, p_old, p_new32 = sp.apply_update(
+                        method, g_shard, p_shard, slot_shard, lr, it,
+                        wd_coeff=wd_shard,
+                        pad_zero=lambda v: fp.zero_pad_shard(v, me),
+                    )
+                else:
+                    p_old = p_shard  # pre-update shard (health ratio)
+                    p_shard, slot_shard = method.update_flat(
+                        g_shard, p_shard, slot_shard, lr, it, wd_coeff=wd_shard
+                    )
+                    # the padding tail must stay zero in the CARRIED master
+                    # vector (e.g. Adamax's subnormal eps guard flushes to 0
+                    # → 0/0 = NaN on the inert tail; donation would persist
+                    # it forever)
+                    p_shard = fp.zero_pad_shard(p_shard, me)
+                    p_new32 = p_shard
+            with jax.named_scope("param_gather"):
+                new_flat = jax.lax.all_gather(p_shard, axis, tiled=True)
+            new_ms, loss = self._state_sync(new_ms, loss, axis)
             outs = (new_flat, new_ms, slot_shard)
             if new_err is not None:
                 outs = outs + (new_err,)
@@ -309,7 +327,7 @@ class DistriOptimizer(Optimizer):
         donate = (0, 1, 2, 3) if use_err else (0, 1, 2)  # EF residual too
         return jax.jit(
             shard_map(
-                per_device,
+                step_program_name(per_device),
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -336,41 +354,45 @@ class DistriOptimizer(Optimizer):
 
         def per_device(flat_p, model_state, slots, err, x, t, lr, it, rng):
             rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
-            if sp is not None:
-                p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
-            else:
-                p32 = flat_p
+            with jax.named_scope("param_views"):
+                if sp is not None:
+                    p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
+                else:
+                    p32 = flat_p
 
             def flat_loss(fvec, ms):
-                return self._loss_fn(fp.unflatten(fvec), ms, x, t, rng_local)
+                with jax.named_scope("param_views"):
+                    tree = fp.unflatten(fvec)
+                return self._loss_fn(tree, ms, x, t, rng_local)
 
             (loss, new_ms), flat_g = jax.value_and_grad(
                 flat_loss, has_aux=True
             )(p32, model_state)
-            if comp is not None:
-                flat_g, new_err, qstats = comp.exchange_replicated(
-                    flat_g, None if err is None else err[0], axis, n_dev,
-                    want_stats=hm is not None,
-                )
-            else:
-                new_err = qstats = None
-                if gdtype is not None:
-                    flat_g = flat_g.astype(gdtype)
-                flat_g = jax.lax.pmean(flat_g, axis).astype(jnp.float32)
-            flat_g = self._clip_grads(flat_g)  # on the aggregated gradient
-            if sp is not None:
-                new_flat, slots, p_old32, p_new32 = sp.apply_update(
-                    method, flat_g, flat_p, slots, lr, it,
-                    wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
-                )
-            else:
-                new_flat, slots = method.update_flat(
-                    flat_g, flat_p, slots, lr, it, wd_coeff=wd_coeff
-                )
-                new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
-                p_old32, p_new32 = flat_p, new_flat
-            new_ms = _tm(lambda a: jax.lax.pmean(a, axis), new_ms)
-            loss = jax.lax.pmean(loss, axis)
+            with jax.named_scope("grad_exchange"):
+                if comp is not None:
+                    flat_g, new_err, qstats = comp.exchange_replicated(
+                        flat_g, None if err is None else err[0], axis, n_dev,
+                        want_stats=hm is not None,
+                    )
+                else:
+                    new_err = qstats = None
+                    if gdtype is not None:
+                        flat_g = flat_g.astype(gdtype)
+                    flat_g = jax.lax.pmean(flat_g, axis).astype(jnp.float32)
+                flat_g = self._clip_grads(flat_g)  # on the aggregated gradient
+            with jax.named_scope("optim_update"):
+                if sp is not None:
+                    new_flat, slots, p_old32, p_new32 = sp.apply_update(
+                        method, flat_g, flat_p, slots, lr, it,
+                        wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
+                    )
+                else:
+                    new_flat, slots = method.update_flat(
+                        flat_g, flat_p, slots, lr, it, wd_coeff=wd_coeff
+                    )
+                    new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
+                    p_old32, p_new32 = flat_p, new_flat
+            new_ms, loss = self._state_sync(new_ms, loss, axis)
             outs = (new_flat, new_ms, slots)
             if new_err is not None:
                 outs = outs + (new_err,)
@@ -406,7 +428,7 @@ class DistriOptimizer(Optimizer):
         donate = (0, 1, 2, 3) if use_err else (0, 1, 2)  # EF residual too
         return jax.jit(
             shard_map(
-                per_device,
+                step_program_name(per_device),
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -425,15 +447,16 @@ class DistriOptimizer(Optimizer):
             (loss, new_ms), grads = jax.value_and_grad(self._loss_fn, has_aux=True)(
                 params, model_state, x, t, rng_local
             )
-            if gdtype is not None:
-                grads = _tm(lambda g: g.astype(gdtype), grads)
-            grads = _tm(
-                lambda g: jax.lax.pmean(g, axis).astype(jnp.float32), grads
-            )
-            grads = self._clip_grads(grads)  # on the aggregated gradient
-            new_params, slots = method.update(grads, params, slots, lr, it)
-            new_ms = _tm(lambda a: jax.lax.pmean(a, axis), new_ms)
-            loss = jax.lax.pmean(loss, axis)
+            with jax.named_scope("grad_exchange"):
+                if gdtype is not None:
+                    grads = _tm(lambda g: g.astype(gdtype), grads)
+                grads = _tm(
+                    lambda g: jax.lax.pmean(g, axis).astype(jnp.float32), grads
+                )
+                grads = self._clip_grads(grads)  # on the aggregated gradient
+            with jax.named_scope("optim_update"):
+                new_params, slots = method.update(grads, params, slots, lr, it)
+            new_ms, loss = self._state_sync(new_ms, loss, axis)
             if hm is None:
                 return new_params, new_ms, slots, loss
             # replicated layout: the same tree-based stats as the local path
@@ -450,7 +473,7 @@ class DistriOptimizer(Optimizer):
         # every iteration — no reference to a donated buffer survives
         return jax.jit(
             shard_map(
-                per_device,
+                step_program_name(per_device),
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(axis), P(axis), P(), P(), P()),
                 out_specs=out_specs,
