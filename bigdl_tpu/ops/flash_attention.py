@@ -16,13 +16,28 @@ Causal masking uses the aligned-at-end convention for rectangular shapes:
 query row i corresponds to global position ``i + Tk - Tq`` (so a single-query
 decode step attends to every cached key).
 
-Backward: Pallas kernels as well — the forward additionally emits the
-per-row logsumexp, and two backward kernels stream tiles through VMEM with
-the same online structure (dQ over k-blocks; dK/dV over q-blocks), so the
-(T, T) probability matrix is never materialized in either direction. The
-classic recomputation trick: ``p = exp(s - lse)`` is rebuilt per tile from
-the saved statistics, ``ds = p * (dp - delta)`` with
-``delta = rowsum(dO * O)`` precomputed outside the grid.
+Backward: a Pallas kernel as well — the forward additionally emits the
+per-row logsumexp, and ONE backward kernel (``flash_bwd``) streams k/v tiles
+past each q tile as the forward does, so the (T, T) probability matrix is
+never materialized in either direction. The classic recomputation trick:
+``p = exp(s - lse)`` is rebuilt per tile from the saved statistics, ``ds = p *
+(dp - delta)`` with ``dp = dO V^T`` and ``delta = rowsum(dO * O)`` precomputed
+outside the grid. Every visited tile builds ``p`` and ``dp`` once and feeds
+all three gradients from them: ``dV += p^T dO``, ``dK += ds^T Q``, ``dQ += ds
+K``. dQ accumulates per q tile; dK and dV accumulate in float32 over the
+WHOLE key axis of one K/V head in VMEM (``Tk x (d + d_v) x 4`` bytes, the k
+tile's rows addressed by a tile-aligned dynamic slice) and are written out
+once when the head — with grouped heads the last query head of its group — is
+done. That accumulator is why the kernel asks for ``_VMEM_LIMIT`` instead of
+Mosaic's default, and the one thing that can keep a shape off it: where the
+key axis is too long for it (``backward_form``: from Tk, the tiles, the head
+sizes and the dtype, at trace time) the backward is the older PAIR of kernels
+with tile-sized accumulators, ``flash_bwd_dq`` (k/v tiles past a q tile) and
+``flash_bwd_dkv`` (q tiles past a k tile), each of which rebuilds ``p`` and
+``dp``: 11 passes of the 128-wide matrix unit a tile where the one kernel
+makes 8 at q/k heads of 192, 7 against 5 at 128 or 64. Both forms round ``p``
+and ``ds`` to the operand dtype at the same places and meet a k tile's q
+tiles in the same order, so their gradients agree bit for bit.
 
 Used via ``scaled_dot_product_attention(..., impl='flash')`` in
 ``bigdl_tpu.nn.attention`` (TPU backend only; dense fallback elsewhere) or
@@ -94,22 +109,10 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     #     and interior tiles are the vast majority at long T.
     kl = jnp.minimum(lens_ref[pl.program_id(0)], t_real_k) if has_lengths \
         else t_real_k
-    visible = j * block_k < kl
-    full = (j + 1) * block_k <= kl
-    if has_lengths and mask_q:
-        # any/all of this q tile's rows inside the valid query horizon
-        visible = visible & (qi * block_q + causal_offset < kl)
-        full = full & ((qi + 1) * block_q - 1 + causal_offset < kl)
-    if causal:
-        visible = visible & (
-            (qi + 1) * block_q - 1 + causal_offset >= j * block_k
-        )
-        full = full & (
-            qi * block_q + causal_offset >= (j + 1) * block_k - 1
-        )
-    if window is not None:
-        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
-                                      block_q, block_k, causal_offset, window)
+    visible, full = _classify(
+        kl, qi, j, block_q=block_q, block_k=block_k, causal=causal,
+        causal_offset=causal_offset, mask_q=has_lengths and mask_q,
+        window=window, nk_real=nk_real)
 
     def _accumulate(masked: bool):
         # MXU dots run in the INPUT dtype (callers pass bf16 under the mixed-
@@ -191,19 +194,39 @@ def _pick_block(requested: int, t: int) -> int:
 # described v5e: 21.0 MiB reported where the sum below says 20.0)
 _VMEM_BUDGET = 15 * 2 ** 20
 _LARGEST_TILE = 1024
+# the one backward kernel holds dK and dV of a whole K/V head: it asks for
+# ``_VMEM_LIMIT`` of the 128 MiB a v5e core has (``ops/ssd_kernel.py`` does
+# the same), and a shape gets it while ``_working_set`` with its terms stays
+# under ``_FUSED_VMEM_BUDGET``. That sum is on the safe side of what Mosaic
+# allocates (compiled for a described v5e: the least limit that compiles is
+# 36 MiB where the sum says 36.0 at q/k 192, v 128, T 8192; 24 for 27.0 at
+# head size 128; 23 for 25.5 at 64), and sums of up to 45 MiB compile under
+# the limit
+_VMEM_LIMIT = 48 * 2 ** 20
+_FUSED_VMEM_BUDGET = 44 * 2 ** 20
 
 
 def _working_set(bq: int, bk: int, d: int, itemsize: int,
-                 d_v: Optional[int] = None) -> int:
-    """Bytes of VMEM the hungriest of the three kernels holds at once: the
-    float32 score and probability tiles, every operand and result tile twice
-    (the pipeline fetches the next while this one computes), the float32
-    accumulators. ``d`` is the head size of q and k, ``d_v`` that of v and
-    the output (``d`` where none is given)."""
+                 d_v: Optional[int] = None, tk: Optional[int] = None) -> int:
+    """Bytes of VMEM the hungriest of the forward kernel and the backward
+    pair holds at once: the float32 score and probability tiles, every
+    operand and result tile twice (the pipeline fetches the next while this
+    one computes), the float32 accumulators. ``d`` is the head size of q and
+    k, ``d_v`` that of v and the output (``d`` where none is given).
+
+    With ``tk``, the padded length of the key axis: what the ONE backward
+    kernel holds instead, whose dK/dV accumulators and result blocks span
+    that axis."""
     d_v = d if d_v is None else d_v
     scores = 2 * bq * bk * 4
     q_tile, k_tile = bq * d * itemsize, bk * d * itemsize
     o_tile, v_tile = bq * d_v * itemsize, bk * d_v * itemsize
+    if tk is not None:
+        # q do dq | k v | dk dv over the whole axis, float32 and as written;
+        # VMEM rows are whole groups of 128 lanes, which at this size counts
+        lanes = -(-d // 128) * 128 + -(-d_v // 128) * 128
+        return scores + 2 * (2 * q_tile + o_tile + k_tile + v_tile) \
+            + bq * d * 4 + tk * lanes * (4 + 2 * itemsize)
     fwd = scores + 2 * (q_tile + o_tile + k_tile + v_tile) + bq * d_v * 4
     # q do dq | k v
     dq = scores + 2 * (2 * q_tile + o_tile + k_tile + v_tile) + bq * d * 4
@@ -213,9 +236,24 @@ def _working_set(bq: int, bk: int, d: int, itemsize: int,
     return max(fwd, dq, dkv)
 
 
+def backward_form(tk: int, bq: int, bk: int, d: int, itemsize: int,
+                  d_v: Optional[int] = None):
+    """(whether a call of these shapes gets the one backward kernel, the
+    bytes of its float32 dK/dV accumulator over the whole key axis). The one
+    kernel builds each tile's ``p`` and ``dp`` once where the pair builds
+    them twice; it is one algorithm for every caller, and only a key axis
+    too long for its accumulator to fit VMEM keeps the pair (bf16, head
+    size 128: the working set is 27 MiB at T 8192 and 43 at 16384, which fit,
+    and 75 at 32768, which does not)."""
+    tkp = -(-tk // bk) * bk
+    acc = tkp * (d + (d if d_v is None else d_v)) * 4
+    fits = _working_set(bq, bk, d, itemsize, d_v, tk=tkp) <= _FUSED_VMEM_BUDGET
+    return fits, acc
+
+
 def pick_tiles(tq: int, tk: int, d: int, itemsize: int,
                d_v: Optional[int] = None):
-    """(block_q, block_k) of the three kernels, from the shapes of a call
+    """(block_q, block_k) of the kernels, from the shapes of a call
     (``d``: head size of q and k; ``d_v``: of v and the output, ``d`` where
     none is given).
 
@@ -284,12 +322,15 @@ def take_tile_records(since: float = 0.0) -> list:
 
 def _resolve_tiles(q, k, v, causal: bool, window: Optional[int],
                    block_q: Optional[int], block_k: Optional[int]):
+    """(block_q, block_k, whether the backward is the one kernel): every
+    choice that follows from a call's shapes, made and recorded here."""
     tq, tk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     bq, bk = pick_tiles(tq, tk, d, q.dtype.itemsize, d_v)
     if block_q is not None:
         bq = _pick_block(block_q, tq)
     if block_k is not None:
         bk = _pick_block(block_k, tk)
+    fused, acc = backward_form(tk, bq, bk, d, q.dtype.itemsize, d_v)
     key = (tq, tk, d, d_v, q.dtype.name, causal, window, bq, bk)
     with _tile_records_lock:
         if key in _tile_records:
@@ -299,11 +340,13 @@ def _resolve_tiles(q, k, v, causal: bool, window: Optional[int],
             record = dict(
                 tq=tq, tk=tk, d=d, dtype=q.dtype.name, causal=causal,
                 window=window, block_q=bq, block_k=bk, visited_tiles=tiles,
-                visited_over_visible=round(waste, 4))
+                visited_over_visible=round(waste, 4),
+                backward="fused" if fused else "pair",
+                backward_acc_bytes=acc)
             if d_v != d:  # v and the output at a head size of their own
                 record["d_v"] = d_v
         _tile_records[key] = (time.perf_counter(), record)
-    return bq, bk
+    return bq, bk, fused
 
 
 def _window_start(qi, block_q: int, block_k: int, causal_offset: int,
@@ -340,6 +383,30 @@ def _window_tiles(visible, full, in_range, qi, j, block_q: int, block_k: int,
     # a step past the last tile (its index was clamped) is neither
     full = full & in_range & (
         (qi + 1) * block_q - 1 + causal_offset - j * block_k < window)
+    return visible, full
+
+
+def _classify(kl, qi, j, *, block_q: int, block_k: int, causal: bool,
+              causal_offset: int, mask_q: bool, window: Optional[int],
+              nk_real: Optional[int]):
+    """(visible, full) of the pair (q tile ``qi``, k tile ``j``), for the
+    kernels whose outer axis is the q tile: whether any entry of it is
+    unmasked, and whether every entry is. ``kl`` is the key horizon (a
+    sequence's length where it has one), ``mask_q`` whether query rows past
+    it are masked too."""
+    visible = j * block_k < kl
+    full = (j + 1) * block_k <= kl
+    if mask_q:
+        # any/all of this q tile's rows inside the valid query horizon
+        visible = visible & (qi * block_q + causal_offset < kl)
+        full = full & ((qi + 1) * block_q - 1 + causal_offset < kl)
+    if causal:
+        visible = visible & (
+            (qi + 1) * block_q - 1 + causal_offset >= j * block_k)
+        full = full & (qi * block_q + causal_offset >= (j + 1) * block_k - 1)
+    if window is not None:
+        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
+                                      block_q, block_k, causal_offset, window)
     return visible, full
 
 
@@ -483,19 +550,10 @@ def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     kl = jnp.minimum(lens_ref[pl.program_id(0)], t_real_k) if has_lengths \
         else t_real_k
-    visible = j * block_k < kl
-    full = (j + 1) * block_k <= kl
-    if has_lengths and mask_q:
-        visible = visible & (qi * block_q + causal_offset < kl)
-        full = full & ((qi + 1) * block_q - 1 + causal_offset < kl)
-    if causal:
-        visible = visible & (
-            (qi + 1) * block_q - 1 + causal_offset >= j * block_k
-        )
-        full = full & (qi * block_q + causal_offset >= (j + 1) * block_k - 1)
-    if window is not None:
-        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
-                                      block_q, block_k, causal_offset, window)
+    visible, full = _classify(
+        kl, qi, j, block_q=block_q, block_k=block_k, causal=causal,
+        causal_offset=causal_offset, mask_q=has_lengths and mask_q,
+        window=window, nk_real=nk_real)
 
     def _accumulate(masked: bool):
         q = q_ref[0]
@@ -605,10 +663,93 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                block_q: int, block_k: int, causal: bool, scale: float,
+                causal_offset: int, t_real_q: int, t_real_k: int, nq: int,
+                nk: int, has_lengths: bool, mask_q: bool,
+                window: Optional[int] = None, nk_real: Optional[int] = None,
+                group: int = 1):
+    """The one backward kernel. Grid (B*Hkv, group * num_q_blocks,
+    num_k_blocks), the dQ kernel's loop order: k/v tiles stream through the
+    inner dim, and every visited tile builds ``p`` and ``dp`` once and feeds
+    all three gradients from them. dQ accumulates per q tile, as in
+    ``_dq_kernel``; dK and dV accumulate in float32 over the WHOLE key axis
+    of this K/V head (``dk_acc`` (Tk, d), ``dv_acc`` (Tk, d_v)), the k
+    tile's rows addressed by a tile-aligned dynamic slice, and are cast and
+    written out once, after the last q tile of the last query head of the
+    group. A k tile meets its q tiles in the order ``_dkv_kernel`` meets
+    them (head by head, q tile by q tile), so the float32 sums are the
+    pair's."""
+    gi, j = pl.program_id(1), pl.program_id(2)
+    qi = gi % nq if group > 1 else gi
+
+    @pl.when((gi == 0) & (j == 0))
+    def _init_head():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    last = j == nk - 1
+    if window is not None:
+        j = j + _window_start(qi, block_q, block_k, causal_offset, window)
+
+    lens_row = pl.program_id(0) * group if group > 1 else pl.program_id(0)
+    kl = jnp.minimum(lens_ref[lens_row], t_real_k) if has_lengths \
+        else t_real_k
+    visible, full = _classify(
+        kl, qi, j, block_q=block_q, block_k=block_k, causal=causal,
+        causal_offset=causal_offset, mask_q=has_lengths and mask_q,
+        window=window, nk_real=nk_real)
+
+    def _accumulate(masked: bool):
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        p = _bwd_masked_p(q, k, lse_ref[0, 0], scale=scale, masked=masked,
+                          causal=causal, causal_offset=causal_offset,
+                          t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
+                          mask_q=has_lengths and mask_q,
+                          qi=qi, ki=j, block_q=block_q, block_k=block_k,
+                          window=window)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        dv_acc[rows, :] += jnp.dot(
+            p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
+        )
+        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0][:, None]) * scale).astype(q.dtype)
+        dk_acc[rows, :] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    @pl.when(full)
+    def _tile_full():
+        _accumulate(masked=False)
+
+    @pl.when(visible & jnp.logical_not(full))
+    def _tile_masked():
+        _accumulate(masked=True)
+
+    @pl.when(last)
+    def _finish():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(last & (gi == group * nq - 1))
+    def _finish_head():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
                     scale: Optional[float], bq: int, bk: int,
                     interpret: bool, mask_q: bool,
-                    window: Optional[int] = None):
+                    window: Optional[int], fused: bool):
+    """dQ, dK, dV off the forward's output and logsumexp. ``fused`` is
+    ``_resolve_tiles``' choice from the shapes: the one kernel with its
+    whole-axis dK/dV accumulator, or the pair where that does not fit."""
     n, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -632,10 +773,9 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
     common = dict(block_q=bq, block_k=bk, causal=causal, scale=scale,
                   causal_offset=off, t_real_q=tq, t_real_k=tk,
                   has_lengths=has_lengths, mask_q=mask_q)
-    kv_row = _kv_row(group, h)
     if window is None:
         nkv, nqv, dq_extra, dkv_extra = nk, nq, {}, {}
-        k_of = lambda i, j: j  # noqa: E731  dQ: the k tile of inner step j
+        k_of = lambda i, j: j  # noqa: E731  the k tile of a q tile's step j
         q_of = lambda i, j: j  # noqa: E731  dK/dV: the q tile of inner step j
     else:
         nkv = _window_count(nq, bq, bk, off, 0, window - 1, nk)
@@ -649,6 +789,63 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
     if group > 1:
         dkv_extra["group"] = group
 
+    def unpadded(dq, dk, dv):
+        return (dq[:, :tq].reshape(n, h, tq, d),
+                dk[:, :tk].reshape(n, hkv, tk, d),
+                dv[:, :tk].reshape(n, hkv, tk, d_v))
+
+    if fused:
+        # step i of the middle dim of K/V row b: query head i // nq of the
+        # group, and its q tile i % nq
+        if group == 1:
+            head, tile = (lambda b, i: b), (lambda i: i)
+        else:
+            head = lambda b, i: b * group + i // nq  # noqa: E731
+            tile = lambda i: i % nq  # noqa: E731
+        q_map = lambda b, i, j, lens: (head(b, i), tile(i), 0)  # noqa: E731
+        row_map = lambda b, i, j, lens: (head(b, i), 0, tile(i))  # noqa: E731
+        kv_map = lambda b, i, j, lens: (b, k_of(tile(i), j), 0)  # noqa: E731
+        whole = lambda b, i, j, lens: (b, 0, 0)  # noqa: E731
+        return unpadded(*pallas_call(
+            partial(_bwd_kernel, nq=nq, nk=nkv, group=group, **common,
+                    **dq_extra),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n * hkv, group * nq, nkv),
+                in_specs=[
+                    pl.BlockSpec((1, bq, d), q_map),
+                    pl.BlockSpec((1, bk, d), kv_map),
+                    pl.BlockSpec((1, bk, d_v), kv_map),
+                    pl.BlockSpec((1, bq, d_v), q_map),
+                    pl.BlockSpec((1, 1, bq), row_map),
+                    pl.BlockSpec((1, 1, bq), row_map),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, bq, d), q_map),
+                    pl.BlockSpec((1, tkp, d), whole),
+                    pl.BlockSpec((1, tkp, d_v), whole),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((bq, d), jnp.float32),
+                    pltpu.VMEM((tkp, d), jnp.float32),
+                    pltpu.VMEM((tkp, d_v), jnp.float32),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
+                jax.ShapeDtypeStruct((n * hkv, tkp, d), k.dtype),
+                jax.ShapeDtypeStruct((n * hkv, tkp, d_v), v.dtype),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=interpret,
+            name="flash_bwd",
+        )(lens, qf, kf, vf, dof, lse, delta))
+
+    # the pair: a key axis too long for the one kernel's accumulator
+    kv_row = _kv_row(group, h)
     kv_map = lambda b, i, j, lens: (kv_row(b), k_of(i, j), 0)  # noqa: E731
     dq = pallas_call(
         partial(_dq_kernel, nk=nkv, **common, **dq_extra),
@@ -719,9 +916,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
         name="flash_bwd_dkv",
     )(lens, qf, kf, vf, dof, lse, delta)
 
-    return (dq[:, :tq].reshape(n, h, tq, d),
-            dk[:, :tk].reshape(n, hkv, tk, d),
-            dv[:, :tk].reshape(n, hkv, tk, d_v))
+    return unpadded(dq, dk, dv)
 
 
 def _dense_reference(q, k, v, causal: bool, scale: Optional[float],
@@ -753,16 +948,16 @@ def _dense_reference(q, k, v, causal: bool, scale: Optional[float],
     return jnp.einsum("nhqk,nhkd->nhqd", w.astype(q.dtype), v)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-                mask_q, window):
+                mask_q, window, fused):
     out, _ = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
                              block_k, interpret, mask_q, window)
     return out
 
 
 def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-              mask_q, window):
+              mask_q, window, fused):
     out, lse = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
                                block_k, interpret, mask_q, window)
     # what only a second run of the forward kernel could rebuild: under
@@ -772,10 +967,11 @@ def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
 
 
 def _bwd_rule(causal, scale, block_q, block_k, interpret, mask_q, window,
-              res, g):
+              fused, res, g):
     q, k, v, lengths, o, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal, scale,
-                                 block_q, block_k, interpret, mask_q, window)
+                                 block_q, block_k, interpret, mask_q, window,
+                                 fused)
     return dq, dk, dv, None
 
 
@@ -823,12 +1019,14 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     convention (row i ↔ global position ``i + Tk - Tq``), matching
     ``causal``. Composes with ``causal``.
 
-    The (q, k) tile of the three kernels follows the shapes
-    (``pick_tiles``); ``block_q`` / ``block_k`` override it, for tests.
+    The (q, k) tile of the kernels follows the shapes (``pick_tiles``);
+    ``block_q`` / ``block_k`` override it, for tests.
 
     ``interpret=True`` runs through the Pallas interpreter (for CPU
-    tests). Differentiable: the backward is a pair of Pallas kernels
-    streaming tiles off the saved logsumexp (module docstring).
+    tests). Differentiable: the backward is one Pallas kernel streaming
+    tiles off the saved logsumexp, or the pair of them where the key axis is
+    too long for its dK/dV accumulator; the shapes decide (module docstring,
+    ``backward_form``).
     """
     if mask_q is None:
         mask_q = q.shape[2] == k.shape[2]
@@ -842,6 +1040,6 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
         raise ValueError(
             f"flash_attention: q heads of {q.shape[3]} against k heads of "
             f"{k.shape[3]}; q and k share a head size, v may have its own")
-    bq, bk = _resolve_tiles(q, k, v, causal, window, block_q, block_k)
+    bq, bk, fused = _resolve_tiles(q, k, v, causal, window, block_q, block_k)
     return _flash_core(q, k, v, lengths, causal, scale, bq, bk,
-                       interpret, bool(mask_q), window)
+                       interpret, bool(mask_q), window, fused)

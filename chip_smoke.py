@@ -331,25 +331,58 @@ def _normal(seed: int, shape, dtype):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, dtype)
 
 
-def check_flash(t: int, d: int, n: int = 2, h: int = 4) -> None:
+def _flash_fwd_bwd(fn, args, cot, what: str):
+    """``_fwd_bwd`` of a function that calls the flash kernel: (lowered text,
+    results, a line for the log: the Mosaic calls and which backward the
+    traced call got). The ``flash_tiles`` record of ops/flash_attention.py
+    is held against the lowered program: the one backward kernel is one call
+    beside the forward's, the pair two."""
+    from bigdl_tpu.ops.flash_attention import take_tile_records
+
+    take_tile_records()
+    text, got = _fwd_bwd(fn, args, cot)
+    n_calls = assert_mosaic(text, what)
+    records = take_tile_records()
+    if not records:  # off the TPU impl='flash' is the dense path
+        return text, got, f"{n_calls} Mosaic calls, no kernel traced"
+    record, = records
+    want = 2 if record["backward"] == "fused" else 3
+    if on_tpu() and n_calls != want:
+        raise AssertionError(
+            f"{what}: {n_calls} Mosaic calls with the backward "
+            f"{record['backward']!r}; want {want}")
+    return text, got, (
+        f"{n_calls} Mosaic calls, backward {record['backward']}, dK/dV "
+        f"accumulator {record['backward_acc_bytes'] / 2 ** 20:.1f} MiB")
+
+
+def check_flash(t: int, d: int, n: int = 2, h: int = 4, hkv: int = None,
+                window: int = None, lengths: bool = True) -> None:
+    """The flash kernel, forward and backward, against the dense path:
+    outputs and the gradients of q, k and v; with ``hkv`` grouped K/V heads,
+    with ``window`` a sliding window."""
     import jax.numpy as jnp
 
     from bigdl_tpu.nn.attention import scaled_dot_product_attention as sdpa
 
-    q, k, v = (_normal(t + i, (n, h, t, d), jnp.bfloat16) for i in range(3))
+    hkv = hkv or h
+    q = _normal(t, (n, h, t, d), jnp.bfloat16)
+    k, v = (_normal(t + i, (n, hkv, t, d), jnp.bfloat16) for i in (1, 2))
     lens = jnp.asarray(
-        np.random.default_rng(t).integers(t // 2, t + 1, n), jnp.int32)
+        np.random.default_rng(t).integers(t // 2, t + 1, n), jnp.int32) \
+        if lengths else None
     cot = _normal(t + 3, (n, h, t, d), jnp.float32)
 
     def attend(impl):
         return lambda q, k, v: sdpa(q, k, v, impl=impl, causal=True,
-                                    lengths=lens, mask_q=True)
+                                    lengths=lens, mask_q=True, window=window)
 
-    what = f"flash attention T={t} d={d} bf16 causal+lengths"
-    text, got = _fwd_bwd(attend("flash"), (q, k, v), cot)
-    n_calls = assert_mosaic(text, what)
+    what = (f"flash attention T={t} d={d} heads {h}/{hkv} bf16 causal"
+            + (f" window {window}" if window else "")
+            + ("+lengths" if lengths else ""))
+    _, got, ran = _flash_fwd_bwd(attend("flash"), (q, k, v), cot, what)
     _close(got, _fwd_bwd(attend("dense"), (q, k, v), cot)[1], BF16_TOL, what)
-    log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
+    log(f"  {what}: fwd+bwd match impl='dense' ({ran})")
 
 
 def check_flash_latent(t: int = 8192, d: int = 192, d_v: int = 128,
@@ -370,17 +403,17 @@ def check_flash_latent(t: int = 8192, d: int = 192, d_v: int = 128,
                                     mask_q=True)
 
     what = f"flash attention T={t} q/k {d} v {d_v} bf16 causal"
-    text, got = _fwd_bwd(attend("flash"), (q, k, v), cot)
-    n_calls = assert_mosaic(text, what)
+    _, got, ran = _flash_fwd_bwd(attend("flash"), (q, k, v), cot, what)
     _close(got, _fwd_bwd(attend("dense"), (q, k, v), cot)[1], BF16_TOL, what)
-    log(f"  {what}: fwd+bwd match impl='dense' ({n_calls} Mosaic calls)")
+    log(f"  {what}: fwd+bwd match impl='dense' ({ran})")
 
 
 def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
     """A GroupedQueryAttention under nn.Remat against the same module bare:
-    the same outputs and gradients, and 3 Mosaic calls in the program, not 4:
-    the kernel's output and logsumexp are kept across the boundary, so the
-    backward does not run flash_fwd again (utils/remat_keep.py)."""
+    the same outputs and gradients, and as many Mosaic calls in the program
+    (the forward kernel once, then the backward's): the kernel's output and
+    logsumexp are kept across the boundary, so the backward does not run
+    flash_fwd again (utils/remat_keep.py)."""
     import jax
     import jax.numpy as jnp
     from bigdl_tpu import nn
@@ -393,19 +426,19 @@ def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
     wrapped = nn.Remat(attn)
     cot = _normal(t + 1, x.shape, jnp.float32)
     what = f"nn.Remat(flash attention) T={t} d={d}"
-    text, got = _fwd_bwd(
+    text, got, ran = _flash_fwd_bwd(
         lambda p, x: wrapped.apply({attn.name(): p}, {attn.name(): state}, x)[0],
-        (params, x), cot)
+        (params, x), cot, what)
     bare, want = _fwd_bwd(lambda p, x: attn.apply(p, state, x)[0],
                           (params, x), cot)
-    n_calls, n_bare = assert_mosaic(text, what), bare.count(MOSAIC_CALL)
-    if on_tpu() and (n_calls, n_bare) != (3, 3):
+    n_calls, n_bare = text.count(MOSAIC_CALL), bare.count(MOSAIC_CALL)
+    if on_tpu() and n_calls != n_bare:
         raise AssertionError(f"{what}: {n_calls} Mosaic calls under Remat, "
-                             f"{n_bare} bare; want 3 and 3")
+                             f"{n_bare} bare")
     # the module's products round their operands to bf16; XLA may fuse the
     # rematerialised projections differently from the stored ones
     _close(got, want, BF16_TOL, what)
-    log(f"  {what}: fwd+bwd match the bare module ({n_calls} Mosaic calls)")
+    log(f"  {what}: fwd+bwd match the bare module ({ran})")
 
 
 def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
@@ -641,6 +674,12 @@ def check_transformer_step(t=2048, batch=8, vocab=8192, hidden=512, heads=8,
 def phase_kernels() -> None:
     check_flash(t=1024, d=64)
     check_flash(t=4096, d=128)
+    # the cells' shapes, a quarter of their heads: Mellum2's full and
+    # sliding layers (groups of 8), granite's (head size 64, groups of 4),
+    # JoyAI's (q/k 192, v 128)
+    check_flash(t=8192, d=128, n=1, h=8, hkv=1, lengths=False)
+    check_flash(t=8192, d=128, n=1, h=8, hkv=1, window=1024, lengths=False)
+    check_flash(t=8192, d=64, n=1, h=8, hkv=2, lengths=False)
     check_flash_latent()
     check_flash_remat(t=2048, d=128)
     check_ssd_scan()

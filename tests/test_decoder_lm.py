@@ -195,7 +195,8 @@ def test_tile_records_say_what_ran_and_how_much_of_it_is_masked():
     assert got[(None, 1024, 1024)] == dict(
         tq=2048, tk=2048, d=16, dtype="bfloat16", causal=True, window=None,
         block_q=1024, block_k=1024, visited_tiles=3,
-        visited_over_visible=pytest.approx(1.4993, abs=1e-4))
+        visited_over_visible=pytest.approx(1.4993, abs=1e-4),
+        backward="fused", backward_acc_bytes=2048 * 32 * 4)
     assert take_tile_records() == []
     attend()                     # a trace older than the caller asks about
     assert take_tile_records(since=time.perf_counter()) == []

@@ -406,3 +406,182 @@ class TestCrossAttentionMaskQ:
         # rows with global position >= 12 (i.e. i + 8 >= 12 → i >= 4) zeroed
         np.testing.assert_array_equal(np.asarray(out)[0, :, 4:], 0.0)
         assert float(jnp.abs(out[0, :, :4]).min()) > 0.0
+
+
+# --------------------------------------------------------------------------
+# the backward's two forms (PR 38): the one kernel that builds each tile's p
+# and dp once, with dK/dV accumulated over the whole key axis in VMEM, and
+# the pair that a key axis too long for that accumulator keeps
+# --------------------------------------------------------------------------
+def _dense_masked_reference(q, k, v, causal, window, lengths, mask_q):
+    """``_dense_reference`` where there are no lengths; with them, dense
+    attention whose keys (and, with ``mask_q``, query rows) past a
+    sequence's length are masked, rows without a key giving zero."""
+    from bigdl_tpu.ops.flash_attention import _dense_reference
+
+    if lengths is None:
+        return _dense_reference(q, k, v, causal, None, window)
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    tq, tk = q.shape[2], k.shape[2]
+    rows = (jnp.arange(tq) + (tk - tq))[None, :, None]
+    cols = jnp.arange(tk)[None, None, :]
+    horizon = lengths[:, None, None]
+    allowed = cols < horizon
+    if mask_q:
+        allowed = allowed & (rows < horizon)
+    if causal:
+        allowed = allowed & (rows >= cols)
+    if window is not None:
+        allowed = allowed & (rows - cols < window)
+    allowed = allowed[:, None]
+    s = jnp.einsum("nhqd,nhkd->nhqk", q, k) / np.sqrt(q.shape[-1])
+    has = allowed.any(-1, keepdims=True)
+    w = jnp.where(has, jax.nn.softmax(
+        jnp.where(has, jnp.where(allowed, s, -jnp.inf), 0.0), axis=-1), 0.0)
+    return jnp.einsum("nhqk,nhkd->nhqd", w, v)
+
+
+# n, query heads, K/V heads, Tq, Tk, d, d_v, causal, window, lengths,
+# mask_q, tile: every case has more than one tile on each axis
+BACKWARD_CASES = {
+    "full": (2, 2, 2, 32, 32, 16, 16, False, None, None, True, 8),
+    "causal": (2, 2, 2, 32, 32, 16, 16, True, None, None, True, 8),
+    "window": (1, 2, 2, 640, 640, 16, 16, True, 300, None, True, 128),
+    "group2": (1, 4, 2, 48, 48, 16, 16, True, None, None, True, 16),
+    "group4+window": (1, 4, 1, 640, 640, 16, 16, True, 300, None, True, 128),
+    "lengths+mask_q": (2, 4, 2, 40, 40, 16, 16, True, None, (17, 40), True, 8),
+    "lengths-mask_q": (2, 2, 2, 40, 40, 16, 16, False, None, (17, 33), False, 8),
+    "d_v=2d/3": (1, 2, 1, 320, 320, 24, 16, True, None, None, True, 128),
+    "Tq<Tk": (2, 2, 2, 16, 40, 16, 16, True, None, None, False, 8),
+    "Tq>Tk": (1, 2, 2, 40, 24, 16, 16, True, None, None, False, 8),
+    "ragged-T": (2, 2, 2, 13, 21, 16, 16, False, None, None, False, 8),
+    "rule-tiles": (1, 2, 1, 2304, 2304, 16, 16, True, 1100, None, True, None),
+}
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "pair"])
+@pytest.mark.parametrize("case", BACKWARD_CASES)
+def test_backward_forms_match_dense(case, fused):
+    """dQ, dK and dV of each form of the backward against dense attention's,
+    and the forward with them."""
+    from bigdl_tpu.ops.flash_attention import _flash_core, _resolve_tiles
+
+    n, h, hkv, tq, tk, d, d_v, causal, window, lengths, mask_q, tile = \
+        BACKWARD_CASES[case]
+    rng = np.random.default_rng(len(case))
+    q, k, v, w = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                  for shape in ((n, h, tq, d), (n, hkv, tk, d),
+                                (n, hkv, tk, d_v), (n, h, tq, d_v)))
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
+    bq, bk, _ = _resolve_tiles(q, k, v, causal, window, tile, tile)
+
+    def flash(q, k, v):
+        return _flash_core(q, k, v, lengths, causal, None, bq, bk, True,
+                           mask_q, window, fused)
+
+    def dense(q, k, v):
+        return _dense_masked_reference(q, k, v, causal, window, lengths,
+                                       mask_q)
+
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(w * flash(*a)), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(w * dense(*a)), (0, 1, 2))(q, k, v)
+    for g, r, like in zip(got, want, (q, k, v)):
+        assert g.shape == like.shape
+        np.testing.assert_allclose(g, r, atol=5e-5)
+
+
+def test_backward_forms_sum_in_the_same_order():
+    """A k tile meets its q tiles in one order in both forms (head by head
+    of a group, q tile by q tile), so the float32 sums agree bit for bit."""
+    from bigdl_tpu.ops.flash_attention import _flash_core
+
+    rng = np.random.default_rng(5)
+    q, w = (jnp.asarray(rng.standard_normal((2, 4, 48, 16)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((2, 2, 48, 16)), jnp.float32)
+            for _ in range(2))
+    lengths = jnp.asarray((29, 48), jnp.int32)
+    grads = [jax.grad(lambda *a: jnp.sum(w * _flash_core(
+        *a, lengths, True, None, 16, 16, True, True, 20, fused)),
+        (0, 1, 2))(q, k, v) for fused in (True, False)]
+    for one, pair in zip(*grads):
+        np.testing.assert_array_equal(one, pair)
+
+
+def _kernel_names(fn, *args):
+    import re
+
+    return sorted(set(re.findall(r"name=(flash_(?:fwd|bwd)\w*)",
+                                 str(jax.make_jaxpr(fn)(*args)))))
+
+
+def test_the_backward_follows_the_shapes():
+    """No argument chooses the backward: a key axis whose float32 dK/dV
+    accumulator fits VMEM gets the one kernel, a longer one the pair, and
+    the tile record says which and how large the accumulator is."""
+    from bigdl_tpu.ops.flash_attention import (
+        _dense_reference, backward_form, take_tile_records)
+
+    grad = lambda q, k, v: jax.grad(lambda *a: jnp.sum(  # noqa: E731
+        flash_attention(*a, interpret=True)), (0, 1, 2))(q, k, v)
+    take_tile_records()
+    q = jnp.ones((1, 2, 256, 16))
+    assert _kernel_names(grad, q, q, q) == ["flash_bwd", "flash_fwd"]
+    record, = take_tile_records()
+    assert (record["backward"], record["backward_acc_bytes"]) == (
+        "fused", 256 * (16 + 16) * 4)
+
+    # 16 queries against 16384 float32 keys of 128: 16 MiB of accumulator,
+    # and as much again twice over for the result blocks
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((1, 1, 16, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 1, 16384, 128)), jnp.float32)
+            for _ in range(2))
+    assert backward_form(16384, 16, 1024, 128, 4) == (False, 16 * 2 ** 20)
+    assert backward_form(16384, 16, 1024, 128, 2) == (True, 16 * 2 ** 20)
+    assert _kernel_names(grad, q, k, v) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    record, = take_tile_records()
+    assert (record["backward"], record["backward_acc_bytes"]) == (
+        "pair", 16 * 2 ** 20)
+    want = jax.grad(lambda *a: jnp.sum(_dense_reference(*a, False, None)),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(grad(q, k, v), want):
+        np.testing.assert_allclose(g, r, atol=2e-5)
+
+
+def _table_shapes():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "flash_tile_table.py")
+    spec = importlib.util.spec_from_file_location("flash_tile_table", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.SHAPES
+
+
+TABLE_SHAPES = _table_shapes()
+
+
+@pytest.mark.parametrize("row", sorted(TABLE_SHAPES))
+def test_the_tables_shapes_fit_the_limits(row):
+    """Every shape of ``tools/flash_tile_table.py``: the rule's tiles keep
+    the forward kernel under Mosaic's default limit, and the one backward
+    kernel, which every one of them gets, under the limit it asks for."""
+    from bigdl_tpu.ops.flash_attention import (
+        _FUSED_VMEM_BUDGET, _VMEM_BUDGET, _VMEM_LIMIT, _working_set,
+        backward_form, pick_tiles)
+
+    shape = TABLE_SHAPES[row]
+    t, d, d_v = shape["t"], shape["d"], shape.get("d_v")
+    bq, bk = pick_tiles(t, t, d, 2, d_v)
+    assert _working_set(bq, bk, d, 2, d_v) <= _VMEM_BUDGET
+    fused, acc = backward_form(t, bq, bk, d, 2, d_v)
+    assert fused and acc == t * (d + (d_v or d)) * 4
+    assert _working_set(bq, bk, d, 2, d_v, tk=t) <= _FUSED_VMEM_BUDGET \
+        < _VMEM_LIMIT

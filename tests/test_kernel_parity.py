@@ -119,7 +119,7 @@ class TestFusedEpilogueParity:
 @pytest.mark.parametrize("tq,tk", ((128, 128), (96, 160)),
                          ids=("square", "rect"))
 def test_flash_attention_parity(tq, tk, dtype, tile):
-    """The pre-existing flash kernel rides the same gate: fwd + q-grad vs the
+    """The pre-existing flash kernel rides the same gate: fwd + gradients vs the
     dense softmax reference, in interpret mode; at tiles of 64 and at the
     tiles the rule picks from the shapes."""
     n, h, d = 1, 2, 16
@@ -132,12 +132,16 @@ def test_flash_attention_parity(tq, tk, dtype, tile):
                           block_q=tile, block_k=tile)
     ref = _dense_reference(q, k, v, True, None)
     _close(out, ref, tol, "flash fwd")
-    gk = jax.grad(lambda q: jnp.sum(
-        flash_attention(q, k, v, causal=True, interpret=True,
-                        block_q=tile, block_k=tile).astype(jnp.float32) ** 2))(q)
-    gr = jax.grad(lambda q: jnp.sum(
-        _dense_reference(q, k, v, True, None).astype(jnp.float32) ** 2))(q)
-    _close(gk, gr, tol, "flash dq")
+    # dQ, dK and dV: one backward kernel feeds all three
+    gk = jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, causal=True, interpret=True, block_q=tile,
+                        block_k=tile).astype(jnp.float32) ** 2),
+        (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(
+        _dense_reference(*a, True, None).astype(jnp.float32) ** 2),
+        (0, 1, 2))(q, k, v)
+    for got, want, name in zip(gk, gr, ("dq", "dk", "dv")):
+        _close(got, want, tol, f"flash {name}")
 
 
 @pytest.mark.parametrize("dtype", (jnp.float32,), ids=("f32",))

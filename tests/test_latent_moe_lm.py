@@ -279,18 +279,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_the_three_kernels_compile_at_the_cells_shapes_for_a_v5e(one_chip):
+@pytest.mark.parametrize("n,h,hkv,t,d,d_v,window,calls", [
+    (2, 32, 32, 8192, 192, 128, None, 2),    # JoyAI's blocks
+    (2, 32, 4, 8192, 128, 128, None, 2),     # Mellum2's full layer
+    (2, 32, 4, 8192, 128, 128, 1024, 2),     # and its sliding layers
+    (1, 32, 8, 8192, 64, 64, None, 2),       # granite's attention layer
+    (1, 2, 2, 32768, 128, 128, None, 3),     # too long a key axis: the pair
+], ids=["joyai", "mellum-full", "mellum-window", "granite", "T32768"])
+def test_the_kernels_compile_at_the_cells_shapes_for_a_v5e(
+        one_chip, n, h, hkv, t, d, d_v, window, calls):
     """Interpret mode passes what Mosaic refuses: q/k heads of 192 (one and a
-    half lane groups) and v heads of 128 at 2 x 32 heads x 8192, forward and
-    both backward kernels, at the tiles the rule picks."""
+    half lane groups) with v heads of 128, grouped heads, a window, head size
+    64, each at the cell's real sizes and the tiles the rule picks: the
+    forward kernel and the one backward kernel with its whole-axis dK/dV
+    accumulator under the VMEM limit it asks for; a key axis of 32768 gets
+    the pair."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    shape = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
-        (2, 32, 8192, d), jnp.bfloat16, sharding=one_chip)
+    shape = lambda heads, size: jax.ShapeDtypeStruct(  # noqa: E731
+        (n, heads, t, size), jnp.bfloat16, sharding=one_chip)
 
     def fwd_bwd(q, k, v, g):
-        out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, True),
-                           q, k, v)
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, True, window=window), q, k, v)
         return out, vjp(g)
 
     was = jax.config.jax_enable_compilation_cache
@@ -298,11 +309,12 @@ def test_the_three_kernels_compile_at_the_cells_shapes_for_a_v5e(one_chip):
     compilation_cache.reset_cache()
     try:
         text = jax.jit(fwd_bwd).lower(
-            shape(192), shape(192), shape(128), shape(128)).compile().as_text()
+            shape(h, d), shape(hkv, d), shape(hkv, d_v),
+            shape(h, d_v)).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == calls
 
 
 def test_the_sized_buffers_loop_compiles_at_the_cells_shapes_for_a_v5e(
