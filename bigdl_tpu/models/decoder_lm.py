@@ -2,9 +2,9 @@
 next one is a dict and not a class.
 
 ``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses.
-Three families so far, told apart by their own keys (the cuts to one chip are
-``benchmark/configs/mellum2_12b.json``, ``granite_4_0_h_micro.json`` and
-``joyai_llm_flash.json``)::
+Four families so far, told apart by their own keys (the cuts to one chip are
+``benchmark/configs/mellum2_12b.json``, ``granite_4_0_h_micro.json``,
+``joyai_llm_flash.json`` and ``nemotron_3_nano_30b_a3b.json``)::
 
     vocab_size, hidden_size, num_hidden_layers, layer_types,
     num_attention_heads, num_key_value_heads, head_dim, rms_norm_eps
@@ -32,21 +32,41 @@ Three families so far, told apart by their own keys (the cuts to one chip are
     norm_topk_prob, routed_scaling_factor, num_nextn_predict_layers (0 | 1),
     tie_word_embeddings (false)
 
-and three of this repo's own: ``experts_held`` (ids of the experts this chip
+    state-space / attention / routed-experts hybrid whose every layer is ONE
+    mixer (NVIDIA-Nemotron-3-Nano-30B-A3B; ``nemotron_h``'s key set, told by
+    ``hybrid_override_pattern``: one letter a layer, ``M`` a Mamba-2 mixer,
+    ``*`` attention without positional encoding, ``E`` routed experts; ``-``,
+    a dense relu2 MLP layer, is refused): hybrid_override_pattern,
+    mamba_num_heads, mamba_head_dim, ssm_state_size, n_groups (the scan's B/C
+    groups), conv_kernel, chunk_size, use_conv_bias (true), mamba_proj_bias
+    (false), mlp_hidden_act (relu2: ungated experts of two matrices),
+    moe_intermediate_size, moe_shared_expert_intermediate_size,
+    n_routed_experts, n_shared_experts, num_experts_per_tok, norm_topk_prob,
+    routed_scaling_factor, n_group and topk_group (1: the router's, no
+    group-limited routing), layer_norm_epsilon, tie_word_embeddings (false);
+    sigmoid scores with a selection bias, as the third family's
+
+and five of this repo's own: ``experts_held`` (ids of the experts this chip
 holds, default all: one chip's share of an expert-parallel layer),
-``initializer_range`` (default 0.02) and, for the third family,
-``router_bias_update_rate`` (the speed of the selection bias, default 0.001:
-DeepSeek-V3's, arXiv:2412.19437 section 4.2). ``layer_types`` may be longer
+``first_layer`` (the fourth family's: the layer of the pattern that the
+``num_hidden_layers`` built start at, default 0: one pipeline stage),
+``router_width`` (the fourth family's: the router's width where
+``n_routed_experts`` counts the experts held), ``initializer_range`` (default
+0.02) and, for the third and fourth families, ``router_bias_update_rate``
+(the speed of the selection bias, default 0.001: DeepSeek-V3's,
+arXiv:2412.19437 section 4.2). ``layer_types`` may be longer
 than ``num_hidden_layers``: the first that many are built. ``head_dim``
 defaults to ``hidden_size / num_attention_heads``. A model with
 ``num_nextn_predict_layers`` 1 returns ``Table(logits, logits_1)`` and
 trains under ``nn.MultiTokenCrossEntropyCriterion``.
 
 ``decoder_lm_reference`` (attention + routed experts),
-``hybrid_lm_reference`` (state-space + attention, dense MLP) and
+``hybrid_lm_reference`` (state-space + attention, dense MLP),
 ``latent_moe_lm_reference`` (latent attention, dense and sparse layers, the
-multi-token-prediction module) are the plain float32 references of the same
-equations; ``reference_config`` and ``reference_params`` hand the one that
+multi-token-prediction module) and ``nemotron_h_lm_reference`` (one mixer a
+layer: grouped state-space scan, attention, relu2 experts) are the plain
+float32 references of the same equations; ``reference_config`` and
+``reference_params`` hand the one that
 fits this model's sizes and parameters.
 """
 
@@ -62,9 +82,30 @@ def is_latent(config: Dict) -> bool:
     return "kv_lora_rank" in config
 
 
+def is_one_mixer(config: Dict) -> bool:
+    """Whether ``config`` is of the family whose every layer is one mixer."""
+    return "hybrid_override_pattern" in config
+
+
+# hybrid_override_pattern's letters; "-" (a dense relu2 MLP layer) is refused
+PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+
+
 def layer_types(config: Dict):
     if is_latent(config):
         return ["latent_attention"] * int(config["num_hidden_layers"])
+    if is_one_mixer(config):
+        first, n = int(config.get("first_layer", 0)), int(
+            config["num_hidden_layers"])
+        pattern = config["hybrid_override_pattern"][first:first + n]
+        bad = sorted(set(pattern) - set(PATTERN_KINDS))
+        if bad or len(pattern) != n:
+            raise ValueError(
+                f"decoder_lm.from_config: hybrid_override_pattern {pattern!r} "
+                f"from layer {first}; accepts one of {sorted(PATTERN_KINDS)} "
+                f"for each of num_hidden_layers {config['num_hidden_layers']} "
+                "layers ('-', a dense MLP layer, is not built)")
+        return [PATTERN_KINDS[letter] for letter in pattern]
     kinds = list(config["layer_types"])[:int(config["num_hidden_layers"])]
     if len(kinds) != int(config["num_hidden_layers"]):
         raise ValueError(
@@ -73,12 +114,19 @@ def layer_types(config: Dict):
     return kinds
 
 
+def router_width(config: Dict) -> int:
+    if is_one_mixer(config):
+        return int(config.get("router_width", config["n_routed_experts"]))
+    return int(config["n_routed_experts" if is_latent(config)
+                      else "num_experts"])
+
+
 def experts_held(config: Dict):
-    width = config["n_routed_experts" if is_latent(config) else "num_experts"]
-    return tuple(config.get("experts_held", range(int(width))))
+    return tuple(config.get("experts_held", range(router_width(config))))
 
 
-MLP_KINDS = ("sparse",)        # of mlp_layer_types; no key: one dense gated MLP
+# of a config's mlp_layer_types; no such key: one dense gated MLP a layer
+CONFIG_MLP_KINDS = ("sparse",)
 
 
 def is_hybrid(config: Dict) -> bool:
@@ -119,7 +167,7 @@ def _accepts(config: Dict, key: str, accepted, default=None):
 
 
 def bias_update_rate(config: Dict) -> float:
-    """The speed of the latent family's selection bias."""
+    """The speed of the selection bias (the latent and one-mixer families)."""
     return float(config.get("router_bias_update_rate", 1e-3))
 
 
@@ -190,21 +238,65 @@ def _latent(config: Dict) -> nn.DecoderLM:
         experts_held=experts_held(config), router=router, mtp_modules=mtp)
 
 
+def _one_mixer(config: Dict) -> nn.DecoderLM:
+    """``nemotron_h``'s key set: every layer one mixer behind one norm."""
+    _accepts(config, "n_group", (1,), 1)
+    _accepts(config, "topk_group", (1,), 1)
+    _accepts(config, "mamba_proj_bias", (False,), False)
+    _accepts(config, "use_conv_bias", (True,), True)
+    _accepts(config, "use_bias", (False,), False)
+    _accepts(config, "attention_bias", (False,), False)
+    _accepts(config, "mlp_bias", (False,), False)
+    _accepts(config, "mlp_hidden_act", ("relu2",))
+    _accepts(config, "mamba_hidden_act", ("silu",), "silu")
+    _accepts(config, "norm_topk_prob", (True,), True)
+    _accepts(config, "tie_word_embeddings", (False,), False)
+    return nn.DecoderLM(
+        vocab_size=int(config["vocab_size"]),
+        hidden_size=int(config["hidden_size"]),
+        layer_types=layer_types(config),
+        mlp_layer_types=["none"] * int(config["num_hidden_layers"]),
+        num_heads=int(config["num_attention_heads"]),
+        num_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=head_dim(config),
+        eps=float(config["layer_norm_epsilon"]),
+        init_std=float(config.get("initializer_range", 0.02)),
+        qk_norm=False,       # and no rope_parameters: no positional encoding
+        mamba=dict(heads=int(config["mamba_num_heads"]),
+                   head_dim=int(config["mamba_head_dim"]),
+                   state=int(config["ssm_state_size"]),
+                   groups=int(config["n_groups"]),
+                   conv=int(config["conv_kernel"]),
+                   chunk=int(config["chunk_size"])),
+        n_experts=router_width(config),
+        experts_per_token=int(config["num_experts_per_tok"]),
+        expert_size=int(config["moe_intermediate_size"]),
+        experts_held=experts_held(config),
+        router=dict(
+            form="relu2", scoring="sigmoid",
+            routed_scaling=float(config["routed_scaling_factor"]),
+            bias_update_rate=bias_update_rate(config),
+            shared_size=int(config["moe_shared_expert_intermediate_size"])
+            * int(config.get("n_shared_experts", 0))))
+
+
 def from_config(config: Dict) -> nn.DecoderLM:
     """The ``nn.DecoderLM`` that ``config`` describes (not yet built: the
     optimizer builds it from the first batch, or call ``build``)."""
     if is_latent(config):
         return _latent(config)
+    if is_one_mixer(config):
+        return _one_mixer(config)
     kinds = layer_types(config)
     bad = sorted(set(kinds) - set(nn.decoder.LAYER_KINDS))
     if bad:
         raise ValueError(f"decoder_lm.from_config: layer_types {bad}; accepts "
                          f"{nn.decoder.LAYER_KINDS}")
     bad = sorted(set(config.get("mlp_layer_types", [])[:len(kinds)])
-                 - set(MLP_KINDS))
+                 - set(CONFIG_MLP_KINDS))
     if bad:
         raise ValueError(f"decoder_lm.from_config: mlp_layer_types {bad}; "
-                         f"accepts {MLP_KINDS}, or no such key and "
+                         f"accepts {CONFIG_MLP_KINDS}, or no such key and "
                          "num_local_experts 0 for one dense gated MLP a layer")
     common = dict(
         vocab_size=int(config["vocab_size"]),
@@ -251,6 +343,17 @@ def from_config(config: Dict) -> nn.DecoderLM:
 
 def reference_config(config: Dict) -> Dict:
     """What the family's reference reads, from the same dict."""
+    if is_one_mixer(config):
+        keys = ("num_attention_heads", "num_key_value_heads",
+                "layer_norm_epsilon", "mamba_num_heads", "mamba_head_dim",
+                "ssm_state_size", "n_groups", "chunk_size",
+                "num_experts_per_tok", "routed_scaling_factor")
+        out = {k: config[k] for k in keys}
+        out["head_dim"] = head_dim(config)
+        out["layer_types"] = layer_types(config)
+        out["experts_held"] = experts_held(config)
+        out["bias_update_rate"] = bias_update_rate(config)
+        return out
     if is_latent(config):
         keys = ("num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
                 "qk_rope_head_dim", "rope_theta", "rope_interleave",
@@ -279,16 +382,17 @@ def reference_config(config: Dict) -> Dict:
 
 
 def _reference_layer(block: Dict) -> Dict:
-    out = {"ln1": block["ln1"]["weight"], "ln2": block["ln2"]["weight"]}
+    out = {name: block[name]["weight"]   # a one-mixer block has the one norm
+           for name in ("ln", "ln1", "ln2") if name in block}
     for name in ("attn", "ssm", "experts", "mlp"):   # the leaves as they are
         out.update(block.get(name, {}))
     return out
 
 
 def reference_biases(state: Dict) -> list:
-    """A built latent-family ``DecoderLM``'s router biases (from its state
-    tree) in the order of ``latent_moe_lm_reference``: one for each routed
-    layer, the MTP module's last."""
+    """A built ``DecoderLM``'s router biases (from its state tree) in the
+    order of ``latent_moe_lm_reference`` and ``nemotron_h_lm_reference``: one
+    for each routed layer, the MTP module's last."""
     names = sorted((k for k in state if k.startswith("layer_")),
                    key=lambda k: int(k.split("_")[1]))
     blocks = [state[n]["block"] for n in names]

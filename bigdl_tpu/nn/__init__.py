@@ -200,6 +200,7 @@ from .decoder import (
     GroupedQueryAttention,
     LatentAttention,
     LMHead,
+    MixerBlock,
     MultiTokenPredictor,
 )
 from .ssm import Mamba2Mixer

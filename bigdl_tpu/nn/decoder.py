@@ -7,7 +7,12 @@
                                         LatentAttention | Mamba2Mixer
     y = h + r * FFN_l(RMSNorm(h))       nn.RoutedExperts | GatedMLP
 
-then a final RMSNorm and the head. The layer kinds (``LAYER_KINDS``):
+then a final RMSNorm and the head. A layer whose ``mlp_layer_types`` entry is
+``"none"`` is ONE mixer behind one norm with one residual add
+(``MixerBlock``: ``y = x + r * Mixer_l(RMSNorm(x))``), and the layer kind
+``"experts"`` makes the routed experts such a layer's mixer: the models whose
+every layer is a state-space mixer, attention or routed experts alone. The
+layer kinds (``LAYER_KINDS``):
 ``"sliding_attention"`` and ``"full_attention"`` / ``"attention"`` are
 grouped-query attention, causal, with a sliding window on the layers that say
 so, through ``scaled_dot_product_attention`` (the flash kernel on the TPU
@@ -23,7 +28,9 @@ kind without an entry in ``rope_parameters`` has no positional encoding) and
 the score scale ``1/sqrt(head_dim)`` unless ``attention_scale`` gives
 another. The feed-forward is routed experts where the model has experts and
 one gated MLP where it has none, or layer by layer what ``mlp_layer_types``
-says (``"dense"`` / ``"sparse"``: leading dense layers before sparse ones).
+says (``"dense"`` / ``"sparse"``: leading dense layers before sparse ones;
+``"none"``: no feed-forward in the block). The experts are gated (three
+matrices) or ``relu2`` (two): ``router["form"]``, see ``nn.RoutedExperts``.
 With ``mtp_modules`` 1 the model carries DeepSeek-V3's multi-token-prediction
 module (``MultiTokenPredictor``) and returns ``Table(logits, logits_1)``:
 the second predicts the token after next through one more block, using the
@@ -46,7 +53,8 @@ by strings) stays beside it until D1 merges the two.
 
 Device time is attributed by ``jax.named_scope``: ``embed``, ``attn_proj``,
 ``attn_window`` / ``attn_full`` (the kernel call alone), ``mla_proj``,
-``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``moe_route``, ``moe_experts``,
+``ssm_proj`` (inside it ``ssm_gate_norm``), ``ssm_conv``, ``ssm_scan``,
+``moe_route``, ``moe_experts``,
 ``moe_shared``, ``mlp``, ``mtp`` (around the whole module), ``lm_head``
 (docs/observability.md).
 """
@@ -73,8 +81,8 @@ from ..utils.table import Table
 
 # "attention" is "full_attention" under the name the hybrid models give it
 LAYER_KINDS = ("sliding_attention", "full_attention", "attention", "mamba",
-               "latent_attention")
-MLP_KINDS = ("dense", "sparse")
+               "latent_attention", "experts")
+MLP_KINDS = ("dense", "sparse", "none")
 
 
 def rope_inv_freq(rope: Dict, head_dim: int):
@@ -325,6 +333,30 @@ class DecoderBlock(Container):
         return h + scaled(run(ffn, run(ln2, h))), new_state
 
 
+class MixerBlock(Container):
+    """``y = x + r * mixer(ln(x))``: one norm, one mixer, one residual add;
+    any mixer that maps ``(N, T, D)`` to itself, ``RoutedExperts`` among
+    them."""
+
+    def __init__(self, mixer: AbstractModule, eps: float = 1e-6,
+                 residual_multiplier: float = 1.0):
+        super().__init__(RMSNorm(eps=eps).set_name("ln"),
+                         mixer.set_name(_CHILD_NAMES.get(type(mixer), "mixer")))
+        self.residual_multiplier = float(residual_multiplier)
+
+    build = DecoderBlock.build
+    infer_shape = DecoderBlock.infer_shape
+
+    def _apply(self, params, state, x, training, rng):
+        ln, mixer = self.modules
+        new_state: Dict = {}
+        run = lambda m, v: self._child_apply(  # noqa: E731
+            m, v, training, rng, params, state, new_state)
+        r = self.residual_multiplier
+        mixed = run(mixer, run(ln, x))
+        return x + (mixed if r == 1.0 else r * mixed), new_state
+
+
 class LMHead(AbstractModule):
     """``logits = x @ W / divisor`` (D -> vocabulary), no bias, float32
     logits. ``tied``: the module holds no matrix of its own; its container
@@ -438,10 +470,12 @@ class DecoderLM(Container):
         n_experts, experts_per_token, expert_size: router width, k, F; with
             ``n_experts`` 0 the feed-forward is one ``GatedMLP(mlp_size)``.
         mlp_layer_types: one of ``MLP_KINDS`` per layer (default: every
-            layer sparse where the model has experts, dense where not).
+            layer sparse where the model has experts, dense where not, and
+            ``"none"`` for an ``"experts"`` layer, which takes no other).
         experts_held: ids of the experts this chip holds (default all).
         router: further ``nn.RoutedExperts`` arguments (``scoring``,
-            ``routed_scaling``, ``bias_update_rate``, ``shared_size``).
+            ``routed_scaling``, ``bias_update_rate``, ``shared_size``,
+            ``form``).
         latent: the ``"latent_attention"`` layers' ``LatentAttention`` sizes
             (``q_rank``, ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim``,
             ``interleaved``).
@@ -450,7 +484,7 @@ class DecoderLM(Container):
             logits_1)``.
         qk_norm, attention_scale: see ``GroupedQueryAttention``.
         mamba: the ``"mamba"`` layers' ``Mamba2Mixer`` arguments (``heads``,
-            ``head_dim``, ``state``, ``conv``, ``chunk``).
+            ``head_dim``, ``state``, ``conv``, ``chunk``, ``groups``).
         embedding_multiplier, residual_multiplier, logits_divisor: the
             module docstring's three scalars.
         tie_embeddings: the head is the embedding's transpose.
@@ -485,16 +519,22 @@ class DecoderLM(Container):
             raise ValueError("neither experts nor a dense MLP: give "
                              "n_experts or mlp_size")
         if mlp_layer_types is None:
-            mlp_layer_types = ["sparse" if n_experts else "dense"] * len(
-                layer_types)
+            mlp_layer_types = [
+                "none" if kind == "experts"
+                else "sparse" if n_experts else "dense" for kind in layer_types]
         bad = [k for k in mlp_layer_types if k not in MLP_KINDS]
         if bad or len(mlp_layer_types) != len(layer_types):
             raise ValueError(f"mlp_layer_types {list(mlp_layer_types)}: one of "
                              f"{MLP_KINDS} for each of {len(layer_types)} layers")
-        if ("sparse" in mlp_layer_types and not n_experts) or (
+        routed = "sparse" in mlp_layer_types or "experts" in layer_types
+        if (routed and not n_experts) or (
                 "dense" in mlp_layer_types and not mlp_size):
-            raise ValueError("a 'sparse' layer needs n_experts, a 'dense' "
-                             "one mlp_size")
+            raise ValueError("a 'sparse' or 'experts' layer needs n_experts, "
+                             "a 'dense' one mlp_size")
+        if any(kind == "experts" and mlp != "none"
+               for kind, mlp in zip(layer_types, mlp_layer_types)):
+            raise ValueError("an 'experts' layer is the routed experts alone: "
+                             "its mlp_layer_types entry is 'none'")
         if mtp_modules not in (0, 1):
             raise ValueError(f"mtp_modules {mtp_modules}: 0 or 1")
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
@@ -507,8 +547,15 @@ class DecoderLM(Container):
         last_mamba = max((i for i, k in enumerate(layer_types) if k == "mamba"),
                          default=None)
 
+        def experts():
+            return RoutedExperts(n_experts, expert_size, experts_per_token,
+                                 experts_held=experts_held, init_std=init_std,
+                                 **(router or {}))
+
         def block(i, kind, mlp_kind):
-            if kind == "mamba":
+            if kind == "experts":
+                mixer = experts()
+            elif kind == "mamba":
                 mixer = Mamba2Mixer(**mamba, eps=eps, init_std=init_std,
                                     report_state=i == last_mamba)
             elif kind == "latent_attention":
@@ -522,10 +569,11 @@ class DecoderLM(Container):
                     else None,
                     rope=rope_parameters.get(kind), eps=eps,
                     init_std=init_std, qk_norm=qk_norm, scale=attention_scale)
-            ffn = RoutedExperts(n_experts, expert_size, experts_per_token,
-                                experts_held=experts_held, init_std=init_std,
-                                **(router or {})) \
-                if mlp_kind == "sparse" else GatedMLP(mlp_size, init_std)
+            if mlp_kind == "none":
+                return MixerBlock(mixer, eps=eps,
+                                  residual_multiplier=residual_multiplier)
+            ffn = experts() if mlp_kind == "sparse" \
+                else GatedMLP(mlp_size, init_std)
             return DecoderBlock(mixer, ffn, eps=eps,
                                 residual_multiplier=residual_multiplier)
 

@@ -260,7 +260,7 @@ class MoE(AbstractModule):
 
 # --------------------------------------------------------------------------
 # routed experts without capacity: the chip's share of an expert-parallel
-# layer (top-k softmax router, gated experts, grouped matrix products)
+# layer (top-k router, gated or relu2 experts, grouped matrix products)
 # --------------------------------------------------------------------------
 
 def _megablox():
@@ -273,10 +273,18 @@ def _megablox():
 
 
 def _tile(size: int, most: int = 1024) -> int:
-    """Largest multiple of 128 that divides ``size`` and is at most ``most``
-    (``size`` itself where none does)."""
-    fits = [t for t in range(128, min(size, most) + 1, 128) if size % t == 0]
-    return fits[-1] if fits else size
+    """Largest multiple of 128 that divides ``size`` and is at most ``most``.
+    Where none does (1856 = 14.5 x 128), the multiple of 128 whose tiles
+    overhang ``size`` least, the largest of those: the grouped kernel masks a
+    ragged last tile of the contracted axis and leaves a ragged last tile of
+    the columns to the block's bounds, and ``size`` itself as the one tile
+    does not fit the kernel's fast memory at 1856 (docs/performance.md has
+    the probe's table). Nothing is padded in the parameter tree."""
+    tiles = range(128, min(size, most) + 1, 128)
+    fits = [t for t in tiles if size % t == 0]
+    if fits or not tiles:
+        return fits[-1] if fits else size
+    return min(reversed(tiles), key=lambda t: -size % t)
 
 
 def _tiling(k: int, n: int):
@@ -415,12 +423,28 @@ def _pairs_bwd(res, g):
 _pairs_of_rows.defvjp(_pairs_fwd, _pairs_bwd)
 
 
-def _gated(xs, w_gate, w_up, w_down, sizes):
-    """The held experts over sorted rows: ``W_down(silu(W_gate x) * W_up
-    x)``, each row by its group's matrices."""
+FORMS = ("gated", "relu2")
+
+
+def _expert_weights(params):
+    """The held experts' matrices in the order ``_held_experts`` takes them:
+    (w_gate, w_up, w_down) of the gated form, (w_up, w_down) of ``relu2``."""
+    return tuple(params[k] for k in ("w_gate", "w_up", "w_down") if k in params)
+
+
+def _held_experts(xs, weights, sizes):
+    """The held experts over sorted rows, each row by its group's matrices.
+    Three matrices are the gated form, ``W_down(silu(W_gate x) * W_up x)``;
+    two the ungated ``relu2``, ``W_down relu(W_up x)^2``, the square taken of
+    the float32 product before the down product rounds it."""
     with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(grouped_dot(xs, w_gate, sizes)) \
-            * grouped_dot(xs, w_up, sizes)
+        if len(weights) == 3:
+            w_gate, w_up, w_down = weights
+            h = jax.nn.silu(grouped_dot(xs, w_gate, sizes)) \
+                * grouped_dot(xs, w_up, sizes)
+        else:
+            w_up, w_down = weights
+            h = jnp.square(jax.nn.relu(grouped_dot(xs, w_up, sizes)))
         return grouped_dot(h, w_down, sizes)
 
 
@@ -479,15 +503,16 @@ def _weights_bwd(res, g):
 _block_of_weights.defvjp(_weights_fwd, _weights_bwd)
 
 
-def _block_pass(acc, x, w_gate, w_up, w_down, top_p, first, pos, sizes, local):
+def _block_pass(acc, x, weights, top_p, first, pos, sizes, local):
     """``acc`` (T, D) float32 plus the held experts' part of the layer from
     one block of the sort: its pairs ``first`` (C,), their groups' ``sizes``
     inside the block, its first ``local`` rows those of held experts; x
-    (T, D) in the compute dtype. The products and the gate run over C rows."""
+    (T, D) in the compute dtype, ``weights`` as ``_held_experts`` takes them.
+    The products and what lies between them run over C rows."""
     with jax.named_scope("moe_route"):
         tokens = first // top_p.shape[1]
         xs = _block_of_tokens(x, tokens, local)
-    ys = _gated(xs, w_gate, w_up, w_down, sizes)                # (C, D)
+    ys = _held_experts(xs, weights, sizes)                      # (C, D)
     with jax.named_scope("moe_route"):
         # the select before the product: rows past ``local`` are unwritten
         weighted = _live_rows(ys, local) \
@@ -522,8 +547,7 @@ def _while_blocks(c: int, local, turn, start):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _block_by_block(c: int, x, w_gate, w_up, w_down, top_p, order, pos,
-                    group_sizes, local):
+def _block_by_block(c: int, x, weights, top_p, order, pos, group_sizes, local):
     """``_block_pass`` over the sorted rows in blocks of ``c``, as many as
     hold the ``local`` pairs of held experts: one where they fit the buffer
     (the sizing's case), more in a step whose routing overflows it, so that
@@ -534,10 +558,10 @@ def _block_by_block(c: int, x, w_gate, w_up, w_down, top_p, order, pos,
     size and the full one compiled both sets of kernels, and differentiated
     as it stands would have written the full-size residuals every step.)"""
     block = _blocks(c, order, pos, group_sizes, local)
-    floats = (x, w_gate, w_up, w_down, top_p)
+    floats = (x, weights, top_p)
     return _while_blocks(
         c, local, lambda i, acc: _block_pass(acc, *floats, *block(i)),
-        jnp.zeros((x.shape[0], w_down.shape[2]), jnp.float32))
+        jnp.zeros((x.shape[0], weights[-1].shape[2]), jnp.float32))
 
 
 def _block_by_block_fwd(c, *args):
@@ -606,7 +630,8 @@ def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int,
 
     Every (token, choice) pair is kept: the pairs are sorted by the slot of
     their expert among the experts held (pairs of absent experts last), the
-    three grouped products run over the held experts' rows, and each token
+    grouped products (three of the gated form, two of ``relu2``: what
+    ``params`` holds) run over the held experts' rows, and each token
     sums its k rows weighted by the router. No capacity, no drop. The rows
     between the two permutations live in a buffer of ``buffer_rows(T*k,
     n_held, n_experts)`` rows. Where all experts (or half of them and more)
@@ -634,17 +659,17 @@ def routed_experts(x, params, *, n_experts: int, experts_held, top_k: int,
             jnp.int32)
         local = jnp.sum(group_sizes)  # pairs that hit an expert held here
     xc = x.astype(precision.compute_dtype())
-    weights = params["w_gate"], params["w_up"], params["w_down"]
+    weights = _expert_weights(params)
     if c == pairs:  # a row for every pair: one pass, pairs gather their rows
         with jax.named_scope("moe_route"):
             xs = _rows_of_tokens(xc, order, pos, local, top_k)
-        ys = _gated(xs, *weights, group_sizes)                  # (T*k, D)
+        ys = _held_experts(xs, weights, group_sizes)            # (T*k, D)
         with jax.named_scope("moe_route"):
             rows = _pairs_of_rows(ys, order, pos, local).reshape(
                 x.shape[0], top_k, -1)
             out = jnp.sum(rows * top_p[..., None], axis=1)
     else:
-        out = _block_by_block(c, xc, *weights, top_p, order, pos, group_sizes,
+        out = _block_by_block(c, xc, weights, top_p, order, pos, group_sizes,
                               local)
     with jax.named_scope("moe_route"):
         blocks = -(-local // c)   # turns of the loop (one, where c == pairs)
@@ -665,12 +690,16 @@ SCORINGS = ("softmax", "sigmoid")
 
 
 class RoutedExperts(AbstractModule):
-    """Top-k routed, gated experts without capacity: ``(..., D) -> (..., D)``.
+    """Top-k routed experts without capacity: ``(..., D) -> (..., D)``.
 
     ``p = softmax(x W_r)`` over ``n_experts`` in float32; the ``top_k``
     largest, renormalised over the chosen; ``sum_e w_e W_down,e(silu(
     W_gate,e x) * W_up,e x)`` over the chosen experts THIS MODULE HOLDS
-    (``experts_held``: ids among ``range(n_experts)``, default all). Held
+    (``experts_held``: ids among ``range(n_experts)``, default all). That is
+    ``form="gated"``, three matrices an expert; ``form="relu2"`` is the
+    ungated ``W_down,e relu(W_up,e x)^2``, two matrices an expert (no
+    ``w_gate`` leaf, and the shared expert of the same form: ``shared_in``
+    (D, shared_size)), through the same paths. Held
     fewer than all, it is one chip's share of an expert-parallel layer run
     without its exchange: the router keeps its width, pairs routed to absent
     experts add nothing, and the shares of all chips sum to the whole layer
@@ -689,8 +718,8 @@ class RoutedExperts(AbstractModule):
     parameter (``selection_bias``: zero at the start, no gradient, no
     optimizer slot), and a training forward hands on ``b + rate * sign(mean
     count - count)`` from this step's counts over all ``n_experts``, as batch
-    norm hands on its running statistics. ``shared_size`` adds one gated MLP
-    of that width that every token passes, whole on every chip, to the routed
+    norm hands on its running statistics. ``shared_size`` adds one MLP of
+    the experts' form and that width that every token passes, whole on every chip, to the routed
     sum (scope ``moe_shared``); in the share test it counts once.
 
     State: ``{"_counters": {moe_pairs_local, moe_load_max_over_mean,
@@ -701,8 +730,10 @@ class RoutedExperts(AbstractModule):
                  experts_held=None, init_std: float = 0.02,
                  scoring: str = "softmax", routed_scaling: float = 1.0,
                  bias_update_rate: Optional[float] = None,
-                 shared_size: int = 0):
+                 shared_size: int = 0, form: str = "gated"):
         super().__init__()
+        if form not in FORMS:
+            raise ValueError(f"form {form!r}: one of {FORMS}")
         held = tuple(range(n_experts) if experts_held is None else experts_held)
         if not held or not all(0 <= e < n_experts for e in held) \
                 or len(set(held)) != len(held):
@@ -721,7 +752,7 @@ class RoutedExperts(AbstractModule):
         self.init_std = init_std
         self.scoring, self.routed_scaling = scoring, float(routed_scaling)
         self.bias_update_rate = bias_update_rate
-        self.shared_size = shared_size
+        self.shared_size, self.form = shared_size, form
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
@@ -731,10 +762,12 @@ class RoutedExperts(AbstractModule):
         ks = jax.random.split(rng, 4)
         normal = lambda k, shape: self.init_std * jax.random.normal(  # noqa: E731
             k, shape, jnp.float32)
-        params = {"router": normal(ks[0], (d, self.n_experts)),
-                  "w_gate": normal(ks[1], (e, d, f)),
-                  "w_up": normal(ks[2], (e, d, f)),
-                  "w_down": normal(ks[3], (e, f, d))}
+        gated = self.form == "gated"
+        params = {"router": normal(ks[0], (d, self.n_experts))}
+        if gated:
+            params["w_gate"] = normal(ks[1], (e, d, f))
+        params.update(w_up=normal(ks[2], (e, d, f)),
+                      w_down=normal(ks[3], (e, f, d)))
         zero = jnp.zeros((), jnp.float32)
         state = {"_counters": {
             "moe_pairs_local": zero, "moe_load_max_over_mean": zero,
@@ -742,7 +775,7 @@ class RoutedExperts(AbstractModule):
         if self.shared_size:
             k_in, k_out = jax.random.split(jax.random.fold_in(rng, 4))
             params.update(
-                shared_in=normal(k_in, (d, 2 * self.shared_size)),
+                shared_in=normal(k_in, (d, (1 + gated) * self.shared_size)),
                 shared_out=normal(k_out, (self.shared_size, d)))
         if self.bias_update_rate is not None:
             state["selection_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
@@ -770,8 +803,12 @@ class RoutedExperts(AbstractModule):
                 counters["moe_bias_abs_max"] = jnp.max(jnp.abs(bias))
         if self.shared_size:
             with jax.named_scope("moe_shared"):
-                a, b = jnp.split(
-                    precision.dot_acc32(tokens, params["shared_in"]), 2, axis=-1)
+                h = precision.dot_acc32(tokens, params["shared_in"])
+                if self.form == "gated":
+                    a, b = jnp.split(h, 2, axis=-1)
+                    h = jax.nn.silu(a) * b
+                else:
+                    h = jnp.square(jax.nn.relu(h))
                 out = out + precision.dot_acc32(
-                    jax.nn.silu(a) * b, params["shared_out"]).astype(x.dtype)
+                    h, params["shared_out"]).astype(x.dtype)
         return out.reshape(x.shape), new_state

@@ -1,19 +1,23 @@
 """The Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060): a state-space layer
 whose recurrence runs as a chunked scan (``ops/ssd.py``).
 
-``(N, T, D) -> (N, T, D)`` with ``d_inner = heads * head_dim``, one B/C group
-of ``state`` shared by all heads, no bias except the conv's::
+``(N, T, D) -> (N, T, D)`` with ``d_inner = heads * head_dim``, ``G =
+groups`` B/C groups of ``state`` (one shared by all heads, or several: head
+``h`` then reads group ``h // (heads / G)``), no bias except the conv's::
 
-    [z, xBC, dt] = split(h in_proj)         widths d_inner, d_inner + 2 state, heads
+    [z, xBC, dt] = split(h in_proj)         widths d_inner, d_inner + 2 G state, heads
     xBC = silu(conv1d(xBC))                 depthwise, causal, kernel k, with bias
-    [x, B, C] = split(xBC)                  widths d_inner, state, state
+    [x, B, C] = split(xBC)                  widths d_inner, G state, G state
     dt = softplus(dt + dt_bias),  A = -exp(A_log)           one scalar a head
-    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t,  y_t = S_t C_t + D x_t
-    out = RMSNorm(y * silu(z)) out_proj     gate first, then the norm over d_inner
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t[g],  y_t = S_t C_t[g] + D x_t
+    out = RMSNorm(y * silu(z)) out_proj     gate first, then the norm: its
+                                            statistic over each group's
+                                            d_inner / G channels
 
 Device time is attributed by ``jax.named_scope``: ``ssm_proj`` (both
-projections and the gated norm), ``ssm_conv``, ``ssm_scan`` (from dt to y,
-the D term included).
+projections and the gated norm), inside it ``ssm_gate_norm`` (the gate and the
+norm alone: elementwise passes, no product), ``ssm_conv``, ``ssm_scan`` (from
+dt to y, the D term included).
 """
 
 from __future__ import annotations
@@ -43,10 +47,12 @@ def causal_depthwise_conv(x, weight, bias):
 
 
 class Mamba2Mixer(AbstractModule):
-    """Args: ``heads`` x ``head_dim`` = d_inner; ``state``: N of the B/C
-    group; ``conv``: the causal conv's kernel; ``chunk``: the scan's chunk
-    (``mamba_chunk_size``); ``report_state``: also count ``ssm_state_rms``
-    (the model asks its last such layer).
+    """Args: ``heads`` x ``head_dim`` = d_inner; ``state``: N of a B/C
+    group; ``groups``: how many B/C groups (a divisor of ``heads``; also the
+    groups of the gated norm's statistic); ``conv``: the causal conv's
+    kernel; ``chunk``: the scan's chunk (``mamba_chunk_size``);
+    ``report_state``: also count ``ssm_state_rms`` (the model asks its last
+    such layer).
 
     Initialisation (the ``mamba_ssm`` Mamba-2 defaults): matrices
     N(0, ``init_std``); the conv's weight and bias U(+-1/sqrt(K));
@@ -58,10 +64,13 @@ class Mamba2Mixer(AbstractModule):
 
     def __init__(self, heads: int, head_dim: int, state: int, conv: int = 4,
                  chunk: int = 256, eps: float = 1e-5, init_std: float = 0.02,
-                 report_state: bool = False):
+                 report_state: bool = False, groups: int = 1):
         super().__init__()
+        if heads % groups:
+            raise ValueError(f"{heads} heads do not split into {groups} "
+                             "B/C groups")
         self.heads, self.head_dim, self.state = heads, head_dim, state
-        self.conv, self.chunk = conv, chunk
+        self.conv, self.chunk, self.groups = conv, chunk, groups
         self.init_std, self.report_state = init_std, report_state
         self._norm = RMSNorm(heads * head_dim, eps)  # statistics in float32
 
@@ -75,7 +84,7 @@ class Mamba2Mixer(AbstractModule):
     def _build(self, rng, in_spec):
         d_model = in_spec.shape[-1]
         d_inner, channels = self.heads * self.head_dim, \
-            self.heads * self.head_dim + 2 * self.state
+            self.heads * self.head_dim + 2 * self.groups * self.state
         ks = jax.random.split(rng, 6)
         normal = lambda k, shape: self.init_std * jax.random.normal(  # noqa: E731
             k, shape, jnp.float32)
@@ -104,24 +113,34 @@ class Mamba2Mixer(AbstractModule):
         from ..ops.ssd import ssd_scan
 
         n, t, _ = x.shape
-        d_inner = self.heads * self.head_dim
+        d_inner, groups = self.heads * self.head_dim, self.groups
+        bc = groups * self.state
         with jax.named_scope("ssm_proj"):
             z, xbc, dt = jnp.split(
                 precision.dot_acc32(x, params["in_proj"]),
-                [d_inner, 2 * d_inner + 2 * self.state], axis=-1)
+                [d_inner, 2 * d_inner + 2 * bc], axis=-1)
         with jax.named_scope("ssm_conv"):
             xbc = jax.nn.silu(causal_depthwise_conv(
                 xbc, params["conv_w"], params["conv_b"]))
         with jax.named_scope("ssm_scan"):
-            xs, b, c = jnp.split(xbc, [d_inner, d_inner + self.state], axis=-1)
+            xs, b, c = jnp.split(xbc, [d_inner, d_inner + bc], axis=-1)
             y, stats = ssd_scan(
                 xs.reshape(n, t, self.heads, self.head_dim),
                 jax.nn.softplus(dt + params["dt_bias"]),
-                -jnp.exp(params["A_log"]), b, c, params["D"], self.chunk)
+                -jnp.exp(params["A_log"]), b.reshape(n, t, groups, self.state),
+                c.reshape(n, t, groups, self.state), params["D"], self.chunk)
             y = y.reshape(n, t, d_inner)
         with jax.named_scope("ssm_proj"):
-            y = self._norm._apply({"weight": params["norm"]}, {},
-                                  y * jax.nn.silu(z), training, None)[0]
+            with jax.named_scope("ssm_gate_norm"):
+                y = y * jax.nn.silu(z)
+                if groups == 1:
+                    y = self._norm._apply({"weight": params["norm"]}, {}, y,
+                                          training, None)[0]
+                else:   # the statistic over each group's channels, float32
+                    yg = y.astype(jnp.float32).reshape(n, t, groups, -1)
+                    ms = jnp.mean(yg * yg, axis=-1, keepdims=True)
+                    y = ((yg * jax.lax.rsqrt(ms + self._norm.eps)).reshape(
+                        n, t, d_inner) * params["norm"]).astype(y.dtype)
             out = precision.dot_acc32(y, params["out_proj"]).astype(x.dtype)
         return out, self._counters(
             stats.log_decay_min,

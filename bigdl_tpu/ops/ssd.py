@@ -6,6 +6,11 @@ Per head, with a scalar decay a token::
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t        S is (P, N), S_0 = 0
     y_t = S_t C_t + D x_t
 
+B and C come in ``groups`` B/C groups of ``state`` each, (n, T, groups,
+state): head ``h`` of ``heads`` reads group ``h // (heads / groups)``, and
+product 1 below is a group's (one group shared by all heads is Mamba-2's
+default; a model with eight has eight ``C B^T`` a chunk).
+
 Run token by token that is T sequential steps. ``ssd_scan`` cuts the record
 into chunks of ``chunk`` tokens and computes the same values from four matrix
 products and one short scan over the chunks' states. With ``a = dt A <= 0``
@@ -27,7 +32,8 @@ the model (``utils/precision``).
 
 Two forms compute that, chosen from what the code can see and by no caller.
 On the TPU backend, where the shapes tile (``ssd_kernel.heads_per_step``: a
-chunk that is a multiple of 128, heads that fill whole groups of 128 lanes),
+chunk that is a multiple of 128, heads that fill whole groups of 128 lanes
+inside one B/C group),
 products 2, 3 and 4, the D term and every other pass over an array of the
 tokens' size are four Pallas kernels (``ops/ssd_kernel.py``: the chunks'
 states and the chunks' outputs, each forward and backward), in which ``L``
@@ -39,8 +45,9 @@ and autodiff would keep several arrays of its size, so product 2 runs over
 the heads in groups (``lax.map``), each group recomputed in the backward pass
 (``jax.checkpoint``): what is live is one group's ``L`` and what the backward
 keeps is the group's inputs (``head_group``, from the shapes). What a compiled
-step chose is in its telemetry ``compile`` record (``ssd_scans``: ``kernel``,
-and the heads a grid step or the head group).
+step chose is in its telemetry ``compile`` record (``ssd_scans``: ``groups``
+and the heads of one, ``kernel``, and the heads a grid step or the head
+group).
 
 ``ssd_sequential`` is the recurrence as written, one token at a time: the
 tests' and ``chip_smoke.py``'s yardstick, not a path of the model.
@@ -70,7 +77,8 @@ def take_scan_records(since: float = 0.0) -> list:
     """The scans traced at or after ``since`` (a ``time.perf_counter``
     reading): tokens, chunk, chunks a record, heads, whether the kernels ran
     (``kernel``) and their heads a grid step (``heads_per_step``) or the XLA
-    form's head group (``head_group``), one entry per distinct shape with the
+    form's head group (``head_group``), the B/C ``groups`` and the heads of
+    one (``group_heads``), one entry per distinct shape with the
     number of ``calls``. Forgets everything, as
     ``ops/flash_attention.take_tile_records`` does."""
     with _scan_records_lock:
@@ -86,12 +94,16 @@ def _record_scan(**record) -> None:
         _scan_records[key] = (time.perf_counter(), {**record, "calls": calls})
 
 
-def head_group(records: int, chunks: int, heads: int, chunk: int) -> int:
-    """Heads whose ``L`` is live at once: the largest divisor of ``heads``
-    whose float32 ``L`` fits ``_GROUP_BYTES`` (at least one head)."""
+def head_group(records: int, chunks: int, heads: int, chunk: int,
+               groups: int = 1) -> int:
+    """Heads whose ``L`` is live at once, the same number from each of the
+    ``groups`` B/C groups: the largest such divisor of ``heads`` whose float32
+    ``L`` fits ``_GROUP_BYTES`` (at least one head a group)."""
     per_head = records * chunks * chunk * chunk * 4
     fit = max(_GROUP_BYTES // per_head, 1)
-    return max(g for g in range(1, heads + 1) if heads % g == 0 and g <= fit)
+    return groups * max(g for g in range(1, heads // groups + 1)
+                        if (heads // groups) % g == 0
+                        and (g * groups <= fit or g == 1))
 
 
 class ScanStats(NamedTuple):
@@ -108,26 +120,27 @@ def _dot(spec: str, a, b):
 
 
 def _within_chunks(cb, cum, xdt, group: int):
-    """Products 1's result ``cb`` (n, c, l, s), running sums ``cum``
-    (n, c, h, l) and ``dt x`` (n, c, h, s, p) -> (n, c, h, l, p), the heads in
-    groups of ``group``."""
-    n, c, h, q = cum.shape
+    """Products 1's result ``cb`` (n, c, G, l, s), running sums ``cum``
+    (n, c, G, h, l) and ``dt x`` (n, c, G, h, s, p), ``h`` the heads of one of
+    the G B/C groups -> (n, c, G, h, l, p), ``group`` heads of each B/C group
+    at a time."""
+    n, c, bc, h, q = cum.shape
     p = xdt.shape[-1]
     seen = jnp.tril(jnp.ones((q, q), bool))
 
     @jax.checkpoint
-    def one_group(cb, cum_g, xdt_g):               # (n, c, g, q), (n, c, g, q, p)
+    def one_group(cb, cum_g, xdt_g):         # (n, c, G, g, q), (n, c, G, g, q, p)
         seg = cum_g[..., :, None] - cum_g[..., None, :]
         decay = jnp.exp(jnp.where(seen, seg, -jnp.inf))   # L: 0 above the diagonal
-        return _dot("ncgls,ncgsp->ncglp", cb[:, :, None] * decay, xdt_g)
+        return _dot("ncbgls,ncbgsp->ncbglp", cb[:, :, :, None] * decay, xdt_g)
 
     if group == h:
         return one_group(cb, cum, xdt)
     # group-major for lax.map, and back
-    cum = jnp.moveaxis(cum.reshape(n, c, h // group, group, q), 2, 0)
-    xdt = jnp.moveaxis(xdt.reshape(n, c, h // group, group, q, p), 2, 0)
+    cum = jnp.moveaxis(cum.reshape(n, c, bc, h // group, group, q), 3, 0)
+    xdt = jnp.moveaxis(xdt.reshape(n, c, bc, h // group, group, q, p), 3, 0)
     out = jax.lax.map(lambda args: one_group(cb, *args), (cum, xdt))
-    return jnp.moveaxis(out, 0, 2).reshape(n, c, h, q, p)
+    return jnp.moveaxis(out, 0, 3).reshape(n, c, bc, h, q, p)
 
 
 def _carry(decay, added):
@@ -147,18 +160,24 @@ def _carry(decay, added):
 def _chunks_xla(xdt, cum, b, c, cb, group: int):
     """The three products on arrays of the tokens' size and the carry between
     them in plain ``jax.numpy``: ``dt x`` (n, c q, h, p), running sums ``cum``
-    (n, c, h, q), b and c (n, c, q, s), ``cb`` product 1's result -> (y
-    without the D term (n, c q, h, p), the states entering the chunks, the
-    last state)."""
+    (n, c, h, q), b and c (n, c, G, q, s), ``cb`` product 1's result (n, c, G,
+    q, q), ``group`` of the h heads at a time -> (y without the D term (n,
+    c q, h, p), the states entering the chunks, the last state). Inside, the
+    heads are (G, h / G): each B/C group's own."""
     n, nc, h, q = cum.shape
+    bc, s = b.shape[2], b.shape[-1]
     p = xdt.shape[-1]
-    xdt = xdt.reshape(n, nc, q, h, p).transpose(0, 1, 3, 2, 4)   # (n, c, h, q, p)
-    y = _within_chunks(cb, cum, xdt, group)                       # product 2
+    xdt = xdt.reshape(n, nc, q, bc, h // bc, p).transpose(0, 1, 3, 4, 2, 5)
+    cum = cum.reshape(n, nc, bc, h // bc, q)
+    y = _within_chunks(cb, cum, xdt, group // bc)                 # product 2
     last = cum[..., -1:]
-    added = _dot("ncqs,nchqp->nchps", b, xdt * jnp.exp(last - cum)[..., None])
-    final, entering = _carry(jnp.exp(last)[..., None], added)     # (n, c, h, p, s)
-    y = y + _dot("ncqs,nchps->nchqp", c, entering) * jnp.exp(cum)[..., None]
-    return y.transpose(0, 1, 3, 2, 4).reshape(n, nc * q, h, p), entering, final
+    added = _dot("ncbqs,ncbhqp->ncbhps", b,
+                 xdt * jnp.exp(last - cum)[..., None])
+    final, entering = _carry(jnp.exp(last)[..., None], added)  # (n, c, G, h/G, p, s)
+    y = y + _dot("ncbqs,ncbhps->ncbhqp", c, entering) * jnp.exp(cum)[..., None]
+    y = y.reshape(n, nc, h, q, p).transpose(0, 1, 3, 2, 4)
+    return (y.reshape(n, nc * q, h, p), entering.reshape(n, nc, h, p, s),
+            final.reshape(n, h, p, s))
 
 
 def _chunks_kernels(x, dt, cum, b, c, cb, d, per_step: int, interpret: bool):
@@ -183,7 +202,8 @@ def _chunks_kernels(x, dt, cum, b, c, cb, d, per_step: int, interpret: bool):
 
 def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
     """x (n, T, H, P); dt (n, T, H) > 0 (after softplus); a (H,) < 0; b and c
-    (n, T, N), one group shared by all heads; d (H,) -> (y (n, T, H, P)
+    (n, T, G, N) in G B/C groups, head h reading group ``h // (H / G)`` (or
+    (n, T, N): one group shared by all heads); d (H,) -> (y (n, T, H, P)
     float32, ``ScanStats``). ``chunk`` is the model's (``mamba_chunk_size``);
     a record shorter than one chunk is one chunk, and a ragged last chunk is
     padded with tokens that leave the state as it is (dt = 0). On the TPU
@@ -191,7 +211,12 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
     is ``ops/ssd_kernel``'s; ``interpret=True`` runs those kernels through
     the Pallas interpreter wherever the shapes tile: the CPU tests' way in."""
     n, t, h, p = x.shape
-    s = b.shape[-1]
+    if b.ndim == 3:
+        b, c = b[:, :, None], c[:, :, None]
+    groups, s = b.shape[2:]
+    if h % groups:
+        raise ValueError(f"ssd_scan: {h} heads do not split into {groups} "
+                         "B/C groups")
     q = min(chunk, t)
     pad = -t % q
     x32, dt, d = x.astype(jnp.float32), dt.astype(jnp.float32), d.astype(jnp.float32)
@@ -201,24 +226,26 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
         x, dt, b, c = widen(x), widen(dt), widen(b), widen(c)
     nc = (t + pad) // q
     shape = dict(records=n, tokens=t, chunk=q, chunks=nc, heads=h, head_dim=p,
-                 state=s)
+                 state=s, groups=groups, group_heads=h // groups)
 
     # (n, c, h, q): inclusive running sums of the log decay inside a chunk
     cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(n, nc, q, h)
                      .transpose(0, 1, 3, 2), axis=-1)
-    b, c = b.reshape(n, nc, q, s), c.reshape(n, nc, q, s)
-    cb = _dot("ncls,ncts->nclt", c, b)                            # product 1
+    # (n, c, G, q, s): a B/C group's chunk is one block
+    b = b.reshape(n, nc, q, groups, s).transpose(0, 1, 3, 2, 4)
+    c = c.reshape(n, nc, q, groups, s).transpose(0, 1, 3, 2, 4)
+    cb = _dot("ncgls,ncgts->ncglt", c, b)                         # product 1
     per_step = None
     if interpret or jax.default_backend() == "tpu":
         per_step = ssd_kernel.heads_per_step(
-            h, p, q, s, precision.compute_dtype().itemsize)
+            h, p, q, s, precision.compute_dtype().itemsize, groups)
     if per_step:
         _record_scan(**shape, kernel=True, heads_per_step=per_step)
         y, entering, final = _chunks_kernels(x, dt, cum, b, c, cb, d,
                                              per_step, interpret)
         y = y[:, :t]
     else:
-        group = head_group(n, nc, h, q)
+        group = head_group(n, nc, h, q, groups)
         _record_scan(**shape, kernel=False, head_group=group)
         y, entering, final = _chunks_xla(x * dt[..., None], cum, b, c, cb, group)
         y = y[:, :t] + d[:, None] * x32
@@ -234,15 +261,21 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
 
 def ssd_sequential(x, dt, a, b, c, d, segment=None):
     """The recurrence one token at a time, in the inputs' own precision:
-    same arguments as ``ssd_scan`` without the chunk, -> y (n, T, H, P).
+    same arguments as ``ssd_scan`` without the chunk (b and c in their G B/C
+    groups or as one), -> y (n, T, H, P).
     With ``segment`` (a divisor of T) the tokens run in segments that the
     backward pass recomputes, so that its stored states are one segment's
     and not the record's (17 GB at 8192 tokens of a 64 x 64 x 128 state)."""
+    if b.ndim == 3:
+        b, c = b[:, :, None], c[:, :, None]
+    per_group = x.shape[2] // b.shape[2]
+
     def token(state, inputs):
-        x_t, dt_t, b_t, c_t = inputs               # (H, P), (H,), (N,), (N,)
+        x_t, dt_t, b_t, c_t = inputs               # (H, P), (H,), (G, N), (G, N)
+        b_t, c_t = (jnp.repeat(v, per_group, axis=0) for v in (b_t, c_t))
         state = state * jnp.exp(dt_t * a)[:, None, None] \
-            + (dt_t[:, None] * x_t)[:, :, None] * b_t
-        return state, state @ c_t + d[:, None] * x_t
+            + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+        return state, jnp.einsum("hpn,hn->hp", state, c_t) + d[:, None] * x_t
 
     def one_record(x, dt, b, c):
         zero = jnp.zeros(x.shape[1:] + (b.shape[-1],), x.dtype)

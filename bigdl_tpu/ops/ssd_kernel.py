@@ -12,7 +12,12 @@ kernel, with the short scan over the chunks' states between them (XLA's)::
 
 One grid step takes one chunk and ``heads_per_step`` heads: a block of
 (chunk, heads_per_step x head_dim), cut into lane groups of 128 (two heads of
-64). What is one number a head and token (dt, the running sums, w) arrives as
+64). B and C come in ``groups`` B/C groups, (n, chunks, groups, chunk, state),
+head ``h`` reading group ``h // (heads / groups)``: a step's heads lie inside
+one group, and its B, C and ``C B^T`` blocks are that group's (the index maps
+divide the head step by the steps a group). What is summed over heads (dB, dC,
+d(C B^T)) is summed over the head steps of ONE group: zeroed at the group's
+first step, written back when the next group's block takes its place. What is one number a head and token (dt, the running sums, w) arrives as
 rows of (heads, chunk) and is spread over the head's lanes inside the kernel:
 XLA has no cheap way to do that to an array whose lanes hold several heads.
 A chunk is cut into 128 x 128 blocks, of which only those at or below the
@@ -79,18 +84,21 @@ def _working_set(per_step: int, head_dim: int, chunk: int, state: int,
 
 
 def heads_per_step(heads: int, head_dim: int, chunk: int, state: int,
-                   itemsize: int) -> Optional[int]:
+                   itemsize: int, groups: int = 1) -> Optional[int]:
     """Heads a grid step takes, from the shapes: the most whose blocks fit
     ``_VMEM_BUDGET`` (each step costs ~0.35 us whatever it holds), among the
-    divisors of ``heads`` that tile: the step's heads fill whole groups of 128
+    divisors of the heads of one B/C group (``heads / groups``: a step reads
+    one group's B and C) that tile: the step's heads fill whole groups of 128
     lanes and, as rows of (heads, chunk), whole groups of 8 sublanes (or are
     all the heads). None where nothing tiles: the chunk is not a multiple of
     128, the state not of 8, the head size neither divides 128 nor is a
-    multiple of it. The caller then takes the XLA form."""
-    if chunk % _BLOCK or state % 8 or (_BLOCK % head_dim and head_dim % _BLOCK):
+    multiple of it, the groups do not divide the heads. The caller then takes
+    the XLA form."""
+    if chunk % _BLOCK or state % 8 or (_BLOCK % head_dim and head_dim % _BLOCK) \
+            or heads % groups:
         return None
-    fits = [g for g in range(1, heads + 1)
-            if heads % g == 0 and (g * head_dim) % _BLOCK == 0
+    fits = [g for g in range(1, heads // groups + 1)
+            if (heads // groups) % g == 0 and (g * head_dim) % _BLOCK == 0
             and (g % 8 == 0 or g == heads)
             and _working_set(g, head_dim, chunk, state, itemsize) <= _VMEM_BUDGET]
     return max(fits, default=None)
@@ -185,11 +193,11 @@ def _states_kernel(x_ref, w_ref, bt_ref, added_ref, *, head_dim: int):
 
 
 def _states_bwd_kernel(x_ref, w_ref, b_ref, da_ref, dx_ref, dw_ref, db_ref, *,
-                       head_dim: int):
+                       head_dim: int, steps: int):
     """b_ref (q, state) in the operands' dtype; da_ref (state, lanes) float32;
     dx_ref like x_ref, dw_ref like w_ref, db_ref (q, state) float32 summed
-    over the grid's head axis."""
-    @pl.when(pl.program_id(2) == 0)
+    over the ``steps`` head steps of its B/C group."""
+    @pl.when(pl.program_id(2) % steps == 0)
     def _first_heads():
         db_ref[...] = jnp.zeros_like(db_ref)
 
@@ -240,17 +248,17 @@ def _outputs_kernel(cb_ref, dt_ref, cum_ref, x_ref, c_ref, s_ref, d_ref, y_ref,
 
 def _outputs_bwd_kernel(cbt_ref, dt_ref, cum_ref, x_ref, c_ref, ct_ref, s_ref,
                         d_ref, dy_ref, dx_ref, ddt_ref, dcum_ref, dcbt_ref,
-                        dc_ref, ds_ref, dd_ref, *, head_dim: int):
+                        dc_ref, ds_ref, dd_ref, *, head_dim: int, steps: int):
     """The forward's tiles transposed, rows ``s`` and lanes ``t``, so that no
     product transposes a tile: cbt_ref (q, q) is ``(C B^T)^T``, ct_ref (state,
     q) is ``C^T``. Gradients like what they are of, all float32; dcbt_ref and
-    dc_ref summed over the grid's head axis; dd_ref (1, lanes): ``dY x``
-    summed over the chunk's tokens."""
+    dc_ref summed over the ``steps`` head steps of their B/C group; dd_ref (1,
+    lanes): ``dY x`` summed over the chunk's tokens."""
     q, width = x_ref.shape
     blocks = _blocks(q // _BLOCK)
     dtype = c_ref.dtype
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(pl.program_id(2) % steps == 0)
     def _first_heads():
         dcbt_ref[...] = jnp.zeros_like(dcbt_ref)
         dc_ref[...] = jnp.zeros_like(dc_ref)
@@ -316,25 +324,27 @@ def _outputs_bwd_kernel(cbt_ref, dt_ref, cum_ref, x_ref, c_ref, ct_ref, s_ref,
 
 # ------------------------------------------------------------------- the calls
 
-def _layouts(n, c, q, h, p, s, per_step):
+def _layouts(n, c, q, h, p, s, groups, per_step):
     """Each kind of array's (whole shape, block, index map) over the grid
     (records, chunks, head steps), for n records, c chunks of q tokens, h
-    heads of p, state s. Arrays with no head axis keep one block for every
-    head step."""
+    heads of p, state s, ``groups`` B/C groups. Arrays with no head axis keep
+    one block for every head step of a B/C group."""
     wide = per_step * p
+    steps = h // groups // per_step     # head steps a B/C group
+    of_group = lambda r, k, g: (r, k, g // steps, 0, 0)  # noqa: E731
     return dict(
         tokens=((n, c * q, h * p), (None, q, wide), lambda r, k, g: (r, k, g)),
         rows=((n, c, h, q), (None, None, per_step, q), lambda r, k, g: (r, k, g, 0)),
-        square=((n, c, q, q), (None, None, q, q), lambda r, k, g: (r, k, 0, 0)),
-        by_state=((n, c, q, s), (None, None, q, s), lambda r, k, g: (r, k, 0, 0)),
-        state_by=((n, c, s, q), (None, None, s, q), lambda r, k, g: (r, k, 0, 0)),
+        square=((n, c, groups, q, q), (None, None, None, q, q), of_group),
+        by_state=((n, c, groups, q, s), (None, None, None, q, s), of_group),
+        state_by=((n, c, groups, s, q), (None, None, None, s, q), of_group),
         states=((n, c, s, h * p), (None, None, s, wide), lambda r, k, g: (r, k, 0, g)),
         lane_row=((1, h * p), (1, wide), lambda r, k, g: (0, g)),
         chunk_row=((n, c, 1, h * p), (None, None, 1, wide), lambda r, k, g: (r, k, 0, g)))
 
 
 # name -> (kernel, its inputs' kinds, its outputs' kinds (all float32), whether
-# an output is summed over the head steps)
+# an output is summed over a B/C group's head steps)
 _KERNELS = {
     "ssd_states_fwd": (_states_kernel, ("tokens", "rows", "state_by"),
                        ("states",), False),
@@ -354,15 +364,19 @@ _KERNELS = {
 
 @partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _run(name: str, dims, per_step: int, interpret: bool, *args):
-    """The kernel ``name`` over the grid of ``dims`` = (n, c, q, h, p, s).
+    """The kernel ``name`` over the grid of ``dims`` = (n, c, q, h, p, s,
+    groups).
     Jitted, so that a model's layers share one trace and one lowering of each
     kernel: traced anew at every call, the four cost a step of nine layers
     under ``nn.Remat`` 20 s of set-up, each time it is lowered."""
     kernel, ins, outs, sequential_heads = _KERNELS[name]
-    n, c, q, h, p, s = dims
+    n, c, q, h, p, s, groups = dims
     kinds = _layouts(*dims, per_step)
+    sizes = dict(head_dim=p)
+    if sequential_heads:
+        sizes["steps"] = h // groups // per_step
     return pallas_call(
-        partial(kernel, head_dim=p), grid=(n, c, h // per_step),
+        partial(kernel, **sizes), grid=(n, c, h // per_step),
         in_specs=[pl.BlockSpec(*kinds[k][1:]) for k in ins],
         out_specs=[pl.BlockSpec(*kinds[k][1:]) for k in outs],
         out_shape=[jax.ShapeDtypeStruct(kinds[k][0], jnp.float32) for k in outs],
@@ -375,15 +389,15 @@ def _run(name: str, dims, per_step: int, interpret: bool, *args):
 
 
 def _dims(rows, x, by_state):
-    """(n, c, q, h, p, s) from an array of each kind."""
+    """(n, c, q, h, p, s, groups) from an array of each kind."""
     n, c, h, q = rows.shape
-    return n, c, q, h, x.shape[-1] // h, by_state.shape[-1]
+    return n, c, q, h, x.shape[-1] // h, by_state.shape[-1], by_state.shape[2]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def chunk_states(x, w, b, per_step: int, interpret: bool):
-    """x (n, T, h p) float32, w (n, c, h, q) float32, b (n, c, q, s) float32
-    -> (n, c, s, h p) float32: what each chunk adds to the state, ``B^T (w
+    """x (n, T, h p) float32, w (n, c, h, q) float32, b (n, c, g, q, s)
+    float32 -> (n, c, s, h p) float32: what each chunk adds to the state, ``B^T (w
     x)`` with ``w = dt exp(cum_last - cum)``."""
     return _states_fwd(x, w, b, per_step, interpret)[0]
 
@@ -391,7 +405,7 @@ def chunk_states(x, w, b, per_step: int, interpret: bool):
 def _states_fwd(x, w, b, per_step, interpret):
     b = precision.cast_compute(b)
     added, = _run("ssd_states_fwd", _dims(w, x, b), per_step, interpret,
-                  x, w, jnp.swapaxes(b, 2, 3))
+                  x, w, jnp.swapaxes(b, 3, 4))
     return added, (x, w, b)
 
 
@@ -406,8 +420,8 @@ chunk_states.defvjp(_states_fwd, _states_bwd)
 
 @partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def chunk_outputs(cb, dt, cum, x, c, entering, d, per_step: int, interpret: bool):
-    """``C B^T`` (n, c, q, q), dt and the running sums ``cum`` (n, c, h, q),
-    x (n, T, h p), c (n, c, q, s), the states ``entering`` the chunks (n, c,
+    """``C B^T`` (n, c, g, q, q), dt and the running sums ``cum`` (n, c, h, q),
+    x (n, T, h p), c (n, c, g, q, s), the states ``entering`` the chunks (n, c,
     s, h p), d (1, h p), all float32 -> y (n, T, h p) float32."""
     return _outputs_fwd(cb, dt, cum, x, c, entering, d, per_step, interpret)[0]
 
@@ -423,9 +437,9 @@ def _outputs_bwd(per_step, interpret, residuals, dy):
     cb, dt, cum, x, c, entering, d = residuals
     dx, ddt, dcum, dcbt, dc, ds, dd = _run(
         "ssd_outputs_bwd", _dims(cum, x, c), per_step, interpret,
-        jnp.swapaxes(cb, 2, 3), dt, cum, x, c, jnp.swapaxes(c, 2, 3), entering,
+        jnp.swapaxes(cb, 3, 4), dt, cum, x, c, jnp.swapaxes(c, 3, 4), entering,
         d, dy)
-    return (jnp.swapaxes(dcbt, 2, 3), ddt, dcum, dx, dc, ds,
+    return (jnp.swapaxes(dcbt, 3, 4), ddt, dcum, dx, dc, ds,
             jnp.sum(dd, axis=(0, 1)))
 
 

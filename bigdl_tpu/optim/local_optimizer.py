@@ -55,7 +55,7 @@ log = logging.getLogger("bigdl_tpu.optim")
 # shows, of whichever compile wrote the entry. The revision is part of the
 # step's program name, which IS in the key: bump it when the names change,
 # or a profile of a cached step reads as the older program.
-STEP_SCOPES_REV = "s1"
+STEP_SCOPES_REV = "s2"
 
 
 def step_program_name(fn):

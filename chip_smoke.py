@@ -442,10 +442,12 @@ def check_flash_remat(t: int, d: int, n: int = 2, heads: int = 4) -> None:
 
 
 def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
-                   n=1, kernel=True) -> None:
+                   n=1, kernel=True, groups=1) -> None:
     """The chunked state-space scan (ops/ssd.py, the Mamba-2 layers' path)
     against the recurrence one token at a time in float32, outputs and the
-    gradient of every input, at granite-4.0-h-micro's widths: decays from
+    gradient of every input, at granite-4.0-h-micro's widths (or, with
+    ``groups`` 8, chunk 128 and n 2, Nemotron-3-Nano's: B and C in 8 B/C
+    groups, head h reading group h // 8): decays from
     one to a thousand tokens, so the chunks' carried states matter. On the
     chip these shapes must take the Pallas kernels (ops/ssd_kernel.py;
     ``kernel``: what the scan's record has to say; off the chip every shape
@@ -462,11 +464,13 @@ def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
     a = -jnp.exp(jax.random.uniform(ks[0], (heads,), maxval=math.log(16.0)))
     rate = jnp.exp(jnp.linspace(math.log(1e-3), 0.0, heads))   # -dt a, a head
     dt = rate / -a * jnp.exp(0.3 * jax.random.normal(ks[1], (n, t, heads)))
-    b, c = (_normal(s, (n, t, state), jnp.float32) for s in (2, 3))
+    bc_shape = (n, t, state) if groups == 1 else (n, t, groups, state)
+    b, c = (_normal(s, bc_shape, jnp.float32) for s in (2, 3))
     d = 1.0 + 0.1 * jax.random.normal(ks[2], (heads,))
     cot = _normal(4, x.shape, jnp.float32)
     args = (x, dt, a, b, c, d)
-    what = f"ssd_scan T={t} {heads}x{head_dim} state {state} chunk {chunk}"
+    what = (f"ssd_scan N={n} T={t} {heads}x{head_dim} state {state} in "
+            f"{groups} B/C group(s) chunk {chunk}")
     ssd.take_scan_records()
     text, got = _fwd_bwd(lambda *v: ssd.ssd_scan(*v, chunk=chunk)[0], args, cot)
     record, = ssd.take_scan_records()
@@ -483,6 +487,71 @@ def check_ssd_scan(t=8192, heads=64, head_dim=64, state=128, chunk=256,
     _close(got, want, BF16_TOL, what)
     log(f"  {what}: fwd + 6 input gradients match the sequential recurrence "
         f"({record['chunks']} chunks, {how})")
+
+
+def check_relu2_tilings(rows=12288, hidden=2688, ffn=1856, held=8,
+                        tiles=(128, 256, 384, 512, 640, 896, 1024),
+                        reps=5) -> dict:
+    """The two grouped products of ``relu2`` experts whose width no multiple
+    of 128 divides (Nemotron-3-Nano's 2688 -> 1856 -> 2688 over the sized
+    buffer's 12,288 rows, 8 groups), forward and backward, under each tile
+    the grouped kernel may take for the ragged axis: each against
+    ``jax.lax.ragged_dot`` in float32, then timed. ``nn/moe._tile`` takes its
+    choice from this table (docs/performance.md). -> {tile: ms}."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn import moe
+
+    x = _normal(1, (rows, hidden), jnp.float32)
+    w_up = 0.02 * _normal(2, (held, hidden, ffn), jnp.float32)
+    w_down = 0.02 * _normal(3, (held, ffn, hidden), jnp.float32)
+    cot = _normal(4, (rows, hidden), jnp.float32)
+    # uneven groups that leave the buffer's last rows empty, as a step does
+    sizes = jnp.asarray([(i + 1) * rows // (held * (held + 1) // 2) * 7 // 8
+                         for i in range(held)], jnp.int32)
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+
+    def layer(dot):
+        # rows past the groups' total are unwritten memory from the kernel,
+        # forward (the output) and backward (dx): selected away on both sides
+        def fn(x, w_up, w_down):
+            x = jnp.where(live, x, 0.0)
+            h = jnp.square(jax.nn.relu(dot(x, w_up, sizes)))
+            return jnp.where(live, dot(jnp.where(live, h, 0.0), w_down, sizes),
+                             0.0)
+        return fn
+
+    _, want = _fwd_bwd(layer(lambda a, b, g: jax.lax.ragged_dot(
+        a, b, g, precision=jax.lax.Precision.HIGHEST)), (x, w_up, w_down),
+        jnp.where(live, cot, 0.0))
+    rule, plain, table = moe._tile(ffn), moe._tile, {}
+    for tile in tiles:
+        moe._tile = lambda size, most=1024, t=tile: (
+            t if size == ffn else plain(size, most))
+        what = f"relu2 grouped products {rows}x{hidden}->{ffn}, tile {tile}"
+        try:
+            run = jax.jit(lambda *a: jax.vjp(layer(moe.grouped_dot), *a)[1](
+                jnp.where(live, cot, 0.0)))
+            got = (layer(moe.grouped_dot)(x, w_up, w_down),
+                   run(x, w_up, w_down))
+            _close(got, want, BF16_TOL, what)
+            jax.block_until_ready(run(x, w_up, w_down))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = run(x, w_up, w_down)
+            jax.block_until_ready(out)
+            table[tile] = (time.perf_counter() - t0) / reps * 1e3
+            log(f"  {what}: matches ragged_dot, forward + backward "
+                f"{table[tile]:.2f} ms{' (the rule)' if tile == rule else ''}")
+        except Exception as e:  # noqa: BLE001 - a tile Mosaic refuses is a row
+            table[tile] = None
+            log(f"  {what}: refused: {str(e)[:200]}")
+        finally:
+            moe._tile = plain
+    return table
 
 
 def check_routed_experts(tokens=16384, hidden=2048, ffn=768, n_experts=256,
@@ -685,7 +754,10 @@ def phase_kernels() -> None:
     check_ssd_scan()
     # a chunk of 192 is no multiple of 128: this one must fall back
     check_ssd_scan(t=1536, heads=8, chunk=192, kernel=False)
+    # Nemotron-3-Nano's scan: 8 B/C groups, a group's 8 heads a grid step
+    check_ssd_scan(chunk=128, n=2, groups=8)
     check_routed_experts()
+    check_relu2_tilings(tiles=(512, 640))
     # hidden 2048 (the LM widths the roadmap names) and ResNet-50's
     # res2 conv epilogue (b128: 128x256x56x56)
     check_fused(rows=4096, hidden=2048, conv_shape=(128, 256, 56, 56))

@@ -73,8 +73,12 @@ def test_chip_smoke_phases_rehearse_at_tiny_size(tmp_path, monkeypatch):  # not 
         chip_smoke.check_flash_remat(t=128, d=16, n=1, heads=2)
         chip_smoke.check_ssd_scan(t=48, heads=4, head_dim=8, state=16,
                                   chunk=16, n=2)
+        chip_smoke.check_ssd_scan(t=48, heads=8, head_dim=8, state=16,
+                                  chunk=16, n=2, groups=4)
         chip_smoke.check_routed_experts(tokens=1024, hidden=32, ffn=16,
                                         n_experts=16, n_held=2, top_k=2)
+        assert all(chip_smoke.check_relu2_tilings(
+            rows=256, hidden=32, ffn=24, held=4, tiles=(8, 24), reps=1).values())
         model, rec = chip_smoke.phase_train(
             depth=18, classes=10, image=32, batch=4, iters=6)
         assert rec["compile_s"] > 0

@@ -124,8 +124,8 @@ def test_heads_in_groups_give_what_all_heads_at_once_give(monkeypatch):
     np.testing.assert_allclose(g_grouped, g_whole, atol=2e-5)
     record, = ssd.take_scan_records()
     assert record == dict(records=2, tokens=32, chunk=8, chunks=4, heads=8,
-                          head_dim=8, state=16, kernel=False, head_group=2,
-                          calls=2)
+                          head_dim=8, state=16, groups=1, group_heads=8,
+                          kernel=False, head_group=2, calls=2)
     assert ssd.take_scan_records() == []
 
 

@@ -100,7 +100,7 @@ def test_scan_statistics_are_the_same_on_both_paths(scans):
 def test_the_record_says_which_form_ran(scans):
     n, t, chunk, h, p = scans["shape"]
     shape = dict(records=n, tokens=t, chunk=chunk, chunks=-(-t // chunk), heads=h,
-                 head_dim=p, state=16)
+                 head_dim=p, state=16, groups=1, group_heads=h)
     kernel, = scans["records"]
     assert kernel == dict(shape, kernel=True, heads_per_step=h, calls=2)
     # the CPU backend without ``interpret`` takes the XLA form of any shape
@@ -176,17 +176,24 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+# (records, chunks, chunk, heads, head size, state, B/C groups, heads a step)
+CELL_SCANS = {"one_group": (1, 32, 256, 64, 64, 128, 1, 16),    # granite's cell
+              "eight_groups": (2, 64, 128, 64, 64, 128, 8, 8)}  # nemotron's
+
+
+@pytest.mark.parametrize("cell", list(CELL_SCANS))
 @pytest.mark.parametrize("name", ["states", "states_bwd", "outputs", "outputs_bwd"])
 def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, no_compile_cache,
-                                                       name):
-    n, nc, q, h, p, s = 1, 32, 256, 64, 64, 128
-    per_step = ssd_kernel.heads_per_step(h, p, q, s, 2)
+                                                       name, cell):
+    n, nc, q, h, p, s, g, want = CELL_SCANS[cell]
+    per_step = ssd_kernel.heads_per_step(h, p, q, s, 2, g)
+    assert per_step == want
 
     def spec(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    x, rows, bc = spec(n, nc * q, h * p), spec(n, nc, h, q), spec(n, nc, q, s)
-    cb, entering, d = spec(n, nc, q, q), spec(n, nc, s, h * p), spec(1, h * p)
+    x, rows, bc = spec(n, nc * q, h * p), spec(n, nc, h, q), spec(n, nc, g, q, s)
+    cb, entering, d = spec(n, nc, g, q, q), spec(n, nc, s, h * p), spec(1, h * p)
     states = lambda *a: ssd_kernel.chunk_states(*a, per_step, False)  # noqa: E731
     outputs = lambda *a: ssd_kernel.chunk_outputs(*a, per_step, False)  # noqa: E731
     fn, args = {
