@@ -2,9 +2,10 @@
 next one is a dict and not a class.
 
 ``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses.
-Four families so far, told apart by their own keys (the cuts to one chip are
-``benchmark/configs/mellum2_12b.json``, ``granite_4_0_h_micro.json``,
-``joyai_llm_flash.json`` and ``nemotron_3_nano_30b_a3b.json``)::
+Four families so far, told apart by their own keys, the first in two key sets
+(the cuts to one chip are ``benchmark/configs/mellum2_12b.json``,
+``laguna_s_2_1.json``, ``granite_4_0_h_micro.json``, ``joyai_llm_flash.json``
+and ``nemotron_3_nano_30b_a3b.json``)::
 
     vocab_size, hidden_size, num_hidden_layers, layer_types,
     num_attention_heads, num_key_value_heads, head_dim, rms_norm_eps
@@ -12,6 +13,18 @@ Four families so far, told apart by their own keys (the cuts to one chip are
     sparse, windowed (Mellum2-12B-A2.5B-Instruct):
     sliding_window, rope_parameters, num_experts, num_experts_per_tok,
     moe_intermediate_size, norm_topk_prob, mlp_layer_types
+
+    the same with gated attention, a dense leading layer and a shared expert
+    (Laguna-S-2.1; Qwen3-MoE's and Qwen3-Next's keys beside its own):
+    num_attention_heads_per_layer (query heads layer by layer), gating
+    ("per-head") and gating_types ("per_head" each: one sigmoid a head and
+    token on the attention's output), rope_parameters' partial_rotary_factor
+    (the rotated share of each head), intermediate_size, mlp_only_layers and
+    decoder_sparse_step (the dense layers, which mlp_layer_types states
+    too), shared_expert_intermediate_size (a shared expert behind a sigmoid
+    gate), moe_routed_scaling_factor (the softmax router's chosen weights
+    times it); moe_router_logit_softcapping 0 and
+    moe_apply_router_weight_on_input false, the only values built
 
     state-space hybrid (granite-4.0-h-micro; ``layer_types`` of ``mamba`` and
     ``attention``, ``num_local_experts`` 0 so the gated MLP is the whole
@@ -60,7 +73,8 @@ defaults to ``hidden_size / num_attention_heads``. A model with
 ``num_nextn_predict_layers`` 1 returns ``Table(logits, logits_1)`` and
 trains under ``nn.MultiTokenCrossEntropyCriterion``.
 
-``decoder_lm_reference`` (attention + routed experts),
+``decoder_lm_reference`` (attention, gated or not, + routed experts or a
+dense layer),
 ``hybrid_lm_reference`` (state-space + attention, dense MLP),
 ``latent_moe_lm_reference`` (latent attention, dense and sparse layers, the
 multi-token-prediction module) and ``nemotron_h_lm_reference`` (one mixer a
@@ -125,8 +139,10 @@ def experts_held(config: Dict):
     return tuple(config.get("experts_held", range(router_width(config))))
 
 
-# of a config's mlp_layer_types; no such key: one dense gated MLP a layer
-CONFIG_MLP_KINDS = ("sparse",)
+# of the first family's mlp_layer_types (first_family_mlp_kinds)
+CONFIG_MLP_KINDS = ("dense", "sparse")
+# the attention output gates built: gating's value, gating_types' entries
+GATINGS, GATING_TYPES = ("per-head",), ("per_head",)
 
 
 def is_hybrid(config: Dict) -> bool:
@@ -280,6 +296,75 @@ def _one_mixer(config: Dict) -> nn.DecoderLM:
             * int(config.get("n_shared_experts", 0))))
 
 
+def first_family_mlp_kinds(config: Dict):
+    """The first family's feed-forward, layer by layer: Qwen3-MoE's rule (a
+    layer is sparse unless ``mlp_only_layers`` names it or ``(i + 1) %
+    decoder_sparse_step`` is not 0), which ``mlp_layer_types``, where given,
+    has to state as well."""
+    n = int(config["num_hidden_layers"])
+    only = set(config.get("mlp_only_layers") or ())
+    step = int(config.get("decoder_sparse_step", 1))
+    kinds = ["sparse" if i not in only and (i + 1) % step == 0 else "dense"
+             for i in range(n)]
+    given = list(config.get("mlp_layer_types", kinds))[:n]
+    bad = sorted(set(given) - set(CONFIG_MLP_KINDS))
+    if bad:
+        raise ValueError(f"decoder_lm.from_config: mlp_layer_types {bad}; "
+                         f"accepts {CONFIG_MLP_KINDS}, or no such key and "
+                         "num_local_experts 0 for one dense gated MLP a layer")
+    if given != kinds:
+        raise ValueError(
+            f"decoder_lm.from_config: mlp_layer_types {given} is not what "
+            f"mlp_only_layers {sorted(only)} and decoder_sparse_step {step} "
+            f"give ({kinds})")
+    return kinds
+
+
+def attention_gated(config: Dict) -> bool:
+    """Whether the first family's attention layers gate their heads' outputs
+    (``gating`` ``per-head``, every ``gating_types`` entry ``per_head``)."""
+    gating = _accepts(config, "gating", (None,) + GATINGS)
+    types = list(config.get("gating_types") or ())[
+        :int(config["num_hidden_layers"])]
+    if types and (gating is None or set(types) - set(GATING_TYPES)):
+        raise ValueError(
+            f"decoder_lm.from_config: gating {gating!r}, gating_types "
+            f"{sorted(set(types))}; accepts gating {GATINGS[0]!r} with "
+            f"gating_types {GATING_TYPES[0]!r} (one sigmoid a head), or "
+            "neither")
+    return gating is not None
+
+
+def layer_heads(config: Dict):
+    """Query heads layer by layer (``num_attention_heads_per_layer``, or
+    ``num_attention_heads`` in every layer)."""
+    n = int(config["num_hidden_layers"])
+    heads = config.get("num_attention_heads_per_layer")
+    if heads is None:
+        return [int(config["num_attention_heads"])] * n
+    if len(heads) < n:
+        raise ValueError(f"decoder_lm.from_config: num_attention_heads_per_"
+                         f"layer names {len(heads)} layers, num_hidden_layers "
+                         f"is {n}")
+    return [int(h) for h in heads[:n]]
+
+
+def _first_family_moe(config: Dict) -> Dict:
+    """The router's further arguments: the routed sum's scale and a shared
+    expert behind its sigmoid gate (Qwen2-MoE's and Qwen3-Next's, whose keys
+    these are)."""
+    _accepts(config, "moe_router_logit_softcapping", (0, None), 0)
+    _accepts(config, "moe_apply_router_weight_on_input", (False,), False)
+    router = {}
+    scaling = float(config.get("moe_routed_scaling_factor", 1.0))
+    if scaling != 1.0:
+        router["routed_scaling"] = scaling
+    shared = int(config.get("shared_expert_intermediate_size", 0))
+    if shared:
+        router.update(shared_size=shared, shared_gate=True)
+    return router
+
+
 def from_config(config: Dict) -> nn.DecoderLM:
     """The ``nn.DecoderLM`` that ``config`` describes (not yet built: the
     optimizer builds it from the first batch, or call ``build``)."""
@@ -292,12 +377,6 @@ def from_config(config: Dict) -> nn.DecoderLM:
     if bad:
         raise ValueError(f"decoder_lm.from_config: layer_types {bad}; accepts "
                          f"{nn.decoder.LAYER_KINDS}")
-    bad = sorted(set(config.get("mlp_layer_types", [])[:len(kinds)])
-                 - set(CONFIG_MLP_KINDS))
-    if bad:
-        raise ValueError(f"decoder_lm.from_config: mlp_layer_types {bad}; "
-                         f"accepts {CONFIG_MLP_KINDS}, or no such key and "
-                         "num_local_experts 0 for one dense gated MLP a layer")
     common = dict(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
@@ -309,6 +388,11 @@ def from_config(config: Dict) -> nn.DecoderLM:
         init_std=float(config.get("initializer_range", 0.02)),
     )
     if is_hybrid(config):
+        if "mlp_layer_types" in config:
+            raise ValueError(
+                f"decoder_lm.from_config: mlp_layer_types "
+                f"{config['mlp_layer_types']}; a state-space hybrid takes no "
+                "such key (one dense gated MLP a layer)")
         if int(config.get("num_local_experts", 0)):
             raise ValueError("decoder_lm.from_config: a state-space hybrid "
                              "with routed experts (num_local_experts > 0) is "
@@ -330,6 +414,10 @@ def from_config(config: Dict) -> nn.DecoderLM:
     if not config.get("norm_topk_prob", True):
         raise ValueError("decoder_lm.from_config: the router's chosen "
                          "probabilities are renormalised (norm_topk_prob)")
+    if config.get("partial_rotary_factor", 1) != 1:
+        raise ValueError("decoder_lm.from_config: partial_rotary_factor is "
+                         "read from each rope_parameters entry")
+    mlp_kinds = first_family_mlp_kinds(config)
     return nn.DecoderLM(
         **common,
         sliding_window=int(config["sliding_window"]),
@@ -338,6 +426,12 @@ def from_config(config: Dict) -> nn.DecoderLM:
         experts_per_token=int(config["num_experts_per_tok"]),
         expert_size=int(config["moe_intermediate_size"]),
         experts_held=experts_held(config),
+        mlp_layer_types=mlp_kinds,
+        mlp_size=int(config["intermediate_size"]) if "dense" in mlp_kinds
+        else 0,
+        layer_heads=layer_heads(config),
+        attention_gate=attention_gated(config),
+        router=_first_family_moe(config),
     )
 
 
@@ -378,6 +472,12 @@ def reference_config(config: Dict) -> Dict:
     out = {k: config[k] for k in keys}
     out["layer_types"] = layer_types(config)
     out["experts_held"] = experts_held(config)
+    out["layer_heads"] = layer_heads(config)
+    out["mlp_layer_types"] = first_family_mlp_kinds(config)
+    out["attention_gate"] = attention_gated(config)
+    moe = _first_family_moe(config)
+    out["routed_scaling"] = moe.get("routed_scaling", 1.0)
+    out["shared_expert_gate"] = moe.get("shared_gate", False)
     return out
 
 

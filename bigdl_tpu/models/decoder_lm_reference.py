@@ -5,20 +5,34 @@ dense masked attention, a loop over the experts held with a mask.
 
 The equations (sizes from the catalog-style config dict, see ``decoder_lm``):
 
-* block: ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``; after the
-  last block a final RMSNorm, then ``logits = y @ W_head``.
-  ``RMSNorm(x) = x * rsqrt(mean(x^2) + eps) * g``.
-* attention: ``q = x W_q``, ``k = x W_k``, ``v = x W_v``; per-head RMSNorm
-  with a learned gain on q and on k before RoPE; RoPE rotates the pairs
-  ``(i, i + d/2)`` by ``pos * inv_freq_i`` and multiplies cos and sin by a
-  factor; query head ``h`` reads K/V head ``h // (Hq / Hkv)``; scores
-  ``q k^T / sqrt(d)``; query ``i`` sees key ``j`` iff ``j <= i`` and, on a
-  sliding layer, ``i - j < window``.
+* block: ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, the FFN
+  ``MoE`` on a sparse layer and ``SwiGLU`` on a dense one
+  (``cfg["mlp_layer_types"]``, default all sparse); after the last block a
+  final RMSNorm, then ``logits = y @ W_head``.
+  ``RMSNorm(x) = x * rsqrt(mean(x^2) + eps) * g``;
+  ``SwiGLU(x) = (silu(a) * b) W_out`` with ``[a, b] = x W_in``.
+* attention, layer l with ``Hq_l`` query heads (``cfg["layer_heads"]``,
+  default ``num_attention_heads`` in every layer): ``q = x W_q``,
+  ``k = x W_k``, ``v = x W_v``; per-head RMSNorm with a learned gain on q
+  and on k before RoPE; RoPE rotates the pairs ``(i, i + r/2)`` of the first
+  ``r = partial_rotary_factor * d`` features (default all) by ``pos *
+  inv_freq_i``, frequencies reckoned over ``r``, multiplies cos and sin by a
+  factor and passes features ``r..d`` through untouched; query head ``h``
+  reads K/V head ``h // (Hq_l / Hkv)``; scores ``q k^T / sqrt(d)``; query
+  ``i`` sees key ``j`` iff ``j <= i`` and, on a sliding layer,
+  ``i - j < window``. With ``cfg["attention_gate"]`` each head's output is
+  multiplied by ``g = sigmoid(x W_g)`` (one scalar a token and head, x the
+  normed input of q, k and v) before ``W_o``; ``attn_gate_mean`` is the mean
+  of g over tokens, heads and layers.
 * experts: ``p = softmax(x W_r)`` over all experts; the k largest;
-  ``w_e = p_e / sum_chosen p`` (``norm_topk_prob``);
-  ``MoE(x) = sum_{e chosen and held} w_e W_down,e(silu(W_gate,e x) * W_up,e x)``.
-  Pairs routed to experts that are not held contribute nothing: the chip's
-  share of an expert-parallel layer, without its exchange.
+  ``w_e = s p_e / sum_chosen p`` (``norm_topk_prob``, ``s`` the
+  ``routed_scaling``, default 1);
+  ``MoE(x) = sum_{e chosen and held} w_e W_down,e(silu(W_gate,e x) * W_up,e x)``
+  plus, where the layer has one, the shared expert
+  ``SwiGLU_shared(x)``, times ``sigmoid(x w_s)`` with
+  ``cfg["shared_expert_gate"]``. Pairs routed to experts that are not held
+  contribute nothing: the chip's share of an expert-parallel layer, without
+  its exchange.
 * loss: mean over positions of the cross-entropy of ``logits[t]`` against
   ``labels[t]`` (the caller shifts: the label of position t is token t + 1).
 
@@ -31,6 +45,18 @@ Departures from the published description of the first model built on this
 * the "MTP head" its model card mentions has no key in the config and is
   left out: the config wins.
 
+and of the second (Laguna-S-2.1), each **assumed** where its config is
+silent, its key set being Qwen3-MoE's and Qwen3-Next's:
+
+* the router's scores are a softmax;
+* the per-head q/k RMSNorm, as above;
+* the shared expert passes a sigmoid gate of one scalar a token, as in both
+  families that name ``shared_expert_intermediate_size``;
+* the attention gate reads the normed x that feeds q, k and v;
+* the partial rotary's YaRN frequencies are reckoned over the rotated 64
+  features, as the transformers library does for a partial rotary, and the
+  attention factor scales the rotated features alone.
+
 Parameters, one float32 array each::
 
     {"embed": (V, D), "final_norm": (D,), "head": (D, V),
@@ -39,6 +65,11 @@ Parameters, one float32 array each::
                  "wo": (Hq*d, D), "ln2": (D,), "router": (D, E),
                  "w_gate": (E_held, D, F), "w_up": (E_held, D, F),
                  "w_down": (E_held, F, D)}, ...]}
+
+and where the model has them: ``"wg": (D, Hq)`` (the attention gate);
+``"shared_in": (D, 2 F_s)``, ``"shared_out": (F_s, D)``, ``"shared_gate":
+(D, 1)`` (the shared expert); on a dense layer ``"w_in": (D, 2 F_d)``,
+``"w_out": (F_d, D)`` in place of the router and the experts.
 
 Callers on a TPU wrap calls in ``jax.default_matmul_precision("highest")``:
 a float32 matrix product otherwise runs in one bf16 pass there.
@@ -125,8 +156,13 @@ def rms_norm(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def rotate(x, inv_freq, factor):
-    """x (heads, T, d): pairs (i, i + d/2) rotated by pos * inv_freq_i."""
+def rotate(x, inv_freq, factor, rotary_dim=None):
+    """x (heads, T, d): pairs (i, i + r/2) of the first ``rotary_dim`` = r
+    features (default all) rotated by pos * inv_freq_i, the rest as they
+    are."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate([rotate(x[..., :rotary_dim], inv_freq, factor),
+                                x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
     ang = jnp.arange(x.shape[-2], dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
@@ -161,15 +197,24 @@ def attention(q, k, v, window, block_q, operands=None):
     return jnp.moveaxis(out, 0, 1).reshape(hq, t, d)
 
 
+def swiglu(x, w_in, w_out, operands=None):
+    """``(silu(a) * b) W_out`` with ``[a, b] = x W_in``: x (T, D) -> (T, D)."""
+    a, b = jnp.split(product("td,df->tf", x, w_in, operands), 2, axis=-1)
+    return product("tf,fd->td", jax.nn.silu(a) * b, w_out, operands)
+
+
 def experts(x, lp, cfg):
     """x (T, D) -> (this share's part of the expert layer's result (T, D),
     routed pairs per held expert (E_held,)). A loop over the experts held,
     each over ALL tokens with a mask; ``lax.scan`` and not a Python loop only
-    so that the TPU compiles one expert's body, not sixteen."""
+    so that the TPU compiles one expert's body, not sixteen. The shared
+    expert, where the layer has one, is every share's alike."""
     operands = cfg.get("operands")
     p = jax.nn.softmax(x @ lp["router"], axis=-1)
     top_p, top_e = jax.lax.top_k(p, cfg["num_experts_per_tok"])
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if cfg.get("routed_scaling", 1.0) != 1.0:
+        top_p = top_p * cfg["routed_scaling"]
 
     def one_expert(out, expert):
         e, w_gate, w_up, w_down = expert
@@ -181,20 +226,31 @@ def experts(x, lp, cfg):
                 jnp.sum(chosen))
 
     held = jnp.asarray(cfg["experts_held"], top_e.dtype)
-    return jax.lax.scan(one_expert, jnp.zeros_like(x),
-                        (held, lp["w_gate"], lp["w_up"], lp["w_down"]))
+    out, counts = jax.lax.scan(one_expert, jnp.zeros_like(x),
+                               (held, lp["w_gate"], lp["w_up"], lp["w_down"]))
+    if "shared_in" in lp:
+        shared = swiglu(x, lp["shared_in"], lp["shared_out"], operands)
+        if cfg.get("shared_expert_gate"):
+            shared = shared * jax.nn.sigmoid(
+                product("td,de->te", x, lp["shared_gate"], operands))
+        out = out + shared
+    return out, counts
 
 
-def layer(x, lp, cfg, kind: str, block_q: int):
-    """One block over one record: x (T, D) -> (y (T, D), routed pairs per
-    held expert (E_held,))."""
+def layer(x, lp, cfg, kind: str, block_q: int, hq=None, mlp="sparse"):
+    """One block over one record with ``hq`` query heads (default
+    ``num_attention_heads``) and a ``"sparse"`` or ``"dense"`` feed-forward:
+    x (T, D) -> (y (T, D), routed pairs per held expert (E_held,) or None,
+    the attention gate's mean or None)."""
     eps = cfg["rms_norm_eps"]
-    hq, hkv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
+    hkv, d = cfg["num_key_value_heads"], cfg["head_dim"]
+    hq = cfg["num_attention_heads"] if hq is None else hq
     t = x.shape[0]
     project = functools.partial(product, "td,de->te",
                                 operands=cfg.get("operands"))
-    inv_freq, factor = rope_inv_freq(cfg["rope_parameters"][kind], d)
+    rope = cfg["rope_parameters"][kind]
+    r = round(d * float(rope.get("partial_rotary_factor", 1)))
+    inv_freq, factor = rope_inv_freq(rope, r)
     window = cfg["sliding_window"] if kind == "sliding_attention" else None
     y = rms_norm(x, lp["ln1"], eps)
     q = project(y, lp["wq"]).reshape(t, hq, d).transpose(1, 0, 2)
@@ -202,66 +258,91 @@ def layer(x, lp, cfg, kind: str, block_q: int):
     v = project(y, lp["wv"]).reshape(t, hkv, d).transpose(1, 0, 2)
     # assumed, see the module docstring
     q, k = rms_norm(q, lp["q_norm"], eps), rms_norm(k, lp["k_norm"], eps)
-    q, k = rotate(q, inv_freq, factor), rotate(k, inv_freq, factor)
+    q, k = rotate(q, inv_freq, factor, r), rotate(k, inv_freq, factor, r)
     a = attention(q, k, v, window, block_q, cfg.get("operands"))
-    x = x + project(a.transpose(1, 0, 2).reshape(t, hq * d), lp["wo"])
-    m, counts = experts(rms_norm(x, lp["ln2"], eps), lp, cfg)
-    return x + m, counts
+    a, gate_mean = a.transpose(1, 0, 2), None                  # (T, Hq, d)
+    if cfg.get("attention_gate"):
+        g = jax.nn.sigmoid(project(y, lp["wg"]))              # (T, Hq)
+        a, gate_mean = a * g[..., None], jnp.mean(g)
+    x = x + project(a.reshape(t, hq * d), lp["wo"])
+    h = rms_norm(x, lp["ln2"], eps)
+    if mlp == "dense":
+        return x + swiglu(h, lp["w_in"], lp["w_out"], cfg.get("operands")), \
+            None, gate_mean
+    m, counts = experts(h, lp, cfg)
+    return x + m, counts, gate_mean
 
 
 def forward(params, tokens, cfg, block_q: int = 512):
-    """One record: tokens (T,) int -> (logits (T, V), routed pairs per layer
-    and held expert (L, E_held)). Each layer is recomputed in the backward
-    pass (``jax.checkpoint``), so that one layer's activations are live at a
-    time at T = 8192."""
+    """One record: tokens (T,) int -> (logits (T, V), stats): ``counts``, the
+    routed pairs per sparse layer and held expert (L_sparse, E_held), and,
+    with ``cfg["attention_gate"]``, ``attn_gate_mean``. Each layer is
+    recomputed in the backward pass (``jax.checkpoint``), so that one layer's
+    activations are live at a time at T = 8192."""
     x = params["embed"][tokens]
-    counts = []
-    for kind, lp in zip(cfg["layer_types"], params["layers"]):
-        x, c = jax.checkpoint(
-            lambda x, lp, kind=kind: layer(x, lp, cfg, kind, block_q))(x, lp)
-        counts.append(c)
+    kinds = cfg["layer_types"]
+    heads = cfg.get("layer_heads") or [cfg["num_attention_heads"]] * len(kinds)
+    mlps = cfg.get("mlp_layer_types") or ["sparse"] * len(kinds)
+    counts, gates = [], []
+    for kind, lp, hq, mlp in zip(kinds, params["layers"], heads, mlps):
+        x, c, g = jax.checkpoint(
+            lambda x, lp, kind=kind, hq=hq, mlp=mlp: layer(
+                x, lp, cfg, kind, block_q, hq, mlp))(x, lp)
+        counts += [] if c is None else [c]
+        gates += [] if g is None else [g]
     logits = product(
         "td,dv->tv", rms_norm(x, params["final_norm"], cfg["rms_norm_eps"]),
         params["head"], cfg.get("operands"))
-    return logits, jnp.stack(counts)
+    stats = {"counts": jnp.stack(counts)}
+    if gates:
+        stats["attn_gate_mean"] = jnp.mean(jnp.stack(gates))
+    return logits, stats
 
 
 def record_loss(params, tokens, labels, cfg, block_q: int = 512, at=None):
-    """Summed cross-entropy of one record; beside it the routing counts and,
+    """Summed cross-entropy of one record; beside it ``forward``'s stats and,
     where ``at`` names positions, the logits there."""
-    logits, counts = forward(params, tokens, cfg, block_q)
+    logits, stats = forward(params, tokens, cfg, block_q)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.sum(lse - picked), (counts, None if at is None else logits[at])
+    return jnp.sum(lse - picked), (stats, None if at is None else logits[at])
 
 
 def loss_and_grad(params, tokens, labels, cfg, block_q: int = 512, at=None):
-    """Mean cross-entropy over a batch (N, T), its gradient, the routing
-    counts (L, E_held) summed over the batch and the logits at the positions
-    ``at`` (N, m) of each record (or None): record by record, so that one
-    record's activations are live at a time."""
+    """Mean cross-entropy over a batch (N, T), its gradient, the stats (the
+    routing counts (L_sparse, E_held) summed over the batch, the attention
+    gate's mean over it) and the logits at the positions ``at`` (N, m) of
+    each record (or None): record by record, so that one record's
+    activations are live at a time."""
     n, t = tokens.shape
     grad_fn = jax.jit(jax.value_and_grad(
         lambda p, x, y, a: record_loss(p, x, y, cfg, block_q, a), has_aux=True))
-    total, grads, counts, logits = 0.0, None, 0, []
+    total, grads, stats, logits = 0.0, None, None, []
     for i in range(n):
-        (l, (c, z)), g = grad_fn(params, tokens[i], labels[i],
+        (l, (s, z)), g = grad_fn(params, tokens[i], labels[i],
                                  None if at is None else at[i])
-        total, counts = total + l, counts + c
+        total = total + l
+        stats = s if stats is None else jax.tree_util.tree_map(jnp.add, stats, s)
         logits.append(z)
         grads = g if grads is None else jax.tree_util.tree_map(jnp.add, grads, g)
     scale = 1.0 / (n * t)
     grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-    return (total * scale, grads, counts,
+    if "attn_gate_mean" in stats:
+        stats["attn_gate_mean"] = stats["attn_gate_mean"] / n
+    return (total * scale, grads, stats,
             None if at is None else jnp.stack(logits))
 
 
-def routing_counters(counts):
-    """The step's three counters from (L, E_held) routed-pair counts:
-    pairs that hit a held expert (summed over layers), the worst layer's
-    load max over mean, and dropped pairs (none: nothing is ever dropped)."""
-    counts = jnp.asarray(counts, jnp.float32)
+def routing_counters(stats):
+    """The step's counters from ``loss_and_grad``'s stats: of the (L, E_held)
+    routed-pair counts, pairs that hit a held expert (summed over layers),
+    the worst layer's load max over mean, and dropped pairs (none: nothing is
+    ever dropped); and the attention gate's mean where the model has one."""
+    counts = jnp.asarray(stats["counts"], jnp.float32)
     load = jnp.max(counts, axis=-1) / jnp.maximum(jnp.mean(counts, axis=-1), 1.0)
-    return {"moe_pairs_local": float(jnp.sum(counts)),
-            "moe_load_max_over_mean": float(jnp.max(load)),
-            "moe_dropped_pairs": 0.0}
+    out = {"moe_pairs_local": float(jnp.sum(counts)),
+           "moe_load_max_over_mean": float(jnp.max(load)),
+           "moe_dropped_pairs": 0.0}
+    if "attn_gate_mean" in stats:
+        out["attn_gate_mean"] = float(stats["attn_gate_mean"])
+    return out

@@ -483,7 +483,8 @@ def _block_params(rng, hidden_size: int, num_heads: int, filter_size: int,
 def apply_rotary(x: jax.Array, positions: jax.Array,
                  inv_freq: Optional[jax.Array] = None,
                  factor: Optional[float] = None,
-                 interleaved: bool = False) -> jax.Array:
+                 interleaved: bool = False,
+                 rotary_dim: Optional[int] = None) -> jax.Array:
     """Rotary position embedding (RoPE, Su et al. 2021) over the last dim.
 
     ``x`` (..., T, d) with d even; ``positions`` (T,) absolute positions.
@@ -496,7 +497,14 @@ def apply_rotary(x: jax.Array, positions: jax.Array,
     YaRN's blended ones: ``nn.decoder.rope_inv_freq``) and ``factor``
     multiplies cos and sin (YaRN's attention factor); without them the
     result is what it always was, bit for bit. ``interleaved`` rotates the
-    pairs (2i, 2i+1) instead, each left in its place."""
+    pairs (2i, 2i+1) instead, each left in its place. ``rotary_dim`` (partial
+    rotary) rotates the first ``rotary_dim`` features alone, ``inv_freq``
+    then of ``rotary_dim/2``, and passes the rest through: ``factor`` scales
+    the rotated part only."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rotary(x[..., :rotary_dim], positions, inv_freq, factor,
+                              interleaved)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rotary needs an even feature dim, got {d}")

@@ -53,8 +53,8 @@ by strings) stays beside it until D1 merges the two.
 
 Device time is attributed by ``jax.named_scope``: ``embed``, ``attn_proj``,
 ``attn_window`` / ``attn_full`` (the kernel call alone), ``mla_proj``,
-``ssm_proj`` (inside it ``ssm_gate_norm``), ``ssm_conv``, ``ssm_scan``,
-``moe_route``, ``moe_experts``,
+``attn_gate``, ``ssm_proj`` (inside it ``ssm_gate_norm``), ``ssm_conv``,
+``ssm_scan``, ``moe_route``, ``moe_experts``,
 ``moe_shared``, ``mlp``, ``mtp`` (around the whole module), ``lm_head``
 (docs/observability.md).
 """
@@ -124,22 +124,39 @@ class GroupedQueryAttention(AbstractModule):
     """Causal self-attention, ``num_heads`` query heads over ``num_kv_heads``
     K/V heads of ``head_dim``: ``(N, T, D) -> (N, T, D)``. ``window`` makes it
     sliding-window attention; ``rope`` is the layer kind's entry of
-    ``rope_parameters`` (None: no positional encoding). With ``qk_norm`` q
-    and k pass a per-head RMSNorm with a learned gain before RoPE. ``scale``
-    multiplies the scores (None: ``1/sqrt(head_dim)``)."""
+    ``rope_parameters`` (None: no positional encoding); its
+    ``partial_rotary_factor`` (default 1) turns the first ``factor *
+    head_dim`` features of each head alone, with frequencies reckoned over
+    those, and passes the rest through. With ``qk_norm`` q and k pass a
+    per-head RMSNorm with a learned gain before RoPE. ``scale`` multiplies
+    the scores (None: ``1/sqrt(head_dim)``). ``gate`` multiplies each head's
+    output by ``sigmoid(x W_g)`` (``wg`` (D, num_heads), one scalar a head
+    and token, float32) between the kernel and ``W_o``: the headwise gate of
+    Qiu et al. 2025, arXiv:2505.06708, in scope ``attn_gate``; its mean over
+    tokens and heads is the counter ``attn_gate_mean``."""
 
     def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                  window: Optional[int] = None, rope: Optional[Dict] = None,
                  eps: float = 1e-6, init_std: float = 0.02,
-                 qk_norm: bool = True, scale: Optional[float] = None):
+                 qk_norm: bool = True, scale: Optional[float] = None,
+                 gate: bool = False):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads cannot share "
                              f"{num_kv_heads} K/V heads")
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.window = head_dim, window
-        self.init_std, self.scale = init_std, scale
-        self._rope = rope_inv_freq(rope, head_dim) if rope else None
+        self.init_std, self.scale, self.gate = init_std, scale, gate
+        self.rotary_dim = None
+        if rope and float(rope.get("partial_rotary_factor", 1)) != 1:
+            self.rotary_dim = round(head_dim * float(rope["partial_rotary_factor"]))
+            if self.rotary_dim % 2 or not 0 < self.rotary_dim < head_dim:
+                raise ValueError(
+                    f"partial_rotary_factor {rope['partial_rotary_factor']} "
+                    f"of head_dim {head_dim}: an even number of features "
+                    "between 0 and the head's")
+        self._rope = rope_inv_freq(rope, self.rotary_dim or head_dim) \
+            if rope else None
         # statistics in float32
         self._norm = RMSNorm(head_dim, eps) if qk_norm else None
 
@@ -160,7 +177,12 @@ class GroupedQueryAttention(AbstractModule):
         if self._norm is not None:
             params.update(q_norm=jnp.ones((self.head_dim,)),
                           k_norm=jnp.ones((self.head_dim,)))
-        return params, {}
+        if not self.gate:
+            return params, {}
+        params["wg"] = normal(jax.random.fold_in(rng, 4),
+                              (d_model, self.num_heads))
+        return params, {"_counters": {
+            "attn_gate_mean": jnp.zeros((), jnp.float32)}}
 
     def _heads(self, x, w, heads: int):
         n, t, _ = x.shape
@@ -181,14 +203,23 @@ class GroupedQueryAttention(AbstractModule):
             if self._rope is not None:
                 inv_freq, factor = self._rope
                 positions = jnp.arange(t)
-                q = apply_rotary(q, positions, inv_freq, factor)
-                k = apply_rotary(k, positions, inv_freq, factor)
+                q = apply_rotary(q, positions, inv_freq, factor,
+                                 rotary_dim=self.rotary_dim)
+                k = apply_rotary(k, positions, inv_freq, factor,
+                                 rotary_dim=self.rotary_dim)
         with jax.named_scope("attn_window" if self.window else "attn_full"):
             ctx = scaled_dot_product_attention(
                 q, k, v, causal=True, mask_q=True, window=self.window,
                 scale=self.scale)
         with jax.named_scope("attn_proj"):
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(n, t, -1)
+            ctx = ctx.transpose(0, 2, 1, 3)                  # (N, T, H, d)
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(precision.dot_acc32(x, params["wg"]))
+                ctx = (ctx * g[..., None]).astype(ctx.dtype)
+                state = {"_counters": {"attn_gate_mean": jnp.mean(g)}}
+        with jax.named_scope("attn_proj"):
+            ctx = ctx.reshape(n, t, -1)
             return precision.dot_acc32(ctx, params["wo"]).astype(x.dtype), state
 
 
@@ -464,6 +495,10 @@ class DecoderLM(Container):
         vocab_size, hidden_size: V and D.
         layer_types: one of ``LAYER_KINDS`` per layer.
         num_heads, num_kv_heads, head_dim: attention geometry.
+        layer_heads: query heads layer by layer, where they differ (default
+            ``num_heads`` in every layer).
+        attention_gate: every grouped-query attention layer gates its heads'
+            outputs (``GroupedQueryAttention``'s ``gate``).
         sliding_window: the window of the sliding layers.
         rope_parameters: ``{kind: rope dict}`` (``rope_inv_freq``); a kind
             without an entry has no positional encoding.
@@ -506,11 +541,16 @@ class DecoderLM(Container):
                  logits_divisor: float = 1.0, tie_embeddings: bool = False,
                  mlp_layer_types: Optional[Sequence[str]] = None,
                  router: Optional[Dict] = None,
-                 latent: Optional[Dict] = None, mtp_modules: int = 0):
+                 latent: Optional[Dict] = None, mtp_modules: int = 0,
+                 layer_heads: Optional[Sequence[int]] = None,
+                 attention_gate: bool = False):
         super().__init__()
         bad = [k for k in layer_types if k not in LAYER_KINDS]
         if bad:
             raise ValueError(f"layer_types {bad}: each of {LAYER_KINDS}")
+        if layer_heads is not None and len(layer_heads) != len(layer_types):
+            raise ValueError(f"layer_heads {list(layer_heads)}: one for each "
+                             f"of {len(layer_types)} layers")
         if "mamba" in layer_types and not mamba:
             raise ValueError("a 'mamba' layer needs the mamba sizes")
         if "latent_attention" in layer_types and not latent:
@@ -564,11 +604,13 @@ class DecoderLM(Container):
                     init_std=init_std)
             else:
                 mixer = GroupedQueryAttention(
-                    num_heads, num_kv_heads, head_dim,
+                    num_heads if layer_heads is None or i is None
+                    else layer_heads[i], num_kv_heads, head_dim,
                     window=sliding_window if kind == "sliding_attention"
                     else None,
                     rope=rope_parameters.get(kind), eps=eps,
-                    init_std=init_std, qk_norm=qk_norm, scale=attention_scale)
+                    init_std=init_std, qk_norm=qk_norm, scale=attention_scale,
+                    gate=attention_gate)
             if mlp_kind == "none":
                 return MixerBlock(mixer, eps=eps,
                                   residual_multiplier=residual_multiplier)

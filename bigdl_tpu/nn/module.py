@@ -477,12 +477,14 @@ class AbstractModule:
         """Counters a forward pass stashed in the state pytree under
         ``'_counters'`` keys (``{name: scalar}``, e.g. the routed experts'
         ``moe_pairs_local``), reduced over the modules that wrote them: a name
-        with ``max`` in it by maximum, one with ``min`` by minimum, any other
-        by sum. The standard train
+        with ``max`` in it by maximum, one with ``min`` by minimum, one with
+        ``mean`` by the mean over those modules, any other by sum. The
+        standard train
         step hands them out as one more output, pulled with the one-step-late
         loss and written into the telemetry step record under their names
         (docs/observability.md); ``{}`` for a model that keeps none."""
         found: Dict[str, Any] = {}
+        writers: Dict[str, int] = {}
 
         def walk(s):
             if not isinstance(s, dict):
@@ -492,6 +494,7 @@ class AbstractModule:
                     walk(v)
                     continue
                 for name, value in v.items():
+                    writers[name] = writers.get(name, 0) + 1
                     if name not in found:
                         found[name] = value
                     elif "max" in name:
@@ -502,6 +505,9 @@ class AbstractModule:
                         found[name] = found[name] + value
 
         walk(state)
+        for name, n in writers.items():
+            if n > 1 and "mean" in name and not ("max" in name or "min" in name):
+                found[name] = found[name] / n
         return found
 
     def with_counters(self, state, counted: Dict[str, Any]):

@@ -313,12 +313,28 @@ def _grouped_impl(lhs, rhs, group_sizes, transpose_rhs=False):
                               preferred_element_type=jnp.float32)
 
 
+# The transposed kernel keeps a (K, N) tile of float32 sums and its output
+# block in fast memory: at 1024 x 1024 that is 17 MiB of the 16 the compiler
+# allows a v5e kernel (a 3072-wide model's 1024-wide experts), at the 768 x
+# 1024 of the widest that ran before it fits.
+_TRANSPOSED_TILE_MOST = 768 * 1024
+
+
+def _transposed_tiling(k: int, n: int):
+    """``_tiling(k, n)``, the columns' tile cut to 768 where the (K, N) tile
+    would pass ``_TRANSPOSED_TILE_MOST``."""
+    rows, tk, tn = _tiling(k, n)
+    if tk * tn > _TRANSPOSED_TILE_MOST:
+        tn = _tile(n, 768)
+    return rows, tk, tn
+
+
 def _grouped_transposed(lhs, g, group_sizes, out_dtype):
     """Per group, ``lhs_rows^T (K, m_g) @ g_rows (m_g, N)`` -> (G, K, N)."""
     n_groups = group_sizes.shape[0]
     if jax.default_backend() == "tpu":
         return _megablox().tgmm(lhs.swapaxes(0, 1), g, group_sizes, out_dtype,
-                                _tiling(lhs.shape[1], g.shape[1]),
+                                _transposed_tiling(lhs.shape[1], g.shape[1]),
                                 num_actual_groups=n_groups)
     dims = jax.lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((0,), (0,)), ((), ())),
@@ -586,14 +602,15 @@ def _block_by_block_bwd(c, args, g):
 _block_by_block.defvjp(_block_by_block_fwd, _block_by_block_bwd)
 
 
-def route_top_k(x, router_w, top_k: int):
+def route_top_k(x, router_w, top_k: int, scaling: float = 1.0):
     """Softmax router in float32 over ALL experts: x (T, D) -> the k largest
-    probabilities (T, k), renormalised over the chosen, and their expert ids
-    (T, k)."""
+    probabilities (T, k), renormalised over the chosen and multiplied by
+    ``scaling``, and their expert ids (T, k)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+    w = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return (w if scaling == 1.0 else w * scaling), top_e
 
 
 def route_sigmoid_top_k(x, router_w, bias, top_k: int, scaling: float = 1.0):
@@ -711,16 +728,19 @@ class RoutedExperts(AbstractModule):
     (``routed_experts``). Beside ``MoE`` (switch / GShard with capacity
     buffers that drop) until ROADMAP D2 merges them.
 
+    ``routed_scaling`` multiplies the chosen weights of either router.
     ``scoring="sigmoid"`` is DeepSeek-V3's router (``route_sigmoid_top_k``):
-    sigmoid scores, weights normalised over the chosen and multiplied by
-    ``routed_scaling``. With ``bias_update_rate`` (its auxiliary-loss-free
+    sigmoid scores, weights normalised over the chosen. With ``bias_update_rate`` (its auxiliary-loss-free
     balancing) the choice is by ``s + b``: ``b`` (E,) is STATE, not a
     parameter (``selection_bias``: zero at the start, no gradient, no
     optimizer slot), and a training forward hands on ``b + rate * sign(mean
     count - count)`` from this step's counts over all ``n_experts``, as batch
     norm hands on its running statistics. ``shared_size`` adds one MLP of
     the experts' form and that width that every token passes, whole on every chip, to the routed
-    sum (scope ``moe_shared``); in the share test it counts once.
+    sum (scope ``moe_shared``); in the share test it counts once. With
+    ``shared_gate`` its output is multiplied by ``sigmoid(x w_s)``, one
+    scalar a token (``shared_gate`` (D, 1), float32), as Qwen2-MoE's shared
+    expert is.
 
     State: ``{"_counters": {moe_pairs_local, moe_load_max_over_mean,
     moe_dropped_pairs, moe_overflow_layers[, moe_bias_abs_max]}[,
@@ -730,7 +750,8 @@ class RoutedExperts(AbstractModule):
                  experts_held=None, init_std: float = 0.02,
                  scoring: str = "softmax", routed_scaling: float = 1.0,
                  bias_update_rate: Optional[float] = None,
-                 shared_size: int = 0, form: str = "gated"):
+                 shared_size: int = 0, form: str = "gated",
+                 shared_gate: bool = False):
         super().__init__()
         if form not in FORMS:
             raise ValueError(f"form {form!r}: one of {FORMS}")
@@ -743,16 +764,18 @@ class RoutedExperts(AbstractModule):
             raise ValueError(f"top_k {top_k} not in [1, {n_experts}]")
         if scoring not in SCORINGS:
             raise ValueError(f"scoring {scoring!r}: one of {SCORINGS}")
-        if scoring == "softmax" and (routed_scaling != 1.0
-                                     or bias_update_rate is not None):
-            raise ValueError("a scaling factor and a selection bias belong "
-                             "to scoring='sigmoid'")
+        if scoring == "softmax" and bias_update_rate is not None:
+            raise ValueError("a selection bias belongs to scoring='sigmoid'")
+        if shared_gate and not shared_size:
+            raise ValueError("shared_gate gates a shared expert: give "
+                             "shared_size")
         self.n_experts, self.ffn_size, self.top_k = n_experts, ffn_size, top_k
         self.experts_held = held
         self.init_std = init_std
         self.scoring, self.routed_scaling = scoring, float(routed_scaling)
         self.bias_update_rate = bias_update_rate
         self.shared_size, self.form = shared_size, form
+        self.shared_gate = shared_gate
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
@@ -777,6 +800,9 @@ class RoutedExperts(AbstractModule):
             params.update(
                 shared_in=normal(k_in, (d, (1 + gated) * self.shared_size)),
                 shared_out=normal(k_out, (self.shared_size, d)))
+            if self.shared_gate:
+                params["shared_gate"] = normal(
+                    jax.random.fold_in(rng, 5), (d, 1))
         if self.bias_update_rate is not None:
             state["selection_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
             state["_counters"]["moe_bias_abs_max"] = zero
@@ -786,7 +812,7 @@ class RoutedExperts(AbstractModule):
         x = jnp.asarray(x)
         tokens = x.reshape(-1, x.shape[-1])
         bias = state.get("selection_bias")
-        route = None
+        route = functools.partial(route_top_k, scaling=self.routed_scaling)
         if self.scoring == "sigmoid":
             route = lambda x, w, k: route_sigmoid_top_k(  # noqa: E731
                 x, w, bias, k, self.routed_scaling)
@@ -809,6 +835,9 @@ class RoutedExperts(AbstractModule):
                     h = jax.nn.silu(a) * b
                 else:
                     h = jnp.square(jax.nn.relu(h))
-                out = out + precision.dot_acc32(
-                    h, params["shared_out"]).astype(x.dtype)
+                shared = precision.dot_acc32(h, params["shared_out"])
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(precision.dot_acc32(
+                        tokens, params["shared_gate"]))
+                out = out + shared.astype(x.dtype)
         return out.reshape(x.shape), new_state

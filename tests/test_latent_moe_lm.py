@@ -285,7 +285,10 @@ def one_chip():
     (2, 32, 4, 8192, 128, 128, 1024, 2),     # and its sliding layers
     (1, 32, 8, 8192, 64, 64, None, 2),       # granite's attention layer
     (1, 2, 2, 32768, 128, 128, None, 3),     # too long a key axis: the pair
-], ids=["joyai", "mellum-full", "mellum-window", "granite", "T32768"])
+    (1, 48, 8, 8192, 128, 128, None, 2),     # Laguna's full layers: 6 a K/V head
+    (1, 72, 8, 8192, 128, 128, 512, 2),      # its sliding layers: 9 a K/V head
+], ids=["joyai", "mellum-full", "mellum-window", "granite", "T32768",
+        "laguna-full", "laguna-window"])
 def test_the_kernels_compile_at_the_cells_shapes_for_a_v5e(
         one_chip, n, h, hkv, t, d, d_v, window, calls):
     """Interpret mode passes what Mosaic refuses: q/k heads of 192 (one and a
@@ -358,6 +361,46 @@ def test_the_sized_buffers_loop_compiles_at_the_cells_shapes_for_a_v5e(
     assert " while(" in text and " conditional(" not in text
     # a row for every pair held 2 float32 buffers of 131072 x 2048 at least
     assert compiled.memory_analysis().temp_size_in_bytes < 131072 * 2048 * 4
+
+
+def test_the_routed_layer_compiles_at_the_gated_models_widths_for_a_v5e(
+        one_chip, monkeypatch):
+    """Laguna-S-2.1's routed layer (8192 tokens of 3072, 8 of 256 experts of
+    1024 held, 10 a token) forward and backward for the described chip: the
+    transposed grouped product at a (1024, 3072) tile pair asked 17 MiB of
+    the 16 MiB of scoped VMEM a kernel may have; with the columns' tile cut
+    to 768 (``_transposed_tiling``) the 12 calls compile."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.utils.engine import Engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # megablox
+    monkeypatch.setattr(Engine._state, "compute_dtype", "bfloat16")
+    t, d, f, e, held, k = 8192, 3072, 1024, 256, 8, 10
+    assert moe.buffer_rows(t * k, held, e) == 5120
+    assert moe._transposed_tiling(f, d) == (512, 1024, 768)
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.float32, sharding=one_chip)
+    params = {"router": shape(d, e), "w_gate": shape(held, d, f),
+              "w_up": shape(held, d, f), "w_down": shape(held, f, d)}
+
+    def fwd_bwd(params, x, g):
+        out, vjp = jax.vjp(lambda p, x: moe.routed_experts(
+            x, p, n_experts=e, experts_held=tuple(range(held)), top_k=k)[0],
+            params, x)
+        return out, vjp(g)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fwd_bwd).lower(
+            params, shape(t, d), shape(t, d)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 12
 
 
 # ------------------------------------------------------------- latent attention
@@ -479,7 +522,7 @@ def test_softmax_router_is_what_it_was():
         "moe_dropped_pairs", "moe_load_max_over_mean", "moe_overflow_layers",
         "moe_pairs_local"]
     with pytest.raises(ValueError, match="sigmoid"):
-        nn.RoutedExperts(6, 4, 2, routed_scaling=2.5)
+        nn.RoutedExperts(6, 4, 2, bias_update_rate=0.001)
     with pytest.raises(ValueError, match="one of"):
         nn.RoutedExperts(6, 4, 2, scoring="tanh")
 
