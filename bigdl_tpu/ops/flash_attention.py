@@ -39,6 +39,14 @@ makes 8 at q/k heads of 192, 7 against 5 at 128 or 64. Both forms round ``p``
 and ``ds`` to the operand dtype at the same places and meet a k tile's q
 tiles in the same order, so their gradients agree bit for bit.
 
+Masked tiles (the causal diagonal's, a window's edge, a sequence's end)
+are where the work exceeds what the mask keeps: under a window of 512 in
+tiles of 1024 every visited tile is masked and a head computes 3.87 times
+the pairs it keeps. Every kernel computes such a tile as (sub x sub)
+sub-blocks (``sub_block``: from the tiles and the mask, at trace time),
+skips those the mask wholly hides and masks only those it cuts; a full tile
+stays one product, and a tile no larger than a sub-block runs as one tile.
+
 Used via ``scaled_dot_product_attention(..., impl='flash')`` in
 ``bigdl_tpu.nn.attention`` (TPU backend only; dense fallback elsewhere) or
 directly. ``interpret=True`` runs the kernel in the Pallas interpreter (CPU)
@@ -51,7 +59,7 @@ import math
 import threading
 import time
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,16 +74,82 @@ from ..utils.remat_keep import keep
 NEG_BIG = -1e30
 
 
+class _Part(NamedTuple):
+    """What one product of a kernel covers: a whole tile, or one sub-block
+    of a masked tile. ``qi``, ``kj``: its q and k block indices at its own
+    sizes ``bq``, ``bk``; ``rows``, ``cols``: where it lies in the tile's
+    q rows and k rows."""
+    qi: object
+    kj: object
+    bq: int
+    bk: int
+    rows: object = slice(None)
+    cols: object = slice(None)
+
+
+def _each_sub_block(accumulate, kl, qi, j, *, block_q: int, block_k: int,
+                    sub: int, causal: bool, causal_offset: int, mask_q: bool,
+                    window: Optional[int]):
+    """Runs ``accumulate(masked, part)`` on the (sub x sub) sub-blocks of the
+    masked tile (q tile ``qi``, k tile ``j``) that the mask does not wholly
+    hide, each classified by ``_classify`` at its own size: a wholly visible
+    one skips the mask, a wholly hidden one is skipped. Row strip by row
+    strip (unrolled: each keeps static slices of the row state), and in a
+    strip its sub-blocks in order; every kernel visits them in this order,
+    so both backward forms sum alike."""
+    nr, nc = block_q // sub, block_k // sub
+    for r in range(nr):
+        def step(c, carry, r=r):
+            part = _Part(qi * nr + r, j * nc + c, sub, sub,
+                         slice(r * sub, (r + 1) * sub),
+                         pl.ds(pl.multiple_of(c * sub, sub), sub))
+            visible, full = _classify(
+                kl, part.qi, part.kj, block_q=sub, block_k=sub,
+                causal=causal, causal_offset=causal_offset, mask_q=mask_q,
+                window=window, nk_real=None)
+            pl.when(full)(lambda: accumulate(False, part))
+            pl.when(visible & jnp.logical_not(full))(
+                lambda: accumulate(True, part))
+            return carry
+
+        lax.fori_loop(0, nc, step, 0)
+
+
+def _tile_or_sub_blocks(accumulate, visible, full, kl, qi, j, *,
+                        block_q: int, block_k: int, sub: Optional[int],
+                        **classify):
+    """A kernel's step on the tile pair (q tile ``qi``, k tile ``j``): a full
+    tile is one ``accumulate(False, tile)``; a masked one is one
+    ``accumulate(True, tile)`` or, with ``sub``, its sub-blocks that the
+    mask does not wholly hide (``_each_sub_block``)."""
+    tile = _Part(qi, j, block_q, block_k)
+
+    @pl.when(full)
+    def _tile_full():
+        accumulate(False, tile)
+
+    @pl.when(visible & jnp.logical_not(full))
+    def _tile_masked():
+        if sub is None:
+            accumulate(True, tile)
+        else:
+            _each_sub_block(accumulate, kl, qi, j, block_q=block_q,
+                            block_k=block_k, sub=sub, **classify)
+
+
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 acc_ref, *, block_q: int, block_k: int, causal: bool,
                 scale: float, causal_offset: int, t_real_k: int, nk: int,
                 has_lengths: bool, mask_q: bool, window: Optional[int] = None,
-                nk_real: Optional[int] = None):
+                nk_real: Optional[int] = None, sub: Optional[int] = None):
     """Grid (BH, num_q_blocks, num_k_blocks); innermost dim streams k/v tiles.
 
     With a ``window`` the innermost dim has only the ``nk`` k/v tiles a q tile
     can see (``_window_start`` names the first of them; ``nk_real`` is how
     many the keys have), so tiles outside the window are never visited.
+    With ``sub`` a masked tile is computed sub-block by sub-block
+    (``_each_sub_block``), each row strip with its own slice of the
+    online-softmax state.
 
     q_ref (1, block_q, D) and o_ref depend on (b, i); k_ref/v_ref
     (1, block_k, D) on (b, j). Online-softmax state persists in VMEM scratch
@@ -114,23 +188,33 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         causal_offset=causal_offset, mask_q=has_lengths and mask_q,
         window=window, nk_real=nk_real)
 
-    def _accumulate(masked: bool):
+    # with sub-blocks the row statistics m and l are kept as columns
+    # repeated over 128 lanes ((block_q, 128) scratch): a sub-block then
+    # reads and writes its rows of them without turning a row vector
+    # between the lane and the sublane axis, which costs as much for a
+    # sub-block's few rows as for a whole tile's
+    columns = sub is not None
+
+    def column(x):  # a row statistic as a (rows, 1) column
+        return x[:, :1] if columns else x[:, None]
+
+    def _accumulate(masked: bool, part: _Part):
         # MXU dots run in the INPUT dtype (callers pass bf16 under the mixed-
         # precision policy, f32 for exact paths) with f32 accumulation; softmax
         # bookkeeping is always f32, and the scale applies to the f32 product.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        q = q_ref[0, part.rows]
+        k = k_ref[0, part.cols]
+        v = v_ref[0, part.cols]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # MXU
 
         if masked:
-            cols = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+            cols = part.kj * part.bk + lax.broadcasted_iota(
+                jnp.int32, (part.bq, part.bk), 1
             )
             allowed = cols < kl
             if causal or (has_lengths and mask_q):
-                rows = qi * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
+                rows = part.qi * part.bq + lax.broadcasted_iota(
+                    jnp.int32, (part.bq, part.bk), 0
                 )
                 if has_lengths and mask_q:
                     allowed = allowed & (rows + causal_offset < kl)
@@ -140,39 +224,39 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                     allowed = allowed & (rows + causal_offset - cols < window)
             s = jnp.where(allowed, s, NEG_BIG)
 
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[part.rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=columns))
+        p = jnp.exp(s - column(m_new))
         if masked:
             # explicitly zero masked entries (when a whole tile is masked
             # m_new stays NEG_BIG and exp(s - m_new) would be 1)
             p = jnp.where(allowed, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jnp.dot(
+        m_ref[part.rows] = m_new
+        l_ref[part.rows] = l_ref[part.rows] * corr + jnp.sum(
+            p, axis=-1, keepdims=columns)
+        acc_ref[part.rows] = acc_ref[part.rows] * column(corr) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
-    @pl.when(full)
-    def _tile_full():
-        _accumulate(masked=False)
-
-    @pl.when(visible & jnp.logical_not(full))
-    def _tile_masked():
-        _accumulate(masked=True)
+    _tile_or_sub_blocks(
+        _accumulate, visible, full, kl, qi, j, block_q=block_q,
+        block_k=block_k, sub=sub, causal=causal, causal_offset=causal_offset,
+        mask_q=has_lengths and mask_q, window=window)
 
     @pl.when(last)
     def _finish():
         o_ref[0] = (
-            acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[:, None]
+            acc_ref[:] / column(jnp.maximum(l_ref[:], 1e-30))
         ).astype(o_ref.dtype)
         # per-row logsumexp of the (scaled, masked) logits — the backward
         # residual; NEG_BIG marks rows with no visible keys
-        lse_ref[0, 0] = jnp.where(
+        lse = jnp.where(
             l_ref[:] > 0.0, m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30)),
             NEG_BIG,
         )
+        # the columns' lanes are equal: their max is the row's value
+        lse_ref[0, 0] = jnp.max(lse, axis=-1) if columns else lse
 
 
 def _pick_block(requested: int, t: int) -> int:
@@ -191,7 +275,10 @@ def _pick_block(requested: int, t: int) -> int:
 # Mosaic's default scoped-VMEM limit on a v5e core is 16 MiB (later chips
 # allow more); ``_working_set`` leaves out the compiler's own scratch and the
 # (bq,) row statistics, about 1 MiB at the largest tiles (compiled for a
-# described v5e: 21.0 MiB reported where the sum below says 20.0)
+# described v5e: 21.0 MiB reported where the sum below says 20.0), and the
+# (bq, 128) columns that hold the row statistics where masked tiles have
+# sub-blocks (2 MiB at bq 1024 over both directions; the forward at the
+# cells' shapes holds 11 - 12.5 MiB with them)
 _VMEM_BUDGET = 15 * 2 ** 20
 _LARGEST_TILE = 1024
 # the one backward kernel holds dK and dV of a whole K/V head: it asks for
@@ -266,9 +353,11 @@ def pick_tiles(tq: int, tk: int, d: int, itemsize: int,
     ``_pick_block`` tolerates, the k axis 1024 only where the keys fill one
     (below that nothing was measured and it stays at 512's), and while
     ``_working_set`` is over ``_VMEM_BUDGET`` the larger of the two halves,
-    the q tile first. The mask's geometry does not enter: under a window of
-    1024 a smaller q tile computes fewer masked pairs and still lost,
-    forward and with the forward recomputed."""
+    the q tile first. The mask's geometry does not enter the tiles (under a
+    window of 1024 a smaller q tile computes fewer masked pairs and still
+    lost, forward and with the forward recomputed, for K/V is read again
+    for each q tile); it enters inside a masked tile, whose sub-blocks
+    ``sub_block`` sizes from the tiles and the mask."""
     bq = _pick_block(_LARGEST_TILE, tq)
     bk = _pick_block(
         _LARGEST_TILE if tk >= _LARGEST_TILE else _LARGEST_TILE // 2, tk)
@@ -281,27 +370,75 @@ def pick_tiles(tq: int, tk: int, d: int, itemsize: int,
     return bq, bk
 
 
+_SUB_BLOCK = 512
+
+
+def sub_block(bq: int, bk: int, causal: bool) -> Optional[int]:
+    """The size of the square sub-blocks that the kernels cut a masked tile
+    into, from the tiles and the mask, or ``None`` where a masked tile is
+    computed whole, as one product.
+
+    Under a causal mask the masked tiles are the diagonal's and, with a
+    window, the window's edge: there a sub-block wholly hidden is skipped
+    and one wholly visible skips the mask. On a v5e, at tiles of 1024,
+    sub-blocks of 512 were the fastest of 128 / 256 / 512 / whole in every
+    shape timed, forward and forward + backward: under a window of 512
+    12.36 against 18.13 ms, of 1024 14.76 against 16.55, full causal at
+    head sizes 64, 128, 192 by 10 - 13 %; 256 and 128 lost to 512
+    everywhere, a sub-block's products being too small for what each one
+    costs (``tools/flash_tile_table.py``; the table is in
+    docs/performance.md): so neither the head size nor the window enters.
+    A tile no larger than a sub-block, or one that it does not divide (a
+    whole-T tile of a ragged T), is computed whole."""
+    if not causal or min(bq, bk) <= _SUB_BLOCK or bq % _SUB_BLOCK \
+            or bk % _SUB_BLOCK:
+        return None
+    return _SUB_BLOCK
+
+
 def _tile_geometry(tq: int, tk: int, bq: int, bk: int, causal: bool,
-                   window: Optional[int]):
+                   window: Optional[int], sub: Optional[int] = None):
     """(tiles a head's grid computes on, pairs in them / pairs the mask lets
-    through): the kernels' own tile classification without per-sequence
-    lengths, which only a run knows."""
+    through, pairs in the products the kernels compute / pairs the mask lets
+    through): the kernels' own tile classification (``_classify``) without
+    per-sequence lengths, which only a run knows. With ``sub`` a masked
+    tile computes only its sub-blocks that the mask does not wholly hide;
+    without, the last two agree."""
     off = tk - tq
-    first_row = np.arange(-(-tq // bq))[:, None] * bq + off
-    first_col = np.arange(-(-tk // bk))[None, :] * bk
-    visited = np.ones((first_row.size, first_col.size), bool)
+    nq, nk = -(-tq // bq), -(-tk // bk)
+
+    def classify(b_q, b_k):
+        # (visible, full) of every (b_q x b_k) block of the padded grid
+        first_row = np.arange(nq * bq // b_q)[:, None] * b_q + off
+        first_col = np.arange(nk * bk // b_k)[None, :] * b_k
+        visible = np.broadcast_to(first_col < tk, (first_row.size,
+                                                    first_col.size))
+        full = first_col + b_k <= tk
+        if causal:
+            visible = visible & (first_row + b_q - 1 >= first_col)
+            full = full & (first_row >= first_col + b_k - 1)
+        if window is not None:
+            visible = visible & (first_row - (first_col + b_k - 1) < window)
+            full = full & (first_row + b_q - 1 - first_col < window)
+        return visible, full
+
     rows = np.arange(tq) + off
     hi = np.full(tq, tk - 1)
     lo = np.zeros(tq, np.int64)
     if causal:
-        visited = visited & (first_row + bq - 1 >= first_col)
         hi = np.minimum(rows, hi)
     if window is not None:
-        visited = visited & (first_row - (first_col + bk - 1) < window)
         lo = np.maximum(rows - (window - 1), 0)
-    visible = int(np.maximum(hi - lo + 1, 0).sum())
+    visible = max(int(np.maximum(hi - lo + 1, 0).sum()), 1)
+    visited, full = classify(bq, bk)
     tiles = int(visited.sum())
-    return tiles, tiles * bq * bk / max(visible, 1)
+    computed = tiles * bq * bk
+    if sub is not None:
+        masked = np.kron(visited & ~full,
+                         np.ones((bq // sub, bk // sub), bool))
+        computed = int((visited & full).sum()) * bq * bk \
+            + int((classify(sub, sub)[0] & masked).sum()) * sub * sub
+    return tiles, tiles * bq * bk / visible, computed / visible
 
 
 _tile_records: dict = {}  # shape and tile -> (when last traced, the record)
@@ -322,8 +459,9 @@ def take_tile_records(since: float = 0.0) -> list:
 
 def _resolve_tiles(q, k, v, causal: bool, window: Optional[int],
                    block_q: Optional[int], block_k: Optional[int]):
-    """(block_q, block_k, whether the backward is the one kernel): every
-    choice that follows from a call's shapes, made and recorded here."""
+    """(block_q, block_k, whether the backward is the one kernel, the
+    sub-block of a masked tile or ``None``): every choice that follows from
+    a call's shapes, made and recorded here."""
     tq, tk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     bq, bk = pick_tiles(tq, tk, d, q.dtype.itemsize, d_v)
     if block_q is not None:
@@ -331,22 +469,25 @@ def _resolve_tiles(q, k, v, causal: bool, window: Optional[int],
     if block_k is not None:
         bk = _pick_block(block_k, tk)
     fused, acc = backward_form(tk, bq, bk, d, q.dtype.itemsize, d_v)
+    sub = sub_block(bq, bk, causal)
     key = (tq, tk, d, d_v, q.dtype.name, causal, window, bq, bk)
     with _tile_records_lock:
         if key in _tile_records:
             record = _tile_records[key][1]
         else:
-            tiles, waste = _tile_geometry(tq, tk, bq, bk, causal, window)
+            tiles, waste, computed = _tile_geometry(tq, tk, bq, bk, causal,
+                                                    window, sub)
             record = dict(
                 tq=tq, tk=tk, d=d, dtype=q.dtype.name, causal=causal,
                 window=window, block_q=bq, block_k=bk, visited_tiles=tiles,
-                visited_over_visible=round(waste, 4),
+                visited_over_visible=round(waste, 4), sub_block=sub,
+                computed_over_visible=round(computed, 4),
                 backward="fused" if fused else "pair",
                 backward_acc_bytes=acc)
             if d_v != d:  # v and the output at a head size of their own
                 record["d_v"] = d_v
         _tile_records[key] = (time.perf_counter(), record)
-    return bq, bk, fused
+    return bq, bk, fused, sub
 
 
 def _window_start(qi, block_q: int, block_k: int, causal_offset: int,
@@ -377,11 +518,12 @@ def _window_count(n_outer: int, block_o: int, block_i: int, offset: int,
 def _window_tiles(visible, full, in_range, qi, j, block_q: int, block_k: int,
                   causal_offset: int, window: int):
     """Tile classification under a window: (row - col) spans
-    [first row - last col, last row - first col] over a tile."""
-    visible = visible & in_range & (
+    [first row - last col, last row - first col] over a tile. ``in_range``
+    is ``None`` for a sub-block, whose tile is in range."""
+    visible = (visible if in_range is None else visible & in_range) & (
         qi * block_q + causal_offset - ((j + 1) * block_k - 1) < window)
     # a step past the last tile (its index was clamped) is neither
-    full = full & in_range & (
+    full = (full if in_range is None else full & in_range) & (
         (qi + 1) * block_q - 1 + causal_offset - j * block_k < window)
     return visible, full
 
@@ -393,7 +535,8 @@ def _classify(kl, qi, j, *, block_q: int, block_k: int, causal: bool,
     kernels whose outer axis is the q tile: whether any entry of it is
     unmasked, and whether every entry is. ``kl`` is the key horizon (a
     sequence's length where it has one), ``mask_q`` whether query rows past
-    it are masked too."""
+    it are masked too. At a sub-block's size and indices (``nk_real``
+    ``None``) the same arithmetic classifies a sub-block of a tile."""
     visible = j * block_k < kl
     full = (j + 1) * block_k <= kl
     if mask_q:
@@ -405,8 +548,9 @@ def _classify(kl, qi, j, *, block_q: int, block_k: int, causal: bool,
             (qi + 1) * block_q - 1 + causal_offset >= j * block_k)
         full = full & (qi * block_q + causal_offset >= (j + 1) * block_k - 1)
     if window is not None:
-        visible, full = _window_tiles(visible, full, j < nk_real, qi, j,
-                                      block_q, block_k, causal_offset, window)
+        visible, full = _window_tiles(
+            visible, full, None if nk_real is None else j < nk_real, qi, j,
+            block_q, block_k, causal_offset, window)
     return visible, full
 
 
@@ -439,9 +583,10 @@ def _expand_lengths(lengths, n: int, h: int, tk: int):
 
 def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
                     bq: int, bk: int, interpret: bool, mask_q: bool,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, sub: Optional[int] = None):
     """Returns (out (N,H,Tq,d_v), lse (N*H, Tq_padded)) — lse is the bwd
-    residual. ``bq``/``bk`` are the resolved tiles (``_resolve_tiles``)."""
+    residual. ``bq``/``bk`` are the resolved tiles and ``sub`` the masked
+    tiles' sub-block (``_resolve_tiles``)."""
     n, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -467,6 +612,10 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
             kv_row(b),
             jnp.minimum(_window_start(i, bq, bk, tk - tq, window) + j, nk - 1),
             0)
+    stat = (bq,)  # the row statistics' scratch: columns with sub-blocks
+    if sub is not None:
+        extra["sub"] = sub
+        stat = (bq, 128)
 
     out, lse = pallas_call(
         partial(_fwd_kernel, block_q=bq, block_k=bk, causal=causal,
@@ -485,8 +634,8 @@ def _flash_fwd_impl(q, k, v, lengths, causal: bool, scale: Optional[float],
                 pl.BlockSpec((1, 1, bq), lambda b, i, j, lens: (b, 0, i)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM(stat, jnp.float32),
+                pltpu.VMEM(stat, jnp.float32),
                 pltpu.VMEM((bq, d_v), jnp.float32),
             ],
         ),
@@ -513,7 +662,7 @@ def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
     key/query horizon (= t_real_k when no per-batch lengths)."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if not masked:
-        return jnp.exp(s - lse[:, None])
+        return jnp.exp(s - (lse if lse.ndim == 2 else lse[:, None]))
     rows = qi * block_q + lax.broadcasted_iota(jnp.int32, (q.shape[0], k.shape[0]), 0)
     cols = ki * block_k + lax.broadcasted_iota(jnp.int32, (q.shape[0], k.shape[0]), 1)
     allowed = (cols < kl) & (rows < t_real_q)
@@ -525,24 +674,47 @@ def _bwd_masked_p(q, k, lse, *, scale, masked, causal, causal_offset,
         allowed = allowed & (rows + causal_offset - cols < window)
     # masked/fully-masked entries: s and lse are both NEG_BIG-ish; clamp the
     # exponent so the unselected branch of the where never overflows
-    expo = jnp.clip(s - lse[:, None], NEG_BIG, 0.0)
+    expo = jnp.clip(s - (lse if lse.ndim == 2 else lse[:, None]), NEG_BIG,
+                    0.0)
     return jnp.where(allowed, jnp.exp(expo), 0.0)
 
 
+def _fill_columns(columns, lse_ref, delta_ref):
+    """With sub-blocks (``columns``: two (block_q, 128) scratch refs), a q
+    tile's logsumexp and delta turned into columns once, when the tile's
+    first k step starts: each sub-block then reads its rows as a column and
+    no row vector crosses between the lane and the sublane axis in it."""
+    for col, ref in zip(columns, (lse_ref, delta_ref)):
+        col[:] = jnp.broadcast_to(ref[0, 0][:, None], col.shape)
+
+
+def _row_stats(columns, lse_ref, delta_ref, rows):
+    """(the logsumexp of these rows, a function giving their delta as a
+    column): from the columns where there are sub-blocks, else from the row
+    vectors, read where they always were."""
+    if columns:
+        lse_cols, delta_cols = columns
+        return lse_cols[rows, :1], lambda: delta_cols[rows, :1]
+    return lse_ref[0, 0, rows], lambda: delta_ref[0, 0, rows][:, None]
+
+
 def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, block_q: int, block_k: int, causal: bool,
-               scale: float, causal_offset: int, t_real_q: int,
+               dq_ref, dq_acc, *columns, block_q: int, block_k: int,
+               causal: bool, scale: float, causal_offset: int, t_real_q: int,
                t_real_k: int, nk: int, has_lengths: bool, mask_q: bool,
-               window: Optional[int] = None, nk_real: Optional[int] = None):
+               window: Optional[int] = None, nk_real: Optional[int] = None,
+               sub: Optional[int] = None):
     """Grid (BH, num_q_blocks, num_k_blocks): k/v tiles stream through the
     inner dim while the dQ accumulator for the current q tile sits in VMEM.
-    Under a ``window`` the inner dim holds the tiles inside it alone, as in
-    the forward kernel."""
+    Under a ``window`` the inner dim holds the tiles inside it alone, and
+    with ``sub`` a masked tile goes sub-block by sub-block, as in the
+    forward kernel."""
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        _fill_columns(columns, lse_ref, delta_ref)
 
     last = j == nk - 1
     if window is not None:
@@ -555,28 +727,27 @@ def _dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         causal_offset=causal_offset, mask_q=has_lengths and mask_q,
         window=window, nk_real=nk_real)
 
-    def _accumulate(masked: bool):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        p = _bwd_masked_p(q, k, lse_ref[0, 0], scale=scale, masked=masked,
-                          causal=causal, causal_offset=causal_offset,
+    def _accumulate(masked: bool, part: _Part):
+        q = q_ref[0, part.rows]
+        k = k_ref[0, part.cols]
+        v = v_ref[0, part.cols]
+        do = do_ref[0, part.rows]
+        lse, delta = _row_stats(columns, lse_ref, delta_ref, part.rows)
+        p = _bwd_masked_p(q, k, lse, scale=scale,
+                          masked=masked, causal=causal,
+                          causal_offset=causal_offset,
                           t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
                           mask_q=has_lengths and mask_q,
-                          qi=qi, ki=j, block_q=block_q, block_k=block_k,
-                          window=window)
+                          qi=part.qi, ki=part.kj, block_q=part.bq,
+                          block_k=part.bk, window=window)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0][:, None]) * scale).astype(k.dtype)
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta()) * scale).astype(k.dtype)
+        dq_acc[part.rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(full)
-    def _tile_full():
-        _accumulate(masked=False)
-
-    @pl.when(visible & jnp.logical_not(full))
-    def _tile_masked():
-        _accumulate(masked=True)
+    _tile_or_sub_blocks(
+        _accumulate, visible, full, kl, qi, j, block_q=block_q,
+        block_k=block_k, sub=sub, causal=causal, causal_offset=causal_offset,
+        mask_q=has_lengths and mask_q, window=window)
 
     @pl.when(last)
     def _finish():
@@ -588,13 +759,16 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 block_k: int, causal: bool, scale: float,
                 causal_offset: int, t_real_q: int, t_real_k: int, nq: int,
                 has_lengths: bool, mask_q: bool, window: Optional[int] = None,
-                nq_real: Optional[int] = None, group: int = 1):
+                nq_real: Optional[int] = None, group: int = 1,
+                sub: Optional[int] = None):
     """Grid (B*Hkv, num_k_blocks, group * num_q_blocks): q/do tiles stream
     through the inner dim; dK/dV accumulators for the current k tile sit in
     VMEM. With grouped heads the inner dim runs over the ``group`` query heads
     that read this K/V head, one after another, and their contributions sum
     in the accumulators. Under a ``window`` each head's stretch holds only
-    the ``nq`` q tiles whose window reaches this k tile."""
+    the ``nq`` q tiles whose window reaches this k tile. With ``sub`` a
+    masked tile goes sub-block by sub-block in the order the one kernel
+    takes them."""
     ki, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -631,31 +805,31 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         visible, full = _window_tiles(visible, full, j < nq_real, j, ki,
                                       block_q, block_k, causal_offset, window)
 
-    def _accumulate(masked: bool):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        p = _bwd_masked_p(q, k, lse_ref[0, 0], scale=scale, masked=masked,
-                          causal=causal, causal_offset=causal_offset,
+    def _accumulate(masked: bool, part: _Part):
+        q = q_ref[0, part.rows]
+        k = k_ref[0, part.cols]
+        v = v_ref[0, part.cols]
+        do = do_ref[0, part.rows]
+        p = _bwd_masked_p(q, k, lse_ref[0, 0, part.rows], scale=scale,
+                          masked=masked, causal=causal,
+                          causal_offset=causal_offset,
                           t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
                           mask_q=has_lengths and mask_q,
-                          qi=j, ki=ki, block_q=block_q, block_k=block_k,
-                          window=window)
-        dv_acc[:] += jnp.dot(
+                          qi=part.qi, ki=part.kj, block_q=part.bq,
+                          block_k=part.bk, window=window)
+        dv_acc[part.cols] += jnp.dot(
             p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
         )
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0][:, None]) * scale).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0, part.rows][:, None])
+              * scale).astype(q.dtype)
+        dk_acc[part.cols] += jnp.dot(ds.T, q,
+                                     preferred_element_type=jnp.float32)
 
-    @pl.when(full)
-    def _tile_full():
-        _accumulate(masked=False)
-
-    @pl.when(visible & jnp.logical_not(full))
-    def _tile_masked():
-        _accumulate(masked=True)
+    _tile_or_sub_blocks(
+        _accumulate, visible, full, kl, j, ki, block_q=block_q,
+        block_k=block_k, sub=sub, causal=causal, causal_offset=causal_offset,
+        mask_q=has_lengths and mask_q, window=window)
 
     @pl.when(last)
     def _finish():
@@ -664,12 +838,12 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *columns,
                 block_q: int, block_k: int, causal: bool, scale: float,
                 causal_offset: int, t_real_q: int, t_real_k: int, nq: int,
                 nk: int, has_lengths: bool, mask_q: bool,
                 window: Optional[int] = None, nk_real: Optional[int] = None,
-                group: int = 1):
+                group: int = 1, sub: Optional[int] = None):
     """The one backward kernel. Grid (B*Hkv, group * num_q_blocks,
     num_k_blocks), the dQ kernel's loop order: k/v tiles stream through the
     inner dim, and every visited tile builds ``p`` and ``dp`` once and feeds
@@ -679,8 +853,9 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     tile's rows addressed by a tile-aligned dynamic slice, and are cast and
     written out once, after the last q tile of the last query head of the
     group. A k tile meets its q tiles in the order ``_dkv_kernel`` meets
-    them (head by head, q tile by q tile), so the float32 sums are the
-    pair's."""
+    them (head by head, q tile by q tile), and with ``sub`` a masked tile's
+    sub-blocks in the order ``_each_sub_block`` gives both, so the float32
+    sums are the pair's."""
     gi, j = pl.program_id(1), pl.program_id(2)
     qi = gi % nq if group > 1 else gi
 
@@ -692,6 +867,7 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        _fill_columns(columns, lse_ref, delta_ref)
 
     last = j == nk - 1
     if window is not None:
@@ -705,33 +881,33 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         causal_offset=causal_offset, mask_q=has_lengths and mask_q,
         window=window, nk_real=nk_real)
 
-    def _accumulate(masked: bool):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        p = _bwd_masked_p(q, k, lse_ref[0, 0], scale=scale, masked=masked,
-                          causal=causal, causal_offset=causal_offset,
+    def _accumulate(masked: bool, part: _Part):
+        q = q_ref[0, part.rows]
+        k = k_ref[0, part.cols]
+        v = v_ref[0, part.cols]
+        do = do_ref[0, part.rows]
+        lse, delta = _row_stats(columns, lse_ref, delta_ref, part.rows)
+        p = _bwd_masked_p(q, k, lse, scale=scale,
+                          masked=masked, causal=causal,
+                          causal_offset=causal_offset,
                           t_real_q=t_real_q, t_real_k=t_real_k, kl=kl,
                           mask_q=has_lengths and mask_q,
-                          qi=qi, ki=j, block_q=block_q, block_k=block_k,
-                          window=window)
-        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+                          qi=part.qi, ki=part.kj, block_q=part.bq,
+                          block_k=part.bk, window=window)
+        # this part's key rows of the whole-axis accumulators
+        rows = pl.ds(pl.multiple_of(part.kj * part.bk, part.bk), part.bk)
         dv_acc[rows, :] += jnp.dot(
             p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
         )
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0][:, None]) * scale).astype(q.dtype)
+        ds = (p * (dp - delta()) * scale).astype(q.dtype)
         dk_acc[rows, :] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        dq_acc[part.rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(full)
-    def _tile_full():
-        _accumulate(masked=False)
-
-    @pl.when(visible & jnp.logical_not(full))
-    def _tile_masked():
-        _accumulate(masked=True)
+    _tile_or_sub_blocks(
+        _accumulate, visible, full, kl, qi, j, block_q=block_q,
+        block_k=block_k, sub=sub, causal=causal, causal_offset=causal_offset,
+        mask_q=has_lengths and mask_q, window=window)
 
     @pl.when(last)
     def _finish():
@@ -746,10 +922,12 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
                     scale: Optional[float], bq: int, bk: int,
                     interpret: bool, mask_q: bool,
-                    window: Optional[int], fused: bool):
+                    window: Optional[int], fused: bool,
+                    sub: Optional[int] = None):
     """dQ, dK, dV off the forward's output and logsumexp. ``fused`` is
     ``_resolve_tiles``' choice from the shapes: the one kernel with its
-    whole-axis dK/dV accumulator, or the pair where that does not fit."""
+    whole-axis dK/dV accumulator, or the pair where that does not fit;
+    ``sub`` the masked tiles' sub-block, the forward's."""
     n, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -788,6 +966,10 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             _window_start(i, bk, bq, -off, 1) + j, nq - 1)
     if group > 1:
         dkv_extra["group"] = group
+    columns = []  # with sub-blocks: the q tile's lse and delta as columns
+    if sub is not None:
+        common["sub"] = sub
+        columns = [pltpu.VMEM((bq, 128), jnp.float32)] * 2
 
     def unpadded(dq, dk, dv):
         return (dq[:, :tq].reshape(n, h, tq, d),
@@ -829,7 +1011,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
                     pltpu.VMEM((bq, d), jnp.float32),
                     pltpu.VMEM((tkp, d), jnp.float32),
                     pltpu.VMEM((tkp, d_v), jnp.float32),
-                ],
+                ] + columns,
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
@@ -862,7 +1044,7 @@ def _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal: bool,
             ],
             out_specs=pl.BlockSpec((1, bq, d),
                                    lambda b, i, j, lens: (b, i, 0)),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] + columns,
         ),
         out_shape=jax.ShapeDtypeStruct((n * h, tqp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -948,18 +1130,18 @@ def _dense_reference(q, k, v, causal: bool, scale: Optional[float],
     return jnp.einsum("nhqk,nhkd->nhqd", w.astype(q.dtype), v)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_core(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-                mask_q, window, fused):
+                mask_q, window, fused, sub=None):
     out, _ = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
-                             block_k, interpret, mask_q, window)
+                             block_k, interpret, mask_q, window, sub)
     return out
 
 
 def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
-              mask_q, window, fused):
+              mask_q, window, fused, sub=None):
     out, lse = _flash_fwd_impl(q, k, v, lengths, causal, scale, block_q,
-                               block_k, interpret, mask_q, window)
+                               block_k, interpret, mask_q, window, sub)
     # what only a second run of the forward kernel could rebuild: under
     # nn.Remat these two are kept and the backward runs flash_fwd no more
     out, lse = keep(out, "flash_out"), keep(lse, "flash_lse")
@@ -967,11 +1149,11 @@ def _fwd_rule(q, k, v, lengths, causal, scale, block_q, block_k, interpret,
 
 
 def _bwd_rule(causal, scale, block_q, block_k, interpret, mask_q, window,
-              fused, res, g):
+              fused, sub, res, g):
     q, k, v, lengths, o, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, lengths, o, lse, g, causal, scale,
                                  block_q, block_k, interpret, mask_q, window,
-                                 fused)
+                                 fused, sub)
     return dq, dk, dv, None
 
 
@@ -1001,7 +1183,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
 
     ``window`` (with ``causal``) is sliding-window attention: query i sees
     key j iff ``j <= i`` and ``i - j < window``. Tiles wholly outside the
-    window are not in the grid at all, so the work is the window's.
+    window are not in the grid at all, and the sub-blocks of a tile that
+    the window wholly hides are skipped, so the work is near the window's.
 
     ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
     attention): query head h reads K/V head ``h // (Hq / Hkv)``; nothing is
@@ -1040,6 +1223,7 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
         raise ValueError(
             f"flash_attention: q heads of {q.shape[3]} against k heads of "
             f"{k.shape[3]}; q and k share a head size, v may have its own")
-    bq, bk, fused = _resolve_tiles(q, k, v, causal, window, block_q, block_k)
+    bq, bk, fused, sub = _resolve_tiles(q, k, v, causal, window, block_q,
+                                        block_k)
     return _flash_core(q, k, v, lengths, causal, scale, bq, bk,
-                       interpret, bool(mask_q), window, fused)
+                       interpret, bool(mask_q), window, fused, sub)

@@ -172,13 +172,22 @@ def test_tile_records_say_what_ran_and_how_much_of_it_is_masked():
     # 12 % more pairs than the mask lets through; under a window of 1024 a
     # q tile computes two half-masked k tiles: twice the visible pairs
     assert _tile_geometry(8192, 8192, 1024, 1024, True, None) == (
-        36, pytest.approx(1.1249, abs=1e-4))
+        36, pytest.approx(1.1249, abs=1e-4), pytest.approx(1.1249, abs=1e-4))
     assert _tile_geometry(8192, 8192, 1024, 1024, True, 1024) == (
-        15, pytest.approx(2.0, abs=1e-3))
+        15, pytest.approx(2.0, abs=1e-3), pytest.approx(2.0, abs=1e-3))
     assert _tile_geometry(8192, 8192, 512, 512, True, 1024) == (
-        45, pytest.approx(1.5, abs=1e-3))
+        45, pytest.approx(1.5, abs=1e-3), pytest.approx(1.5, abs=1e-3))
     assert _tile_geometry(600, 600, 600, 128, False, None) == (
-        5, pytest.approx(640 / 600))
+        5, pytest.approx(640 / 600), pytest.approx(640 / 600))
+    # sub-blocks of a masked tile: a window of 512 in tiles of 1024 computes
+    # 3.87 times the pairs it keeps, 1.5 in sub-blocks of 256; a window of
+    # 1024 1.25; the causal diagonal 1.03
+    assert _tile_geometry(8192, 8192, 1024, 1024, True, 512, 256) == (
+        15, pytest.approx(3.871, abs=1e-3), pytest.approx(1.5, abs=1e-3))
+    assert _tile_geometry(8192, 8192, 1024, 1024, True, 1024, 256)[2] == \
+        pytest.approx(1.25, abs=1e-3)
+    assert _tile_geometry(8192, 8192, 1024, 1024, True, None, 256)[2] == \
+        pytest.approx(1.031, abs=1e-3)
 
     take_tile_records()
     q = jax.ShapeDtypeStruct((1, 2, 2048, 16), jnp.bfloat16)
@@ -195,7 +204,8 @@ def test_tile_records_say_what_ran_and_how_much_of_it_is_masked():
     assert got[(None, 1024, 1024)] == dict(
         tq=2048, tk=2048, d=16, dtype="bfloat16", causal=True, window=None,
         block_q=1024, block_k=1024, visited_tiles=3,
-        visited_over_visible=pytest.approx(1.4993, abs=1e-4),
+        visited_over_visible=pytest.approx(1.4993, abs=1e-4), sub_block=512,
+        computed_over_visible=pytest.approx(1.2494, abs=1e-4),
         backward="fused", backward_acc_bytes=2048 * 32 * 4)
     assert take_tile_records() == []
     attend()                     # a trace older than the caller asks about

@@ -443,7 +443,12 @@ def _dense_masked_reference(q, k, v, causal, window, lengths, mask_q):
 
 
 # n, query heads, K/V heads, Tq, Tk, d, d_v, causal, window, lengths,
-# mask_q, tile: every case has more than one tile on each axis
+# mask_q, tile: every case has more than one tile on each axis. The "sub"
+# cases cut their masked tiles of 512 into sub-blocks of 256
+# (``SUB_BLOCKS``; the rule's own sub-blocks are those of "sub-rule"):
+# windows of 512 and a ragged 300 smaller than a tile, a causal diagonal,
+# L's 9 and 6 query heads a K/V head, lengths with and without ``mask_q``,
+# Tq != Tk, and v heads of another size
 BACKWARD_CASES = {
     "full": (2, 2, 2, 32, 32, 16, 16, False, None, None, True, 8),
     "causal": (2, 2, 2, 32, 32, 16, 16, True, None, None, True, 8),
@@ -457,7 +462,21 @@ BACKWARD_CASES = {
     "Tq>Tk": (1, 2, 2, 40, 24, 16, 16, True, None, None, False, 8),
     "ragged-T": (2, 2, 2, 13, 21, 16, 16, False, None, None, False, 8),
     "rule-tiles": (1, 2, 1, 2304, 2304, 16, 16, True, 1100, None, True, None),
+    "sub+window512": (1, 2, 2, 1536, 1536, 16, 16, True, 512, None, True, 512),
+    "sub+window300": (1, 2, 1, 1024, 1024, 16, 16, True, 300, None, True, 512),
+    "sub+causal": (1, 2, 2, 1024, 1024, 16, 16, True, None, None, True, 512),
+    "sub+group9+window": (1, 9, 1, 1024, 1024, 16, 16, True, 512, None, True,
+                          512),
+    "sub+group6": (1, 6, 1, 1024, 1024, 16, 16, True, None, None, True, 512),
+    "sub+lengths+mask_q": (2, 2, 2, 1024, 1024, 16, 16, True, 300, (700, 1024),
+                           True, 512),
+    "sub+lengths-mask_q": (2, 2, 2, 1024, 1536, 16, 16, True, 300, (900, 1536),
+                           False, 512),
+    "sub+Tq>Tk": (1, 2, 2, 1536, 1024, 16, 16, True, None, None, False, 512),
+    "sub+d_v=2d/3": (1, 2, 1, 1024, 1024, 24, 16, True, None, None, True, 512),
+    "sub-rule": (1, 2, 1, 2048, 2048, 16, 16, True, 300, None, True, 1024),
 }
+SUB_BLOCKS = {case: 256 for case in BACKWARD_CASES if case.startswith("sub+")}
 
 
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "pair"])
@@ -475,11 +494,13 @@ def test_backward_forms_match_dense(case, fused):
                                 (n, hkv, tk, d_v), (n, h, tq, d_v)))
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
-    bq, bk, _ = _resolve_tiles(q, k, v, causal, window, tile, tile)
+    bq, bk, _, sub = _resolve_tiles(q, k, v, causal, window, tile, tile)
+    sub = SUB_BLOCKS.get(case, sub)
+    assert sub is not None or not case.startswith("sub")
 
     def flash(q, k, v):
         return _flash_core(q, k, v, lengths, causal, None, bq, bk, True,
-                           mask_q, window, fused)
+                           mask_q, window, fused, sub)
 
     def dense(q, k, v):
         return _dense_masked_reference(q, k, v, causal, window, lengths,
@@ -509,6 +530,110 @@ def test_backward_forms_sum_in_the_same_order():
         (0, 1, 2))(q, k, v) for fused in (True, False)]
     for one, pair in zip(*grads):
         np.testing.assert_array_equal(one, pair)
+
+
+def test_backward_forms_sum_in_the_same_order_in_sub_blocks():
+    """Inside a masked tile both forms meet a k sub-block's row strips in
+    one order too: the gradients still agree bit for bit."""
+    from bigdl_tpu.ops.flash_attention import _flash_core
+
+    rng = np.random.default_rng(6)
+    q, w = (jnp.asarray(rng.standard_normal((1, 4, 1024, 16)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 1024, 16)), jnp.float32)
+            for _ in range(2))
+    lengths = jnp.asarray((900,), jnp.int32)
+    grads = [jax.grad(lambda *a: jnp.sum(w * _flash_core(
+        *a, lengths, True, None, 512, 512, True, True, 300, fused, 128)),
+        (0, 1, 2))(q, k, v) for fused in (True, False)]
+    for one, pair in zip(*grads):
+        np.testing.assert_array_equal(one, pair)
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,causal,window,sub", [
+    (8192, 8192, 1024, 1024, True, 512, 256),    # L's window layers
+    (8192, 8192, 1024, 1024, True, 1024, 256),   # M's
+    (8192, 8192, 1024, 1024, True, None, 128),   # a causal diagonal
+    (1300, 1700, 512, 512, True, 300, 128),      # ragged, Tq < Tk
+    (1700, 1300, 512, 1024, True, None, 256),    # Tq > Tk, unequal tiles
+    (1000, 1000, 512, 512, False, None, 256),    # padded keys alone
+])
+def test_computed_pairs_are_the_sub_blocks_with_a_visible_pair(
+        tq, tk, bq, bk, causal, window, sub):
+    """``computed_over_visible`` of the tile record against a count over the
+    dense mask: a tile with every pair visible is computed whole, a tile
+    with some is computed as its sub-blocks that hold a visible pair."""
+    from bigdl_tpu.ops.flash_attention import _tile_geometry
+
+    tqp, tkp = -(-tq // bq) * bq, -(-tk // bk) * bk
+    rows = np.arange(tqp)[:, None] + (tk - tq)   # padded rows are positions
+    cols = np.arange(tkp)[None, :]
+    allowed = np.broadcast_to(cols < tk, (tqp, tkp))
+    if causal:
+        allowed = allowed & (rows >= cols)
+    if window is not None:
+        allowed = allowed & (rows - cols < window)
+    computed = 0
+    for i in range(0, tqp, bq):
+        for j in range(0, tkp, bk):
+            tile = allowed[i:i + bq, j:j + bk]
+            if tile.all():
+                computed += bq * bk
+            elif tile.any():
+                blocks = tile.reshape(bq // sub, sub, bk // sub, sub)
+                computed += int(blocks.any(axis=(1, 3)).sum()) * sub * sub
+    visible = int(allowed[:tq].sum())
+    tiles, visited, got = _tile_geometry(tq, tk, bq, bk, causal, window, sub)
+    assert got == pytest.approx(computed / visible, rel=1e-12)
+    # without sub-blocks every visited tile is computed whole
+    assert _tile_geometry(tq, tk, bq, bk, causal, window)[2] == visited
+
+
+def test_the_sub_block_follows_the_tiles_and_the_mask():
+    """No argument chooses the sub-block: the rule reads the tiles and the
+    mask. A causal tile of 1024 is cut in sub-blocks of 512; a tile no
+    larger than one, one it does not divide, and a call without a causal
+    mask keep whole tiles."""
+    from bigdl_tpu.ops.flash_attention import sub_block
+
+    assert sub_block(1024, 1024, True) == 512
+    assert sub_block(512, 1024, True) is None
+    assert sub_block(512, 512, True) is None
+    assert sub_block(768, 768, True) is None  # T 768: a whole-T tile
+    assert sub_block(1024, 1024, False) is None
+
+
+# the programs a 128-row tile traced to before masked tiles had sub-blocks
+# (digests of the jaxpr of the forward and both backward forms): a tile no
+# larger than a sub-block runs exactly the code it ran
+TILE_128_PROGRAMS = {
+    (None, False, True): "6722992e4f5d6f68",
+    (200, True, True): "a9dccc51e37208ca",
+    (200, True, False): "db4d88218e86d1dc",
+    (None, True, False): "250ab9da55440b89",
+}
+
+
+@pytest.mark.parametrize("window,lengths,fused", sorted(
+    TILE_128_PROGRAMS, key=str))
+def test_a_128_row_tile_traces_to_the_program_it_traced(window, lengths,
+                                                        fused):
+    import hashlib
+
+    from bigdl_tpu.ops.flash_attention import _flash_core, sub_block
+
+    assert sub_block(128, 128, True) is None
+    q, k = jnp.ones((1, 4, 320, 16)), jnp.ones((1, 2, 320, 16))
+    horizon = jnp.asarray([300], jnp.int32) if lengths else None
+
+    def attend(q, k, v):
+        return _flash_core(q, k, v, horizon, True, None, 128, 128, True,
+                           True, window, fused)
+
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(attend(*a)), (0, 1, 2)))(q, k, k))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        TILE_128_PROGRAMS[(window, lengths, fused)]
 
 
 def _kernel_names(fn, *args):

@@ -10,12 +10,16 @@ operands, so nothing is hoisted or overlapped), timed on the host around
 alone in both of its forms, off one forward's output and logsumexp: the one
 kernel (``bwd_fused_ms``) and the pair (``bwd_pair_ms``); which of them a
 shape gets is ``ops/flash_attention.backward_form``'s choice, and
-``fwd_bwd_ms`` runs that one. One JSON line a row on stdout; the whole table
-to ``chiprun_out/flash_tile_table.json``. Needs the tpu backend.
+``fwd_bwd_ms`` runs that one. The sub-block axis (``--subs``): the size of
+the sub-blocks a masked tile is computed in, ``whole`` for none, ``rule``
+for ``ops/flash_attention.sub_block``'s choice (the default); a size no
+smaller than a tile is ``whole``. One JSON line a row on stdout; the whole
+table to ``chiprun_out/flash_tile_table.json``. Needs the tpu backend.
 
     python tools/flash_tile_table.py            # every shape
     python tools/flash_tile_table.py a b        # the named rows only
     python tools/flash_tile_table.py a e --tiles 1024x1024,512x512
+    python tools/flash_tile_table.py l b --tiles rule --subs whole,512,256
 """
 
 import json
@@ -37,20 +41,24 @@ SHAPES = {
     "e": dict(n=2, h=32, hkv=32, t=8192, d=192, d_v=128, window=None,
               lengths=False),
     "g": dict(n=1, h=32, hkv=8, t=8192, d=64, window=None, lengths=False),
+    "l": dict(n=1, h=72, hkv=8, t=8192, d=128, window=512, lengths=False),
 }
-# the cells' shapes (M full and windowed, J, G): the backward in both forms
-BACKWARD = ("a", "b", "e", "g")
+# the cells' shapes (M full and windowed, J, G, L windowed): the backward in
+# both forms
+BACKWARD = ("a", "b", "e", "g", "l")
 TILES = [(bq, bk) for bq in (256, 512, 1024) for bk in (256, 512, 1024)]
 TARGET_S = 0.25  # a timed call repeats the kernels until it lasts about this
 
 
-def _functions(shape, bq, bk):
+def _functions(shape, bq, bk, sub):
+    """The kernels at the tile (``bq``, ``bk``) and the sub-block ``sub``
+    (``None``: masked tiles whole), jitted."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from bigdl_tpu.ops.flash_attention import (
-        _flash_bwd_impl, _flash_fwd_impl, flash_attention, pick_tiles)
+        _flash_bwd_impl, _flash_core, _flash_fwd_impl, backward_form)
 
     n, h, hkv, t, d = (shape[key] for key in ("n", "h", "hkv", "t", "d"))
     d_v = shape.get("d_v", d)
@@ -65,9 +73,11 @@ def _functions(shape, bq, bk):
         lens = jnp.asarray(
             np.random.default_rng(t).integers(t // 2, t + 1, n), jnp.int32)
 
+    fused, _ = backward_form(t, bq, bk, d, 2, d_v)
+
     def attend(q, k, v):
-        return flash_attention(q, k, v, True, block_q=bq, block_k=bk,
-                               lengths=lens, window=shape["window"])
+        return _flash_core(q, k, v, lens, True, None, bq, bk, False, True,
+                           shape["window"], fused, sub)
 
     def fwd(reps, q, k, v, cot):
         def body(_, q):
@@ -85,20 +95,18 @@ def _functions(shape, bq, bk):
             return tuple(x + 1e-6 * g for x, g in zip(qkv, vjp(cot)))
         return jax.lax.fori_loop(0, reps, body, (q, k, v))
 
-    # the backward alone, in the form asked for, at this tile (the rule's
-    # where none is given), off a forward at the same tile
-    tile = (bq, bk) if bq else pick_tiles(t, t, d, 2, d_v)
-
+    # the backward alone, in the form asked for, off a forward at the same
+    # tile and sub-block
     def residuals(q, k, v):
-        return _flash_fwd_impl(q, k, v, lens, True, None, *tile, False, True,
-                               shape["window"])
+        return _flash_fwd_impl(q, k, v, lens, True, None, bq, bk, False, True,
+                               shape["window"], sub)
 
     def bwd(fused):
         def run(reps, q, k, v, cot, out, lse):
             def body(_, qkv):
                 grads = _flash_bwd_impl(
-                    *qkv, lens, out, lse, cot, True, None, *tile, False, True,
-                    shape["window"], fused)
+                    *qkv, lens, out, lse, cot, True, None, bq, bk, False, True,
+                    shape["window"], fused, sub)
                 return tuple(x + 1e-6 * g for x, g in zip(qkv, grads))
             return jax.lax.fori_loop(0, reps, body, (q, k, v))
         return jax.jit(run)
@@ -127,40 +135,58 @@ def main(argv) -> None:
         raise SystemExit(
             f"flash_tile_table measures the Mosaic kernels: needs the tpu "
             f"backend, found {jax.default_backend()!r}")
-    from bigdl_tpu.ops.flash_attention import backward_form, pick_tiles
+    from bigdl_tpu.ops.flash_attention import (
+        _tile_geometry, backward_form, pick_tiles, sub_block)
 
-    tiles = TILES
-    if "--tiles" in argv:
-        at = argv.index("--tiles")
-        tiles = [tuple(int(b) for b in pair.split("x"))
-                 for pair in argv[at + 1].split(",")]
-        argv = argv[:at] + argv[at + 2:]
+    def option(flag, default):
+        nonlocal argv
+        if flag not in argv:
+            return default
+        at = argv.index(flag)
+        value, argv = argv[at + 1].split(","), argv[:at] + argv[at + 2:]
+        return value
+
+    tiles = option("--tiles", None)
+    subs = option("--subs", ["rule"])
     rows = []
     for name in (argv or list(SHAPES)):
         shape = SHAPES[name]
-        d_v = shape.get("d_v")
-        rule = pick_tiles(shape["t"], shape["t"], shape["d"], 2, d_v)
-        for bq, bk in tiles + [(None, None)]:
-            fused, acc = backward_form(
-                shape["t"], bq or rule[0], bk or rule[1], shape["d"], 2, d_v)
-            row = dict(shape=name, **shape, block_q=bq, block_k=bk,
-                       rule=list(rule), backward="fused" if fused else "pair",
-                       backward_acc_bytes=acc)
-            try:
-                fwd, fwd_bwd, args, residuals, bwd = _functions(shape, bq, bk)
-                row["fwd_ms"] = round(_ms_per_rep(fwd, args), 4)
-                row["fwd_bwd_ms"] = round(_ms_per_rep(fwd_bwd, args), 4)
-                if name in BACKWARD:
-                    both = args + tuple(residuals(*args[:3]))
-                    for form, key in ((True, "bwd_fused_ms"),
-                                      (False, "bwd_pair_ms")):
-                        if form and not fused:
-                            continue  # the accumulator does not fit
-                        row[key] = round(_ms_per_rep(bwd(form), both), 4)
-            except Exception as e:  # a tile the compiler refuses is a row too
-                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
-            print(json.dumps(row), flush=True)
-            rows.append(row)
+        t, d, d_v, window = shape["t"], shape["d"], shape.get("d_v"), \
+            shape["window"]
+        rule = pick_tiles(t, t, d, 2, d_v)
+        pairs = TILES + [(None, None)] if tiles is None else [
+            (None, None) if pair == "rule" else
+            tuple(int(b) for b in pair.split("x")) for pair in tiles]
+        for bq, bk in pairs:
+            tile = (bq or rule[0], bk or rule[1])
+            fused, acc = backward_form(t, *tile, d, 2, d_v)
+            for asked in subs:
+                sub = sub_block(*tile, True) if asked == "rule" \
+                    else None if asked == "whole" else int(asked)
+                if sub is not None and sub >= min(tile):
+                    sub = None  # no smaller than a tile: the tile whole
+                row = dict(shape=name, **shape, block_q=bq, block_k=bk,
+                           rule=list(rule), sub_asked=asked, sub_block=sub,
+                           computed_over_visible=round(_tile_geometry(
+                               t, t, *tile, True, window, sub)[2], 4),
+                           backward="fused" if fused else "pair",
+                           backward_acc_bytes=acc)
+                try:
+                    fwd, fwd_bwd, args, residuals, bwd = _functions(
+                        shape, *tile, sub)
+                    row["fwd_ms"] = round(_ms_per_rep(fwd, args), 4)
+                    row["fwd_bwd_ms"] = round(_ms_per_rep(fwd_bwd, args), 4)
+                    if name in BACKWARD:
+                        both = args + tuple(residuals(*args[:3]))
+                        for form, key in ((True, "bwd_fused_ms"),
+                                          (False, "bwd_pair_ms")):
+                            if form and not fused:
+                                continue  # the accumulator does not fit
+                            row[key] = round(_ms_per_rep(bwd(form), both), 4)
+                except Exception as e:  # a refused compile is a row too
+                    row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                print(json.dumps(row), flush=True)
+                rows.append(row)
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "flash_tile_table.json"), "w") as f:
