@@ -197,10 +197,10 @@ class Optimizer:
         self.criterion = criterion
         # flat_update=True carries ONE padded f32 master vector per state
         # tensor (params + each optimizer slot) through the jitted step
-        # instead of the per-leaf tree: the tree exists only as slice+reshape
-        # VIEWS inside the step (XLA aliases them into the vector's buffer)
-        # and the optimizer update collapses to a single fused segment-wise
-        # pass (docs/performance.md flat-parameter hot path). The ZeRO-1
+        # instead of the per-leaf tree: the tree exists only as split+reshape
+        # VIEWS inside the step (FlatParameter.unflatten) and the optimizer
+        # update collapses to a single fused segment-wise pass
+        # (docs/performance.md flat-parameter hot path). The ZeRO-1
         # sharded DistriOptimizer path always runs this layout; here it is
         # the opt-in single-chip / replicated variant.
         self.flat_update = flat_update
@@ -1512,13 +1512,12 @@ class Optimizer:
     def _make_flat_step(self, method, fp):
         """jit one step over the FLAT master state: the padded f32 vector (and
         the flat slot vectors) are the carried, donated arrays; the per-layer
-        tree exists only as slice+reshape+cast VIEWS materialized inside the
-        step for the forward/backward (XLA aliases them into the vector — no
-        params-sized HBM copy), the gradient arrives directly as one flat
-        vector (differentiated w.r.t. the vector, so there is no per-step
-        tree→vector concatenate either), and the optimizer update is a single
-        fused segment-wise ``update_flat`` pass instead of N per-leaf kernel
-        chains."""
+        tree exists only as split+reshape+cast VIEWS materialized inside the
+        step for the forward/backward (``FlatParameter.unflatten``), the
+        gradient arrives directly as one flat vector (differentiated w.r.t.
+        the vector: one concatenate of the leaves' gradients, written in
+        place), and the optimizer update is a single fused segment-wise
+        ``update_flat`` pass instead of N per-leaf kernel chains."""
         use_mask = self._mask_ragged = (
             self._criterion_maskable and not self._has_batch_coupled_state()
         )

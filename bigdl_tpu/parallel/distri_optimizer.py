@@ -190,13 +190,14 @@ class DistriOptimizer(Optimizer):
         """The ZeRO-1 sharded step over the FLAT master state: the padded f32
         vector is the carried (donated) canonical weights — mirroring
         AllReduceParameter, where the flat vector IS the training state. The
-        per-layer tree exists only as slice+reshape+cast VIEWS materialized
-        inside the step for the forward/backward (XLA aliases them into the
-        vector's buffer), the loss is differentiated w.r.t. the vector itself
-        (the gradient arrives flat — no params- or grads-sized concatenate
-        anywhere in the program), and the owned shard updates through ONE
-        fused segment-wise ``update_flat`` pass with weight-decay exclusions
-        precomputed as a per-element coefficient vector."""
+        per-layer tree exists only as split+reshape+cast VIEWS materialized
+        inside the step for the forward/backward (``FlatParameter.unflatten``),
+        the loss is differentiated w.r.t. the vector itself (the gradient
+        arrives flat, assembled as ONE concatenate of the leaves' gradients
+        that XLA writes in place and feeds to the reduce-scatter), and the
+        owned shard updates through ONE fused segment-wise ``update_flat``
+        pass with weight-decay exclusions precomputed as a per-element
+        coefficient vector."""
         axis = mesh.axis_names[0]
         gdtype = self.gradient_dtype
         hm = self.health

@@ -138,17 +138,22 @@ class FlatParameter:
     def unflatten(self, vec: jnp.ndarray):
         """Padded vector → tree with original shapes/dtypes (pure; jit-friendly).
 
-        Inside jit this is the zero-copy tree VIEW of the flat master state:
-        slice+reshape+cast chains that XLA aliases into the vector's buffer —
-        the forward/backward consume these views while the padded flat vector
-        stays the carried (donated) training state."""
-        leaves = []
-        for off, size, shape, dtype in zip(
-            self._offsets, self.sizes, self.shapes, self.dtypes
-        ):
-            leaves.append(
-                jax.lax.dynamic_slice(vec, (off,), (size,)).reshape(shape).astype(dtype)
-            )
+        Inside jit this is the tree VIEW of the flat master state: ONE
+        ``lax.split`` of the vector into the leaves and the padding tail
+        (dropped), each piece reshaped and cast. The forward/backward
+        consume these views while the padded flat vector stays the carried
+        (donated) training state. The split's transpose is one
+        ``concatenate`` of the leaves' cotangents and the tail's zeros, which
+        XLA writes in place: each leaf's gradient lands in its slot of the
+        flat gradient. Per-leaf ``dynamic_slice`` views would transpose to
+        one zero-padded full-length vector per leaf, all summed (18.8 ms of
+        ResNet-50's 143 ms ZeRO-1 step on a TPU v5e)."""
+        pad = self.padded_total - self.total
+        pieces = jax.lax.split(vec, self.sizes + [pad])
+        leaves = [
+            piece.reshape(shape).astype(dtype)
+            for piece, shape, dtype in zip(pieces, self.shapes, self.dtypes)
+        ]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     # ------------------------------------------------- slot-vector tree views
