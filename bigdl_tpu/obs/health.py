@@ -307,17 +307,6 @@ class HealthMonitor:
             )
         return jax.lax.psum(self._flat_reduce(fp, cols, seg), axis)
 
-    def flat_stats(self, fp, g_vec, old_vec, new_vec):
-        """Per-layer statistics over the FULL flat master vector — the
-        non-collective twin of :meth:`flat_shard_stats` for the single-device
-        / replicated ``flat_update=True`` paths (``LocalOptimizer``,
-        replicated ``DistriOptimizer``)."""
-        import jax.numpy as jnp
-
-        cols = self._flat_cols(g_vec, old_vec, new_vec)
-        seg = jnp.asarray(self._seg_ids) if self.config.per_layer else None
-        return self._flat_reduce(fp, cols, seg)
-
     def mesh_shard_stats(self, x, t, n_shards: int):
         """Per-data-shard non-finite counts over the batch input/target
         trees — the GSPMD/hybrid path's mesh localization. Under pjit the

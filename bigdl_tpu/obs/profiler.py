@@ -115,16 +115,16 @@ def memory_breakdown(params, slots=None) -> Dict[str, Any]:
 
 def flat_memory_breakdown(fp, method=None) -> Dict[str, Any]:
     """Per-layer byte table for the flat master-state layout (DistriOptimizer
-    ``parameter_sync='sharded'``, ``flat_update=True`` elsewhere).
+    ``parameter_sync='sharded'``).
 
     The padded f32 flat vector is the CARRIED master buffer (donated each
     step, the all-gather/update aliases into it — ``totals.master_bytes``);
     the per-layer tree exists as slice views inside the step plus the entry
     tree the model object still references (``param_bytes``, counted at the
     tree dtypes — stale after step 0 but resident until the run's cold seams
-    re-materialize it). Optimizer slots live as f32 flat vectors — SHARDED
-    across devices on the ZeRO-1 path (``shard_size`` elements per device per
-    slot vector), replicated under ``flat_update=True``. ``fp`` is the
+    re-materialize it). Optimizer slots live as f32 flat vectors SHARDED
+    across devices (``shard_size`` elements per device per slot vector).
+    ``fp`` is the
     :class:`~bigdl_tpu.parallel.parameter.FlatParameter` codec; ``method``
     (when given) determines the slot-vector count by initializing slots on
     an abstract flat spec."""

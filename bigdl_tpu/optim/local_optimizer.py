@@ -186,7 +186,6 @@ class Optimizer:
         criterion: AbstractCriterion,
         validate: bool = True,
         donate: bool = True,
-        flat_update: bool = False,
         comms_dtype=None,
         error_feedback: bool = True,
         master_dtype=None,
@@ -195,23 +194,15 @@ class Optimizer:
         self.model = model
         self.dataset = dataset
         self.criterion = criterion
-        # flat_update=True carries ONE padded f32 master vector per state
-        # tensor (params + each optimizer slot) through the jitted step
-        # instead of the per-leaf tree: the tree exists only as split+reshape
-        # VIEWS inside the step (FlatParameter.unflatten) and the optimizer
-        # update collapses to a single fused segment-wise pass
-        # (docs/performance.md flat-parameter hot path). The ZeRO-1
-        # sharded DistriOptimizer path always runs this layout; here it is
-        # the opt-in single-chip / replicated variant.
-        self.flat_update = flat_update
-        # low-precision policy of the flat hot path (docs/performance.md):
-        # comms_dtype compresses the flat gradient collective (bf16/fp8/int8
-        # wire format with per-segment scales + error feedback), slot_dtype
-        # stores the flat optimizer slot vectors in bf16, master_dtype the
-        # master weight vector (bf16, or the experimental fp8 tier with
-        # per-segment scales). Resolved + validated HERE so an fp8 request
-        # on a stack without float8 dies with a clean ValueError at
-        # construction, never mid-trace (utils/compat.probe_float8).
+        # low-precision policy of the ZeRO-1 flat layout (the sharded
+        # DistriOptimizer step; docs/performance.md): comms_dtype compresses
+        # the flat gradient collective (bf16/fp8/int8 wire format with
+        # per-segment scales + error feedback), slot_dtype stores the flat
+        # optimizer slot vectors in bf16, master_dtype the master weight
+        # vector in bf16. Resolved + validated HERE so a bad request (a
+        # float8 master, fp8 on a stack without float8) dies with a clean
+        # ValueError at construction, never mid-trace; every other path
+        # refuses an active policy when its fit starts.
         from .quantization import LowPrecisionPolicy
 
         _pol = LowPrecisionPolicy(
@@ -297,7 +288,6 @@ class Optimizer:
         # elastic remeshes, so a rejoin back to a previously-seen mesh
         # configuration reuses its codec (and the jitted programs below)
         self._flat_fp: Dict[int, object] = {}
-        self._flat_step_cache = None  # (method, fp, health, jitted flat step)
         self._counter_step = (None, ())  # (standard step, its counters' names)
         self._counter_names = ()
         # jitted (flatten, unflatten, slots_tree_view) per codec identity
@@ -416,7 +406,6 @@ class Optimizer:
         # the step's output signature changes with health on/off: drop any
         # cached jitted step so the next optimize() rebuilds consistently
         self._step_cache = None
-        self._flat_step_cache = None
         return self
 
     def set_perf(self, config=True) -> "Optimizer":
@@ -1138,36 +1127,22 @@ class Optimizer:
                 comp = self._compressor = GradCompressor(fp, pol)
         return sp, comp
 
-    def _flat_state_thunks(self, codec, box, state_key: str, slots_key: str):
+    def _flat_state_thunks(self, codec, box):
         """(get_params, get_slots) thunks for the cold seams of a flat-path
         run (checkpoint/validation/histograms/final sync): one jitted
         unflatten into the tree view — decoding any low-precision storage
         back to f32 first, so checkpoints stay tree-layout/f32 and
-        bit-compatible with unquantized runs (the fp8 master's reserved
-        per-segment scale entry never leaks into a manifest)."""
+        bit-compatible with unquantized runs."""
         _, unflatten, slots_view = self._flat_fns(codec)
         sp = self._state_prec
         if self._precision is not None and sp is not None and sp.fp is codec:
-            from .quantization import MASTER_SCALE_KEY
-
-            def get_params():
-                return unflatten(
-                    sp.decode_master(
-                        box[state_key], box[slots_key].get(MASTER_SCALE_KEY)
-                    )
-                )
-
-            def get_slots():
-                clean = {
-                    k: v for k, v in box[slots_key].items()
-                    if k != MASTER_SCALE_KEY
-                }
-                return slots_view(sp.decode_slots(clean))
-
-            return get_params, get_slots
+            return (
+                lambda: unflatten(sp.decode_master(box["params"])),
+                lambda: slots_view(sp.decode_slots(box["slots"])),
+            )
         return (
-            lambda: unflatten(box[state_key]),
-            lambda: slots_view(box[slots_key]),
+            lambda: unflatten(box["params"]),
+            lambda: slots_view(box["slots"]),
         )
 
     def _wd_coefficients(self, method, fp):
@@ -1509,143 +1484,19 @@ class Optimizer:
         self._step_cache = (method, n_micro, self.health, step)
         return step
 
-    def _make_flat_step(self, method, fp):
-        """jit one step over the FLAT master state: the padded f32 vector (and
-        the flat slot vectors) are the carried, donated arrays; the per-layer
-        tree exists only as split+reshape+cast VIEWS materialized inside the
-        step for the forward/backward (``FlatParameter.unflatten``), the
-        gradient arrives directly as one flat vector (differentiated w.r.t.
-        the vector: one concatenate of the leaves' gradients, written in
-        place), and the optimizer update is a single fused segment-wise
-        ``update_flat`` pass instead of N per-leaf kernel chains."""
-        use_mask = self._mask_ragged = (
-            self._criterion_maskable and not self._has_batch_coupled_state()
-        )
-        hm = self.health
-        wd_coeff = self._wd_coefficients(method, fp)
-        # low-precision policy (docs/performance.md): the state policy wraps
-        # the fused update (decode → f32 update → stochastically-rounded
-        # downcast), the compressor bottlenecks the gradient through the
-        # exact quantize→dequantize numerics of the distributed wire (with
-        # the carried error-feedback residual as an extra donated arg). With
-        # no policy both are None and the traced program is byte-identical
-        # to the pre-policy build.
-        sp, comp = self._precision_for(fp)
-        use_err = comp is not None and comp.error_feedback
-        # the EF residual is donated alongside the master vector
-        donate = ((0, 1, 2, 3) if use_err else (0, 1, 2)) if self.donate else ()
-
-        def loss_fn(params, ms, x, t, rng, nvalid):
-            if use_mask:
-                return self._masked_loss_fn(params, ms, x, t, rng, nvalid)
-            return self._loss_fn(params, ms, x, t, rng)
-
-        from .quantization import MASTER_SCALE_KEY
-
-        def step_body(flat_p, model_state, slots, err, x, t, nvalid, lr, step,
-                      rng):
-            # the forward differentiates w.r.t. the DECODED f32 master, so
-            # gradients stay full-precision whatever the storage dtype
-            with jax.named_scope("param_views"):
-                if sp is not None:
-                    p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
-                else:
-                    p32 = flat_p
-
-            def flat_loss(fvec, ms):
-                # the views' transpose is the flat gradient's assembly
-                with jax.named_scope("param_views"):
-                    tree = fp.unflatten(fvec)
-                return loss_fn(tree, ms, x, t, rng, nvalid)
-
-            (loss, new_ms), flat_g = jax.value_and_grad(
-                flat_loss, has_aux=True
-            )(p32, model_state)
-            if comp is not None:
-                # single-device wire simulation: quantize→dequantize with
-                # error feedback — the distributed paths' exact numerics
-                with jax.named_scope("grad_exchange"):
-                    g_used, new_err, qstats = comp.exchange_local(
-                        flat_g, err, want_stats=hm is not None
-                    )
-            else:
-                g_used, new_err, qstats = flat_g, None, None
-            with jax.named_scope("optim_update"):
-                g_used = self._clip_grads(g_used)  # one vector: one fused clip
-                if sp is not None:
-                    new_flat, new_slots, p_old32, p_new32 = sp.apply_update(
-                        method, g_used, flat_p, slots, lr, step,
-                        wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
-                    )
-                else:
-                    new_flat, new_slots = method.update_flat(
-                        g_used, flat_p, slots, lr, step, wd_coeff=wd_coeff
-                    )
-                    new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
-                    p_old32, p_new32 = flat_p, new_flat
-            outs = (new_flat, new_ms, new_slots)
-            if new_err is not None:
-                outs = outs + (new_err,)
-            outs = outs + (loss,)
-            if hm is None:
-                return outs
-            # per-layer rows via the codec's segment geometry (g_used is the
-            # post-dequant, post-clip effective gradient; the f32 weight
-            # views keep norms meaningful under fp8 master codes)
-            health = {"layers": hm.flat_stats(fp, g_used, p_old32, p_new32)}
-            if qstats is not None:
-                health["quant"] = qstats
-            acts = hm.act_stats(new_ms)
-            if acts is not None:
-                health["acts"] = acts
-            return outs + (health,)
-
-        if use_err:
-            @partial(jax.jit, donate_argnums=donate)
-            @step_program_name
-            def flat_step(flat_p, model_state, slots, err, x, t, nvalid, lr,
-                          step, rng):
-                return step_body(flat_p, model_state, slots, err, x, t,
-                                 nvalid, lr, step, rng)
-        else:
-            @partial(jax.jit, donate_argnums=donate)
-            @step_program_name
-            def flat_step(flat_p, model_state, slots, x, t, nvalid, lr, step,
-                          rng):
-                return step_body(flat_p, model_state, slots, None, x, t,
-                                 nvalid, lr, step, rng)
-
-        return flat_step
-
-    def _cached_flat_step(self, method, fp):
-        """Flat-path twin of :meth:`_cached_standard_step`: the jitted flat
-        step for (method, codec, health) — reused across retry/resume
-        attempts so the exactly-1-compile invariant holds through a retry."""
-        if self.health is not None:
-            # row labels + segment ids for THIS codec (refresh on hits too)
-            self.health.bind_flat(fp)
-            self.health.bind_acts(self.model.get_state())
-        cached = self._flat_step_cache
-        if (
-            cached is not None
-            and cached[0] is method
-            and cached[1] is fp
-            and cached[2] is self.health
-        ):
-            return cached[3]
-        step = self._make_flat_step(method, fp)
-        self._flat_step_cache = (method, fp, self.health, step)
-        return step
-
     def _run_with_step(self, train_step, params, model_state, slots,
                        place_batch=None, codec=None,
                        entry_params=None, entry_slots=None,
-                       extra=None) -> AbstractModule:
-        """Drive the epoch loop over a jitted step with the standard signature.
+                       extra=None, bind_box=None) -> AbstractModule:
+        """Drive the epoch loop over a jitted step with the standard signature
+        ``(params, model_state, slots[, extra], x, t, nvalid, lr, step, rng)``
+        — the one run loop of every optimizer (local, Distri, hybrid,
+        pipeline and expert).
 
         ``place_batch(x, t)`` optionally commits the batch to a sharding before
-        dispatch (used by the hybrid pjit optimizer); it runs inside the
-        prefetch thread so the placement overlaps compute.
+        dispatch (the hybrid pjit batch sharding, the Distri ``P(axis)``
+        commit); it runs inside the prefetch thread so the placement overlaps
+        compute.
 
         With ``codec`` (a FlatParameter), ``params``/``slots`` are the FLAT
         master vectors: the hot loop carries them untouched, and the per-leaf
@@ -1656,7 +1507,9 @@ class Optimizer:
         ``entry_slots`` the f32 slot representation to snapshot when the run
         carries low-precision-encoded slots. ``extra`` is an additional
         carried+donated step state (the comms error-feedback residual),
-        threaded through the step right after the slots."""
+        threaded through the step right after the slots. ``bind_box(box)``
+        is called once with the carried state before the first dispatch
+        (the elastic fleet writer slices the live master vector from it)."""
         self._capture_entry_snapshot(
             entry_params if codec is not None else params, model_state,
             entry_slots if entry_slots is not None else slots,
@@ -1665,6 +1518,8 @@ class Optimizer:
         box = {"params": params, "model_state": model_state, "slots": slots,
                "extra": extra}
         has_extra = extra is not None
+        if bind_box is not None:
+            bind_box(box)
         self._place_batch = place_batch
         self._jit_step = train_step  # compile-count introspection (tests)
 
@@ -1674,20 +1529,30 @@ class Optimizer:
         made, names = self._counter_step
         counter_names = self._counter_names = names if made is train_step else ()
 
+        # the real (unpadded) rows of each batch as a device scalar, put once
+        # for each count a fit sees (the full batch, a ragged tail): a fresh
+        # put every step is a host→device transfer on the driver thread,
+        # ~2 ms a step on a four-chip v5e host beside the worker's batch
+        # transfers
+        nvalid_of = {}
+
         def run_iteration(batch, lr: float):
             # the three spans below split the driver's `dispatch` span: host
             # work that delays the enqueue, the enqueue, and what follows it
             # (the carried state rebound, the walk of the module tree)
             with obs_span("step_args"):
-                x = _to_device_tree(batch.get_input())
-                t = _to_device_tree(batch.get_target())
+                n = batch.size()
+                nvalid = nvalid_of.get(n)
+                if nvalid is None:
+                    nvalid = nvalid_of[n] = jnp.asarray(n, jnp.float32)
                 args = (box["params"], box["model_state"], box["slots"])
                 if has_extra:
                     args = args + (box["extra"],)
                 args = args + (
-                    x,
-                    t,
-                    jnp.asarray(batch.size(), jnp.float32),  # real (unpadded) rows
+                    # device arrays: the prefetch seam converted and placed them
+                    batch.get_input(),
+                    batch.get_target(),
+                    nvalid,
                     jnp.asarray(lr, jnp.float32),
                     jnp.asarray(state["neval"]),
                     RandomGenerator.next_key(),
@@ -1729,9 +1594,7 @@ class Optimizer:
             get_params = lambda: box["params"]  # noqa: E731
             get_slots = lambda: box["slots"]  # noqa: E731
         else:
-            get_params, get_slots = self._flat_state_thunks(
-                codec, box, "params", "slots"
-            )
+            get_params, get_slots = self._flat_state_thunks(codec, box)
         self._drive_loop(
             run_iteration,
             get_params,
@@ -2432,8 +2295,9 @@ class Optimizer:
 
     def _maybe_checkpoint(self, state, get_params, get_slots) -> None:
         """``get_params``/``get_slots`` are THUNKS, evaluated only when the
-        trigger fires: on the flat master-state paths, materializing the tree
-        view costs a params-sized copy, which must never ride every step."""
+        trigger fires: on the flat (ZeRO-1) master-state path, materializing
+        the tree view costs a params-sized copy, which must never ride every
+        step."""
         if self.checkpoint_path is None or self.checkpoint_trigger is None:
             return
         if self.checkpoint_trigger(state):
@@ -2631,7 +2495,7 @@ class Optimizer:
 
     def _run_validation(self, get_params, get_model_state) -> Optional[Dict[str, ValidationResult]]:
         """``get_params``/``get_model_state`` are THUNKS — evaluated only when
-        the trigger fires (the flat paths pay a tree materialization)."""
+        the trigger fires (the flat path pays a tree materialization)."""
         if (
             self.validation_trigger is None
             or self.validation_dataset is None
@@ -2715,59 +2579,14 @@ class LocalOptimizer(Optimizer):
         self._audit_params()
         self._install_health()  # hooks seed state BEFORE the pytree is read
         params, model_state = model.get_parameters(), model.get_state()
-        if self._precision is not None and not self.flat_update:
+        if self._precision is not None:
             raise ValueError(
                 "low-precision policies (comms_dtype/master_dtype/slot_dtype) "
-                "hang off the flat master buffer; construct the optimizer "
-                "with flat_update=True (or use the ZeRO-1 sharded "
-                "DistriOptimizer, which always carries the flat layout)"
+                "hang off the ZeRO-1 flat master buffer; use "
+                "DistriOptimizer(parameter_sync='sharded'), which runs on a "
+                "one-device mesh too"
             )
-        if not self.flat_update:
-            slots = self._init_slots(method, params)
-            return self._run_with_step(
-                self._cached_standard_step(method), params, model_state, slots
-            )
-        # flat master-state path (opt-in): one padded f32 vector per state
-        # tensor, tree views only inside the step, single fused update
-        if getattr(self, "_micro_batches", 1) != 1:
-            raise NotImplementedError(
-                "flat_update does not compose with set_micro_batches; pick one"
-            )
-        if not getattr(method, "elementwise", True):
-            raise ValueError(
-                f"{type(method).__name__} is layer-structure-aware and cannot "
-                "run on the flat parameter layout; use flat_update=False"
-            )
-        fp = self._flat_codec(params, n_shards=1)
-        flatten, _, _ = self._flat_fns(fp)
-        flat = flatten(params)  # the ONE tree→vector copy of this run
-        if self.validate:
-            # same pre-step hygiene gate the ZeRO-1 sharded path runs, on the
-            # exact flat layout the step consumes
-            from ..analysis import FlatParamAudit
-
-            with obs_span("flat_param_audit"):
-                FlatParamAudit(fp, flat).check()
-        slots = self._init_flat_slots(method, fp)
-        entry_slots = slots  # f32 representation: what the snapshot stores
-        extra = None
-        sp, comp = self._precision_for(fp)
-        if sp is not None:
-            # encode ONCE at entry (round-to-nearest; stochastic rounding
-            # only matters on the repeated per-step downcasts) — from here
-            # the carried master/slots live in storage precision and the
-            # cold seams decode through _flat_state_thunks
-            from .quantization import MASTER_SCALE_KEY
-
-            flat, mscale = sp.encode_master(flat)
-            slots = sp.encode_slots(slots)
-            if mscale is not None:
-                slots = dict(slots)
-                slots[MASTER_SCALE_KEY] = mscale
-        if comp is not None and comp.error_feedback:
-            extra = jnp.asarray(comp.init_residual(1, row=False))
+        slots = self._init_slots(method, params)
         return self._run_with_step(
-            self._cached_flat_step(method, fp), flat, model_state, slots,
-            codec=fp, entry_params=params, entry_slots=entry_slots,
-            extra=extra,
+            self._cached_standard_step(method), params, model_state, slots
         )

@@ -63,9 +63,8 @@ class OptimMethod:
                     wd_coeff=None, lr_scale=None):
         """Single fused segment-wise update over a flat f32 parameter vector.
 
-        The flat-parameter hot path (ZeRO-1 sharded ``DistriOptimizer``,
-        ``flat_update=True`` on ``LocalOptimizer``) carries ONE padded f32
-        vector per state tensor instead of a per-leaf tree; this entry point
+        The flat-parameter hot path (the ZeRO-1 sharded ``DistriOptimizer``
+        step) carries ONE padded f32 vector per state tensor instead of a per-leaf tree; this entry point
         collapses the N-leaf ``update`` chains into one elementwise pass over
         that vector. Per-segment hyperparameters arrive as per-ELEMENT
         coefficient vectors precomputed once by

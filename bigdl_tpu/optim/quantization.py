@@ -1,8 +1,9 @@
 """Low-precision training policy for the flat master-state hot path.
 
-The flat-parameter layout (PR 6) made precision a property you can hang off
-ONE vector per state tensor instead of N tree leaves — this module is where
-it hangs. Three knobs, all resolved/validated at optimizer construction
+The flat-parameter layout made precision a property you can hang off ONE
+vector per state tensor instead of N tree leaves — this module is where it
+hangs. That layout lives on the ZeRO-1 sharded ``DistriOptimizer`` step, so
+the policy does too. Three knobs, all resolved/validated at optimizer construction
 through :func:`bigdl_tpu.utils.compat.resolve_precision_dtype` (so an fp8
 request on a stack without float8 dies with a clean ``ValueError``, never an
 import crash mid-trace):
@@ -14,10 +15,7 @@ import crash mid-trace):
   (``"bfloat16"``): carried/donated in bf16, upcast to f32 inside the fused
   ``update_flat``, downcast back with stochastic rounding.
 * ``master_dtype`` — storage dtype of the flat master weight vector:
-  ``"bfloat16"`` (plain low-precision master, stochastic-rounded) or the
-  experimental ``"float8_e4m3"`` tier, which stores the master as fp8 codes
-  plus a per-segment f32 scale vector riding next to the codec (under the
-  reserved ``"_master_scale"`` slot key).
+  ``"bfloat16"`` (a plain low-precision master, stochastic-rounded).
 
 Every downcast is stochastically rounded with a key derived from the STEP
 COUNTER (``fold_in(base, step)``) — never the host RNG stream, so enabling a
@@ -38,7 +36,7 @@ dtype-promoting ops — every ``jnp.zeros``/``arange`` spells its dtype, and
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -48,13 +46,7 @@ from ..utils.compat import resolve_precision_dtype
 __all__ = [
     "LowPrecisionPolicy", "StatePrecision", "stochastic_round",
     "segment_amax", "scales_from_amax", "quant_range_max",
-    "MASTER_SCALE_KEY",
 ]
-
-# reserved slot key carrying the fp8 master's per-segment scale vector —
-# stripped before the checkpoint/validation tree views (cold seams persist
-# the DECODED f32 state, not the codes)
-MASTER_SCALE_KEY = "_master_scale"
 
 # base PRNG key for stochastic rounding; folded with the step counter (and a
 # small per-tensor salt) at trace time. A constant, not host RNG: rounding
@@ -145,11 +137,13 @@ class LowPrecisionPolicy:
         self.comms_dtype = resolve_precision_dtype(comms_dtype, "comms_dtype")
         self.master_dtype = resolve_precision_dtype(master_dtype, "master_dtype")
         self.slot_dtype = resolve_precision_dtype(slot_dtype, "slot_dtype")
-        if self.master_dtype is not None and jnp.dtype(self.master_dtype) == jnp.dtype(jnp.int8):
+        if self.master_dtype is not None and jnp.dtype(self.master_dtype) not in (
+            jnp.dtype(jnp.bfloat16),
+        ):
             raise ValueError(
-                "master_dtype='int8' is not supported (integer master "
-                "weights have no gradient); use 'bfloat16' or the "
-                "experimental 'float8_e4m3' tier"
+                "master_dtype supports 'bfloat16' (f32 is the default; int8 "
+                "master weights have no gradient, and a float8 master would "
+                "need per-segment scales gathered beside the ZeRO-1 shards)"
             )
         if self.slot_dtype is not None and jnp.dtype(self.slot_dtype) not in (
             jnp.dtype(jnp.bfloat16),
@@ -174,15 +168,6 @@ class LowPrecisionPolicy:
     def quantizes_state(self) -> bool:
         return self.master_dtype is not None or self.slot_dtype is not None
 
-    @property
-    def master_scaled(self) -> bool:
-        """True when the master is stored as scaled codes (fp8 tier) rather
-        than a plain lower-precision float vector (bf16)."""
-        return (
-            self.master_dtype is not None
-            and jnp.dtype(self.master_dtype).name in _QUANT_RANGE
-        )
-
     def describe(self) -> Dict[str, Any]:
         """JSON-able policy summary for telemetry/bench artifacts."""
         name = lambda d: None if d is None else jnp.dtype(d).name  # noqa: E731
@@ -204,48 +189,25 @@ class StatePrecision:
     def __init__(self, fp, policy: LowPrecisionPolicy):
         self.fp = fp
         self.policy = policy
-        self._seg_ids = None
-        if policy.master_scaled:
-            self._seg_ids = jnp.asarray(fp.segment_ids())
-            self._qmax = quant_range_max(policy.master_dtype)
 
     # ------------------------------------------------------- master encoding
-    def encode_master(self, vec_f32) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
-        """f32 master vector → (stored vector, per-segment scale | None).
-        Runs once per optimize()/resume at the entry-commit seam (round to
-        nearest — SR only matters on the repeated per-step downcasts)."""
+    def encode_master(self, vec_f32) -> jnp.ndarray:
+        """f32 master vector → stored vector. Runs once per optimize()/resume
+        at the entry-commit seam (round to nearest — SR only matters on the
+        repeated per-step downcasts)."""
         md = self.policy.master_dtype
-        if md is None:
-            return vec_f32, None
-        if not self.policy.master_scaled:
-            return vec_f32.astype(md), None
-        amax = segment_amax(vec_f32, self._seg_ids, len(self.fp.sizes) + 1)
-        scales = scales_from_amax(amax, self._qmax)
-        return (vec_f32 / scales[self._seg_ids]).astype(md), scales
+        return vec_f32 if md is None else vec_f32.astype(md)
 
-    def decode_master(self, stored, scale=None) -> jnp.ndarray:
+    def decode_master(self, stored) -> jnp.ndarray:
         """Stored master → f32 (the sanctioned master dequant seam)."""
         if self.policy.master_dtype is None:
             return stored
-        if not self.policy.master_scaled:
-            return stored.astype(jnp.float32)  # lint: disable=BDL013 the sanctioned bf16-master dequant seam
-        deq = stored.astype(jnp.float32)  # lint: disable=BDL013 the sanctioned fp8-master dequant seam
-        return deq * scale[self._seg_ids]
+        return stored.astype(jnp.float32)  # lint: disable=BDL013 the sanctioned bf16-master dequant seam
 
-    def downcast_master(self, vec_f32, key) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
-        """Per-step f32 → stored downcast with stochastic rounding; for the
-        fp8 tier the per-segment scales are recomputed from the UPDATED
-        weights (dynamic range tracking, one segment-wise amax)."""
+    def downcast_master(self, vec_f32, key) -> jnp.ndarray:
+        """Per-step f32 → stored downcast with stochastic rounding."""
         md = self.policy.master_dtype
-        if md is None:
-            return vec_f32, None
-        if not self.policy.master_scaled:
-            return stochastic_round(vec_f32, md, key), None
-        amax = segment_amax(vec_f32, self._seg_ids, len(self.fp.sizes) + 1)
-        scales = scales_from_amax(amax, self._qmax)
-        y = vec_f32 / scales[self._seg_ids]
-        # dithered SR happens in the SCALED domain, where the ulp is uniform
-        return stochastic_round(y, md, key), scales
+        return vec_f32 if md is None else stochastic_round(vec_f32, md, key)
 
     # --------------------------------------------------------- slot encoding
     def _is_flat_slot(self, v) -> bool:
@@ -253,13 +215,12 @@ class StatePrecision:
 
     def encode_slots(self, slots: Dict[str, Any]) -> Dict[str, Any]:
         """Entry-commit cast of the flat slot vectors to ``slot_dtype``
-        (scalar slot state and reserved keys pass through)."""
+        (scalar slot state passes through)."""
         sd = self.policy.slot_dtype
         if sd is None:
             return slots
         return {
-            k: v.astype(sd)
-            if k != MASTER_SCALE_KEY and self._is_flat_slot(v) else v
+            k: v.astype(sd) if self._is_flat_slot(v) else v
             for k, v in slots.items()
         }
 
@@ -272,7 +233,7 @@ class StatePrecision:
         sd = jnp.dtype(self.policy.slot_dtype)
         return {
             k: v.astype(jnp.float32)  # lint: disable=BDL013 the sanctioned slot dequant seam
-            if k != MASTER_SCALE_KEY and getattr(v, "dtype", None) == sd
+            if getattr(v, "dtype", None) == sd
             else v
             for k, v in slots.items()
         }
@@ -286,7 +247,7 @@ class StatePrecision:
             return slots
         out: Dict[str, Any] = {}
         for i, (k, v) in enumerate(sorted(slots.items())):
-            if k != MASTER_SCALE_KEY and getattr(v, "dtype", None) == jnp.dtype(
+            if getattr(v, "dtype", None) == jnp.dtype(
                 jnp.float32
             ) and getattr(v, "ndim", 0) == 1:
                 out[k] = stochastic_round(v, sd, jax.random.fold_in(key, i))
@@ -306,33 +267,22 @@ class StatePrecision:
                      pad_zero=None, p32=None):
         """The policy-wrapped fused update: decode stored state to f32, run
         the method's segment-wise ``update_flat``, stochastically downcast
-        the results back to storage precision. The fp8 master's per-segment
-        scale vector rides ``slots_stored`` under :data:`MASTER_SCALE_KEY`
-        (this function owns attaching the refreshed one). ``p32`` short-cuts
-        the master decode when the caller already materialized it for the
-        forward. Returns ``(stored_master, stored_slots, p32_old, p32_new)``
-        — the f32 views ride out so health statistics see real weight
-        values, not fp8 codes."""
-        mscale = slots_stored.get(MASTER_SCALE_KEY)
+        the results back to storage precision. ``p32`` short-cuts the master
+        decode when the caller already materialized it for the forward.
+        Returns ``(stored_master, stored_slots, p32_old, p32_new)`` — the
+        f32 views ride out so health statistics see full-precision weights."""
         if p32 is None:
-            p32 = self.decode_master(master_stored, mscale)
-        s32 = self.decode_slots(
-            {k: v for k, v in slots_stored.items() if k != MASTER_SCALE_KEY}
-        )
+            p32 = self.decode_master(master_stored)
+        s32 = self.decode_slots(slots_stored)
         new_p32, new_s32 = method.update_flat(
             gvec_f32, p32, s32, lr, step, wd_coeff=wd_coeff, lr_scale=lr_scale
         )
         if pad_zero is not None:
-            # re-zero the inert tail in f32, BEFORE quantization — a scaled
-            # code of a stale tail value must never survive in the codes
+            # re-zero the inert tail in f32, BEFORE the downcast
             new_p32 = pad_zero(new_p32)
         key = self.sr_key(step)
-        stored_p, new_scale = self.downcast_master(
-            new_p32, jax.random.fold_in(key, 0xA)
-        )
+        stored_p = self.downcast_master(new_p32, jax.random.fold_in(key, 0xA))
         stored_slots = self.downcast_slots(
             new_s32, jax.random.fold_in(key, 0xB)
         )
-        if new_scale is not None:
-            stored_slots[MASTER_SCALE_KEY] = new_scale
         return stored_p, stored_slots, p32, new_p32

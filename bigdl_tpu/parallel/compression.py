@@ -12,15 +12,15 @@ counting collective operand bytes on the lowered SPMD program
 
 Wire schemes per dtype:
 
-* **bfloat16** — plain cast; the collective itself (``psum_scatter`` /
-  ``pmean``) runs on bf16 operands and accumulates in bf16. Lossy partial
+* **bfloat16** — plain cast; the collective itself (``psum_scatter``)
+  runs on bf16 operands and accumulates in bf16. Lossy partial
   sums are what the error-feedback residual compensates.
 * **int8 / float8** — per-segment symmetric scales from ONE segment-wise
   amax over ``FlatParameter.segment_ids()`` (the same machinery health's
   flat reductions ride), ``pmax``-shared across devices so every device
   quantizes against identical scales. The exchange is an ``all_to_all``
-  (ZeRO-1 reduce-scatter shape) or ``all_gather`` (replicated shape) of the
-  quantized codes with the summation done in f32 AFTER dequantization —
+  (the ZeRO-1 reduce-scatter shape) of the quantized codes with the
+  summation done in f32 AFTER dequantization —
   quantized partial sums would overflow int8 and saturate fp8, so the
   reduction deliberately never runs in the wire dtype.
 
@@ -55,9 +55,8 @@ __all__ = ["GradCompressor"]
 
 
 class GradCompressor:
-    """One codec-bound compressed-gradient exchange, shared by the ZeRO-1
-    sharded step, the replicated flat step and the single-device flat step.
-    All methods below are pure jnp and trace straight into the jitted step
+    """One codec-bound compressed-gradient exchange for the ZeRO-1 sharded
+    step. All methods below are pure jnp and trace straight into the jitted step
     builders; construction is host-side and happens once per fit."""
 
     def __init__(self, fp, policy: LowPrecisionPolicy):
@@ -73,15 +72,11 @@ class GradCompressor:
         self.n_rows = len(fp.sizes) + 1  # + the padding-tail segment
 
     # ---------------------------------------------------------------- host
-    def init_residual(self, n_dev: int, row: bool = True) -> np.ndarray:
+    def init_residual(self, n_dev: int) -> np.ndarray:
         """Zero error-feedback residual: one padded-master-geometry vector
         PER DEVICE (the residual is each device's private untransmitted
-        signal). ``row=True`` shapes it ``(n_dev, padded_total)`` for the
-        shard_map paths (sharded ``P(axis)`` over the device axis — works
-        for any ``n_dev`` including 1); ``row=False`` is the bare
-        ``(padded_total,)`` vector of the single-device path."""
-        if not row:
-            return np.zeros((self.fp.padded_total,), np.float32)
+        signal), shaped ``(n_dev, padded_total)`` and sharded ``P(axis)``
+        over the device axis — works for any ``n_dev`` including 1."""
         return np.zeros((n_dev, self.fp.padded_total), np.float32)
 
     # -------------------------------------------------------------- traced
@@ -92,16 +87,16 @@ class GradCompressor:
             return g32
         return g32 + err_row
 
-    def _quantize(self, g_work, axis: Optional[str]):
+    def _quantize(self, g_work, axis: str):
         """f32 working gradient → (wire codes, per-element scale | None).
         For the scaled dtypes the per-segment scales are ``pmax``-shared
         across ``axis`` (a tiny f32 all-reduce over n_segments scalars) so
         every device's codes dequantize against identical scales."""
         if self.cast_only:
             return g_work.astype(self.dtype), None
-        amax = segment_amax(g_work, self._seg_ids, self.n_rows)
-        if axis is not None:
-            amax = jax.lax.pmax(amax, axis)
+        amax = jax.lax.pmax(
+            segment_amax(g_work, self._seg_ids, self.n_rows), axis
+        )
         scales = scales_from_amax(amax, self.qmax)
         scale_elem = scales[self._seg_ids]
         y = g_work / scale_elem
@@ -118,14 +113,14 @@ class GradCompressor:
             return deq
         return deq * scale_elem
 
-    def _residual_out(self, g_work, q, scale_elem, row: bool):
-        """EF update: the untransmitted remainder, tail re-zeroed. ``row``
-        shapes it ``(1, padded)`` for the per-device slice of the sharded
-        residual carry."""
+    def _residual_out(self, g_work, q, scale_elem):
+        """EF update: the untransmitted remainder, tail re-zeroed, shaped
+        ``(1, padded)`` for the per-device slice of the sharded residual
+        carry."""
         if not self.error_feedback:
             return None
         err = self.fp.zero_pad(g_work - self._dequant(q, scale_elem))
-        return err[None, :] if row else err
+        return err[None, :]
 
     def quant_stats(self, g_work, q, scale_elem):
         """Per-segment ``(n_rows, 3)`` f32 quantizer telemetry — [amax,
@@ -200,47 +195,10 @@ class GradCompressor:
                 scale_elem, (me * self.fp.shard_size,), (self.fp.shard_size,)
             )
             shard_sum = jnp.sum(deq, axis=0) * scale_shard
-        new_err = self._residual_out(g_work, q, scale_elem, row=True)
+        new_err = self._residual_out(g_work, q, scale_elem)
         stats = None
         if want_stats:
             stats = self._combine_stats(
                 self.quant_stats(g_work, q, scale_elem), axis
             )
         return shard_sum, new_err, stats
-
-    def exchange_replicated(
-        self, flat_g, err_row, axis: str, n_dev: int, want_stats: bool
-    ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]:
-        """Replicated (all-reduce) shape: local flat gradient in, MEAN f32
-        gradient out. bf16 rides ``pmean`` on bf16 operands; the scaled
-        dtypes all-gather their codes and average dequantized rows in f32."""
-        g_work = self._carry_in(flat_g, err_row)
-        q, scale_elem = self._quantize(g_work, axis)
-        if self.cast_only:
-            g_mean = jax.lax.pmean(q, axis).astype(jnp.float32)  # lint: disable=BDL013 the sanctioned comms dequant seam (bf16 wire)
-        else:
-            recv = jax.lax.all_gather(q, axis, tiled=False)
-            deq = recv.astype(jnp.float32)  # lint: disable=BDL013 the sanctioned comms dequant seam
-            g_mean = jnp.sum(deq, axis=0) * scale_elem / n_dev
-        new_err = self._residual_out(g_work, q, scale_elem, row=True)
-        stats = None
-        if want_stats:
-            stats = self._combine_stats(
-                self.quant_stats(g_work, q, scale_elem), axis
-            )
-        return g_mean, new_err, stats
-
-    def exchange_local(
-        self, flat_g, err, want_stats: bool
-    ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]:
-        """Single-device shape (``flat_update=True`` LocalOptimizer): no
-        collective, but the gradient still passes through the quantize →
-        dequantize bottleneck with error feedback — the exact on-wire
-        numerics of the distributed paths, reproducible on one chip (this is
-        what the trajectory-tolerance fits lock)."""
-        g_work = self._carry_in(flat_g, err)
-        q, scale_elem = self._quantize(g_work, axis=None)
-        g_used = self._dequant(q, scale_elem)
-        new_err = self._residual_out(g_work, q, scale_elem, row=False)
-        stats = self.quant_stats(g_work, q, scale_elem) if want_stats else None
-        return g_used, new_err, stats

@@ -59,42 +59,25 @@ class DistriOptimizer(Optimizer):
         dataset: AbstractDataSet,
         criterion: AbstractCriterion,
         parameter_sync: str = "sharded",
-        gradient_dtype=None,
         validate: bool = True,
         donate: bool = True,
-        flat_update: bool = False,
-        async_placement: bool = True,
         comms_dtype=None,
         error_feedback: bool = True,
         master_dtype=None,
         slot_dtype=None,
     ):
-        # flat_update only affects the REPLICATED sync mode (flat master
-        # vector + one fused pmean/update instead of per-leaf trees); the
-        # sharded ZeRO-1 mode always carries the flat master state — that
+        # the sharded ZeRO-1 mode carries the flat master state — that
         # layout IS the AllReduceParameter design. comms_dtype/master_dtype/
-        # slot_dtype are the flat path's low-precision policy
-        # (docs/performance.md): compressed gradient collectives with error
-        # feedback + quantized training state.
+        # slot_dtype are its low-precision policy (docs/performance.md):
+        # compressed gradient collectives with error feedback + bf16
+        # training state; the replicated mode refuses them.
         super().__init__(model, dataset, criterion, validate=validate,
-                         donate=donate, flat_update=flat_update,
-                         comms_dtype=comms_dtype,
+                         donate=donate, comms_dtype=comms_dtype,
                          error_feedback=error_feedback,
                          master_dtype=master_dtype, slot_dtype=slot_dtype)
         if parameter_sync not in ("auto", "sharded", "replicated"):
             raise ValueError(f"unknown parameter_sync {parameter_sync!r}")
         self.parameter_sync = parameter_sync
-        # bf16 gradient wire format = the fp16 CompressedTensor analog;
-        # superseded by comms_dtype (which adds per-segment scales + error
-        # feedback) when both are set
-        self.gradient_dtype = gradient_dtype
-        # async_placement=True (default) runs the batch's sharding commit —
-        # the host→device transfer — inside the PREFETCH worker, so it
-        # overlaps the running step's compute; False restores the serialized
-        # behavior (commit on the consumer thread, in front of every SPMD
-        # dispatch) — kept as the measurable baseline for the dispatch-gap
-        # span-overlap tests (docs/performance.md).
-        self.async_placement = bool(async_placement)
         # per-mesh-configuration step cache: device-id tuple → (method,
         # sync, FlatParameter, jitted step, health, mesh). Reused across
         # retry attempts (a resume re-commits shardings and dispatches into
@@ -105,12 +88,13 @@ class DistriOptimizer(Optimizer):
         self._distri_step_cache = {}
 
     def set_micro_batches(self, n: int) -> "DistriOptimizer":
-        """Not supported here: the SPMD steps are built by
-        _make_sharded_step/_make_replicated_step, which don't read the
-        setting — silently dropping the documented HBM lever would leave
-        a user OOMing with no indication why (r5 review finding). Under
-        dp sharding the per-chip batch is already batch/n_dev; to cut
-        activation memory further use ``nn.Remat`` on the model."""
+        """Not supported here: neither SPMD step builder
+        (``_make_sharded_step``, ``_make_replicated_step``) splits the batch
+        into micro batches — only the standard step of ``LocalOptimizer``
+        and the hybrid optimizer does — and silently dropping the HBM lever
+        would leave a user OOMing with no sign why. Under dp sharding the
+        per-chip batch is already batch/n_dev; to cut activation memory
+        further use ``nn.Remat`` on the model."""
         raise NotImplementedError(
             "set_micro_batches is LocalOptimizer-only; with DistriOptimizer "
             "use nn.Remat (gradient checkpointing) for activation memory")
@@ -140,8 +124,8 @@ class DistriOptimizer(Optimizer):
                     jax.lax.pmean(loss, axis))
 
     def _ragged_seam_policy(self) -> str:
-        # the SPMD steps take no nvalid scalar: a padded row would train as
-        # real data. DistributedDataSet already drops non-divisible train
+        # the SPMD steps ignore their nvalid scalar: a padded row would train
+        # as real data. DistributedDataSet already drops non-divisible train
         # batches, so pass the rest through untouched.
         return "pass"
 
@@ -199,7 +183,6 @@ class DistriOptimizer(Optimizer):
         pass with weight-decay exclusions precomputed as a per-element
         coefficient vector."""
         axis = mesh.axis_names[0]
-        gdtype = self.gradient_dtype
         hm = self.health
         wd_coeff_full = self._wd_coefficients(method, fp)
         # low-precision policy (docs/performance.md): comp compresses the
@@ -211,8 +194,8 @@ class DistriOptimizer(Optimizer):
         sp, comp = self._precision_for(fp)
         use_err = comp is not None and comp.error_feedback
 
-        def per_device(flat_p, model_state, slot_shard, err, x, t, lr, it,
-                       rng):
+        def per_device(flat_p, model_state, slot_shard, err, x, t, nvalid, lr,
+                       it, rng):
             rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
             # differentiate w.r.t. the DECODED master so gradients stay
             # full-precision whatever the storage dtype (bf16 master)
@@ -241,13 +224,11 @@ class DistriOptimizer(Optimizer):
                     g_shard = shard_sum / n_dev
                 else:
                     new_err = qstats = None
-                    if gdtype is not None:
-                        flat_g = flat_g.astype(gdtype)
                     # reduce-scatter: each device ends with the summed slice
                     # it owns
                     g_shard = jax.lax.psum_scatter(
                         flat_g, axis, tiled=True
-                    ).astype(jnp.float32) / n_dev
+                    ) / n_dev
                 g_shard = self._clip_shard_global(g_shard, axis)
             g_stat = g_shard  # post-clip effective gradient (health stats)
             with jax.named_scope("optim_update"):
@@ -305,10 +286,10 @@ class DistriOptimizer(Optimizer):
         if not use_err:
             body = per_device
 
-            def per_device_noerr(flat_p, model_state, slot_shard, x, t, lr,
-                                 it, rng):
-                return body(flat_p, model_state, slot_shard, None, x, t, lr,
-                            it, rng)
+            def per_device_noerr(flat_p, model_state, slot_shard, x, t,
+                                 nvalid, lr, it, rng):
+                return body(flat_p, model_state, slot_shard, None, x, t,
+                            nvalid, lr, it, rng)
 
             per_device = per_device_noerr
         # donate flat/model_state/slot_shard (+ the EF residual): the
@@ -321,7 +302,8 @@ class DistriOptimizer(Optimizer):
         if use_err:
             in_specs = in_specs + (P(axis),)
             out_specs = out_specs + (P(axis),)
-        in_specs = in_specs + (P(axis), P(axis), P(), P(), P())
+        # x, t sharded; nvalid, lr, it, rng replicated
+        in_specs = in_specs + (P(axis), P(axis), P(), P(), P(), P())
         out_specs = out_specs + (P(),)
         if hm is not None:
             out_specs = out_specs + (P(),)  # replicated health pytree
@@ -337,120 +319,16 @@ class DistriOptimizer(Optimizer):
             donate_argnums=donate if self.donate else (),
         )
 
-    def _make_replicated_flat_step(self, fp: FlatParameter, mesh, method,
-                                   n_dev: int):
-        """``flat_update=True`` twin of :meth:`_make_replicated_step`: the
-        replicated flat master vector is the carried state, the gradient
-        pmean collapses to ONE fused collective over one vector (instead of a
-        per-leaf collective chain), and the update is a single segment-wise
-        pass."""
-        axis = mesh.axis_names[0]
-        gdtype = self.gradient_dtype
-        hm = self.health
-        wd_coeff = self._wd_coefficients(method, fp)
-        from ..optim.quantization import MASTER_SCALE_KEY
-
-        sp, comp = self._precision_for(fp)
-        use_err = comp is not None and comp.error_feedback
-
-        def per_device(flat_p, model_state, slots, err, x, t, lr, it, rng):
-            rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
-            with jax.named_scope("param_views"):
-                if sp is not None:
-                    p32 = sp.decode_master(flat_p, slots.get(MASTER_SCALE_KEY))
-                else:
-                    p32 = flat_p
-
-            def flat_loss(fvec, ms):
-                with jax.named_scope("param_views"):
-                    tree = fp.unflatten(fvec)
-                return self._loss_fn(tree, ms, x, t, rng_local)
-
-            (loss, new_ms), flat_g = jax.value_and_grad(
-                flat_loss, has_aux=True
-            )(p32, model_state)
-            with jax.named_scope("grad_exchange"):
-                if comp is not None:
-                    flat_g, new_err, qstats = comp.exchange_replicated(
-                        flat_g, None if err is None else err[0], axis, n_dev,
-                        want_stats=hm is not None,
-                    )
-                else:
-                    new_err = qstats = None
-                    if gdtype is not None:
-                        flat_g = flat_g.astype(gdtype)
-                    flat_g = jax.lax.pmean(flat_g, axis).astype(jnp.float32)
-                flat_g = self._clip_grads(flat_g)  # on the aggregated gradient
-            with jax.named_scope("optim_update"):
-                if sp is not None:
-                    new_flat, slots, p_old32, p_new32 = sp.apply_update(
-                        method, flat_g, flat_p, slots, lr, it,
-                        wd_coeff=wd_coeff, pad_zero=fp.zero_pad, p32=p32,
-                    )
-                else:
-                    new_flat, slots = method.update_flat(
-                        flat_g, flat_p, slots, lr, it, wd_coeff=wd_coeff
-                    )
-                    new_flat = fp.zero_pad(new_flat)  # inert tail stays zero
-                    p_old32, p_new32 = flat_p, new_flat
-            new_ms, loss = self._state_sync(new_ms, loss, axis)
-            outs = (new_flat, new_ms, slots)
-            if new_err is not None:
-                outs = outs + (new_err,)
-            outs = outs + (loss,)
-            if hm is None:
-                return outs
-            health = {"layers": hm.flat_stats(fp, flat_g, p_old32, p_new32)}
-            if qstats is not None:
-                health["quant"] = qstats
-            acts = hm.act_stats(new_ms)
-            if acts is not None:
-                health["acts"] = acts
-            return outs + (health,)
-
-        if not use_err:
-            body = per_device
-
-            def per_device_noerr(flat_p, model_state, slots, x, t, lr, it,
-                                 rng):
-                return body(flat_p, model_state, slots, None, x, t, lr, it,
-                            rng)
-
-            per_device = per_device_noerr
-        in_specs = (P(), P(), P())
-        out_specs = (P(), P(), P())
-        if use_err:
-            in_specs = in_specs + (P(axis),)
-            out_specs = out_specs + (P(axis),)
-        in_specs = in_specs + (P(axis), P(axis), P(), P(), P())
-        out_specs = out_specs + (P(),)
-        if hm is not None:
-            out_specs = out_specs + (P(),)
-        donate = (0, 1, 2, 3) if use_err else (0, 1, 2)  # EF residual too
-        return jax.jit(
-            shard_map(
-                step_program_name(per_device),
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=False,
-            ),
-            donate_argnums=donate if self.donate else (),
-        )
-
     def _make_replicated_step(self, mesh, method, n_dev: int):
         axis = mesh.axis_names[0]
-        gdtype = self.gradient_dtype
         hm = self.health
 
-        def per_device(params, model_state, slots, x, t, lr, it, rng):
+        def per_device(params, model_state, slots, x, t, nvalid, lr, it, rng):
             rng_local = jax.random.fold_in(rng, jax.lax.axis_index(axis))
             (loss, new_ms), grads = jax.value_and_grad(self._loss_fn, has_aux=True)(
                 params, model_state, x, t, rng_local
             )
             with jax.named_scope("grad_exchange"):
-                if gdtype is not None:
-                    grads = _tm(lambda g: g.astype(gdtype), grads)
                 grads = _tm(
                     lambda g: jax.lax.pmean(g, axis).astype(jnp.float32), grads
                 )
@@ -476,7 +354,7 @@ class DistriOptimizer(Optimizer):
             shard_map(
                 step_program_name(per_device),
                 mesh=mesh,
-                in_specs=(P(), P(), P(), P(axis), P(axis), P(), P(), P()),
+                in_specs=(P(), P(), P(), P(axis), P(axis), P(), P(), P(), P()),
                 out_specs=out_specs,
                 check_vma=False,
             ),
@@ -545,16 +423,9 @@ class DistriOptimizer(Optimizer):
         mesh_shape = tuple(int(s) for s in np.asarray(mesh.devices).shape)
 
         def write(state):
-            master, slots = box["state"], box["slots"]
+            master, slots = box["params"], box["slots"]
             if quantized:
-                from ..optim.quantization import MASTER_SCALE_KEY
-
-                master = sp.decode_master(
-                    master, slots.get(MASTER_SCALE_KEY)
-                )
-                slots = sp.decode_slots({
-                    k: v for k, v in slots.items() if k != MASTER_SCALE_KEY
-                })
+                master, slots = sp.decode_master(master), sp.decode_slots(slots)
             return save_fleet_checkpoint(
                 self.checkpoint_path,
                 step=int(state["neval"]),
@@ -575,7 +446,6 @@ class DistriOptimizer(Optimizer):
     # --------------------------------------------------------------- optimize
     def _optimize_impl(self) -> AbstractModule:
         model, method = self.model, self.optim_method
-        state = method.state
         mesh = self._training_mesh()  # elastic: the ACTIVE fleet's view
         n_dev = mesh.devices.size
         axis = mesh.axis_names[0]
@@ -603,33 +473,21 @@ class DistriOptimizer(Optimizer):
         params, model_state = model.get_parameters(), model.get_state()
 
         sync = self._resolve_parameter_sync(method, params)
-        # the sharded ZeRO-1 mode ALWAYS carries the flat master state (that
-        # layout is the AllReduceParameter design); flat_update additionally
-        # opts the replicated mode into it
-        flat_mode = sync == "sharded" or self.flat_update
-        if self._elastic is not None and sync != "sharded":
+        # the sharded ZeRO-1 mode carries the flat master state (that layout
+        # is the AllReduceParameter design); replicated carries the tree
+        flat_mode = sync == "sharded"
+        if self._elastic is not None and not flat_mode:
             raise ValueError(
                 "elastic training rides the ZeRO-1 flat master layout (per-"
                 "host shard bounds are FlatParameter arithmetic); use "
                 "parameter_sync='sharded'"
             )
-        if self._precision is not None:
-            if not flat_mode:
-                raise ValueError(
-                    "low-precision policies (comms_dtype/master_dtype/"
-                    "slot_dtype) hang off the flat master buffer; use "
-                    "parameter_sync='sharded' (the ZeRO-1 flat layout) or "
-                    "flat_update=True on the replicated mode"
-                )
-            if sync == "sharded" and self._precision.master_scaled:
-                raise ValueError(
-                    "master_dtype=float8 (scaled master codes) is not "
-                    "supported on the ZeRO-1 sharded layout — the per-"
-                    "segment scales would need a second collective per "
-                    "step; use master_dtype='bfloat16' here, or the "
-                    "replicated/local flat paths for the experimental fp8 "
-                    "master tier"
-                )
+        if self._precision is not None and not flat_mode:
+            raise ValueError(
+                "low-precision policies (comms_dtype/master_dtype/"
+                "slot_dtype) hang off the ZeRO-1 flat master buffer; use "
+                "DistriOptimizer(parameter_sync='sharded')"
+            )
         fp = None
         if flat_mode:
             if not getattr(method, "elementwise", True):
@@ -637,9 +495,8 @@ class DistriOptimizer(Optimizer):
                     f"{type(method).__name__} is layer-structure-aware and "
                     "cannot run on the flat parameter layout; use "
                     "parameter_sync='replicated'"
-                    + (" without flat_update" if sync != "sharded" else "")
                 )
-            fp = self._flat_codec(params, n_dev if sync == "sharded" else 1)
+            fp = self._flat_codec(params, n_dev)
 
         hm = self.health
         mesh_key = self._mesh_key(mesh)
@@ -650,8 +507,10 @@ class DistriOptimizer(Optimizer):
             and cached[4] is hm  # the step's output signature keys on health
         ):
             cached = None  # method/sync/health changed: cached step is stale
+        entry_slots = None
+        use_err = False
         if flat_mode:
-            flatten, unflatten, slots_view = self._flat_fns(fp)
+            flatten, _, _ = self._flat_fns(fp)
             # the ONE tree→vector copy of this run (a resume re-flattens
             # once); from here on the padded flat f32 vector is the carried,
             # donated canonical state and the tree is a per-seam VIEW
@@ -676,27 +535,13 @@ class DistriOptimizer(Optimizer):
                 # encode ONCE at entry; the carried master/slots live in
                 # storage precision from here and the cold seams decode
                 # through _flat_state_thunks
-                from ..optim.quantization import MASTER_SCALE_KEY
-
-                flat, mscale = sp.encode_master(flat)
+                flat = sp.encode_master(flat)
                 slots = sp.encode_slots(slots)
-                if mscale is not None:
-                    slots = dict(slots)
-                    slots[MASTER_SCALE_KEY] = mscale
-            # ZeRO-1: slot vectors live sharded; replicated-flat: replicated
-            slots_spec = P(axis) if sync == "sharded" else P()
-            if cached is not None:
-                step_fn = cached[3]
-            elif sync == "sharded":
-                step_fn = self._make_sharded_step(fp, mesh, method, n_dev)
-            else:
-                step_fn = self._make_replicated_flat_step(
-                    fp, mesh, method, n_dev
-                )
+            slots_spec = P(axis)  # ZeRO-1: the slot vectors live sharded
+            step_fn = (cached[3] if cached is not None
+                       else self._make_sharded_step(fp, mesh, method, n_dev))
             carried = flat
         else:
-            entry_slots = None
-            use_err = False
             if hm is not None:
                 hm.bind_tree(params)
                 hm.bind_acts(model_state)
@@ -707,7 +552,6 @@ class DistriOptimizer(Optimizer):
             carried = params
         self._distri_step_cache[mesh_key] = (method, sync, fp, step_fn, hm,
                                              mesh)
-        self._jit_step = step_fn  # compile-count introspection (tests)
 
         # Commit the initial state to the STEP's output shardings before the
         # first call: otherwise call 1 (plain single-device arrays) and call 2+
@@ -724,6 +568,7 @@ class DistriOptimizer(Optimizer):
             sh = NamedSharding(mesh, spec)
             return repl if sh.is_fully_replicated else sh
 
+        err = None
         with obs_span("commit_shardings"):
             carried = jax.device_put(carried, repl)
             model_state = _tm(lambda a: jax.device_put(jnp.asarray(a), repl),
@@ -741,25 +586,11 @@ class DistriOptimizer(Optimizer):
                 # the comms error-feedback residual: one padded-master-
                 # geometry row per device, committed sharded on the device
                 # axis and donated alongside the master vector
-                box_err = jax.device_put(
+                err = jax.device_put(
                     jnp.asarray(comp.init_residual(n_dev)),
                     out_sharding(P(axis)),
                 )
 
-        # the restore contract is tree-shaped: snapshot the entry TREE (still
-        # live pre-flatten) + the run's f32 slot representation (captured
-        # BEFORE any low-precision encode)
-        self._capture_entry_snapshot(
-            params, model_state,
-            entry_slots if entry_slots is not None else slots,
-        )
-        box = {"state": carried, "model_state": model_state, "slots": slots,
-               "err": box_err if use_err else None}
-        if self._elastic is not None:
-            # every checkpoint from this fit (periodic trigger, preemption,
-            # and the elastic coordination point) routes onto the per-host-
-            # sharded fleet format, sliced straight off the live flat master
-            self._fleet_writer = self._make_fleet_writer(fp, box, mesh)
         batch_sh = NamedSharding(mesh, P(axis))
         if jax.process_count() == 1:
             # commit straight to the step's input sharding in ONE host→device
@@ -770,75 +601,23 @@ class DistriOptimizer(Optimizer):
         else:
             commit = self._make_batch_placer(mesh, axis)  # per-host shards
 
-        if self.async_placement:
-            # sharding commit runs in the PREFETCH worker: the transfer
-            # overlaps the in-flight step's compute (span data proves the
-            # overlap — the place_batch span nests under prefetch/, and the
-            # driver's dispatch seam shrinks to the bare enqueue)
-            def place_pair(x, t):
-                with obs_span("place_batch"):
-                    return commit(x), commit(t)
+        def place_pair(x, t):
+            # runs in the PREFETCH worker: the transfer overlaps the in-flight
+            # step's compute (the place_batch span nests under prefetch/, and
+            # the driver's dispatch seam is the bare enqueue)
+            with obs_span("place_batch"):
+                return commit(x), commit(t)
 
-            self._place_batch = place_pair
-        else:
-            self._place_batch = None  # serialized baseline (see __init__)
+        bind_box = None
+        if self._elastic is not None:
+            # every checkpoint from this fit (periodic trigger, preemption,
+            # and the elastic coordination point) routes onto the per-host-
+            # sharded fleet format, sliced straight off the live flat master
+            def bind_box(box):
+                self._fleet_writer = self._make_fleet_writer(fp, box, mesh)
 
-        def run_iteration(batch, lr: float):
-            # the same three spans as LocalOptimizer's run_iteration, under
-            # the drive loop's `dispatch`
-            with obs_span("step_args"):
-                if self.async_placement:
-                    x, t = batch.get_input(), batch.get_target()  # already placed
-                else:
-                    with obs_span("place_batch"):  # on the DRIVER thread: this
-                        x = commit(batch.get_input())  # transfer serializes in
-                        t = commit(batch.get_target())  # front of the dispatch
-                args = (box["state"], box["model_state"], box["slots"])
-                if use_err:
-                    args = args + (box["err"],)
-                args = args + (
-                    x,
-                    t,
-                    jnp.asarray(lr, jnp.float32),
-                    jnp.asarray(state["neval"]),
-                    RandomGenerator.next_key(),
-                )
-                self._capture_step_specs(step_fn, args)
-            with obs_span("step_call"):
-                outs = step_fn(*args)
-            with obs_span("model_sync"):
-                del args  # the inputs' array objects are freed inside the span
-                if use_err:
-                    (box["state"], box["model_state"], box["slots"],
-                     box["err"], loss) = outs[:5]
-                    tail = 5
-                else:
-                    (box["state"], box["model_state"], box["slots"],
-                     loss) = outs[:4]
-                    tail = 4
-                if not flat_mode:
-                    # flat mode deliberately skips the per-step model sync: the
-                    # tree materialization is exactly the params-sized copy the
-                    # flat layout kills (cold seams go through get_params below)
-                    model.set_parameters(box["state"])
-                model.set_state(box["model_state"])
-            if hm is not None:  # health stats ride the same one-step-late pull
-                return loss, outs[tail]
-            return loss  # device array — _drive_loop pulls it one step later
-
-        if flat_mode:
-            get_params, get_slots = self._flat_state_thunks(
-                fp, box, "state", "slots"
-            )
-        else:
-            get_params = lambda: box["state"]  # noqa: E731
-            get_slots = lambda: box["slots"]  # noqa: E731
-        self._drive_loop(
-            run_iteration,
-            get_params,
-            get_slots,
-            lambda: box["model_state"],
+        return self._run_with_step(
+            step_fn, carried, model_state, slots, place_batch=place_pair,
+            codec=fp, entry_params=params, entry_slots=entry_slots,
+            extra=err, bind_box=bind_box,
         )
-        model.set_parameters(get_params())
-        model.set_state(box["model_state"])
-        return model

@@ -40,7 +40,7 @@ _tm = jax.tree_util.tree_map
 
 class ParallelCompositionError(ValueError):
     """A requested parallelism composition the parameter layouts cannot
-    carry (e.g. a flat replicated master vector under per-leaf
+    carry (e.g. a compressed flat gradient exchange under per-leaf
     ``NamedSharding`` placements). Raised at construction — loudly, before
     any compile — with the reason and the supported alternative."""
 
@@ -73,15 +73,7 @@ class HybridParallelOptimizer(Optimizer):
         data_axis: str = "data",
         validate: bool = True,
         donate: bool = True,
-        flat_update: bool = False,
     ):
-        if flat_update:
-            raise ParallelCompositionError(
-                "flat_update is incompatible with GSPMD sharding plans: a "
-                "flat master vector cannot carry per-leaf NamedShardings "
-                "(use DistriOptimizer parameter_sync='sharded' for the flat "
-                "ZeRO-1 layout)"
-            )
         super().__init__(model, dataset, criterion, validate=validate,
                          donate=donate)
         self.plan = plan or ShardingPlan()

@@ -28,11 +28,11 @@ Composition matrix (docs/parallelism.md):
   'data')``), gradients reduce over ``data`` via GSPMD.
 * dp×ep — mesh ``('data', 'expert')``; tokens shard over BOTH axes, the
   two ``all_to_all`` hops stay within each data row's expert group.
-* flat-parameter / compressed-comms — refused with
-  :class:`ParallelCompositionError`: one replicated flat master vector
-  cannot carry the per-leaf ``P('pipe')``/``P('expert')`` placements the
-  stacked layouts require (only a fully-replicated tree could compose,
-  and then nothing would be pipeline- or expert-parallel).
+* compressed comms — refused with :class:`ParallelCompositionError`: the
+  compressor rides the flat codec, and one flat master vector cannot carry
+  the per-leaf ``P('pipe')``/``P('expert')`` placements the stacked
+  layouts require (only a fully-replicated tree could compose, and then
+  nothing would be pipeline- or expert-parallel).
 """
 
 from __future__ import annotations
@@ -68,18 +68,7 @@ class _StackedParallelOptimizer(HybridParallelOptimizer):
 
     def __init__(self, model, dataset, criterion, mesh=None, axis="",
                  data_axis: Optional[str] = None, validate: bool = True,
-                 donate: bool = True, flat_update: bool = False,
-                 comms_dtype: Optional[str] = None):
-        if flat_update:
-            raise ParallelCompositionError(
-                f"flat_update is incompatible with {self._kind} training: "
-                f"the stacked leaves carry P({axis!r}) NamedShardings that "
-                "one replicated flat master vector cannot represent (only a "
-                "fully-replicated tree could compose, which would disable "
-                "the parallelism). Use the tree-path update here, or "
-                "DistriOptimizer parameter_sync='sharded' for the flat "
-                "ZeRO-1 layout."
-            )
+                 donate: bool = True, comms_dtype: Optional[str] = None):
         if comms_dtype is not None:
             raise ParallelCompositionError(
                 f"comms_dtype={comms_dtype!r} is incompatible with "
@@ -224,8 +213,8 @@ class PipelineOptimizer(_StackedParallelOptimizer):
             stack (default: each module's own setting, default S). The
             schedule's idle fraction (S-1)/(n_micro+S-1) is stamped on
             every perf record as ``pipe_bubble_frac``.
-        flat_update / comms_dtype: refused with
-            :class:`ParallelCompositionError` (see module docstring).
+        comms_dtype: refused with :class:`ParallelCompositionError` (see
+            module docstring).
     """
 
     _kind = "pipeline-parallel"
@@ -233,12 +222,11 @@ class PipelineOptimizer(_StackedParallelOptimizer):
     def __init__(self, model, dataset, criterion, mesh=None,
                  pipe_axis: str = "pipe", data_axis: Optional[str] = None,
                  n_micro: Optional[int] = None, validate: bool = True,
-                 donate: bool = True, flat_update: bool = False,
-                 comms_dtype: Optional[str] = None):
+                 donate: bool = True, comms_dtype: Optional[str] = None):
         super().__init__(model, dataset, criterion, mesh=mesh,
                          axis=pipe_axis, data_axis=data_axis,
                          validate=validate, donate=donate,
-                         flat_update=flat_update, comms_dtype=comms_dtype)
+                         comms_dtype=comms_dtype)
         if n_micro is not None and n_micro < 1:
             raise ValueError(f"n_micro must be >= 1, got {n_micro}")
         self.n_micro = n_micro
@@ -323,12 +311,11 @@ class ExpertParallelOptimizer(_StackedParallelOptimizer):
     def __init__(self, model, dataset, criterion, mesh=None,
                  expert_axis: str = "expert",
                  data_axis: Optional[str] = None, validate: bool = True,
-                 donate: bool = True, flat_update: bool = False,
-                 comms_dtype: Optional[str] = None):
+                 donate: bool = True, comms_dtype: Optional[str] = None):
         super().__init__(model, dataset, criterion, mesh=mesh,
                          axis=expert_axis, data_axis=data_axis,
                          validate=validate, donate=donate,
-                         flat_update=flat_update, comms_dtype=comms_dtype)
+                         comms_dtype=comms_dtype)
 
     def _bind_modules(self, mesh):
         from ..nn.moe import MoE

@@ -129,17 +129,56 @@ class TestDistriOptimizer:
         opt.optimize()
         assert opt.optim_method.state["neval"] == 7
 
-    def test_bf16_gradient_wire(self):
-        set_seed(13)
-        ds = _make_ds(n=64, batch=32)
-        model = LeNet5(10)
+    def test_sharded_drives_through_the_shared_run_loop(self, monkeypatch):
+        """One run loop: the ZeRO-1 fit enters ``Optimizer._run_with_step``
+        with its SPMD step, codec and batch placer, the batch reaches the
+        step committed to ``P('data')`` (the placer's commit survives the
+        loop's ``asarray``), and the loop's per-step outputs — the step
+        record's spans, the health record — come out of it."""
+        from jax.sharding import PartitionSpec as P
+
+        from bigdl_tpu.obs import HealthConfig, Telemetry
+
+        calls = []
+        shared = Optimizer._run_with_step
+
+        def spy(self, train_step, *args, **kw):
+            calls.append((train_step, kw))
+            return shared(self, train_step, *args, **kw)
+
+        monkeypatch.setattr(Optimizer, "_run_with_step", spy)
+        set_seed(7)
         opt = DistriOptimizer(
-            model, ds, nn.ClassNLLCriterion(),
-            parameter_sync="sharded", gradient_dtype=jnp.bfloat16,
+            nn.Sequential(nn.Linear(784, 10), nn.LogSoftMax()),
+            _make_ds(n=64, batch=32), nn.ClassNLLCriterion(),
+            parameter_sync="sharded",
         )
-        opt.set_optim_method(SGD(learningrate=0.1)).set_end_when(Trigger.max_iteration(3))
+        opt.set_optim_method(SGD(learningrate=0.1, momentum=0.9))
+        opt.set_end_when(Trigger.max_iteration(3))
+        tel = Telemetry()
+        opt.set_telemetry(tel)
+        opt.set_health(HealthConfig(every_n_steps=1))
         opt.optimize()
-        assert np.isfinite(opt.optim_method.state["loss"])
+
+        (step, kw), = calls
+        assert step is opt._jit_step
+        assert kw["codec"] is next(iter(opt._flat_fp.values()))
+        assert kw["place_batch"] is opt._place_batch is not None
+        assert opt._counter_names == ()  # set by the shared loop
+        # (flat, model_state, slots, x, t, nvalid, lr, step, rng)
+        specs = opt._step_export_info[1]
+        assert specs[3].sharding.spec == P("data")
+        assert specs[5].shape == () and specs[5].dtype == jnp.float32
+        steps = tel.ring.steps()
+        assert len(steps) == 3
+        spans = {k for r in steps for k in r["spans"]}
+        assert {"dispatch/step_args", "dispatch/step_call",
+                "dispatch/model_sync", "prefetch/place_batch"} <= spans
+        assert "dispatch/step_args/place_batch" not in spans
+        healths = [r for r in tel.ring.records if r["type"] == "health"]
+        assert len(healths) == 3
+        assert healths[-1]["global"]["grad_norm"] > 0
+        assert "Linear_0/weight" in healths[-1]["layers"]
 
 
 class TestReviewRegressions:

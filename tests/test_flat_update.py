@@ -1,7 +1,8 @@
 """Flat-parameter hot path: flat master state + single fused segment-wise
-optimizer update (docs/performance.md).
+optimizer update (docs/performance.md). The layout's one owner is the ZeRO-1
+sharded ``DistriOptimizer`` step, where the flat vector is the sharding.
 
-Locks the PR 6 contract at three levels:
+Locks the contract at three levels:
 
 * **method level** — ``OptimMethod.update_flat`` is BIT-IDENTICAL to the
   per-leaf ``update`` chains for every shipped elementwise method, including
@@ -14,12 +15,11 @@ Locks the PR 6 contract at three levels:
   per-leaf slice form; and the fused update collapses the N-leaf kernel
   chains to a ~constant-size program (cost_analysis op-count/bytes
   thresholds, before vs after);
-* **run level** — ``flat_update=True`` trains bit-identically to the tree
-  layout on the local and replicated-Distri paths, keeps every hot-path
-  invariant (EXACTLY one compile on ragged multi-epoch fits, donation
-  bit-identity, health/telemetry streams), and checkpoints stay
-  bit-compatible across flat↔tree representation switches (slots persist in
-  tree view; resume re-flattens once).
+* **run level** — the sharded step keeps every hot-path invariant (EXACTLY
+  one compile on ragged multi-epoch fits, donation bit-identity,
+  health/telemetry streams, a retry reusing the compiled step), and
+  checkpoints stay compatible across flat↔tree representation switches
+  (slots persist in tree view; resume re-flattens once).
 """
 
 import importlib.util
@@ -39,6 +39,7 @@ from bigdl_tpu.dataset.dataset import (
     SampleToMiniBatch,
 )
 from bigdl_tpu.optim import LocalOptimizer, SGD, Trigger
+from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
 from bigdl_tpu.optim.optim_method import (
     Adadelta,
     Adagrad,
@@ -287,70 +288,35 @@ class TestUpdateFlatBitIdentity:
 
 
 # --------------------------------------------------------------------------
-# run level: flat_update=True on LocalOptimizer
+# run level: the ZeRO-1 sharded step over the 8-device mesh
 # --------------------------------------------------------------------------
 
-def _fit_local(method_factory, flat, donate=True, seed=11, epochs=2,
-               **opt_kw):
-    RandomGenerator.set_seed(seed)
-    x, y = _problem()
-    opt = LocalOptimizer(
-        _model(), DataSet.array(x, y, batch_size=16), nn.ClassNLLCriterion(),
-        flat_update=flat, donate=donate, **opt_kw,
-    )
-    opt.set_optim_method(method_factory())
-    opt.set_end_when(Trigger.max_epoch(epochs))
-    opt.optimize()
-    return opt
+def _sharded(model, base, **kw):
+    return DistriOptimizer(model, DataSet.distributed(base, 8),
+                           nn.ClassNLLCriterion(), parameter_sync="sharded",
+                           **kw)
 
 
-class TestFlatLocalPath:
-    @pytest.mark.parametrize("make", [
-        lambda: SGD(learningrate=0.2),
-        lambda: Adam(learningrate=1e-2),
-    ], ids=["sgd_plain", "adam"])
-    def test_bit_identical_vs_tree_layout(self, make):
-        tree = _fit_local(make, flat=False).model.get_parameters()
-        flat = _fit_local(make, flat=True).model.get_parameters()
-        for a, b in zip(_leaves(tree), _leaves(flat)):
-            np.testing.assert_array_equal(a, b)
-
-    @pytest.mark.parametrize("make", [
-        lambda: SGD(learningrate=0.2, momentum=0.9),
-        lambda: SGD(learningrate=0.2, momentum=0.9, weightdecay=1e-3,
-                    weightdecay_exclude=("bias",)),
-    ], ids=["sgd_momentum", "sgd_wd_exclude"])
-    def test_ulp_close_vs_tree_layout(self, make):
-        """The update rule itself is bit-identical (locked above at the
-        method level), but XLA draws different FUSION boundaries through the
-        one-vector program than through the per-leaf kernels (FMA contraction
-        differs), so multi-term updates — momentum chains, the decay-mask
-        multiply — accumulate ulp-level drift over a fit. Lock them to
-        ulp-tight tolerance instead."""
-        tree = _fit_local(make, flat=False).model.get_parameters()
-        flat = _fit_local(make, flat=True).model.get_parameters()
-        for a, b in zip(_leaves(tree), _leaves(flat)):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
+class TestFlatShardedPath:
     def test_donation_bit_identical_and_one_compile_on_ragged_fit(self):
         """The flat master vector is donated every step; that must stay
         numerically invisible, and a 2-epoch fit with a ragged epoch tail
-        (20 rows / batch 8 → [8, 8, 4]) must compile EXACTLY once with the
-        tail trained through the pad+mask seam."""
+        (20 rows / batch 8 → [8, 8, 4]) must compile EXACTLY once — the
+        4-row tail does not divide over the 8 devices, so the distributed
+        dataset drops it before it reaches the step."""
         def train(donate):
             RandomGenerator.set_seed(7)
             x, y = _problem(n=20, d=5)
             ds = LocalArrayDataSet(
                 x, y, transformer=SampleToMiniBatch(8), batch_size=8
             )
-            opt = LocalOptimizer(_model(d=5), ds, nn.ClassNLLCriterion(),
-                                 flat_update=True, donate=donate)
+            opt = _sharded(_model(d=5), ds, donate=donate)
             opt.set_optim_method(SGD(learningrate=0.2, momentum=0.9))
             opt.set_end_when(Trigger.max_epoch(2))
             opt.optimize()
             assert opt._jit_step._cache_size() == 1
-            # tail trained: 2 epochs x 3 steps (incl. the padded 4-row tail)
-            assert opt.optim_method.state["neval"] == 7
+            # 2 epochs x the 2 full batches (the ragged tail dropped)
+            assert opt.optim_method.state["neval"] == 5
             return opt.model.get_parameters()
 
         for a, b in zip(_leaves(train(True)), _leaves(train(False))):
@@ -365,8 +331,7 @@ class TestFlatLocalPath:
         RandomGenerator.set_seed(7)
         x, y = _problem()
         tel = Telemetry()
-        opt = LocalOptimizer(_model(), DataSet.array(x, y, batch_size=16),
-                             nn.ClassNLLCriterion(), flat_update=True)
+        opt = _sharded(_model(), DataSet.array(x, y, batch_size=16))
         opt.set_optim_method(SGD(learningrate=0.2, momentum=0.9))
         opt.set_end_when(Trigger.max_epoch(2))
         opt.set_telemetry(tel)
@@ -383,36 +348,6 @@ class TestFlatLocalPath:
         assert "Linear_0/weight" in last["layers"]
         assert "Linear_2/bias" in last["layers"]
 
-    def test_flat_refuses_micro_batches(self):
-        RandomGenerator.set_seed(3)
-        x, y = _problem()
-        opt = LocalOptimizer(_model(), DataSet.array(x, y, batch_size=16),
-                             nn.ClassNLLCriterion(), flat_update=True)
-        opt.set_micro_batches(4)
-        opt.set_end_when(Trigger.max_iteration(1))
-        with pytest.raises(NotImplementedError, match="flat_update"):
-            opt.optimize()
-
-    def test_flat_refuses_layer_structure_aware_method(self):
-        RandomGenerator.set_seed(3)
-        x, y = _problem()
-        opt = LocalOptimizer(_model(), DataSet.array(x, y, batch_size=16),
-                             nn.ClassNLLCriterion(), flat_update=True)
-        opt.set_optim_method(LarsSGD(learningrate=0.1))
-        opt.set_end_when(Trigger.max_iteration(1))
-        with pytest.raises(ValueError, match="layer-structure"):
-            opt.optimize()
-
-    def test_hybrid_refuses_flat_update(self):
-        from bigdl_tpu.parallel.hybrid import HybridParallelOptimizer
-
-        x, y = _problem()
-        with pytest.raises(ValueError, match="GSPMD"):
-            HybridParallelOptimizer(
-                _model(), DataSet.array(x, y, batch_size=16),
-                nn.ClassNLLCriterion(), flat_update=True,
-            )
-
     def test_retry_reuses_flat_step_and_codec(self, tmp_path):
         """A transient failure mid-run must restore through the entry
         snapshot / checkpoint seam and REUSE the compiled flat step — the
@@ -420,8 +355,7 @@ class TestFlatLocalPath:
         RandomGenerator.set_seed(21)
         x, y = _problem()
         ds = _FailingDataSet(DataSet.array(x, y, batch_size=8), fail_at=9)
-        opt = LocalOptimizer(_model(), ds, nn.ClassNLLCriterion(),
-                             flat_update=True)
+        opt = _sharded(_model(), ds)
         opt.set_optim_method(SGD(learningrate=0.2, momentum=0.9))
         opt.set_end_when(Trigger.max_iteration(16))
         opt.set_checkpoint(str(tmp_path), Trigger.several_iteration(2))
@@ -433,68 +367,43 @@ class TestFlatLocalPath:
 
 
 # --------------------------------------------------------------------------
-# run level: replicated DistriOptimizer opt-in
-# --------------------------------------------------------------------------
-
-class TestFlatReplicatedDistri:
-    def _train(self, flat):
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
-        RandomGenerator.set_seed(13)
-        x, y = _problem(n=64)
-        ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
-        opt = DistriOptimizer(_model(), ds, nn.ClassNLLCriterion(),
-                              parameter_sync="replicated", flat_update=flat)
-        opt.set_optim_method(SGD(learningrate=0.2, momentum=0.9))
-        opt.set_end_when(Trigger.max_epoch(2))
-        opt.optimize()
-        assert opt._jit_step._cache_size() == 1
-        return opt.model.get_parameters()
-
-    def test_bit_identical_vs_tree_replicated(self):
-        for a, b in zip(_leaves(self._train(False)),
-                        _leaves(self._train(True))):
-            np.testing.assert_array_equal(a, b)
-
-
-# --------------------------------------------------------------------------
 # checkpoints: flat↔tree representations stay bit-compatible
 # --------------------------------------------------------------------------
 
 class TestFlatCheckpointRoundTrip:
-    def _make_opt(self, flat, ds=None):
-        if ds is None:
-            x, y = _problem()
-            ds = DataSet.array(x, y, batch_size=8)
-        opt = LocalOptimizer(_model(), ds, nn.ClassNLLCriterion(),
-                             flat_update=flat)
-        # Adam: two slot vectors through the round trip, and (unlike the
-        # momentum chain) bit-identical between the flat and tree programs
+    def _make_opt(self, sharded):
+        x, y = _problem()
+        ds = DataSet.array(x, y, batch_size=8)
+        if sharded:
+            opt = _sharded(_model(), ds)
+        else:
+            opt = LocalOptimizer(_model(), ds, nn.ClassNLLCriterion())
+        # Adam: two slot vectors through the round trip
         opt.set_optim_method(Adam(learningrate=1e-2))
         opt.set_end_when(Trigger.max_epoch(2))
         return opt
 
     @pytest.mark.parametrize("first,second", [
         (True, False), (False, True), (True, True),
-    ], ids=["flat_to_tree", "tree_to_flat", "flat_to_flat"])
-    def test_resume_across_representations_bit_identical(
-        self, tmp_path, first, second
-    ):
+    ], ids=["sharded_to_tree", "tree_to_sharded", "sharded_to_sharded"])
+    def test_resume_across_representations(self, tmp_path, first, second):
         """Checkpoints persist optimizer slots in TREE view on every path, so
-        a run interrupted under one representation resumes under the other
-        bit-identically (the momentum slots survive the round trip; resume
-        re-flattens exactly once)."""
+        a run interrupted under one representation (the ZeRO-1 flat vector,
+        or the local tree) resumes under the other (the Adam slots survive
+        the round trip; resume re-flattens exactly once). The sharded step
+        sums the gradient over 8 devices where the local step takes it
+        whole, so the comparison has the tolerance of
+        ``test_distri_matches_local_single_step``."""
         from bigdl_tpu.utils import serialization as ser
 
-        # gold: the uninterrupted 2-epoch run (tree layout — both layouts
-        # are bit-identical end-to-end, locked above)
+        # gold: the uninterrupted 2-epoch run on the local tree layout
         RandomGenerator.set_seed(24)
-        ref = _leaves(self._make_opt(flat=False).optimize().get_parameters())
+        ref = _leaves(self._make_opt(sharded=False).optimize().get_parameters())
 
         # interrupted run under `first`: checkpoint every 2 steps, stop at 8
         ckpt = str(tmp_path / "ckpt")
         RandomGenerator.set_seed(24)
-        opt1 = self._make_opt(flat=first)
+        opt1 = self._make_opt(sharded=first)
         opt1.set_end_when(Trigger.max_iteration(8))
         opt1.set_checkpoint(ckpt, Trigger.several_iteration(2))
         opt1.optimize()
@@ -506,11 +415,11 @@ class TestFlatCheckpointRoundTrip:
 
         # rescheduled process under `second`: fresh model, resume, finish
         RandomGenerator.set_seed(24)
-        opt2 = self._make_opt(flat=second)
+        opt2 = self._make_opt(sharded=second)
         opt2.resume(ckpt)
         got = _leaves(opt2.optimize().get_parameters())
         for a, b in zip(got, ref):
-            np.testing.assert_array_equal(a, b)
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
 # --------------------------------------------------------------------------
@@ -568,8 +477,6 @@ class TestFlatProgramShape:
         ResNet-50's step). Control: the same views cut by ``dynamic_slice``
         DO lower to flat-length dynamic_update_slices, so the detector is
         live."""
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
         RandomGenerator.set_seed(5)
         x, y = _problem(n=64)
         ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
@@ -589,8 +496,9 @@ class TestFlatProgramShape:
             jax.eval_shape(method.init_slots, p0),
             jax.ShapeDtypeStruct((16, 6), jnp.float32),
             jax.ShapeDtypeStruct((16,), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.float32),  # nvalid
+            jax.ShapeDtypeStruct((), jnp.float32),  # lr
+            jax.ShapeDtypeStruct((), jnp.int32),    # step
             jax.ShapeDtypeStruct((2,), jnp.uint32),
         )
         lowered = opt._jit_step.lower(*args).as_text()
@@ -610,48 +518,6 @@ class TestFlatProgramShape:
         assert _flat_length_ops(control, "dynamic_update_slice", flat) == len(
             jax.tree_util.tree_leaves(params)
         )
-
-    def test_flat_step_halves_program_size(self):
-        """Before/after on the REAL local step builders: the flat step's
-        compiled program must be substantially smaller than the tree step's
-        per-leaf chains (threshold, not exact — the measured ratio on a
-        16-linear-layer model is ~0.5)."""
-        def lower(flat):
-            RandomGenerator.set_seed(5)
-            x, y = _problem(n=64)
-            opt = LocalOptimizer(
-                _deep_model(), DataSet.array(x, y, batch_size=16),
-                nn.ClassNLLCriterion(), flat_update=flat,
-            )
-            opt.set_optim_method(Adam(learningrate=1e-3))
-            opt.set_end_when(Trigger.max_iteration(1))
-            opt.optimize()
-            method = opt.optim_method
-            if flat:
-                (fp,) = opt._flat_fp.values()
-                p0 = jax.ShapeDtypeStruct((fp.padded_total,), jnp.float32)
-            else:
-                p0 = jax.eval_shape(
-                    lambda: _tm(jnp.asarray, opt.model.get_parameters())
-                )
-            args = (
-                p0,
-                jax.eval_shape(
-                    lambda: _tm(jnp.asarray, opt.model.get_state())
-                ),
-                jax.eval_shape(method.init_slots, p0),
-                jax.ShapeDtypeStruct((16, 6), jnp.float32),
-                jax.ShapeDtypeStruct((16,), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.float32),  # nvalid
-                jax.ShapeDtypeStruct((), jnp.float32),  # lr
-                jax.ShapeDtypeStruct((), jnp.int32),    # step
-                jax.ShapeDtypeStruct((2,), jnp.uint32),
-            )
-            return opt._jit_step.lower(*args).compile()
-
-        tree_instr = _n_instructions(lower(False).as_text())
-        flat_instr = _n_instructions(lower(True).as_text())
-        assert flat_instr < 0.75 * tree_instr, (flat_instr, tree_instr)
 
     def test_fused_update_is_one_segment_wise_pass(self):
         """The optimizer-update subprogram itself: per-leaf ``update`` over a
@@ -754,29 +620,17 @@ class TestFlatGradientAssembly:
         np.testing.assert_array_equal(new[fp.total:], 0.0)
         assert np.count_nonzero(new[: fp.total]) > fp.total // 2
 
-    @pytest.mark.parametrize("path", ["sharded", "replicated_flat",
-                                      "local_flat"])
+    @pytest.mark.parametrize("path", ["sharded"])
     def test_step_gradient_bit_identical_to_dynamic_slice_views(
         self, path, monkeypatch
     ):
-        """One real step of each flat path, its update rule handing the
-        gradient back as the new master: the split views and per-leaf
+        """One real step of the flat (ZeRO-1) path, its update rule handing
+        the gradient back as the new master: the split views and per-leaf
         ``dynamic_slice`` views give the same gradient, bit for bit."""
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
         def one_step():
             RandomGenerator.set_seed(9)
             x, y = _conv_problem()
-            ds = DataSet.array(x, y, batch_size=16)
-            if path == "local_flat":
-                opt = LocalOptimizer(_conv_model(), ds, nn.ClassNLLCriterion(),
-                                     flat_update=True)
-            else:
-                opt = DistriOptimizer(
-                    _conv_model(), DataSet.distributed(ds, 8),
-                    nn.ClassNLLCriterion(),
-                    parameter_sync=path.split("_")[0], flat_update=True,
-                )
+            opt = _sharded(_conv_model(), DataSet.array(x, y, batch_size=16))
             opt.set_optim_method(_GradientAsUpdate(learningrate=0.1))
             opt.set_end_when(Trigger.max_iteration(1))
             opt.optimize()

@@ -1,6 +1,6 @@
-"""Low-precision flat path: compressed gradient collectives with error
-feedback + quantized training state (docs/performance.md low-precision
-section).
+"""Low-precision flat path of the ZeRO-1 sharded step: compressed gradient
+collectives with error feedback + bf16 training state (docs/performance.md
+low-precision section).
 
 Locks the contract at four levels:
 
@@ -14,10 +14,11 @@ Locks the contract at four levels:
   collective operand bytes drop ≥2× (bf16) and ≥3.5× (fp8/int8) versus the
   f32 baseline, while the default (no-policy) program is byte-for-byte the
   pre-policy program;
-* **run level** — trajectory-tolerance fits (compressed loss curves within
-  bound of the f32 baseline; error feedback ON strictly closer than OFF in
-  the same test), exactly-1-compile ragged fits with compression + EF,
-  retry-reuses-cached-step, checkpoint round-trips quantized↔unquantized,
+* **run level** — trajectory-tolerance fits on the 8-device mesh
+  (compressed loss curves within bound of the f32 baseline; error feedback
+  ON strictly closer than OFF in the same test), exactly-1-compile ragged
+  fits with compression + EF, retry-reuses-cached-step, checkpoint
+  round-trips quantized↔unquantized, every other path refusing a policy,
   and the GSPMD/hybrid health path localizing the poisoned mesh shard.
 """
 
@@ -37,11 +38,11 @@ from bigdl_tpu.obs import HealthConfig, Telemetry
 from bigdl_tpu.optim import Adam, LocalOptimizer, SGD, Trigger
 from bigdl_tpu.optim.quantization import (
     LowPrecisionPolicy,
-    MASTER_SCALE_KEY,
     StatePrecision,
     stochastic_round,
 )
 from bigdl_tpu.parallel.compression import GradCompressor
+from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
 from bigdl_tpu.parallel.parameter import FlatParameter
 from bigdl_tpu.obs.profiler import collective_bytes
 from bigdl_tpu.resilience import FailurePolicy
@@ -137,8 +138,7 @@ class TestFloat8Shim:
         with pytest.raises(ValueError, match="float8"):
             LocalOptimizer(
                 _model(), DataSet.array(x, y, batch_size=8),
-                nn.ClassNLLCriterion(), flat_update=True,
-                comms_dtype="float8_e5m2",
+                nn.ClassNLLCriterion(), comms_dtype="float8_e5m2",
             )
 
     def test_bfloat16_policy_survives_unsupported_fp8_stack(self, monkeypatch):
@@ -189,6 +189,24 @@ class TestStochasticRounding:
         assert stochastic_round(x, jnp.float32, jax.random.PRNGKey(0)) is x
 
 
+def _exchange(comp, g, err, want_stats):
+    """``GradCompressor.exchange_sharded`` on a one-device mesh: the summed
+    owned shard is the whole dequantized gradient, and the residual row is
+    the whole untransmitted remainder."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("d",))
+
+    def body(g, err):
+        me = jax.lax.axis_index("d")
+        used, new_err, stats = comp.exchange_sharded(
+            g, err, "d", 1, me, want_stats=want_stats)
+        return used, None if new_err is None else new_err[0], stats
+
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False))(g, err)
+
+
 class TestCompressorMath:
     def _codec(self, seed=0, n_shards=1):
         rng = np.random.default_rng(seed)
@@ -206,7 +224,7 @@ class TestCompressorMath:
             fp, LowPrecisionPolicy(comms_dtype="int8", error_feedback=False)
         )
         g = jax.jit(fp.flatten)(tree)
-        used, err, _ = comp.exchange_local(g, None, want_stats=False)
+        used, err, _ = _exchange(comp, g, None, want_stats=False)
         # per-segment amax/127 grid: every element within half a step of its
         # OWN segment's scale (the big and tiny segments each keep their
         # resolution — the point of per-segment scales)
@@ -227,13 +245,13 @@ class TestCompressorMath:
         )
         g = jax.jit(fp.flatten)(tree)
         err0 = jnp.zeros((fp.padded_total,), jnp.float32)
-        used, err1, _ = comp.exchange_local(g, err0, want_stats=False)
+        used, err1, _ = _exchange(comp, g, err0, want_stats=False)
         np.testing.assert_allclose(
             np.asarray(used + err1), np.asarray(g), rtol=0, atol=1e-6
         )
         # second step recycles the residual: transmitted + new residual
         # still accounts for EVERY gradient bit ever produced
-        used2, err2, _ = comp.exchange_local(g, err1, want_stats=False)
+        used2, err2, _ = _exchange(comp, g, err1, want_stats=False)
         np.testing.assert_allclose(
             np.asarray(used + used2 + err2), np.asarray(g + g),
             rtol=0, atol=1e-5,
@@ -245,22 +263,23 @@ class TestCompressorMath:
         g = jax.jit(fp.flatten)(tree)
         # crush one segment far below its neighbour's scale: with PER-
         # SEGMENT scales nothing underflows; the stats matrix proves it
-        _, _, stats = comp.exchange_local(g, None, want_stats=True)
+        _, _, stats = _exchange(comp, g, None, want_stats=True)
         stats = np.asarray(stats)
         assert stats.shape == (len(fp.sizes) + 1, 3)
         assert (stats[:, 1] == 0).all()  # nothing saturates: scales are amax
 
     def test_state_precision_round_trip(self):
         fp, tree = self._codec()
-        pol = LowPrecisionPolicy(master_dtype="float8_e4m3",
+        pol = LowPrecisionPolicy(master_dtype="bfloat16",
                                  slot_dtype="bfloat16")
         sp = StatePrecision(fp, pol)
         vec = jax.jit(fp.flatten)(tree)
-        stored, scale = sp.encode_master(vec)
-        assert stored.dtype == jnp.float8_e4m3fn and scale is not None
-        back = np.asarray(sp.decode_master(stored, scale))
-        # fp8 e4m3: ~2^-3 relative grid per segment
-        np.testing.assert_allclose(back, np.asarray(vec), rtol=0.07,
+        stored = sp.encode_master(vec)
+        assert stored.dtype == jnp.bfloat16
+        back = np.asarray(sp.decode_master(stored))
+        assert back.dtype == np.float32
+        # bf16: 8 mantissa bits, round to nearest
+        np.testing.assert_allclose(back, np.asarray(vec), rtol=8e-3,
                                    atol=1e-6)
         slots = {"m": vec, "v": vec * 0.5}
         enc = sp.encode_slots(slots)
@@ -292,9 +311,9 @@ def _fit_losses(comms=None, ef=True, master=None, slot=None, seed=11,
     RandomGenerator.set_seed(seed)
     x, y = _problem(n=n, seed=3)
     tel = Telemetry()
-    opt = LocalOptimizer(
-        _model(), DataSet.array(x, y, batch_size=batch),
-        nn.ClassNLLCriterion(), flat_update=True,
+    opt = DistriOptimizer(
+        _model(), DataSet.distributed(DataSet.array(x, y, batch_size=batch), 8),
+        nn.ClassNLLCriterion(), parameter_sync="sharded",
         comms_dtype=comms, error_feedback=ef,
         master_dtype=master, slot_dtype=slot,
     )
@@ -341,14 +360,6 @@ class TestTrajectoryTolerance:
         assert np.max(np.abs(got - ref)) < 0.05
         assert _finite(opt.model.get_parameters())
 
-    def test_fp8_master_experimental_tier_trains_finite(self):
-        got, opt = _fit_losses(master="float8_e4m3", lr=1e-2)
-        assert np.isfinite(got).all()
-        assert _finite(opt.model.get_parameters())
-        # the master really is stored as scaled fp8 codes
-        sp = opt._state_prec
-        assert sp is not None and sp.policy.master_scaled
-
 
 # --------------------------------------------------------------------------
 # run level: default path bit-identity + hot-path invariants
@@ -365,21 +376,7 @@ class TestDefaultPathUnchanged:
 
     def test_default_flat_program_has_no_quant_artifacts(self):
         _, opt = _fit_losses()
-        (fp,) = opt._flat_fp.values()
-        method = opt.optim_method
-        p0 = jax.ShapeDtypeStruct((fp.padded_total,), jnp.float32)
-        args = (
-            p0,
-            jax.eval_shape(lambda: _tm(jnp.asarray, opt.model.get_state())),
-            jax.eval_shape(method.init_slots, p0),
-            jax.ShapeDtypeStruct((16, 6), jnp.float32),
-            jax.ShapeDtypeStruct((16,), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32),
-        )
-        txt = opt._jit_step.lower(*args).as_text()
+        txt = _lower_sharded(opt).as_text()
         assert "f8E" not in txt and "xi8>" not in txt
         assert "all_to_all" not in txt
 
@@ -388,11 +385,13 @@ class TestDefaultPathUnchanged:
         = exactly 1 compile, health/telemetry schema-valid, quant telemetry
         present, run_start self-describing."""
         RandomGenerator.set_seed(19)
-        x, y = _problem(n=56)  # 56 % 16 != 0: ragged epoch tail, pad-masked
+        # 60 % 16 = 12: a ragged epoch tail that does not divide over the 8
+        # devices, so the distributed dataset drops it before the step
+        x, y = _problem(n=60)
         tel = Telemetry()
-        opt = LocalOptimizer(
-            _model(), DataSet.array(x, y, batch_size=16),
-            nn.ClassNLLCriterion(), flat_update=True,
+        opt = DistriOptimizer(
+            _model(), DataSet.distributed(DataSet.array(x, y, batch_size=16), 8),
+            nn.ClassNLLCriterion(), parameter_sync="sharded",
             comms_dtype="int8", error_feedback=True, slot_dtype="bfloat16",
         )
         opt.set_optim_method(Adam(learningrate=1e-2))
@@ -445,9 +444,9 @@ class TestDefaultPathUnchanged:
         RandomGenerator.set_seed(21)
         x, y = _problem()
         ds = _FailOnce(DataSet.array(x, y, batch_size=8), fail_at=9)
-        opt = LocalOptimizer(
-            _model(), ds, nn.ClassNLLCriterion(), flat_update=True,
-            comms_dtype="int8", error_feedback=True,
+        opt = DistriOptimizer(
+            _model(), DataSet.distributed(ds, 8), nn.ClassNLLCriterion(),
+            parameter_sync="sharded", comms_dtype="int8", error_feedback=True,
         )
         opt.set_optim_method(SGD(learningrate=0.1, momentum=0.9))
         opt.set_end_when(Trigger.max_iteration(16))
@@ -457,13 +456,46 @@ class TestDefaultPathUnchanged:
         assert ds.failed
         assert opt._jit_step._cache_size() == 1  # the compiled step survived
 
+    @pytest.mark.parametrize("kw", [
+        dict(comms_dtype="int8", error_feedback=True),
+        dict(master_dtype="bfloat16", slot_dtype="bfloat16"),
+    ], ids=["comms_int8", "bf16_state"])
+    def test_one_device_mesh_carries_the_policy(self, kw):
+        """The one-chip home of the policies: the ZeRO-1 step over a
+        one-device mesh trains with them at one compile, its master and
+        slots carried in the storage dtype."""
+        from bigdl_tpu.utils.engine import Engine
+
+        Engine.init(devices=jax.devices()[:1])
+        RandomGenerator.set_seed(17)
+        x, y = _problem(n=32)
+        tel = Telemetry()
+        opt = DistriOptimizer(
+            _model(), DataSet.distributed(DataSet.array(x, y, batch_size=8), 1),
+            nn.ClassNLLCriterion(), parameter_sync="sharded", **kw,
+        )
+        opt.set_optim_method(Adam(learningrate=1e-2))
+        opt.set_end_when(Trigger.max_epoch(2))
+        opt.set_telemetry(tel)
+        opt.optimize()
+        assert opt._perf_device_count() == 1
+        assert tel.compile_count == 1
+        losses = [r["loss"] for r in tel.ring.steps()]
+        assert len(losses) == 8 and np.isfinite(losses).all()
+        assert _finite(opt.model.get_parameters())
+        master, slots = opt._step_export_info[1][0], opt._step_export_info[1][2]
+        want = jnp.bfloat16 if "master_dtype" in kw else jnp.float32
+        assert master.dtype == want
+        assert all(v.dtype == want for v in jax.tree_util.tree_leaves(slots)
+                   if v.ndim == 1)
+
     def test_non_flat_local_refuses_policy(self):
         x, y = _problem(n=16)
         opt = LocalOptimizer(
             _model(), DataSet.array(x, y, batch_size=8),
             nn.ClassNLLCriterion(), comms_dtype="int8",
         )
-        with pytest.raises(ValueError, match="flat_update=True"):
+        with pytest.raises(ValueError, match="parameter_sync='sharded'"):
             opt.optimize()
 
 
@@ -478,9 +510,9 @@ class TestQuantizedCheckpointRoundTrip:
         if quantized:
             kw = dict(comms_dtype="int8", error_feedback=True,
                       slot_dtype="bfloat16")
-        opt = LocalOptimizer(
-            _model(), DataSet.array(x, y, batch_size=8),
-            nn.ClassNLLCriterion(), flat_update=True, **kw,
+        opt = DistriOptimizer(
+            _model(), DataSet.distributed(DataSet.array(x, y, batch_size=8), 8),
+            nn.ClassNLLCriterion(), parameter_sync="sharded", **kw,
         )
         opt.set_optim_method(Adam(learningrate=1e-2))
         opt.set_end_when(Trigger.max_epoch(2))
@@ -509,10 +541,8 @@ class TestQuantizedCheckpointRoundTrip:
         params, slots, _host, _ms = ser.load_checkpoint(
             ckpt, params_like=opt1.model.get_parameters()
         )
-        for arr in jax.tree_util.tree_leaves(params):
+        for arr in jax.tree_util.tree_leaves((params, slots)):
             assert np.asarray(arr).dtype == np.float32  # f32 on disk, always
-        # no reserved low-precision keys may leak into the manifest payloads
-        assert not any(MASTER_SCALE_KEY in k for k in slots)
 
         RandomGenerator.set_seed(24)
         opt2 = self._make_opt(second)
@@ -535,8 +565,6 @@ def _deep_model(d=6, classes=3, hidden=32, depth=4):
 
 
 def _sharded_fit(**kw):
-    from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
     RandomGenerator.set_seed(5)
     x, y = _problem(n=64)
     ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
@@ -574,8 +602,9 @@ def _lower_sharded(opt):
     args += [
         jax.ShapeDtypeStruct((16, 6), jnp.float32),
         jax.ShapeDtypeStruct((16,), jnp.int32),
-        jax.ShapeDtypeStruct((), jnp.float32),
-        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.float32),  # nvalid
+        jax.ShapeDtypeStruct((), jnp.float32),  # lr
+        jax.ShapeDtypeStruct((), jnp.int32),    # step
         jax.ShapeDtypeStruct((2,), jnp.uint32),
     ]
     return opt._jit_step.lower(*args)
@@ -632,44 +661,37 @@ class TestShardedCollectiveBytes:
         assert txt_a == txt_b
         assert "f8E" not in txt_a and "all_to_all" not in txt_a
 
-    def test_sharded_refuses_fp8_master(self):
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
-        x, y = _problem(n=64)
-        ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
-        opt = DistriOptimizer(_model(), ds, nn.ClassNLLCriterion(),
-                              parameter_sync="sharded",
-                              master_dtype="float8_e4m3")
-        with pytest.raises(ValueError, match="sharded"):
-            opt.optimize()
-
-    def test_replicated_without_flat_update_refuses_policy(self):
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
-
+    def test_replicated_refuses_policy(self):
         x, y = _problem(n=64)
         ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
         opt = DistriOptimizer(_model(), ds, nn.ClassNLLCriterion(),
                               parameter_sync="replicated",
                               comms_dtype="bfloat16")
-        with pytest.raises(ValueError, match="flat"):
+        with pytest.raises(ValueError, match="parameter_sync='sharded'"):
             opt.optimize()
 
-    def test_replicated_flat_with_compression_trains(self):
-        from bigdl_tpu.parallel.distri_optimizer import DistriOptimizer
 
-        RandomGenerator.set_seed(13)
-        x, y = _problem(n=64)
-        ds = DataSet.distributed(DataSet.array(x, y, batch_size=16), 8)
-        tel = Telemetry()
-        opt = DistriOptimizer(_model(), ds, nn.ClassNLLCriterion(),
-                              parameter_sync="replicated", flat_update=True,
-                              comms_dtype="int8", error_feedback=True)
-        opt.set_optim_method(SGD(learningrate=0.1, momentum=0.9))
-        opt.set_end_when(Trigger.max_epoch(2))
-        opt.set_telemetry(tel)
-        opt.optimize()
-        assert tel.compile_count == 1
-        assert _finite(opt.model.get_parameters())
+def _pipeline_with_master(ds, **kw):
+    from bigdl_tpu.parallel.pipeline_optimizer import PipelineOptimizer
+
+    return PipelineOptimizer(_model(), ds, nn.ClassNLLCriterion(), **kw)
+
+
+@pytest.mark.parametrize("make,error", [
+    (lambda ds, **kw: LocalOptimizer(_model(), ds, nn.ClassNLLCriterion(),
+                                     **kw), ValueError),
+    (lambda ds, **kw: DistriOptimizer(_model(), DataSet.distributed(ds, 8),
+                                      nn.ClassNLLCriterion(), **kw),
+     ValueError),
+    # the stacked-parameter optimizers take no state policy at all
+    (_pipeline_with_master, TypeError),
+], ids=["local", "distri", "pipeline"])
+def test_float8_master_refused_at_construction(make, error):
+    """No step carries a float8 master: every optimizer refuses one when it
+    is built, before any data is read or program traced."""
+    x, y = _problem(n=16)
+    with pytest.raises(error, match="master_dtype"):
+        make(DataSet.array(x, y, batch_size=16), master_dtype="float8_e4m3")
 
 
 # --------------------------------------------------------------------------

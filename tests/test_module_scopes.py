@@ -1,7 +1,7 @@
 """Every op of a container-built model's train step has an owner in the
 traced program's name stack: the module path from the containers' one seam
 (``nn.module.run_child``) and the step part from the step builders
-(``model_apply``, ``criterion``, ``optim_update``; in the flat, sharded and
+(``model_apply``, ``criterion``, ``optim_update``; in the sharded and
 replicated steps also ``param_views``, ``grad_exchange``, ``param_gather``,
 ``state_sync``). The scopes are metadata: owners under the benchmark's
 accepted scope readers, outputs, gradients and the compile count are what
@@ -156,16 +156,6 @@ def test_every_op_of_the_local_step_has_a_part(graph_step_paths):
     assert not [p for p in unowned if "fc_" in p or "trunk" in p]
 
 
-def test_local_flat_step_holds_param_views():
-    opt = LocalOptimizer(_graph_model(), _data(), nn.ClassNLLCriterion(),
-                         flat_update=True)
-    paths = _step_paths(_fit(opt))
-    for part in STEP_PARTS + ("param_views",):
-        assert _holds(paths, part), part
-    # the views' transpose assembles the flat gradient
-    assert _holds(paths, "param_views", backward=True)
-
-
 @pytest.fixture(scope="module")
 def distri_paths():
     out = {}
@@ -198,6 +188,14 @@ def test_distributed_steps_hold_their_parts(distri_paths, sync, parts):
                    for p in _holds(paths, part)), (sync, part)
     # the module names are there too, forward and backward
     assert _holds(paths, "fc_a", backward=False)
+
+
+def test_sharded_flat_step_holds_param_views(distri_paths):
+    paths = distri_paths["sharded"]
+    for part in STEP_PARTS + ("param_views",):
+        assert _holds(paths, part), part
+    # the views' transpose assembles the flat gradient
+    assert _holds(paths, "param_views", backward=True)
     assert _holds(paths, "fc_a", backward=True)
 
 
