@@ -2,10 +2,10 @@
 next one is a dict and not a class.
 
 ``decoder_lm.from_config(config)`` reads the keys a model's public ``config.json`` uses.
-Four families so far, told apart by their own keys, the first in two key sets
+Five families so far, told apart by their own keys, the first in two key sets
 (the cuts to one chip are ``benchmark/configs/mellum2_12b.json``,
-``laguna_s_2_1.json``, ``granite_4_0_h_micro.json``, ``joyai_llm_flash.json``
-and ``nemotron_3_nano_30b_a3b.json``)::
+``laguna_s_2_1.json``, ``granite_4_0_h_micro.json``, ``joyai_llm_flash.json``,
+``nemotron_3_nano_30b_a3b.json`` and ``olmo_hybrid_7b.json``)::
 
     vocab_size, hidden_size, num_hidden_layers, layer_types,
     num_attention_heads, num_key_value_heads, head_dim, rms_norm_eps
@@ -59,6 +59,19 @@ and ``nemotron_3_nano_30b_a3b.json``)::
     group-limited routing), layer_norm_epsilon, tie_word_embeddings (false);
     sigmoid scores with a selection bias, as the third family's
 
+    gated delta-rule linear attention beside full attention without
+    positional encoding, under post-norm blocks (Olmo-Hybrid-7B; Qwen3-Next's
+    ``linear_*`` keys beside Olmo 3's, told by ``linear_num_key_heads``;
+    ``layer_types`` of ``linear_attention`` and ``full_attention``):
+    linear_num_key_heads and linear_num_value_heads (equal: one value head
+    a key head), linear_key_head_dim, linear_value_head_dim,
+    linear_conv_kernel_dim, linear_allow_neg_eigval (beta = 2 sigmoid where
+    true), intermediate_size (one dense gated MLP a layer), rope_parameters
+    with rope_theta null (no rotary), hidden_act (silu), attention_bias
+    (false), tie_word_embeddings (false). The blocks are Olmo 2's reordered
+    norm (``h = x + RMSNorm(mixer(x))``) and the full layers' q/k norm is
+    over the whole projection
+
 and five of this repo's own: ``experts_held`` (ids of the experts this chip
 holds, default all: one chip's share of an expert-parallel layer),
 ``first_layer`` (the fourth family's: the layer of the pattern that the
@@ -76,6 +89,7 @@ trains under ``nn.MultiTokenCrossEntropyCriterion``.
 ``decoder_lm_reference`` (attention, gated or not, + routed experts or a
 dense layer),
 ``hybrid_lm_reference`` (state-space + attention, dense MLP),
+``delta_hybrid_lm_reference`` (gated delta rule + attention, post-norm),
 ``latent_moe_lm_reference`` (latent attention, dense and sparse layers, the
 multi-token-prediction module) and ``nemotron_h_lm_reference`` (one mixer a
 layer: grouped state-space scan, attention, relu2 experts) are the plain
@@ -99,6 +113,15 @@ def is_latent(config: Dict) -> bool:
 def is_one_mixer(config: Dict) -> bool:
     """Whether ``config`` is of the family whose every layer is one mixer."""
     return "hybrid_override_pattern" in config
+
+
+def is_delta_hybrid(config: Dict) -> bool:
+    """Whether ``config`` is of the gated delta-rule hybrid family."""
+    return "linear_num_key_heads" in config
+
+
+# the delta-rule family's layer kinds
+DELTA_KINDS = ("linear_attention", "full_attention")
 
 
 # hybrid_override_pattern's letters; "-" (a dense relu2 MLP layer) is refused
@@ -296,6 +319,58 @@ def _one_mixer(config: Dict) -> nn.DecoderLM:
             * int(config.get("n_shared_experts", 0))))
 
 
+def _delta(config: Dict) -> Dict:
+    """``nn.GatedDeltaNet``'s arguments from the ``linear_*`` keys."""
+    keys, values = (int(config["linear_num_key_heads"]),
+                    int(config["linear_num_value_heads"]))
+    if keys != values:
+        raise ValueError(
+            f"decoder_lm.from_config: linear_num_key_heads {keys} and "
+            f"linear_num_value_heads {values}; accepts equal counts (one "
+            "value head a key head)")
+    return dict(heads=keys, key_dim=int(config["linear_key_head_dim"]),
+                value_dim=int(config["linear_value_head_dim"]),
+                conv=int(config["linear_conv_kernel_dim"]),
+                neg_eigval=bool(config["linear_allow_neg_eigval"]))
+
+
+def _delta_hybrid(config: Dict) -> nn.DecoderLM:
+    """Olmo-Hybrid's key set: Gated DeltaNet and full-attention layers
+    without positional encoding, each with one gated MLP, under post-norm
+    blocks."""
+    _accepts(config, "tie_word_embeddings", (False,), False)
+    _accepts(config, "attention_bias", (False,), False)
+    _accepts(config, "hidden_act", ("silu",), "silu")
+    rope = config.get("rope_parameters") or {}
+    if rope.get("rope_theta") is not None or config.get("rope_theta") \
+            is not None:
+        raise ValueError("decoder_lm.from_config: rope_theta "
+                         f"{rope.get('rope_theta', config.get('rope_theta'))!r}; "
+                         "accepts null (no rotary beside linear_attention "
+                         "layers)")
+    kinds = layer_types(config)
+    bad = sorted(set(kinds) - set(DELTA_KINDS))
+    if bad:
+        raise ValueError(f"decoder_lm.from_config: layer_types {bad}; accepts "
+                         f"{DELTA_KINDS} beside linear_num_key_heads")
+    if "mlp_layer_types" in config or int(config.get("num_local_experts", 0)):
+        raise ValueError("decoder_lm.from_config: a delta-rule hybrid takes "
+                         "one dense gated MLP a layer (no mlp_layer_types, "
+                         "no experts)")
+    return nn.DecoderLM(
+        vocab_size=int(config["vocab_size"]),
+        hidden_size=int(config["hidden_size"]),
+        layer_types=kinds,
+        num_heads=int(config["num_attention_heads"]),
+        num_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=head_dim(config),
+        eps=float(config["rms_norm_eps"]),
+        init_std=float(config.get("initializer_range", 0.02)),
+        mlp_size=int(config["intermediate_size"]),
+        qk_norm="whole",     # and no rope_parameters: no positional encoding
+        delta=_delta(config), post_norm=True)
+
+
 def first_family_mlp_kinds(config: Dict):
     """The first family's feed-forward, layer by layer: Qwen3-MoE's rule (a
     layer is sparse unless ``mlp_only_layers`` names it or ``(i + 1) %
@@ -372,11 +447,17 @@ def from_config(config: Dict) -> nn.DecoderLM:
         return _latent(config)
     if is_one_mixer(config):
         return _one_mixer(config)
+    if is_delta_hybrid(config):
+        return _delta_hybrid(config)
     kinds = layer_types(config)
-    bad = sorted(set(kinds) - set(nn.decoder.LAYER_KINDS))
+    # a linear_attention layer is the delta-rule family's, told by its keys
+    accepted = tuple(k for k in nn.decoder.LAYER_KINDS
+                     if k != "linear_attention")
+    bad = sorted(set(kinds) - set(accepted))
     if bad:
         raise ValueError(f"decoder_lm.from_config: layer_types {bad}; accepts "
-                         f"{nn.decoder.LAYER_KINDS}")
+                         f"{accepted} (linear_attention beside "
+                         "linear_num_key_heads)")
     common = dict(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
@@ -437,6 +518,14 @@ def from_config(config: Dict) -> nn.DecoderLM:
 
 def reference_config(config: Dict) -> Dict:
     """What the family's reference reads, from the same dict."""
+    if is_delta_hybrid(config):
+        keys = ("num_attention_heads", "num_key_value_heads", "rms_norm_eps",
+                "linear_num_key_heads", "linear_key_head_dim",
+                "linear_value_head_dim", "linear_allow_neg_eigval")
+        out = {k: config[k] for k in keys}
+        out["head_dim"] = head_dim(config)
+        out["layer_types"] = layer_types(config)
+        return out
     if is_one_mixer(config):
         keys = ("num_attention_heads", "num_key_value_heads",
                 "layer_norm_epsilon", "mamba_num_heads", "mamba_head_dim",
@@ -484,7 +573,7 @@ def reference_config(config: Dict) -> Dict:
 def _reference_layer(block: Dict) -> Dict:
     out = {name: block[name]["weight"]   # a one-mixer block has the one norm
            for name in ("ln", "ln1", "ln2") if name in block}
-    for name in ("attn", "ssm", "experts", "mlp"):   # the leaves as they are
+    for name in ("attn", "ssm", "delta", "experts", "mlp"):   # leaves as they are
         out.update(block.get(name, {}))
     return out
 

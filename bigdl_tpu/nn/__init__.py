@@ -204,6 +204,7 @@ from .decoder import (
     MultiTokenPredictor,
 )
 from .ssm import Mamba2Mixer
+from .delta import GatedDeltaNet
 from .pipelined import PipelinedBlocks
 from .remat import Remat
 from .quantized import (

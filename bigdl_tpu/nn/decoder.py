@@ -4,10 +4,12 @@
 ``DecoderBlock`` per entry of ``layer_types``, each wrapped in ``nn.Remat``::
 
     h = x + r * Mixer_l(RMSNorm(x))     GroupedQueryAttention |
-                                        LatentAttention | Mamba2Mixer
+                                        LatentAttention | Mamba2Mixer |
+                                        GatedDeltaNet
     y = h + r * FFN_l(RMSNorm(h))       nn.RoutedExperts | GatedMLP
 
-then a final RMSNorm and the head. A layer whose ``mlp_layer_types`` entry is
+(with ``post_norm`` each norm on its branch's output instead: ``h = x + r *
+RMSNorm(Mixer_l(x))``), then a final RMSNorm and the head. A layer whose ``mlp_layer_types`` entry is
 ``"none"`` is ONE mixer behind one norm with one residual add
 (``MixerBlock``: ``y = x + r * Mixer_l(RMSNorm(x))``), and the layer kind
 ``"experts"`` makes the routed experts such a layer's mixer: the models whose
@@ -21,8 +23,10 @@ the Mamba-2 mixer (``nn/ssm.py``, a chunked state-space scan);
 ``"latent_attention"`` is multi-head latent attention (``LatentAttention``:
 low-rank q and k/v paths, q/k heads of a no-position part and a rotary part
 that all heads share on the k side, v heads of their own size, through the
-same kernel). Grouped-query attention
-takes a per-head RMSNorm on q and k (``qk_norm``), RoPE from a given
+same kernel); ``"linear_attention"`` is the Gated DeltaNet mixer
+(``nn/delta.py``, a chunked gated delta rule). Grouped-query attention
+takes an RMSNorm on q and k, per head or over the whole projection
+(``qk_norm``), RoPE from a given
 inverse-frequency vector and factor (plain or YaRN, ``rope_inv_freq``; a
 kind without an entry in ``rope_parameters`` has no positional encoding) and
 the score scale ``1/sqrt(head_dim)`` unless ``attention_scale`` gives
@@ -54,7 +58,7 @@ by strings) stays beside it until D1 merges the two.
 Device time is attributed by ``jax.named_scope``: ``embed``, ``attn_proj``,
 ``attn_window`` / ``attn_full`` (the kernel call alone), ``mla_proj``,
 ``attn_gate``, ``ssm_proj`` (inside it ``ssm_gate_norm``), ``ssm_conv``,
-``ssm_scan``, ``moe_route``, ``moe_experts``,
+``ssm_scan``, ``delta_proj``, ``delta_conv``, ``delta_scan``, ``moe_route``, ``moe_experts``,
 ``moe_shared``, ``mlp``, ``mtp`` (around the whole module), ``lm_head``
 (docs/observability.md).
 """
@@ -70,6 +74,7 @@ import numpy as np
 
 from ..utils import precision
 from .attention import apply_rotary, scaled_dot_product_attention
+from .delta import GatedDeltaNet
 from .embedding import LookupTable
 from .initialization import RandomNormal
 from .module import AbstractModule, Container, run_child
@@ -81,7 +86,7 @@ from ..utils.table import Table
 
 # "attention" is "full_attention" under the name the hybrid models give it
 LAYER_KINDS = ("sliding_attention", "full_attention", "attention", "mamba",
-               "latent_attention", "experts")
+               "latent_attention", "experts", "linear_attention")
 MLP_KINDS = ("dense", "sparse", "none")
 
 
@@ -128,7 +133,10 @@ class GroupedQueryAttention(AbstractModule):
     ``partial_rotary_factor`` (default 1) turns the first ``factor *
     head_dim`` features of each head alone, with frequencies reckoned over
     those, and passes the rest through. With ``qk_norm`` q and k pass a
-    per-head RMSNorm with a learned gain before RoPE. ``scale`` multiplies
+    per-head RMSNorm with a learned gain before RoPE; with ``qk_norm``
+    ``"whole"`` the norm is over each token's whole projection (``num_heads
+    x head_dim`` for q, ``num_kv_heads x head_dim`` for k, a gain as wide:
+    Olmo 2's). ``scale`` multiplies
     the scores (None: ``1/sqrt(head_dim)``). ``gate`` multiplies each head's
     output by ``sigmoid(x W_g)`` (``wg`` (D, num_heads), one scalar a head
     and token, float32) between the kernel and ``W_o``: the headwise gate of
@@ -157,8 +165,12 @@ class GroupedQueryAttention(AbstractModule):
                     "between 0 and the head's")
         self._rope = rope_inv_freq(rope, self.rotary_dim or head_dim) \
             if rope else None
+        if qk_norm not in (True, False, "whole"):
+            raise ValueError(f"qk_norm {qk_norm!r}: True (per head), False or "
+                             "'whole' (over the whole projection)")
+        self.qk_norm = qk_norm
         # statistics in float32
-        self._norm = RMSNorm(head_dim, eps) if qk_norm else None
+        self._norm = RMSNorm(eps=eps) if qk_norm else None
 
     def infer_shape(self, in_spec):
         return jax.ShapeDtypeStruct(tuple(in_spec.shape), in_spec.dtype)
@@ -174,7 +186,9 @@ class GroupedQueryAttention(AbstractModule):
                   "wk": normal(ks[1], (d_model, hkv)),
                   "wv": normal(ks[2], (d_model, hkv)),
                   "wo": normal(ks[3], (hq, d_model))}
-        if self._norm is not None:
+        if self.qk_norm == "whole":
+            params.update(q_norm=jnp.ones((hq,)), k_norm=jnp.ones((hkv,)))
+        elif self._norm is not None:
             params.update(q_norm=jnp.ones((self.head_dim,)),
                           k_norm=jnp.ones((self.head_dim,)))
         if not self.gate:
@@ -184,18 +198,23 @@ class GroupedQueryAttention(AbstractModule):
         return params, {"_counters": {
             "attn_gate_mean": jnp.zeros((), jnp.float32)}}
 
-    def _heads(self, x, w, heads: int):
+    def _heads(self, x, w, heads: int, gain=None):
         n, t, _ = x.shape
-        return precision.dot_acc32(x, w).reshape(
-            n, t, heads, self.head_dim).transpose(0, 2, 1, 3)
+        y = precision.dot_acc32(x, w)
+        if gain is not None:
+            y = self._norm._apply({"weight": gain}, {}, y, False, None)[0]
+        return y.reshape(n, t, heads, self.head_dim).transpose(0, 2, 1, 3)
 
     def _apply(self, params, state, x, training, rng):
         n, t, _ = x.shape
+        whole = self.qk_norm == "whole"
         with jax.named_scope("attn_proj"):
-            q = self._heads(x, params["wq"], self.num_heads)
-            k = self._heads(x, params["wk"], self.num_kv_heads)
+            q = self._heads(x, params["wq"], self.num_heads,
+                            params["q_norm"] if whole else None)
+            k = self._heads(x, params["wk"], self.num_kv_heads,
+                            params["k_norm"] if whole else None)
             v = self._heads(x, params["wv"], self.num_kv_heads)
-            if self._norm is not None:
+            if self._norm is not None and not whole:
                 q = self._norm._apply({"weight": params["q_norm"]}, {}, q,
                                       training, None)[0]
                 k = self._norm._apply({"weight": params["k_norm"]}, {}, k,
@@ -328,21 +347,26 @@ class GatedMLP(AbstractModule):
 
 # what a block calls its mixer and its feed-forward in the parameter tree
 _CHILD_NAMES = {GroupedQueryAttention: "attn", LatentAttention: "attn",
-                Mamba2Mixer: "ssm", RoutedExperts: "experts", GatedMLP: "mlp"}
+                Mamba2Mixer: "ssm", GatedDeltaNet: "delta",
+                RoutedExperts: "experts", GatedMLP: "mlp"}
 
 
 class DecoderBlock(Container):
     """``h = x + r * mixer(ln1(x))``, ``y = h + r * ffn(ln2(h))`` with ``r``
     the ``residual_multiplier``; any mixer and any feed-forward that map
-    ``(N, T, D)`` to itself."""
+    ``(N, T, D)`` to itself. ``post_norm``: the norms on the branches'
+    outputs instead, ``h = x + r * ln1(mixer(x))``, ``y = h + r *
+    ln2(ffn(h))`` (Olmo 2's reordered norm)."""
 
     def __init__(self, mixer: AbstractModule, ffn: AbstractModule,
-                 eps: float = 1e-6, residual_multiplier: float = 1.0):
+                 eps: float = 1e-6, residual_multiplier: float = 1.0,
+                 post_norm: bool = False):
         super().__init__(RMSNorm(eps=eps).set_name("ln1"),
                          mixer.set_name(_CHILD_NAMES.get(type(mixer), "mixer")),
                          RMSNorm(eps=eps).set_name("ln2"),
                          ffn.set_name(_CHILD_NAMES.get(type(ffn), "ffn")))
         self.residual_multiplier = float(residual_multiplier)
+        self.post_norm = post_norm
 
     def build(self, rng, in_spec):
         for i, m in enumerate(self.modules):
@@ -360,6 +384,9 @@ class DecoderBlock(Container):
             m, v, training, rng, params, state, new_state)
         r = self.residual_multiplier
         scaled = lambda v: v if r == 1.0 else r * v  # noqa: E731
+        if self.post_norm:
+            h = x + scaled(run(ln1, run(mixer, x)))
+            return h + scaled(run(ln2, run(ffn, h))), new_state
         h = x + scaled(run(mixer, run(ln1, x)))
         return h + scaled(run(ffn, run(ln2, h))), new_state
 
@@ -520,6 +547,11 @@ class DecoderLM(Container):
         qk_norm, attention_scale: see ``GroupedQueryAttention``.
         mamba: the ``"mamba"`` layers' ``Mamba2Mixer`` arguments (``heads``,
             ``head_dim``, ``state``, ``conv``, ``chunk``, ``groups``).
+        delta: the ``"linear_attention"`` layers' ``GatedDeltaNet``
+            arguments (``heads``, ``key_dim``, ``value_dim``, ``conv``,
+            ``neg_eigval``).
+        post_norm: every block normalises its branches' outputs
+            (``DecoderBlock``'s ``post_norm``).
         embedding_multiplier, residual_multiplier, logits_divisor: the
             module docstring's three scalars.
         tie_embeddings: the head is the embedding's transpose.
@@ -543,7 +575,8 @@ class DecoderLM(Container):
                  router: Optional[Dict] = None,
                  latent: Optional[Dict] = None, mtp_modules: int = 0,
                  layer_heads: Optional[Sequence[int]] = None,
-                 attention_gate: bool = False):
+                 attention_gate: bool = False, delta: Optional[Dict] = None,
+                 post_norm: bool = False):
         super().__init__()
         bad = [k for k in layer_types if k not in LAYER_KINDS]
         if bad:
@@ -555,6 +588,8 @@ class DecoderLM(Container):
             raise ValueError("a 'mamba' layer needs the mamba sizes")
         if "latent_attention" in layer_types and not latent:
             raise ValueError("a 'latent_attention' layer needs the latent sizes")
+        if "linear_attention" in layer_types and not delta:
+            raise ValueError("a 'linear_attention' layer needs the delta sizes")
         if not n_experts and not mlp_size:
             raise ValueError("neither experts nor a dense MLP: give "
                              "n_experts or mlp_size")
@@ -577,6 +612,9 @@ class DecoderLM(Container):
                              "its mlp_layer_types entry is 'none'")
         if mtp_modules not in (0, 1):
             raise ValueError(f"mtp_modules {mtp_modules}: 0 or 1")
+        if post_norm and "none" in mlp_layer_types:
+            raise ValueError("post_norm is a two-branch block's: no "
+                             "mlp_layer_types entry 'none'")
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
         self.embedding_multiplier = float(embedding_multiplier)
         self.tie_embeddings = tie_embeddings
@@ -598,6 +636,8 @@ class DecoderLM(Container):
             elif kind == "mamba":
                 mixer = Mamba2Mixer(**mamba, eps=eps, init_std=init_std,
                                     report_state=i == last_mamba)
+            elif kind == "linear_attention":
+                mixer = GatedDeltaNet(**delta, eps=eps, init_std=init_std)
             elif kind == "latent_attention":
                 mixer = LatentAttention(
                     num_heads, **latent, rope=rope_parameters[kind], eps=eps,
@@ -617,7 +657,8 @@ class DecoderLM(Container):
             ffn = experts() if mlp_kind == "sparse" \
                 else GatedMLP(mlp_size, init_std)
             return DecoderBlock(mixer, ffn, eps=eps,
-                                residual_multiplier=residual_multiplier)
+                                residual_multiplier=residual_multiplier,
+                                post_norm=post_norm)
 
         for i, (kind, mlp_kind) in enumerate(zip(layer_types, mlp_layer_types)):
             self.add(Remat(block(i, kind, mlp_kind).set_name("block"))

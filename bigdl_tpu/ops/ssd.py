@@ -94,16 +94,24 @@ def _record_scan(**record) -> None:
         _scan_records[key] = (time.perf_counter(), {**record, "calls": calls})
 
 
+def heads_within_budget(heads: int, per_head_bytes: int,
+                        groups: int = 1) -> int:
+    """Heads whose float32 temporaries, ``per_head_bytes`` a head, are live
+    at once: the largest divisor of ``heads`` that takes the same number
+    from each of ``groups`` groups and fits ``_GROUP_BYTES`` (at least one
+    head a group). The chunked scans' head groups, here and in
+    ``ops/delta_rule.py``."""
+    fit = max(_GROUP_BYTES // per_head_bytes, 1) // groups
+    share = heads // groups
+    return groups * max(g for g in range(1, share + 1)
+                        if share % g == 0 and (g <= fit or g == 1))
+
+
 def head_group(records: int, chunks: int, heads: int, chunk: int,
                groups: int = 1) -> int:
-    """Heads whose ``L`` is live at once, the same number from each of the
-    ``groups`` B/C groups: the largest such divisor of ``heads`` whose float32
-    ``L`` fits ``_GROUP_BYTES`` (at least one head a group)."""
-    per_head = records * chunks * chunk * chunk * 4
-    fit = max(_GROUP_BYTES // per_head, 1)
-    return groups * max(g for g in range(1, heads // groups + 1)
-                        if (heads // groups) % g == 0
-                        and (g * groups <= fit or g == 1))
+    """Heads whose ``L`` is live at once (``heads_within_budget``)."""
+    return heads_within_budget(heads, records * chunks * chunk * chunk * 4,
+                               groups)
 
 
 class ScanStats(NamedTuple):
@@ -111,12 +119,6 @@ class ScanStats(NamedTuple):
     log_decay_min: jax.Array    # most negative within-chunk running sum of dt A
     state_sq_sum: jax.Array     # sum of squares of the chunk-boundary states
     state_count: int            # how many numbers that is the sum over
-
-
-def _dot(spec: str, a, b):
-    """``einsum`` with operands in the compute dtype and a float32 sum."""
-    return jnp.einsum(spec, precision.cast_compute(a), precision.cast_compute(b),
-                      preferred_element_type=jnp.float32)
 
 
 def _within_chunks(cb, cum, xdt, group: int):
@@ -132,7 +134,8 @@ def _within_chunks(cb, cum, xdt, group: int):
     def one_group(cb, cum_g, xdt_g):         # (n, c, G, g, q), (n, c, G, g, q, p)
         seg = cum_g[..., :, None] - cum_g[..., None, :]
         decay = jnp.exp(jnp.where(seen, seg, -jnp.inf))   # L: 0 above the diagonal
-        return _dot("ncbgls,ncbgsp->ncbglp", cb[:, :, :, None] * decay, xdt_g)
+        return precision.einsum_acc32("ncbgls,ncbgsp->ncbglp",
+                                      cb[:, :, :, None] * decay, xdt_g)
 
     if group == h:
         return one_group(cb, cum, xdt)
@@ -171,10 +174,11 @@ def _chunks_xla(xdt, cum, b, c, cb, group: int):
     cum = cum.reshape(n, nc, bc, h // bc, q)
     y = _within_chunks(cb, cum, xdt, group // bc)                 # product 2
     last = cum[..., -1:]
-    added = _dot("ncbqs,ncbhqp->ncbhps", b,
-                 xdt * jnp.exp(last - cum)[..., None])
+    added = precision.einsum_acc32("ncbqs,ncbhqp->ncbhps", b,
+                                   xdt * jnp.exp(last - cum)[..., None])
     final, entering = _carry(jnp.exp(last)[..., None], added)  # (n, c, G, h/G, p, s)
-    y = y + _dot("ncbqs,ncbhps->ncbhqp", c, entering) * jnp.exp(cum)[..., None]
+    y = y + precision.einsum_acc32("ncbqs,ncbhps->ncbhqp", c, entering) \
+        * jnp.exp(cum)[..., None]
     y = y.reshape(n, nc, h, q, p).transpose(0, 1, 3, 2, 4)
     return (y.reshape(n, nc * q, h, p), entering.reshape(n, nc, h, p, s),
             final.reshape(n, h, p, s))
@@ -234,7 +238,7 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
     # (n, c, G, q, s): a B/C group's chunk is one block
     b = b.reshape(n, nc, q, groups, s).transpose(0, 1, 3, 2, 4)
     c = c.reshape(n, nc, q, groups, s).transpose(0, 1, 3, 2, 4)
-    cb = _dot("ncgls,ncgts->ncglt", c, b)                         # product 1
+    cb = precision.einsum_acc32("ncgls,ncgts->ncglt", c, b)       # product 1
     per_step = None
     if interpret or jax.default_backend() == "tpu":
         per_step = ssd_kernel.heads_per_step(

@@ -171,6 +171,14 @@ def conv_general_dilated(x, w, **kwargs):
     )
 
 
+def einsum_acc32(spec: str, a, b):
+    """``jnp.einsum(spec, a, b)`` with both operands in the compute dtype and
+    a FLOAT32 result, its gradient plain autodiff's: the chunked scans' own
+    products (``ops/ssd.py``, ``ops/delta_rule.py``)."""
+    return jnp.einsum(spec, cast_compute(a), cast_compute(b),
+                      preferred_element_type=jnp.float32)
+
+
 def _dot_acc32_impl(x, w):
     dt = compute_dtype()
     return jnp.dot(_cast(x, dt), _cast(w, dt),

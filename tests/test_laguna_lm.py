@@ -449,23 +449,40 @@ N_CONFIG = dict(
     layer_norm_epsilon=1e-5, tie_word_embeddings=False,
     initializer_range=0.125, router_bias_update_rate=0.001,
     experts_held=[0, 1, 5])
+G_CONFIG = dict(
+    vocab_size=96, hidden_size=32, num_hidden_layers=3,
+    layer_types=["mamba", "attention", "mamba"],
+    num_attention_heads=4, num_key_value_heads=2, rms_norm_eps=1e-5,
+    mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16, mamba_d_conv=4,
+    mamba_chunk_size=8, mamba_expand=2, mamba_n_groups=1,
+    mamba_conv_bias=True, mamba_proj_bias=False, shared_intermediate_size=48,
+    num_local_experts=0, attention_multiplier=0.2, embedding_multiplier=12,
+    residual_multiplier=0.22, logits_scaling=8,
+    position_embedding_type="nope", tie_word_embeddings=True)
 # read from the tree before the gate, the per-layer heads, the partial rotary,
-# the dense layer and the scaled router came in: (digest of the loss-and-
-# gradient program's jaxpr, the loss, digest of the parameter tree's names
-# and shapes). A change that rightly changes these models' programs updates
-# them; one that meant to leave them alone fails here.
+# the dense layer and the scaled router came in (M, N), and before the
+# post-norm block and the whole-width q/k norm came in (G: a pre-norm block
+# with scaled residuals around NoPE attention without q/k norm; L: this
+# file's gated model): (digest of the loss-and-gradient program's jaxpr, the
+# loss, digest of the parameter tree's names and shapes). A change that
+# rightly changes these models' programs updates them; one that meant to
+# leave them alone fails here.
 BEFORE = {
     ("M", "float32"): ("6458878d3c749716", 4.854368209838867, "8c76a6ddab395dc6"),
     ("M", "bfloat16"): ("bd2d3721df03b578", 4.854185104370117, "8c76a6ddab395dc6"),
     ("N", "float32"): ("4ff1c27797bcc39d", 4.3778862953186035, "0ba5515738b6b412"),
     ("N", "bfloat16"): ("4c38955bcebfb18e", 4.3778204917907715, "0ba5515738b6b412"),
+    ("G", "float32"): ("78cb1896e6f3cadc", 4.565230369567871, "de0d37e8903b11b6"),
+    ("G", "bfloat16"): ("0f8e25c9284696a8", 4.56523323059082, "de0d37e8903b11b6"),
+    ("L", "float32"): ("1385f6488a692e20", 4.981200218200684, "1fe5814f8d21ee6a"),
+    ("L", "bfloat16"): ("4a63f5ada92b27b4", 4.990013122558594, "1fe5814f8d21ee6a"),
 }
 
 
 @pytest.mark.parametrize("name,compute", sorted(BEFORE))
 def test_the_sparse_and_hybrid_models_build_the_program_they_built(
         name, compute):
-    config = M_CONFIG if name == "M" else N_CONFIG
+    config = {"M": M_CONFIG, "N": N_CONFIG, "G": G_CONFIG, "L": CONFIG}[name]
     prev = Engine.compute_dtype()
     Engine.set_compute_dtype(compute)
     try:
